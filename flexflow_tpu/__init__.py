@@ -7,21 +7,6 @@ parallelization strategy against a profiling-based cost model of the TPU pod;
 execution lowers to JAX/XLA (jit over a jax.sharding.Mesh, Pallas kernels,
 lax collectives) instead of Legion tasks + cuDNN/NCCL.
 """
-from .runtime.platform import honor_env_platform as _honor_env_platform
-
-# An EXPLICIT JAX_PLATFORMS=cpu (or any non-TPU value) in the environment
-# must win: on hosts where a TPU plugin registers via a site hook, the env
-# var alone is silently ignored unless jax.config is also set before the
-# first backend client. No-op when the var is unset or names the TPU, and
-# harmless after jax import as long as no backend client exists yet —
-# which is guaranteed at package-import time in any process that imports
-# flexflow_tpu before running computations. Only the PLATFORM is honored
-# here (n_host_devices=None): injecting a virtual device count from a
-# library import would change pmap/sharding semantics of unrelated code;
-# the entry points that want the 8-device test mesh (tests/conftest.py,
-# bench.py, the example bootstraps) pass it explicitly.
-_honor_env_platform(n_host_devices=None)
-
 from .config import FFConfig, FFIterationConfig
 from .ffconst import (
     ActiMode,

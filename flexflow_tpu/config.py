@@ -205,8 +205,9 @@ class FFConfig:
     # backend is a real accelerator, stay analytic on CPU (tests/dryruns).
     measure_op_costs: Optional[bool] = None
     op_cost_cache_file: Optional[str] = None
-    # Prefer the native C++ search core (src/ffcore) when buildable; the
-    # pure-Python search is the fallback and the reference semantics.
+    # Use the native C++ search core (src/ffcore, built on the spot) where
+    # it covers the search; a failed build is then an error. False = the
+    # pure-Python search, which is the reference semantics.
     use_native_search: bool = True
     export_strategy_file: Optional[str] = None
     import_strategy_file: Optional[str] = None

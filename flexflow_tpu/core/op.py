@@ -78,6 +78,16 @@ class LoweringContext:
         # so constrain() strips them (the data is already the shard)
         self.manual_axes: frozenset = frozenset()
 
+    def gspmd_partitioned(self) -> bool:
+        """True while lowering into a step GSPMD partitions over a mesh —
+        not off a mesh, and not inside an already-manual shard_map region.
+        A Mosaic (Pallas TPU) kernel has no GSPMD partitioning rule: there
+        the chip's compiler refuses it ("cannot be automatically
+        partitioned"), so a lowering either wraps the kernel in shard_map
+        (ops/attention.py `_on_mesh`) or keeps its reference lowering
+        (norm and decode families)."""
+        return self.mesh is not None and not self.in_shard_map
+
     def next_rng(self):
         import jax
 

@@ -7,42 +7,6 @@ kernels/registry.py (docs/kernels.md)."""
 from .registry import KERNELS, KernelChoice, KernelRegistry
 from .ring_attention import ring_attention, ring_attention_sharded
 
-__all__ = ["ring_attention", "ring_attention_sharded", "get_shard_map",
-           "pvary", "KERNELS", "KernelChoice", "KernelRegistry"]
+__all__ = ["ring_attention", "ring_attention_sharded", "KERNELS",
+           "KernelChoice", "KernelRegistry"]
 
-
-def pvary(x, axes):
-    """Mark a value varying over manual mesh axes — jax>=0.7 spells this
-    lax.pcast(..., to="varying") / lax.pvary and requires it on shard_map
-    scan carries (the vma type check); older jax has no vma type system,
-    so the mark is an identity there. One shim for all kernels, same role
-    as get_shard_map below."""
-    import jax.lax as lax
-
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, axes, to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, axes)
-    return x
-
-
-def get_shard_map(check_vma: bool = True):
-    """jax>=0.8 moved shard_map out of experimental — one shim for all
-    kernels. check_vma=False disables the varying-mesh-axes output check
-    (needed when the body contains a pallas_call, whose ShapeDtypeStruct
-    outputs carry no vma annotation); the flag is translated to the old
-    API's check_rep on the experimental fallback."""
-    import functools
-
-    try:
-        from jax import shard_map  # jax >= 0.8
-
-        if not check_vma:
-            return functools.partial(shard_map, check_vma=False)
-        return shard_map
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
-
-        if not check_vma:
-            return functools.partial(shard_map, check_rep=False)
-        return shard_map

@@ -164,6 +164,30 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
 # head, (block_q, heads).
 
 
+def _packed_compiler_params(io_blocks, scratch, block_q, block_k):
+    """Scoped-VMEM request for one packed kernel, derived from its blocks.
+
+    Mosaic's default scoped limit (16 MiB on v5e) is below what the packed
+    kernels need once a block spans every head: at the BERT bench shape
+    (512x512 blocks, 1024 lanes) the forward kernel wants 17.8 MiB in bf16
+    and 25.9 MiB in f32, and the compiler refuses it. The need is the
+    double-buffered I/O blocks, the scratch, and about ten live f32
+    (block_q, block_k) score temporaries of the unrolled head loop —
+    counted as twelve. It is a ceiling, not an allocation, so it is rounded
+    up generously and never set below 32 MiB.
+
+    io_blocks / scratch: (shape, dtype) of every blocked operand+result /
+    scratch buffer."""
+    def nbytes(shape, dtype):
+        return int(np.prod(shape)) * jnp.dtype(dtype).itemsize
+
+    need = (2 * sum(nbytes(*b) for b in io_blocks)
+            + sum(nbytes(*b) for b in scratch)
+            + 12 * 4 * block_q * block_k)
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=max(32 * 2 ** 20, int(1.25 * need)))
+
+
 def _block_mask(iq, ik, *, causal, block_q, block_k, kv_len, q_len, q_offset,
                 check_q=False):
     """Mask for one (q_block, k_block) pair, or None when every position is
@@ -274,6 +298,10 @@ def _flash_fwd_packed(q, k, v, heads, scale, causal, block_q, block_k,
     # single k block -> the kernel's plain-softmax path never touches the
     # online-softmax scratch; don't reserve real VMEM for it
     single = kv_pad // block_k == 1
+    f32 = jnp.float32
+    scratch = [((8, 128) if single else (block_q, e), f32),
+               ((8, heads) if single else (block_q, heads), f32),
+               ((8, heads) if single else (block_q, heads), f32)]
     o, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -290,13 +318,10 @@ def _flash_fwd_packed(q, k, v, heads, scale, causal, block_q, block_k,
             jax.ShapeDtypeStruct((b, lq_pad, e), q.dtype),
             jax.ShapeDtypeStruct((b, lq_pad, heads), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((8, 128) if single else (block_q, e), jnp.float32),
-            pltpu.VMEM((8, heads) if single else (block_q, heads),
-                       jnp.float32),
-            pltpu.VMEM((8, heads) if single else (block_q, heads),
-                       jnp.float32),
-        ],
+        scratch_shapes=[pltpu.VMEM(*sc) for sc in scratch],
+        compiler_params=_packed_compiler_params(
+            [((block_q, e), q.dtype)] * 2 + [((block_k, e), k.dtype)] * 2
+            + [((block_q, 128), f32)], scratch, block_q, block_k),
         interpret=interpret,
     )(qp, kp, vp)
     return o[:, :lq], lse[:, :lq]
@@ -437,6 +462,16 @@ def _flash_bwd_packed(heads, scale, causal, block_q, block_k, interpret,
     k_spec = pl.BlockSpec((1, block_k, e), lambda ib, iq, ik: (ib, ik, 0))
     qvec_spec = pl.BlockSpec((1, block_q, heads),
                              lambda ib, iq, ik: (ib, iq, 0))
+    # VMEM accounting (see _packed_compiler_params): the (., heads) lse /
+    # delta blocks pad to a full 128-lane tile
+    f32 = jnp.float32
+    q_blocks = [((block_q, e), q.dtype)]
+    k_blocks = [((block_k, e), k.dtype)]
+    vec_blocks = [((block_q, 128), f32)] * 2
+    dq_scratch = [((8, 128) if kv_pad // block_k == 1 else (block_q, e),
+                   f32)]
+    dkv_scratch = [((8, 128) if lq_pad // block_q == 1 else (block_k, e),
+                    f32)] * 2
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel_packed, scale=scale, causal=causal,
@@ -447,9 +482,10 @@ def _flash_bwd_packed(heads, scale, causal, block_q, block_k, interpret,
         in_specs=[q_spec, k_spec, k_spec, q_spec, qvec_spec, qvec_spec],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((b, lq_pad, e), q.dtype),
-        scratch_shapes=[pltpu.VMEM(
-            (8, 128) if kv_pad // block_k == 1 else (block_q, e),
-            jnp.float32)],
+        scratch_shapes=[pltpu.VMEM(*sc) for sc in dq_scratch],
+        compiler_params=_packed_compiler_params(
+            q_blocks * 3 + k_blocks * 2 + vec_blocks, dq_scratch,
+            block_q, block_k),
         interpret=interpret,
     )(qp, kp, vp, dop, lsep, deltap)[:, :lq]
 
@@ -470,12 +506,10 @@ def _flash_bwd_packed(heads, scale, causal, block_q, block_k, interpret,
             jax.ShapeDtypeStruct((b, kv_pad, e), k.dtype),
             jax.ShapeDtypeStruct((b, kv_pad, e), v.dtype),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((8, 128) if lq_pad // block_q == 1 else (block_k, e),
-                       jnp.float32),
-            pltpu.VMEM((8, 128) if lq_pad // block_q == 1 else (block_k, e),
-                       jnp.float32),
-        ],
+        scratch_shapes=[pltpu.VMEM(*sc) for sc in dkv_scratch],
+        compiler_params=_packed_compiler_params(
+            q_blocks * 2 + k_blocks * 4 + vec_blocks, dkv_scratch,
+            block_q, block_k),
         interpret=interpret,
     )(qp, kp, vp, dop, lsep, deltap)
     return dq, dk[:, :kv_len], dv[:, :kv_len]

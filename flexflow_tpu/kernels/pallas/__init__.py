@@ -9,14 +9,13 @@ exercises fwd and bwd without a TPU.
 from .decode import (fused_decode_attention,
                      fused_multiquery_decode_attention)
 from .norm import fused_layernorm, fused_rmsnorm, fused_softmax
-from .reduction import fused_cumsum, fused_reduce
+from .reduction import fused_reduce
 
 __all__ = [
     "fused_layernorm",
     "fused_rmsnorm",
     "fused_softmax",
     "fused_reduce",
-    "fused_cumsum",
     "fused_decode_attention",
     "fused_multiquery_decode_attention",
 ]

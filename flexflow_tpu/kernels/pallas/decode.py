@@ -48,9 +48,11 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _decode_kernel(q_ref, k_ref, v_ref, pos_ref, o_ref, acc_ref, m_ref,
+def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
                    l_ref, *, scale, block_k, kv_len, heads, head_dim, c):
-    """Grid = (B, n_k_blocks); k innermost, the C query rows resident."""
+    """Grid = (B, n_k_blocks); k innermost, the C query rows resident.
+    pos_ref is the (B,) position vector, scalar-prefetched into SMEM (a
+    (1, 1) VMEM block of a (B, 1) array is not a legal TPU tile)."""
     ik = pl.program_id(1)
     n_kb = pl.num_programs(1)
     single = n_kb == 1
@@ -65,7 +67,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, pos_ref, o_ref, acc_ref, m_ref,
     q = q_ref[0]                                          # (C, e)
     k = k_ref[0].astype(q.dtype)                          # (bk, e)
     v = v_ref[0].astype(q.dtype)
-    pos = pos_ref[0, 0]
+    pos = pos_ref[pl.program_id(0)]
     k_pos = ik * block_k + jax.lax.broadcasted_iota(
         jnp.int32, (1, block_k), 1)
     # query j sits at absolute position pos + j: causal over the filled
@@ -124,29 +126,30 @@ def _call_decode(q, k_cache, v_cache, pos, *, scale, block_k, interpret):
     if m_pad != m:
         kp = jnp.pad(kp, ((0, 0), (0, m_pad - m), (0, 0)))
         vp = jnp.pad(vp, ((0, 0), (0, m_pad - m), (0, 0)))
-    pos2 = pos.astype(jnp.int32).reshape(b, 1)
     n_kb = m_pad // block_k
 
     out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=float(scale),
                           block_k=block_k, kv_len=m, heads=heads,
                           head_dim=head_dim, c=c),
-        grid=(b, n_kb),
-        in_specs=[
-            pl.BlockSpec((1, c, e), lambda ib, ik: (ib, 0, 0)),
-            pl.BlockSpec((1, block_k, e), lambda ib, ik: (ib, ik, 0)),
-            pl.BlockSpec((1, block_k, e), lambda ib, ik: (ib, ik, 0)),
-            pl.BlockSpec((1, 1), lambda ib, ik: (ib, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, c, e), lambda ib, ik: (ib, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, n_kb),
+            in_specs=[
+                pl.BlockSpec((1, c, e), lambda ib, ik, pos: (ib, 0, 0)),
+                pl.BlockSpec((1, block_k, e), lambda ib, ik, pos: (ib, ik, 0)),
+                pl.BlockSpec((1, block_k, e), lambda ib, ik, pos: (ib, ik, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, c, e), lambda ib, ik, pos: (ib, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((c, e), jnp.float32),
+                pltpu.VMEM((c, heads), jnp.float32),
+                pltpu.VMEM((c, heads), jnp.float32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((b, c, e), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((c, e), jnp.float32),
-            pltpu.VMEM((c, heads), jnp.float32),
-            pltpu.VMEM((c, heads), jnp.float32),
-        ],
         interpret=interpret,
-    )(qp, kp, vp, pos2)
+    )(pos.astype(jnp.int32), qp, kp, vp)
     return out.reshape(b, c, heads, head_dim)
 
 

@@ -366,9 +366,32 @@ def _softmax_bwd_kernel(y_ref, dy_ref, dx_ref):
     dx_ref[...] = (y * (dy - s)).astype(dx_ref.dtype)
 
 
+# Scoped-VMEM budget the softmax row blocks are sized against: well under
+# Mosaic's 16 MiB default on v5e. A (rows, N) block costs about ten f32
+# copies of itself — double-buffered operands and result plus the f32
+# temporaries of the body.
+_SOFTMAX_VMEM_BUDGET = 12 * 2 ** 20
+_SOFTMAX_BLOCK_COPIES = 10
+
+
+def softmax_block_rows(n_cols: int, block_rows: int = 128) -> int:
+    """Rows per softmax block for rows of n_cols: block_rows where that
+    fits the VMEM budget, else the largest multiple of 8 that does. 0
+    means even 8 rows do not fit — the row is too wide for the
+    whole-row-resident kernel and the selector (ops/norm.py, CostModel)
+    keeps the reference lowering."""
+    fit = _SOFTMAX_VMEM_BUDGET // (_SOFTMAX_BLOCK_COPIES * 4 * max(n_cols, 1))
+    return min(block_rows, fit // 8 * 8)
+
+
 def _softmax_call(kernel, outs_like, block_rows, interpret, *arrays):
     x2s = [_rows(a) for a in arrays]
     r, n = x2s[0].shape
+    block_rows = softmax_block_rows(n, block_rows)
+    if block_rows == 0:
+        raise ValueError(
+            f"fused_softmax: rows of {n} do not fit VMEM; use the reference"
+            " softmax (softmax_block_rows is the selector's gate)")
     block_r, n_blocks = _grid_block(r, block_rows)
     row_spec = pl.BlockSpec((block_r, n), lambda i: (i, 0))
     padded = [_pad_rows(a, block_r) for a in x2s]

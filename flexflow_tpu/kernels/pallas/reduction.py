@@ -1,7 +1,7 @@
-"""Fused reduction / scan primitives as Pallas TPU kernels.
+"""Fused scalar reduction as a Pallas TPU kernel.
 
-In the spirit of arXiv:1811.09736 (single-pass tensor-core-era reduction
-and scan): the loss/metrics reductions (`jnp.mean` of a crossentropy
+In the spirit of arXiv:1811.09736 (single-pass tensor-core-era
+reduction): the loss/metrics reductions (`jnp.mean` of a crossentropy
 row, accuracy means, MSE) each cost a full HBM read per reduction when
 XLA schedules them as separate fusions at the step epilogue.
 `fused_reduce` streams the flattened array once through VMEM in
@@ -12,16 +12,10 @@ across the sequential grid — one pass, one scalar out.
    sum/mean carry a custom VJP (broadcast of the cotangent — the
    mathematically exact gradient, no kernel needed); max is
    forward-only (its consumers — metrics — never differentiate).
- - `fused_cumsum(x)`: inclusive scan along the trailing axis, rows
-   resident in VMEM, f32 accumulation. Its VJP is the reversed scan of
-   the cotangent, computed by the SAME kernel on flipped input.
 
 `fused_reduce` is what runtime/losses.py and runtime/metrics.py route
 through the KernelRegistry's `reduction` family (reference impl = plain
-jnp). `fused_cumsum` is the scan half of the arXiv:1811.09736 primitive
-pair — parity-tested and exported, with no runtime consumer yet (the
-natural one is a future fused sampling/top-p kernel over sorted
-probabilities).
+jnp).
 """
 from __future__ import annotations
 
@@ -43,10 +37,12 @@ def _reduce_kernel(x_ref, o_ref, *, kind):
         o_ref[...] = jnp.full_like(
             o_ref, -jnp.inf if kind == "max" else 0.0)
 
+    # whole-block (1, 1) stores: the TPU lowering cannot store a scalar
+    # to VMEM
     if kind == "max":
-        o_ref[0, 0] = jnp.maximum(o_ref[0, 0], jnp.max(x))
+        o_ref[...] = jnp.maximum(o_ref[...], jnp.max(x, keepdims=True))
     else:
-        o_ref[0, 0] += jnp.sum(x)
+        o_ref[...] += jnp.sum(x, keepdims=True)
 
 
 def _reduce_sum_or_max(x, kind, block_rows, interpret):
@@ -113,54 +109,3 @@ def fused_reduce(x, kind: str = "sum", *, block_rows: int = 256,
     if kind not in ("sum", "mean", "max"):
         raise ValueError(f"kind must be sum, mean or max, got {kind!r}")
     return _fused_reduce(x, kind, int(block_rows), bool(interpret))
-
-
-# ---------------------------------------------------------------------------
-# inclusive scan along the trailing axis
-# ---------------------------------------------------------------------------
-
-def _cumsum_kernel(x_ref, o_ref):
-    x = x_ref[...].astype(jnp.float32)
-    o_ref[...] = jnp.cumsum(x, axis=1).astype(o_ref.dtype)
-
-
-def _cumsum_call(x, block_rows, interpret):
-    x2 = x.reshape(-1, x.shape[-1])
-    r, n = x2.shape
-    block_r = max(1, min(block_rows, r))
-    rpad = -(-r // block_r) * block_r
-    xp = jnp.pad(x2, ((0, rpad - r), (0, 0))) if rpad != r else x2
-    row_spec = pl.BlockSpec((block_r, n), lambda i: (i, 0))
-    out = pl.pallas_call(
-        _cumsum_kernel,
-        grid=(rpad // block_r,),
-        in_specs=[row_spec],
-        out_specs=row_spec,
-        out_shape=jax.ShapeDtypeStruct(xp.shape, x.dtype),
-        interpret=interpret,
-    )(xp)
-    return out[:r].reshape(x.shape)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
-def _fused_cumsum(x, block_rows, interpret):
-    return _cumsum_call(x, block_rows, interpret)
-
-
-def _fused_cumsum_fwd(x, block_rows, interpret):
-    return _cumsum_call(x, block_rows, interpret), None
-
-
-def _fused_cumsum_bwd(block_rows, interpret, res, g):
-    # d/dx cumsum = reversed cumsum of the cotangent — the same kernel
-    # on the flipped rows
-    rev = _cumsum_call(jnp.flip(g, axis=-1), block_rows, interpret)
-    return (jnp.flip(rev, axis=-1),)
-
-
-_fused_cumsum.defvjp(_fused_cumsum_fwd, _fused_cumsum_bwd)
-
-
-def fused_cumsum(x, *, block_rows: int = 128, interpret: bool = False):
-    """Inclusive prefix-sum along the trailing axis (f32 accumulation)."""
-    return _fused_cumsum(x, int(block_rows), bool(interpret))

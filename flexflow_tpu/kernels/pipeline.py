@@ -57,9 +57,7 @@ def gpipe_stage_loop(stage_fn: Callable, local_params, x_micro,
 
     # the scan carry becomes stage-varying after one tick: mark the init
     # accordingly (shard_map vma type check; same pattern as ring_attention)
-    from . import pvary
-
-    zero = pvary(jnp.zeros_like(x_micro[0]), (axis_name,))
+    zero = lax.pcast(jnp.zeros_like(x_micro[0]), (axis_name,), to="varying")
     _, outs = lax.scan(tick, zero, jnp.arange(ticks))
     # microbatch i completes on the last stage at tick i + S - 1
     outs = lax.slice_in_dim(outs, n_stages - 1, n_stages - 1 + m, axis=0)
@@ -93,9 +91,7 @@ def gpipe_apply_mesh(stage_fn: Callable, stacked_params, x, mesh,
     backward pipeline schedule)."""
     from jax.sharding import PartitionSpec as P
 
-    from . import get_shard_map
-
-    shard_map = get_shard_map()
+    shard_map = jax.shard_map
 
     b = x.shape[0]
     n_stages = mesh.shape[axis_name]

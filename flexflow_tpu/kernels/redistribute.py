@@ -13,8 +13,6 @@ from __future__ import annotations
 
 from typing import Sequence
 
-from . import get_shard_map
-
 
 def allgather_dims(x, mesh, old_spec, dims: Sequence[int]):
     """All-gather `x` (sharded per `old_spec`, a resharding.ArraySpec) on
@@ -36,7 +34,7 @@ def allgather_dims(x, mesh, old_spec, dims: Sequence[int]):
         return blk
 
     # check_vma=False: the gathered output is replicated over the
-    # gathered axes, which the static rep-checker cannot infer through
-    # all_gather on every jax version this repo supports
-    sm = get_shard_map(check_vma=False)
-    return sm(body, mesh=mesh, in_specs=in_spec, out_specs=out_spec)(x)
+    # gathered axes, which the static vma checker cannot infer through
+    # all_gather
+    return jax.shard_map(body, mesh=mesh, in_specs=in_spec,
+                         out_specs=out_spec, check_vma=False)(x)

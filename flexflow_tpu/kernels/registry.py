@@ -40,9 +40,12 @@ Selection order (first match wins):
     must not force flash onto a seq-128 model below the crossover).
     Everything else defaults to reference until evidence or the knob
     says otherwise; in particular `reduction` — never a graph op, so no
-    residual can ever nominate it — is knob-opt-in only, because its
-    pallas_call inside the GSPMD-jitted step has no SPMD partitioning
-    rule (a sharded loss array would force replication).
+    residual can ever nominate it — is knob-opt-in only, because a Mosaic
+    kernel has no GSPMD partitioning rule: inside a step jitted over a
+    mesh the chip's compiler refuses it. (The attention op runs its flash
+    kernels under shard_map for that reason, and the norm families stay
+    on their reference lowering on a mesh — ops/attention.py `_on_mesh`,
+    core/op.py `LoweringContext.gspmd_partitioned`.)
 
 Every recorded selection bumps `ff_kernel_selected_total{op,impl}`
 (op = family), and `CostModel` prices pallas-selected families with

@@ -64,13 +64,13 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = False,
     b, _, h, dv = v.shape
 
     # accumulators for the online softmax; marked varying over the ring axis
-    # (the new shard_map vma check requires carry in/out types to agree;
-    # identity on jax versions without the vma type system)
-    from . import pvary
+    # (shard_map's vma check requires carry in/out types to agree)
+    def varying(x):
+        return jax.lax.pcast(x, vary_axes, to="varying")
 
-    acc0 = pvary(jnp.zeros((b, l_local, h, dv), jnp.float32), vary_axes)
-    m0 = pvary(jnp.full((b, h, l_local), -jnp.inf, jnp.float32), vary_axes)
-    l0 = pvary(jnp.zeros((b, h, l_local), jnp.float32), vary_axes)
+    acc0 = varying(jnp.zeros((b, l_local, h, dv), jnp.float32))
+    m0 = varying(jnp.full((b, h, l_local), -jnp.inf, jnp.float32))
+    l0 = varying(jnp.zeros((b, h, l_local), jnp.float32))
 
     perm = [(i, (i + 1) % axis_size) for i in range(axis_size)]
 
@@ -121,9 +121,7 @@ def ring_attention_sharded(q, k, v, mesh, axis_name: str = "seq",
     partitions L over `axis_name` and runs the ring. Call inside jit."""
     from jax.sharding import PartitionSpec as P
 
-    from . import get_shard_map
-
-    shard_map = get_shard_map()
+    shard_map = jax.shard_map
 
     # keep the batch dim sharded over 'data' when that axis exists, so DP x SP
     # composes without an all-gather + redundant compute at the region edge

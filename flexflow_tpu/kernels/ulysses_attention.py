@@ -93,12 +93,9 @@ def ulysses_attention_sharded(q, k, v, mesh, axis_name: str = "seq",
     """
     from jax.sharding import PartitionSpec as P
 
-    from . import get_shard_map
-
     # the flash local core is a pallas_call, whose outputs carry no vma
     # annotation — disable the varying-mesh-axes check only on that path
-    # (the shim translates the flag for older jax)
-    shard_map = get_shard_map(check_vma=not use_flash)
+    shard_map = functools.partial(jax.shard_map, check_vma=not use_flash)
 
     axis_size = dict(zip(mesh.axis_names, mesh.devices.shape))[axis_name]
     if q.shape[2] % axis_size:

@@ -5,57 +5,88 @@ C++ (src/runtime/graph.cc, substitution.cc, simulator.cc, machine_model.cc)
 under a C API (src/c/flexflow_c.cc) consumed by Python via cffi. Here the
 native core owns the same device-independent host logic — PCG algorithms,
 TPU machine model, Unity DP + MCMC search — and Python feeds it a line
-protocol. Pure-Python fallbacks (flexflow_tpu.search) remain when the
-library can't be built.
+protocol. The library is always built from the committed src/ffcore
+sources (a source-hash stamp decides whether one on disk may be reused).
+When it cannot be built, `available()` is False and says why once in the
+log; a caller that was asked to use it (`use_native_search`) calls
+`require()` and gets the error.
 """
 from __future__ import annotations
 
 import ctypes
+import fcntl
+import hashlib
+import logging
 import os
 import subprocess
 from typing import Dict, List, Optional
 
+_log = logging.getLogger(__name__)
+
 _SRC_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "src", "ffcore")
 _LIB_NAME = "libffcore.so"
+_STAMP_NAME = "libffcore.so.srchash"
 
 _lib = None
 _load_error: Optional[str] = None
 
 
-def _sources_newer_than(lib_path: str) -> bool:
-    lib_mtime = os.path.getmtime(lib_path)
-    for fn in os.listdir(_SRC_DIR):
-        if fn.endswith((".cc", ".h")):
-            if os.path.getmtime(os.path.join(_SRC_DIR, fn)) > lib_mtime:
-                return True
-    return False
+class NativeBuildError(RuntimeError):
+    """libffcore.so could not be built or loaded from src/ffcore."""
 
 
-def ensure_built() -> Optional[str]:
-    """Build libffcore.so if missing or stale. Returns the path or None."""
-    global _load_error
+def _source_hash(src: str) -> str:
+    """Content hash of everything the library is built from. The .so and
+    its objects are git-ignored build outputs: what decides whether one on
+    disk may be used is that it was built from THESE sources, not its
+    mtime (a copied or checked-out tree carries no meaningful mtimes)."""
+    h = hashlib.sha256()
+    for fn in sorted(os.listdir(src)):
+        if fn.endswith((".cc", ".h")) or fn == "Makefile":
+            h.update(fn.encode())
+            with open(os.path.join(src, fn), "rb") as f:
+                h.update(f.read())
+    return h.hexdigest()
+
+
+def ensure_built() -> str:
+    """Build libffcore.so from src/ffcore unless the one on disk was built
+    from the same sources; returns its path. Raises NativeBuildError when
+    the build fails — a library left over from other sources is never
+    used in its place."""
     src = os.path.abspath(_SRC_DIR)
     lib = os.path.join(src, _LIB_NAME)
-    if os.path.exists(lib) and not _sources_newer_than(lib):
-        return lib
-    try:
-        subprocess.run(["make", "-s"], cwd=src, check=True,
-                       capture_output=True, timeout=120)
-        return lib
-    except Exception as e:  # toolchain missing or compile error
-        _load_error = f"native build failed: {e}"
-        return lib if os.path.exists(lib) else None
+    stamp = os.path.join(src, _STAMP_NAME)
+    want = _source_hash(src)
+    # the stamp doubles as the build lock: two processes starting together
+    # (pytest workers, the multi-process tests) must not both run make
+    with open(stamp, "a+") as f:
+        fcntl.flock(f, fcntl.LOCK_EX)
+        f.seek(0)
+        if os.path.exists(lib) and f.read().strip() == want:
+            return lib
+        try:
+            # -B: objects on disk may be as stale as the library
+            subprocess.run(["make", "-s", "-B", "-j4"], cwd=src, check=True,
+                           capture_output=True, text=True, timeout=300)
+        except (OSError, subprocess.SubprocessError) as e:
+            detail = getattr(e, "stderr", None) or str(e)
+            raise NativeBuildError(
+                f"building {_LIB_NAME} in {src} failed: {detail}") from e
+        f.seek(0)
+        f.truncate()
+        f.write(want)
+    return lib
 
 
 def _load():
+    """The loaded library, or None with `_load_error` set when it cannot
+    be built or loaded (the failure is logged once, never swallowed)."""
     global _lib, _load_error
-    if _lib is not None:
+    if _lib is not None or _load_error is not None:
         return _lib
-    path = ensure_built()
-    if path is None:
-        return None
     try:
-        lib = ctypes.CDLL(path)
+        lib = ctypes.CDLL(ensure_built())
         lib.ffc_run.argtypes = [ctypes.c_char_p]
         lib.ffc_run.restype = ctypes.c_void_p
         lib.ffc_free.argtypes = [ctypes.c_void_p]
@@ -73,12 +104,20 @@ def _load():
         lib.ffdl_reset.argtypes = [ctypes.c_void_p]
         lib.ffdl_destroy.argtypes = [ctypes.c_void_p]
         _lib = lib
-    except (OSError, AttributeError) as e:
-        # AttributeError: a stale .so predating newer symbols, with no
-        # toolchain to rebuild — fall back to the pure-Python paths
-        _load_error = str(e)
-        _lib = None
+    except (NativeBuildError, OSError, AttributeError) as e:
+        _load_error = f"{type(e).__name__}: {e}"
+        _log.warning("native core unavailable: %s", _load_error)
     return _lib
+
+
+def require() -> None:
+    """Raise NativeBuildError unless the native core is loaded — for
+    callers that were ASKED to use it (config.use_native_search)."""
+    if _load() is None:
+        raise NativeBuildError(
+            f"the native core was requested but is unavailable"
+            f" ({_load_error}); fix the toolchain or set"
+            " use_native_search=False")
 
 
 def available() -> bool:

@@ -270,9 +270,9 @@ def run_profile(argv: Optional[List[str]] = None) -> int:
             " under --drift-replan): the thresholds ride on the profile"
             " the refit persists")
 
-    from ..runtime.platform import honor_env_platform
+    from ..runtime.platform import cpu_mesh_from_env
 
-    honor_env_platform()
+    cpu_mesh_from_env()
 
     from . import (calibrate, enable_tracing, get_registry, get_tracer,
                    validate_exposition)

@@ -28,6 +28,7 @@ consumes.
 """
 from __future__ import annotations
 
+import functools
 import json
 import math
 import os
@@ -64,7 +65,6 @@ def sweep_collectives(config, sizes_bytes: List[int],
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from ..kernels import get_shard_map
     from ..runtime.collectives import lower_allreduce, tier_axis_groups
     from ..search.machine_model import make_machine_model
     from .calibration import CollectiveCalibration
@@ -87,7 +87,7 @@ def sweep_collectives(config, sizes_bytes: List[int],
     tier_names = [t.name for t, _ in tier_path] or ["mesh"]
     groups = tier_axis_groups(n, group_sizes)
     outer_tier = tier_names[-1]
-    sm = get_shard_map(check_vma=False)
+    sm = functools.partial(jax.shard_map, check_vma=False)
     rows: List[CollectiveCalibration] = []
 
     def timed(body, elems) -> float:
@@ -169,9 +169,9 @@ def run_collective_bench(argv: Optional[List[str]] = None) -> int:
     if fit_profile:
         argv.remove("--fit-profile")
 
-    from ..runtime.platform import honor_env_platform
+    from ..runtime.platform import cpu_mesh_from_env
 
-    honor_env_platform()
+    cpu_mesh_from_env()
 
     import flexflow_tpu as ff
 

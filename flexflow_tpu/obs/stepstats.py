@@ -38,9 +38,15 @@ def model_train_flops_per_step(model) -> float:
 
 def model_peak_tflops(model) -> float:
     """Aggregate peak TFLOP/s of the device set, from the same machine
-    spec the cost simulator prices against."""
+    spec the cost simulator prices against. 0.0 — "MFU not measured" —
+    on any backend but a TPU: the CPU backend only DESCRIBES a chip, and
+    dividing a host step time by that chip's peak is not a utilization."""
+    import jax
+
     from ..search.machine_model import make_machine_model
 
+    if jax.default_backend() != "tpu":
+        return 0.0
     n_dev = max(1, model.config.total_devices)
     chip = make_machine_model(model.config, n_dev).chip
     per_chip = (chip.peak_bf16_tflops if model.config.allow_mixed_precision

@@ -9,6 +9,7 @@ flexflow_tpu/kernels/ and are selected via params.
 """
 from __future__ import annotations
 
+import functools
 from typing import List
 
 import jax
@@ -18,6 +19,7 @@ import numpy as np
 from ..core.op import Op, WeightSpec, register_op
 from ..ffconst import CompMode, OpType
 from ..runtime.initializers import DefaultInitializer, ZeroInitializer
+from ..runtime.platform import pallas_interpret
 from .common import emit_dtype, matmul_dtype
 
 
@@ -85,6 +87,10 @@ class MultiHeadAttentionOp(Op):
         q_in, k_in, v_in = inputs[:3]
         p = self.params
         _, _, _, embed, heads, kdim, vdim = self._dims()
+        # the heads this trace holds: all of them inside the jitted step,
+        # heads/tp when the search's op-cost measurement hands the op its
+        # tensor-parallel weight shard (search/simulator.py OpCostCache)
+        heads = weights["wq"].shape[1]
         cdt = matmul_dtype(ctx.config, q_in.dtype)
 
         # iteration seq_length truncation (reference: FFIterationConfig
@@ -208,16 +214,16 @@ class MultiHeadAttentionOp(Op):
             if mode in ("ulysses", "all_to_all"):
                 from ..kernels.ulysses_attention import ulysses_attention_sharded
 
+                # the local core is an ordinary dense attention, so the
+                # same measured auto-policy picks flash vs einsum
+                local_flash = (self._use_flash(ctx) and not dropout_active
+                               and kdim == vdim)
                 ctxv = ulysses_attention_sharded(
                     q, k, v, ctx.mesh, axis_name="seq", causal=causal,
-                    scale=scale,
-                    # the local core is an ordinary dense attention, so the
-                    # same measured auto-policy picks flash vs einsum
-                    use_flash=(self._use_flash(ctx) and not dropout_active
-                               and kdim == vdim),
+                    scale=scale, use_flash=local_flash,
                     block_q=getattr(ctx.config, "flash_block_q", 512),
                     block_k=getattr(ctx.config, "flash_block_k", 512),
-                    interpret=jax.default_backend() != "tpu",
+                    interpret=local_flash and pallas_interpret(),
                 )
             elif mode == "ring":
                 from ..kernels.ring_attention import ring_attention_sharded
@@ -236,24 +242,25 @@ class MultiHeadAttentionOp(Op):
             # HBM, no layout transposes (kernels/flash_attention.py)
             from ..kernels.flash_attention import flash_attention_packed
 
-            ctxv = flash_attention_packed(
-                q, k, v, heads, scale=scale, causal=causal,
+            ctxv = self._on_mesh(ctx, functools.partial(
+                flash_attention_packed, num_heads=heads, scale=scale,
+                causal=causal,
                 block_q=getattr(ctx.config, "flash_block_q", 512),
                 block_k=getattr(ctx.config, "flash_block_k", 512),
-                interpret=jax.default_backend() != "tpu",
-            )
+                interpret=pallas_interpret(),
+            ), heads_dim=None)(q, k, v)
         elif flash_selected:
             # flash on a TP head-sharded mesh: head-separated [b,l,h,d]
             # projections (shardable on the heads axis) with the
             # transpose-based kernel wrapper
             from ..kernels.flash_attention import flash_attention
 
-            ctxv = flash_attention(
-                q, k, v, scale=scale, causal=causal,
+            ctxv = self._on_mesh(ctx, functools.partial(
+                flash_attention, scale=scale, causal=causal,
                 block_q=getattr(ctx.config, "flash_block_q", 512),
                 block_k=getattr(ctx.config, "flash_block_k", 512),
-                interpret=jax.default_backend() != "tpu",
-            )
+                interpret=pallas_interpret(),
+            ), heads_dim=2)(q, k, v)
         else:
             drop_key = ctx.next_rng() if dropout_active else None
 
@@ -302,6 +309,30 @@ class MultiHeadAttentionOp(Op):
         if out.shape[1] < full_q_len:  # truncated: pad back to declared shape
             out = jnp.pad(out, [(0, 0), (0, full_q_len - out.shape[1]), (0, 0)])
         return [out]
+
+    def _on_mesh(self, ctx, kernel, heads_dim):
+        """`kernel(q, k, v)` as it must be called on a mesh. A Mosaic
+        kernel has no GSPMD partitioning rule ("cannot be automatically
+        partitioned"), so inside the jitted step it runs under shard_map:
+        every device on its own batch shard and — on the [b, l, h, d]
+        layout, heads_dim=2 — its own heads, with the axes the op's
+        tensors already carry. Where GSPMD partitions nothing
+        (LoweringContext.gspmd_partitioned) the kernel is called as it
+        is."""
+        if not ctx.gspmd_partitioned():
+            return kernel
+        from jax.sharding import PartitionSpec as P
+
+        out_shape = self.outputs[0].parallel_shape
+        spec = [out_shape.partition_spec()[0] if out_shape else None,
+                None, None]
+        if heads_dim is not None:
+            wq = next(w for w in self.weights
+                      if w._weight_spec.name == "wq").parallel_shape
+            spec.insert(heads_dim, wq.partition_spec()[1] if wq else None)
+        spec = P(*spec)
+        return jax.shard_map(kernel, mesh=ctx.mesh, in_specs=(spec,) * 3,
+                             out_specs=spec, check_vma=False)
 
     def _decode_step(self, ctx, q, k, v, weights, scale):
         """One incremental-decoding step: q/k/v are projections of the new
@@ -371,25 +402,22 @@ class MultiHeadAttentionOp(Op):
 
         from ..kernels.registry import KERNELS
 
-        interpret = jax.default_backend() != "tpu"
-        block_k = getattr(ctx.config, "flash_block_k", 512)
-        if vector and c == 1:
-            if KERNELS.select("attention_decode", config=ctx.config):
-                from ..kernels.pallas.decode import fused_decode_attention
+        family = ("attention_decode" if vector and c == 1
+                  else "attention_decode_mq")
+        # GSPMD cannot partition a Mosaic kernel (see _on_mesh): a decode
+        # step jitted over a mesh keeps the reference chain below
+        if (not ctx.gspmd_partitioned()
+                and KERNELS.select(family, config=ctx.config)):
+            from ..kernels.pallas import decode
 
-                ctxv = fused_decode_attention(
-                    q, kc, vc, pos, scale=scale, block_k=block_k,
-                    interpret=interpret)
-                return self._decode_project(ctxv, q.dtype, weights)
-        elif KERNELS.select("attention_decode_mq", config=ctx.config):
-            from ..kernels.pallas.decode import (
-                fused_multiquery_decode_attention)
-
+            fused = (decode.fused_decode_attention
+                     if family == "attention_decode"
+                     else decode.fused_multiquery_decode_attention)
             posv = pos if vector else jnp.full(
                 (kc.shape[0],), pos, jnp.int32)
-            ctxv = fused_multiquery_decode_attention(
-                q, kc, vc, posv, scale=scale, block_k=block_k,
-                interpret=interpret)
+            ctxv = fused(q, kc, vc, posv, scale=scale,
+                         block_k=getattr(ctx.config, "flash_block_k", 512),
+                         interpret=pallas_interpret())
             return self._decode_project(ctxv, q.dtype, weights)
 
         if vector:

@@ -306,8 +306,6 @@ class GradSyncLowering:
         import jax
         from jax.sharding import PartitionSpec as P
 
-        from ..kernels import get_shard_map
-
         self.record()
         mesh = executor.mesh
         axis, dp = self.axis_name, self.degree
@@ -354,9 +352,8 @@ class GradSyncLowering:
                         jax.tree.map(lambda _: P(axis), inputs),
                         P(axis), P())
             out_specs = (P(), P(), P())
-            sm = get_shard_map(check_vma=False)
-            return sm(body, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs)(
+            return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False)(
                 params, state, inputs, label, rng)
 
         return synced_gstep

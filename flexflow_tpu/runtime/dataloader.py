@@ -31,15 +31,14 @@ class SingleDataLoader:
         self.next_index = 0
         self._stream = None
         if prefetch:
-            try:
-                from .. import native
+            from .. import native
 
-                if native.available():
-                    self._stream = native.BatchStream(
-                        self.data[: self.num_samples], self.batch_size,
-                        shuffle=shuffle, seed=seed)
-            except Exception:  # toolchain missing: numpy path
-                self._stream = None
+            # no native core (the loader logs why, once): the numpy path
+            # below yields the same batches, without the prefetch ring
+            if native.available():
+                self._stream = native.BatchStream(
+                    self.data[: self.num_samples], self.batch_size,
+                    shuffle=shuffle, seed=seed)
         self._order = None
         self._epoch = 0
         ffmodel._attach_dataloader(self)

@@ -26,36 +26,6 @@ from typing import Dict, Optional, Sequence
 _initialized = False
 
 
-def cpu_collectives_supported() -> bool:
-    """True when the installed jaxlib ships a cross-process CPU
-    collectives implementation (gloo). Without it, multi-process programs
-    on the CPU backend fail at execution time with 'Multiprocess
-    computations aren't implemented on the CPU backend' — the condition
-    tests/test_distributed_multiprocess.py skips on."""
-    try:
-        from jaxlib.xla_extension import make_gloo_tcp_collectives  # noqa: F401
-    except ImportError:
-        return False
-    return True
-
-
-def _enable_cpu_collectives() -> None:
-    """Route cross-process CPU collectives through gloo. The CPU client is
-    built WITHOUT a cross-host collectives impl by default, so a two-
-    process CPU run would fail at the first jitted collective; the config
-    must be set before the backend client is created (initialize() runs
-    pre-client in every launch pattern)."""
-    import jax
-
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        # unknown option on this jax version: the run either targets a
-        # real accelerator (no CPU collectives needed) or will surface
-        # the jaxlib limitation at execution time
-        pass
-
-
 def initialize(coordinator_address: Optional[str] = None,
                num_processes: Optional[int] = None,
                process_id: Optional[int] = None,
@@ -64,15 +34,10 @@ def initialize(coordinator_address: Optional[str] = None,
     global _initialized
     if _initialized:
         return
-    import jax  # noqa: F401  (backend must be importable before init)
+    import jax
 
-    # unconditionally when available: the option only selects which
-    # implementation the CPU client would use, so it is inert on
-    # accelerator backends — and gating on an explicit JAX_PLATFORMS=cpu
-    # would miss the accelerator-less host that DEFAULTS to cpu
-    if cpu_collectives_supported():
-        _enable_cpu_collectives()
-
+    # cross-process collectives on the CPU backend need nothing here: the
+    # installed jax routes them through gloo by default
     kwargs = {}
     if coordinator_address or os.environ.get("JAX_COORDINATOR_ADDRESS"):
         kwargs["coordinator_address"] = (

@@ -22,9 +22,9 @@ def reduce_scalar(x, kind: str = "mean"):
 
     if kind in ("sum", "mean") and KERNELS.select("reduction"):
         from ..kernels.pallas.reduction import fused_reduce
+        from .platform import pallas_interpret
 
-        return fused_reduce(x, kind,
-                            interpret=jax.default_backend() != "tpu")
+        return fused_reduce(x, kind, interpret=pallas_interpret())
     return jnp.mean(x) if kind == "mean" else jnp.sum(x)
 
 

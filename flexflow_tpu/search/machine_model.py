@@ -57,6 +57,40 @@ CHIP_SPECS = {
 }
 
 
+# jax `device_kind` -> CHIP_SPECS key. Peaks are Google Cloud's published
+# per-chip numbers ("TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s;
+# likewise the v5p and v4 pages). A TPU that is not listed is an error,
+# not a default: pricing or MFU against the wrong chip is silently wrong.
+DEVICE_KIND_CHIP = {
+    "TPU v5 lite": "tpu-v5e",
+    "TPU v5e": "tpu-v5e",
+    "TPU v5": "tpu-v5p",
+    "TPU v5p": "tpu-v5p",
+    "TPU v4": "tpu-v4",
+}
+
+# The chip a CPU run DESCRIBES: the search, the simulator and the plan
+# verifier run on the CPU backend in tests and dryruns, pricing a machine
+# that is not attached.
+DESCRIBED_CHIP = "tpu-v5e"
+
+
+def chip_for_device(device) -> ChipSpec:
+    """ChipSpec of a jax device: looked up by `device_kind` on a TPU
+    (unknown kind raises), the explicitly described chip on the CPU
+    backend."""
+    if device.platform == "cpu":
+        return CHIP_SPECS[DESCRIBED_CHIP]
+    name = DEVICE_KIND_CHIP.get(device.device_kind)
+    if name is None:
+        raise ValueError(
+            f"no chip spec for {device.platform} device kind "
+            f"{device.device_kind!r}; known kinds: "
+            f"{sorted(DEVICE_KIND_CHIP)} — add its published peaks to "
+            "CHIP_SPECS/DEVICE_KIND_CHIP (search/machine_model.py)")
+    return CHIP_SPECS[name]
+
+
 class MachineModel:
     """Abstract cost oracle (reference: simulator.h:212).
 
@@ -810,8 +844,14 @@ def make_machine_model(config, num_chips: int) -> MachineModel:
     EVERY consumer of this factory (Unity search, simulator, calibration,
     MFU accounting, KV-pool sizing) prices with measured reality. A
     profile fitted for a different chip/backend refuses to load (typed
-    FittedProfileMismatch) rather than silently mis-pricing."""
-    chip = CHIP_SPECS.get("tpu-v5e")
+    FittedProfileMismatch) rather than silently mis-pricing.
+
+    The chip is the attached one (`chip_for_device`: by `device_kind`,
+    raising on a TPU it does not know; the described v5e on the CPU
+    backend) unless the machine file names its own."""
+    import jax
+
+    chip = chip_for_device(jax.devices()[0])
     if config.machine_model_file:
         # one read, then dispatch: a spec with a "tiers" list is the
         # hierarchical machine (docs/machine.md); anything else keeps the

@@ -297,9 +297,17 @@ class CostModel:
             memo = self._kernel_factor_memo = {}
         nd = len(op.inputs[0].dims) if op.inputs else 0
         if family in ("layernorm", "rmsnorm", "softmax"):
+            # ops/norm.py gates: never fused inside a step jitted over a
+            # mesh (a Mosaic kernel has no GSPMD partitioning rule)
+            if self.machine.num_chips > 1:
+                return 1.0
             if family == "softmax":
-                # ops/norm.py gates: fused only on the trailing axis
-                if op.params.get("axis", -1) not in (-1, nd - 1):
+                # ops/norm.py gates: fused only on the trailing axis, and
+                # only rows narrow enough to stay resident in VMEM
+                from ..kernels.pallas.norm import softmax_block_rows
+
+                if (op.params.get("axis", -1) not in (-1, nd - 1)
+                        or not softmax_block_rows(op.inputs[0].dims[-1])):
                     return 1.0
             elif tuple(op.params.get("axes", ())) != (nd - 1,):
                 return 1.0
@@ -880,9 +888,10 @@ class OpCostCache:
 
     Cache keys are shape-based (Op.cost_key), so identical ops — e.g. the 12
     identical layers of a BERT stack, or the same op across compiles — share
-    one measurement. Measurement failures are recorded and logged, never
-    silently degraded to the analytic model (the Simulator does the fallback
-    and the search logs the counts)."""
+    one measurement. Measurement failures are recorded; on a TPU they
+    raise, on the CPU backend (tests that opt into measurement) they are
+    logged and the Simulator prices the op analytically, counting it in
+    `analytic_fallbacks`."""
 
     def __init__(self, config=None, warmup: int = 2, repeats: int = 5,
                  path: Optional[str] = None):
@@ -1005,6 +1014,14 @@ class OpCostCache:
                     self.cache[key] = (fwd, bwd)
                 except Exception as exc:
                     self.failures[key] = f"{type(exc).__name__}: {exc}"
+                    import jax
+
+                    if jax.default_backend() == "tpu":
+                        # on the chip a failed measurement is a kernel the
+                        # compiler refused or a shape that does not fit —
+                        # the step would fail the same way; pricing it
+                        # analytically would only hide that
+                        raise
                     _log.warning("op-cost measurement failed for %s: %s",
                                  op.name, self.failures[key])
                     return -1.0, -1.0
@@ -1092,8 +1109,11 @@ class OpCostCache:
             try:
                 bwd_fn = jax.jit(jax.grad(loss, argnums=argnums))
                 bwd_us = max(0.0, self._time(bwd_fn, ins, weights) - fwd_us)
-            except Exception:
-                bwd_us = -1.0  # non-differentiable op: fwd-only measurement
+            except TypeError:
+                # non-differentiable op (integer outputs only): jax.grad
+                # refuses the non-float loss — fwd-only measurement. Any
+                # other failure is a real one and propagates.
+                bwd_us = -1.0
         return fwd_us, bwd_us
 
     def _time(self, fn, ins, weights) -> float:

@@ -1399,8 +1399,10 @@ def unity_optimize(graph: Graph, config, machine: MachineModel,
                    cache_graph_hash: Optional[str] = None) -> SearchResult:
     """Entry point (reference: FFModel::graph_optimize, substitution.cc:3589).
 
-    Dispatches to the native C++ core (src/ffcore, loaded via ctypes) when
-    available; the pure-Python path below is the fallback and the behavioral
+    Dispatches to the native C++ core (src/ffcore, built on the spot and
+    loaded via ctypes) when `config.use_native_search` asks for it and the
+    search needs nothing only the Python path has; a native build that
+    fails is then an error. The pure-Python path below is the behavioral
     spec. A custom simulator (e.g. measured costs) forces the Python path.
 
     Plan cache (docs/search.md): unless disabled, the search is keyed by
@@ -1500,53 +1502,55 @@ def unity_optimize(graph: Graph, config, machine: MachineModel,
             and getattr(config, "use_native_search", True)):
         from .. import native
 
-        if native.available():
-            from ..obs.tracing import get_tracer
+        # asked for by config.use_native_search: a failed build is an
+        # error, not a silent switch to the Python search
+        native.require()
+        from ..obs.tracing import get_tracer
 
-            # the native core runs enumerate/prune/simulate internally;
-            # one "search" span still marks the phase in the trace
-            with get_tracer().span("search", backend="native",
-                                   n_devices=n_devices) as sp:
-                applied = apply_substitutions(
-                    graph, rule_set_from_spec(spec, is_taso))
-                result = native.optimize_strategy(
-                    graph, config, machine, batch_size, n_devices
-                )
-                sp.set(cost_us=result.cost_us, axes=result.mesh_axes)
-            if applied:
-                result.log.append(f"substitutions: {applied}")
-            result.predicted_step_us = result.cost_us
-            # the native core prices from the chip scalars alone — the
-            # fitted latency/step-scale coefficients a profile overlay
-            # sets (obs/refit.py) don't cross the line protocol, and
-            # neither does the kernel tier's PALLAS_COST_GAIN pricing
-            # (docs/kernels.md). When either is active, re-price the
-            # CHOSEN plan with the fully-overlaid Python simulator so
-            # predicted_step_us (what calibration and the drift detector
-            # compare against) reflects them; the native ranking stands
-            # (the extra terms are uniform enough across candidates not
-            # to re-rank them)
-            sim = Simulator(machine, config)
-            tier_active = any(
-                sim.cost.kernel_time_factor(
-                    op, result.strategies.get(op.guid, OpStrategy())) != 1.0
-                for op in graph.ops.values())
-            if (getattr(machine, "step_time_scale", 1.0) != 1.0
-                    or getattr(machine, "dispatch_overhead_us", 1.0) != 1.0
-                    or getattr(machine, "collective_latency_us", 1.0)
-                    != 1.0
-                    or tier_active):
-                repriced = sim.simulate(graph, result.strategies)
-                result.log.append(
-                    f"{'kernel-tier' if tier_active else 'fitted-profile'}"
-                    f" reprice: native {result.cost_us:.1f}"
-                    f"us -> {repriced:.1f}us predicted")
-                result.predicted_step_us = repriced
-                st = sim.last_sync_stats or {}
-                result.overlapped_sync_us = st.get("overlapped_sync_us")
-                result.exposed_sync_us = st.get("exposed_sync_us")
-                result.sync_buckets = len(st.get("buckets") or [])
-            return _finish_search(result, key, cache, t_start, graph)
+        # the native core runs enumerate/prune/simulate internally;
+        # one "search" span still marks the phase in the trace
+        with get_tracer().span("search", backend="native",
+                               n_devices=n_devices) as sp:
+            applied = apply_substitutions(
+                graph, rule_set_from_spec(spec, is_taso))
+            result = native.optimize_strategy(
+                graph, config, machine, batch_size, n_devices
+            )
+            sp.set(cost_us=result.cost_us, axes=result.mesh_axes)
+        if applied:
+            result.log.append(f"substitutions: {applied}")
+        result.predicted_step_us = result.cost_us
+        # the native core prices from the chip scalars alone — the
+        # fitted latency/step-scale coefficients a profile overlay
+        # sets (obs/refit.py) don't cross the line protocol, and
+        # neither does the kernel tier's PALLAS_COST_GAIN pricing
+        # (docs/kernels.md). When either is active, re-price the
+        # CHOSEN plan with the fully-overlaid Python simulator so
+        # predicted_step_us (what calibration and the drift detector
+        # compare against) reflects them; the native ranking stands
+        # (the extra terms are uniform enough across candidates not
+        # to re-rank them)
+        sim = Simulator(machine, config)
+        tier_active = any(
+            sim.cost.kernel_time_factor(
+                op, result.strategies.get(op.guid, OpStrategy())) != 1.0
+            for op in graph.ops.values())
+        if (getattr(machine, "step_time_scale", 1.0) != 1.0
+                or getattr(machine, "dispatch_overhead_us", 1.0) != 1.0
+                or getattr(machine, "collective_latency_us", 1.0)
+                != 1.0
+                or tier_active):
+            repriced = sim.simulate(graph, result.strategies)
+            result.log.append(
+                f"{'kernel-tier' if tier_active else 'fitted-profile'}"
+                f" reprice: native {result.cost_us:.1f}"
+                f"us -> {repriced:.1f}us predicted")
+            result.predicted_step_us = repriced
+            st = sim.last_sync_stats or {}
+            result.overlapped_sync_us = st.get("overlapped_sync_us")
+            result.exposed_sync_us = st.get("exposed_sync_us")
+            result.sync_buckets = len(st.get("buckets") or [])
+        return _finish_search(result, key, cache, t_start, graph)
     helper = GraphSearchHelper(graph, config, machine, simulator)
     budget = None
     if config.memory_search:

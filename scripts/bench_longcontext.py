@@ -8,7 +8,6 @@ as L grows, while the packed flash kernel streams K/V blocks through VMEM
 sequence length; einsum entries record OOM/slowdown honestly.
 
 Usage: python scripts/bench_longcontext.py          (on the TPU)
-       BENCH_PLATFORM=cpu SWEEP_LENS=128,256 ...    (CI validation)
 Env: SWEEP_B/H/D shape knobs, SWEEP_LENS comma list, SWEEP_ITERS.
 """
 from __future__ import annotations
@@ -20,7 +19,7 @@ import sys
 import numpy as np
 
 sys.path.insert(0, os.path.dirname(__file__))
-from _bench_util import force_platform_from_env, timeit_grad  # noqa: E402
+from _bench_util import timeit_grad  # noqa: E402
 
 B = int(os.environ.get("SWEEP_B", 1))
 H = int(os.environ.get("SWEEP_H", 16))
@@ -36,13 +35,15 @@ def attn_flops(l: int) -> float:
 
 
 def main():
-    force_platform_from_env()
     import jax
     import jax.numpy as jnp
 
     from flexflow_tpu.kernels.flash_attention import flash_attention_packed
+    from flexflow_tpu.runtime.platform import pallas_interpret
 
-    interpret = jax.default_backend() != "tpu"
+    dev = jax.devices()[0]
+
+    interpret = pallas_interpret()
     rng = np.random.RandomState(0)
     results = {}
 
@@ -90,6 +91,8 @@ def main():
         print(f"einsum L={L}: {results[f'einsum_L{L}']}", file=sys.stderr)
 
     print(json.dumps({"shape": {"B": B, "H": H, "D": D},
+                      "platform": dev.platform,
+                      "device_kind": dev.device_kind,
                       "fwd_bwd": results}))
 
 

@@ -1,5 +1,5 @@
-"""Flash-attention block-size sweep at the bench config (PROFILE.md's
-"next measurements wanted"). Times fwd+bwd of the Pallas kernel across
+"""Flash-attention block-size sweep at the bench config (ROADMAP S2).
+Times fwd+bwd of the Pallas kernel across
 block_q x block_k combinations against the einsum reference, on the real
 chip. Prints one JSON line with the per-config ms and the winner.
 
@@ -16,7 +16,7 @@ import sys
 import numpy as np
 
 sys.path.insert(0, os.path.dirname(__file__))
-from _bench_util import force_platform_from_env, timeit_grad  # noqa: E402
+from _bench_util import timeit_grad  # noqa: E402
 
 B = int(os.environ.get("SWEEP_B", 8))
 H = int(os.environ.get("SWEEP_H", 16))
@@ -27,13 +27,12 @@ ITERS = int(os.environ.get("SWEEP_ITERS", 20))
 
 
 def main():
-    force_platform_from_env()
-    import jax
     import jax.numpy as jnp
 
     from flexflow_tpu.kernels.flash_attention import flash_attention
+    from flexflow_tpu.runtime.platform import pallas_interpret
 
-    interpret = jax.default_backend() != "tpu"
+    interpret = pallas_interpret()
     rng = np.random.RandomState(0)
     q = jnp.asarray(rng.randn(B, L, H, D), jnp.bfloat16)
     k = jnp.asarray(rng.randn(B, L, H, D), jnp.bfloat16)

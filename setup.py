@@ -1,9 +1,10 @@
 """Packaging (reference parity: setup.py + cmake/pip_install).
 
-The package is pure Python over jax; the optional native core
-(src/ffcore/libffcore.so) is auto-built on first use by
-flexflow_tpu.native.ensure_built() and is not required for any feature
-(pure-Python fallbacks exist)."""
+The package is pure Python over jax, written for jax 0.9 (no shims for
+older releases). The native core (src/ffcore/libffcore.so) is built from
+the committed sources on first use by flexflow_tpu.native.ensure_built();
+without a toolchain set use_native_search=False (the Python search is the
+reference semantics)."""
 from setuptools import find_packages, setup
 
 setup(
@@ -15,7 +16,7 @@ setup(
     ),
     packages=find_packages(include=["flexflow_tpu", "flexflow_tpu.*"]),
     python_requires=">=3.10",
-    install_requires=["jax", "numpy"],
+    install_requires=["jax>=0.9,<0.10", "numpy"],
     extras_require={
         "frontends": ["torch", "onnx"],
         "checkpoint": ["orbax-checkpoint"],

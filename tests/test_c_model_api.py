@@ -12,9 +12,10 @@ from flexflow_tpu import native
 
 
 def _lib():
-    path = native.ensure_built()
-    if path is None:
-        pytest.skip("native core unavailable")
+    try:
+        path = native.ensure_built()
+    except native.NativeBuildError as e:
+        pytest.skip(f"native core unavailable: {e}")
     lib = ctypes.CDLL(path)
     lib.ffc_model_create.argtypes = [ctypes.c_int]
     lib.ffc_model_create.restype = ctypes.c_void_p

@@ -53,8 +53,6 @@ def _apply_strategy(x_global, strategy, sizes, dtype=np.float32):
     import jax
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from flexflow_tpu.kernels import get_shard_map
-
     n = x_global.shape[0]
     mesh = Mesh(np.array(jax.devices()[:n]), ("data",))
     groups = tier_axis_groups(n, sizes)
@@ -62,9 +60,8 @@ def _apply_strategy(x_global, strategy, sizes, dtype=np.float32):
     def body(x):
         return lower_allreduce(x[0], "data", strategy, sizes, groups)[None]
 
-    sm = get_shard_map(check_vma=False)
-    fn = jax.jit(sm(body, mesh=mesh, in_specs=P("data"),
-                    out_specs=P("data")))
+    fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("data"),
+                               out_specs=P("data"), check_vma=False))
     return np.asarray(fn(x_global.astype(dtype)))
 
 

@@ -11,19 +11,6 @@ import sys
 
 import pytest
 
-from flexflow_tpu.runtime.distributed import cpu_collectives_supported
-
-# targeted jaxlib-limitation gate: without a cross-process CPU collectives
-# implementation (gloo) in the installed jaxlib, a two-process CPU run
-# fails at the first jitted collective with "Multiprocess computations
-# aren't implemented on the CPU backend". When gloo IS available,
-# runtime/distributed.initialize() routes CPU collectives through it and
-# these tests run for real.
-pytestmark = pytest.mark.skipif(
-    not cpu_collectives_supported(),
-    reason="installed jaxlib ships no cross-process CPU collectives "
-           "(gloo); multiprocess-on-CPU is a jaxlib limitation here")
-
 WORKER = r"""
 import os, sys
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"

@@ -145,7 +145,7 @@ def test_call_with_retry_exhaustion_and_topology():
 def test_classify_error_patterns():
     assert classify_error(TransientFault("x")) == "transient"
     assert classify_error(TopologyLoss([0])) == "topology"
-    assert classify_error(RuntimeError("DEADLINE_EXCEEDED: tunnel")) \
+    assert classify_error(RuntimeError("DEADLINE_EXCEEDED: rpc")) \
         == "transient"
     assert classify_error(RuntimeError("DATA_LOSS: chip went away")) \
         == "topology"

@@ -1,6 +1,6 @@
 """Loaded substitution rules as EXECUTABLE GraphXfer rewrites.
 
-VERDICT r3 item 4: the rule-file loader must instantiate real source→target
+The rule-file loader must instantiate real source→target
 rewrites (reference: substitution_loader.h:94-187 → GraphXfer::create_xfers,
 substitution.h:119-121), not just a TP-degree menu.
 """

@@ -15,8 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from flexflow_tpu.kernels.pallas import (fused_cumsum,
-                                         fused_decode_attention,
+from flexflow_tpu.kernels.pallas import (fused_decode_attention,
                                          fused_layernorm, fused_reduce,
                                          fused_rmsnorm, fused_softmax)
 from flexflow_tpu.kernels.registry import (KERNELS, PALLAS_COST_GAIN,
@@ -155,18 +154,6 @@ def test_fused_reduce_tiny_and_empty():
     assert float(fused_reduce(jnp.asarray([3.0]), "sum",
                               interpret=True)) == 3.0
     assert float(fused_reduce(jnp.zeros((0,)), "sum", interpret=True)) == 0.0
-
-
-def test_fused_cumsum_parity():
-    rng = np.random.RandomState(5)
-    x = _rand(rng, (3, 5, 17))
-    y = fused_cumsum(x, interpret=True, block_rows=4)
-    np.testing.assert_allclose(np.asarray(y),
-                               np.asarray(jnp.cumsum(x, -1)), **F32_TOL)
-    gf = jax.grad(lambda x: jnp.sum(jnp.sin(
-        fused_cumsum(x, interpret=True, block_rows=4))))(x)
-    gr = jax.grad(lambda x: jnp.sum(jnp.sin(jnp.cumsum(x, -1))))(x)
-    np.testing.assert_allclose(np.asarray(gf), np.asarray(gr), **F32_TOL)
 
 
 # ---------------------------------------------------------------------

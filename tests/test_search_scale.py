@@ -1,4 +1,4 @@
-"""Search scalability soak (VERDICT r3 item 6): a reference-scale graph —
+"""Search scalability soak: a reference-scale graph —
 BERT-24, 170+ ops — searched at 256 devices with every axis enabled must
 finish in bounded wall-clock. The reference's memoized DP exists precisely
 for this regime (graph.cc:1586); here the budget pyramid is: memoized

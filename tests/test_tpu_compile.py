@@ -1,0 +1,120 @@
+"""The chip's compiler, without the chip: every Pallas entry point a
+registry family can select is compiled for a DESCRIBED TPU v5e at the
+widths the main paths use (BERT bench: batch 8, seq 512, hidden 1024, 16
+heads; serving: 16 slots x 2048 cache rows).
+
+Interpret mode (every other kernel test) cannot see what these see: a block
+the TPU tiling rules refuse, more scoped VMEM than a kernel may have, a
+primitive Mosaic does not lower. Nothing executes — a pass here is not a
+chip run (that is chip_smoke.py) — but a refusal here is exactly what the
+chip would raise deep inside a train or decode step.
+
+Skipped, not failed, where the TPU compiler is not installed and the
+topology cannot be described.
+"""
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # before libtpu starts
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import pytest  # noqa: E402
+
+from flexflow_tpu.kernels.flash_attention import (  # noqa: E402
+    flash_attention, flash_attention_packed)
+from flexflow_tpu.kernels.pallas import (  # noqa: E402
+    fused_decode_attention, fused_layernorm,
+    fused_multiquery_decode_attention, fused_reduce, fused_rmsnorm,
+    fused_softmax)
+from flexflow_tpu.kernels.pallas.norm import softmax_block_rows  # noqa: E402
+
+BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+BERT = (8, 512, 1024)   # batch, seq, hidden of the bench config
+HEADS = 16
+SLOTS, ROWS, HEAD_DIM = 16, 2048, 64
+# the widest softmax row the selector admits (ops/norm.py gate)
+WIDEST_ROW = max(n for n in range(39000, 40000) if softmax_block_rows(n))
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """SingleDeviceSharding on chip 0 of a described v5e 2x2 host. The
+    persistent compile cache is off for the module: a described-topology
+    executable written to it cannot be read back without a chip, and the
+    next compile would warn."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _sum_grad(fn, n_args):
+    """fwd+bwd of fn: grad of its f32 sum wrt the first n_args."""
+    return jax.grad(lambda *a: fn(*a).astype(F32).sum(),
+                    argnums=tuple(range(n_args)))
+
+
+def _decode(fn):
+    return lambda q, k, v, pos: fn(q, k, v, pos, scale=HEAD_DIM ** -0.5)
+
+
+_CACHE = ((SLOTS, ROWS, HEADS, HEAD_DIM), BF16)
+# name -> (function, [(shape, dtype), ...])
+CASES = {
+    "flash_packed_fwd_bwd": (
+        _sum_grad(lambda q, k, v: flash_attention_packed(q, k, v, HEADS), 3),
+        [(BERT, BF16)] * 3),
+    "flash_packed_causal_fwd_bwd": (
+        _sum_grad(lambda q, k, v: flash_attention_packed(
+            q, k, v, HEADS, causal=True), 3),
+        [(BERT, BF16)] * 3),
+    "flash_blhd_fwd_bwd": (  # the tensor-parallel mesh's kernel
+        _sum_grad(lambda q, k, v: flash_attention(q, k, v), 3),
+        [((8, 512, HEADS, HEAD_DIM), BF16)] * 3),
+    "decode_c1": (
+        _decode(fused_decode_attention),
+        [((SLOTS, 1, HEADS, HEAD_DIM), BF16), _CACHE, _CACHE,
+         ((SLOTS,), I32)]),
+    "decode_mq_c5": (
+        _decode(fused_multiquery_decode_attention),
+        [((SLOTS, 5, HEADS, HEAD_DIM), BF16), _CACHE, _CACHE,
+         ((SLOTS,), I32)]),
+    "decode_mq_c16_f32": (  # a prefill chunk of the f32 serve-bench LM
+        _decode(fused_multiquery_decode_attention),
+        [((SLOTS, 16, HEADS, HEAD_DIM), F32),
+         (_CACHE[0], F32), (_CACHE[0], F32), ((SLOTS,), I32)]),
+    "layernorm_fwd_bwd": (
+        _sum_grad(lambda x, g, b: fused_layernorm(x, g, b), 3),
+        [(BERT, BF16), ((1024,), BF16), ((1024,), BF16)]),
+    "rmsnorm_fwd_bwd": (
+        _sum_grad(lambda x, g: fused_rmsnorm(x, g), 2),
+        [(BERT, BF16), ((1024,), BF16)]),
+    "softmax_widest_row_fwd_bwd": (
+        _sum_grad(fused_softmax, 1), [((64, WIDEST_ROW), F32)]),
+    "softmax_vocab_fwd_bwd": (
+        _sum_grad(fused_softmax, 1), [((8, 512, 30522), BF16)]),
+    "reduce_mean_1d": (
+        lambda x: fused_reduce(x, "mean"), [((4096,), F32)]),
+    "reduce_max": (
+        lambda x: fused_reduce(x, "max"), [((8, 512, 1), F32)]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_compiles_for_v5e(v5e, name):
+    fn, specs = CASES[name]
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+            for shape, dtype in specs]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
