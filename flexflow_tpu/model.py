@@ -874,7 +874,8 @@ class FFModel:
 
         def upd(params, grads, opt_state, k):
             grads = jax.tree.map(lambda g: g / k, grads)
-            return optimizer.update(params, grads, opt_state)
+            with jax.named_scope("optimizer:update"):
+                return optimizer.update(params, grads, opt_state)
 
         self._accum_update = jax.jit(upd, donate_argnums=(0, 1, 2))
 
@@ -1343,7 +1344,12 @@ class FFModel:
         # config.profiling the same periodic samples/s line prints.
         from .obs.stepstats import (StepStats, model_peak_tflops,
                                     model_train_flops_per_step)
+        from .obs.tracing import get_tracer
 
+        # every dispatch is one `fit.chunk` span (a step marker of the
+        # profiler) whose children — fit.load, fit.stage, the executor's
+        # dispatch span, fit.absorb — name where its host time went
+        tracer = get_tracer()
         stats = StepStats(
             flops_per_step=model_train_flops_per_step(self),
             peak_tflops=model_peak_tflops(self),
@@ -1381,12 +1387,25 @@ class FFModel:
                 return inputs, label
 
             def load(it):
-                inputs, label = load_host(it)
-                return (
-                    {k2: self.executor.shard_batch(v)
-                     for k2, v in inputs.items()},
-                    self.executor.shard_batch(label),
-                )
+                with tracer.span("fit.load"):
+                    inputs, label = load_host(it)
+                with tracer.span("fit.stage"):
+                    return (
+                        {k2: self.executor.shard_batch(v)
+                         for k2, v in inputs.items()},
+                        self.executor.shard_batch(label),
+                    )
+
+            def absorb_step(mvals, samples, scale=1.0):
+                """Fetch one update's metric values (the sync), commit
+                them, and hand the step to the health checks."""
+                with tracer.span("fit.absorb"):
+                    mv = {k2: float(v) / scale for k2, v in mvals.items()}
+                    self.perf_metrics.update(samples, mv)
+                    rec = stats.record_step(samples, loss=mv.get("loss"))
+                _drift_guard(rec)
+                _wd_guard(mv)
+                return mv
 
             if steps_per_execution > 1:
                 K = steps_per_execution
@@ -1396,59 +1415,72 @@ class FFModel:
                 def _absorb(mvals_k):
                     # stacked (K,) per-step values -> per-step mean, weighted
                     # by the K*bs samples that dispatch consumed
-                    mv = {k2: float(np.asarray(v).mean())
-                          for k2, v in mvals_k.items()}
-                    self.perf_metrics.update(K * bs, mv)
-                    # one record per K-step dispatch; StepStats divides the
-                    # interval by K for the per-optimizer-step wall time
-                    _drift_guard(
-                        stats.record_step(K * bs, loss=mv.get("loss"),
-                                          steps=K))
+                    with tracer.span("fit.absorb"):
+                        per_step = {k2: np.asarray(v)  # the sync
+                                    for k2, v in mvals_k.items()}
+                        mv = {k2: float(v.mean())
+                              for k2, v in per_step.items()}
+                        self.perf_metrics.update(K * bs, mv)
+                        # one record per K-step dispatch; StepStats divides
+                        # the interval by K for the per-optimizer-step wall
+                        # time and keeps the K single losses beside the mean
+                        rec = stats.record_step(
+                            K * bs, loss=mv.get("loss"), steps=K,
+                            losses=per_step.get("loss"))
+                    _drift_guard(rec)
                     _wd_guard(mv)  # per-chunk: the K-step mean loss
                     return mv
 
                 for chunk_i in range(chunks):
                     if self._recompile_state is not None:
                         self._recompile_state.step(self)
-                    batches = [load_host(chunk_i * K + j) for j in range(K)]
-                    inputs_k = {
-                        name: self.executor.shard_batch(
-                            np.stack([b[0][name] for b in batches]),
-                            batch_axis=1)
-                        for name in batches[0][0]
-                    }
-                    label_k = self.executor.shard_batch(
-                        np.stack([b[1] for b in batches]), batch_axis=1)
-                    rng_k = jax.random.split(self._next_rng(advance=K), K)
-                    # re-resolved every chunk: a recompile trigger (elastic
-                    # graph alteration) invalidates and rebuilds the jitted
-                    # steps mid-epoch
-                    (self.params, self.opt_state, self.state,
-                     mvals_k) = self._get_multi_step()(
-                        self.params, self.opt_state, self.state, inputs_k,
-                        label_k, rng_k)
-                    # one-deep pipeline: absorb the PREVIOUS dispatch's
-                    # metrics after queuing this one, so host-side metric
-                    # fetches and the next chunk's batch staging overlap
-                    # device execution instead of serializing with it
-                    if prev_mvals_k is not None:
-                        mvals = _absorb(prev_mvals_k)
-                    prev_mvals_k = mvals_k
+                    with tracer.step("fit.chunk", self._step_count,
+                                     chunk=chunk_i, steps=K, samples=K * bs):
+                        with tracer.span("fit.load"):
+                            batches = [load_host(chunk_i * K + j)
+                                       for j in range(K)]
+                            host_k = {name: np.stack([b[0][name]
+                                                      for b in batches])
+                                      for name in batches[0][0]}
+                            host_label_k = np.stack([b[1] for b in batches])
+                        with tracer.span("fit.stage"):
+                            inputs_k = {
+                                name: self.executor.shard_batch(
+                                    v, batch_axis=1)
+                                for name, v in host_k.items()
+                            }
+                            label_k = self.executor.shard_batch(
+                                host_label_k, batch_axis=1)
+                            rng_k = jax.random.split(
+                                self._next_rng(advance=K), K)
+                        # re-resolved every chunk: a recompile trigger
+                        # (elastic graph alteration) invalidates and
+                        # rebuilds the jitted steps mid-epoch
+                        (self.params, self.opt_state, self.state,
+                         mvals_k) = self._get_multi_step()(
+                            self.params, self.opt_state, self.state,
+                            inputs_k, label_k, rng_k)
+                        # one-deep pipeline: absorb the PREVIOUS dispatch's
+                        # metrics after queuing this one, so host-side
+                        # metric fetches and the next chunk's batch staging
+                        # overlap device execution instead of serializing
+                        # with it
+                        if prev_mvals_k is not None:
+                            mvals = _absorb(prev_mvals_k)
+                        prev_mvals_k = mvals_k
                 if prev_mvals_k is not None:
                     mvals = _absorb(prev_mvals_k)
                 # trailing n mod (bs*K) samples: single-step path, so an
                 # epoch performs the same n // bs updates as plain fit
                 for step_i in range(chunks * K, n // bs):
-                    inputs, label = load(step_i)
-                    (self.params, self.opt_state, self.state,
-                     mvals) = self._train_step(
-                        self.params, self.opt_state, self.state, inputs,
-                        label, self._next_rng())
-                    mvals = {k2: float(v) for k2, v in mvals.items()}
-                    self.perf_metrics.update(bs, mvals)
-                    _drift_guard(
-                        stats.record_step(bs, loss=mvals.get("loss")))
-                    _wd_guard(mvals)
+                    with tracer.step("fit.chunk", self._step_count,
+                                     chunk=step_i, steps=1, samples=bs):
+                        inputs, label = load(step_i)
+                        (self.params, self.opt_state, self.state,
+                         mvals) = self._train_step(
+                            self.params, self.opt_state, self.state, inputs,
+                            label, self._next_rng())
+                        mvals = absorb_step(mvals, bs)
                 dt = time.time() - t0
                 summ = self.perf_metrics.summary()
                 summ["epoch"] = epoch
@@ -1468,43 +1500,37 @@ class FFModel:
                 if self._recompile_state is not None:
                     self._recompile_state.step(self)
                 base = step_i * accum_steps
-                inputs, label = load(base)
-                if accum_steps > 1:
-                    # ONE counter advance per optimizer update (microbatches
-                    # are sub-steps, not steps); each microbatch still gets a
-                    # distinct dropout key via split
-                    micro_keys = jax.random.split(self._next_rng(),
-                                                  accum_steps)
-                    grads, mvals = self._accum_grad(
-                        self.params, self.state, inputs, label,
-                        micro_keys[0])
-                    for k in range(1, accum_steps):
-                        inputs, label = load(base + k)
-                        g2, mv2 = self._accum_grad(
+                with tracer.step("fit.chunk", self._step_count, chunk=step_i,
+                                 steps=1, samples=accum_steps * bs):
+                    inputs, label = load(base)
+                    if accum_steps > 1:
+                        # ONE counter advance per optimizer update
+                        # (microbatches are sub-steps, not steps); each
+                        # microbatch still gets a distinct dropout key via
+                        # split
+                        micro_keys = jax.random.split(self._next_rng(),
+                                                      accum_steps)
+                        grads, mvals = self._accum_grad(
                             self.params, self.state, inputs, label,
-                            micro_keys[k])
-                        grads = self._accum_add(grads, g2)
-                        mvals = {k2: mvals[k2] + mv2[k2] for k2 in mvals}
-                    self.params, self.opt_state = self._accum_update(
-                        self.params, grads, self.opt_state,
-                        float(accum_steps))
-                    mvals = {k2: float(v) / accum_steps
-                             for k2, v in mvals.items()}
-                    self.perf_metrics.update(accum_steps * bs, mvals)
-                    _drift_guard(
-                        stats.record_step(accum_steps * bs,
-                                          loss=mvals.get("loss")))
-                    _wd_guard(mvals)
-                else:
-                    self.params, self.opt_state, self.state, mvals = self._train_step(
-                        self.params, self.opt_state, self.state, inputs, label,
-                        self._next_rng(),
-                    )
-                    mvals = {k: float(v) for k, v in mvals.items()}
-                    self.perf_metrics.update(bs, mvals)
-                    _drift_guard(
-                        stats.record_step(bs, loss=mvals.get("loss")))
-                    _wd_guard(mvals)
+                            micro_keys[0])
+                        for k in range(1, accum_steps):
+                            inputs, label = load(base + k)
+                            g2, mv2 = self._accum_grad(
+                                self.params, self.state, inputs, label,
+                                micro_keys[k])
+                            grads = self._accum_add(grads, g2)
+                            mvals = {k2: mvals[k2] + mv2[k2] for k2 in mvals}
+                        self.params, self.opt_state = self._accum_update(
+                            self.params, grads, self.opt_state,
+                            float(accum_steps))
+                        mvals = absorb_step(mvals, accum_steps * bs,
+                                            scale=accum_steps)
+                    else:
+                        (self.params, self.opt_state, self.state,
+                         mvals) = self._train_step(
+                            self.params, self.opt_state, self.state, inputs,
+                            label, self._next_rng())
+                        mvals = absorb_step(mvals, bs)
             dt = time.time() - t0
             summ = self.perf_metrics.summary()
             summ["epoch"] = epoch

@@ -91,9 +91,10 @@ class StepStats:
         self._mark = time.perf_counter()
 
     def record_step(self, samples: int, loss: Optional[float] = None,
-                    steps: int = 1) -> Dict[str, float]:
+                    steps: int = 1, losses=None) -> Dict[str, Any]:
         """Close the current interval as `steps` optimizer steps that
-        consumed `samples` samples total."""
+        consumed `samples` samples total. `losses`: the single steps'
+        losses of a K-step dispatch, kept beside their mean `loss`."""
         now = time.perf_counter()
         if self._mark is None:
             self._mark = now
@@ -116,6 +117,8 @@ class StepStats:
         }
         if loss is not None:
             rec["loss"] = float(loss)
+        if losses is not None:
+            rec["losses"] = [float(x) for x in losses]
         self._records.append(rec)
         self._total_steps += steps
         self._total_samples += samples
@@ -140,10 +143,10 @@ class StepStats:
     def total_steps(self) -> int:
         return self._total_steps
 
-    def records(self) -> List[Dict[str, float]]:
+    def records(self) -> List[Dict[str, Any]]:
         return list(self._records)
 
-    def last(self) -> Optional[Dict[str, float]]:
+    def last(self) -> Optional[Dict[str, Any]]:
         return self._records[-1] if self._records else None
 
     def mean_step_ms(self) -> float:

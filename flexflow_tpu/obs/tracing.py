@@ -4,16 +4,20 @@ tracing & post-mortem timelines").
 
 The runtime is instrumented with `with tracer.span("name"):` blocks at
 every phase boundary (search enumerate/prune/simulate, compile, executor
-step dispatch, checkpoint save/restore, the elastic recovery pipeline,
-serving request handling). The contract that keeps this free to leave in
-hot loops:
+step dispatch, the fit() dispatch loop, checkpoint save/restore, the
+elastic recovery pipeline, the serving scheduler's iteration). Every
+span is written to two places:
 
- - DISABLED (the default): `span()` is one attribute check returning a
-   shared no-op context manager — no allocation, no clock read, no lock.
-   `tests/test_obs.py` bounds the overhead.
- - ENABLED: each span costs two monotonic clock reads plus one dict
-   append under a lock; the buffer is a ring (`max_events`) so a long
-   training run cannot grow memory without bound. Ring overflow is
+ - THE PROFILER'S TRACE, always: the span opens a
+   `jax.profiler.TraceAnnotation` of its name carrying its scalar args,
+   so any profile (`runtime/profiling.trace()`, a benchmark's capture)
+   shows the program's spans on the thread that did the work and on the
+   device trace's clock. Outside a profiler session an annotation is a
+   TraceMe that checks "is anyone recording" and does nothing more
+   (under a microsecond; `tests/test_obs.py` bounds it).
+ - THE RING, only while `enabled`: two monotonic clock reads plus one
+   dict append under a lock; the buffer is a ring (`max_events`) so a
+   long training run cannot grow memory without bound. Ring overflow is
    COUNTED (`dropped_events`, mirrored onto
    `ff_trace_events_dropped_total` and stamped into the exported trace
    metadata) so a truncated timeline is never mistaken for a complete
@@ -29,8 +33,10 @@ the sending side captures `tracer.handoff(name)` (which emits a Chrome
 flow-start "s" event so Perfetto draws the arrow) and the receiving
 thread runs its work under `with tracer.resume(handoff):` (flow-finish
 "f" on first resume, context restored on every resume). Both return
-no-ops when tracing is disabled or no context is current, so the
-serving hot path pays nothing by default.
+no-ops when the ring is disabled or no context is current, so the
+serving hot path pays nothing by default. Request contexts live in the
+ring only: the profiler's copy of a span names its request by the
+`request` arg.
 
 Export is the Chrome trace-event JSON format (complete "X" events with
 `name`/`ph`/`ts`/`dur`/`pid`/`tid`, flow "s"/"f" events for handoffs),
@@ -46,6 +52,7 @@ from __future__ import annotations
 import contextvars
 import itertools
 import json
+import numbers
 import os
 import threading
 import time
@@ -55,7 +62,7 @@ from typing import Any, Dict, List, Optional
 
 
 class _NullSpan:
-    """The disabled-path context manager: a shared, stateless no-op."""
+    """A shared, stateless no-op context manager (`resume()` of nothing)."""
 
     __slots__ = ()
 
@@ -70,6 +77,41 @@ class _NullSpan:
 
 
 _NULL_SPAN = _NullSpan()
+
+
+# -- the profiler's copy of a span -------------------------------------------
+_Annotation = None   # bound on first use: this module imports without jax
+
+
+def _bind_annotation():
+    from jax.profiler import TraceAnnotation
+
+    class _Annotation(TraceAnnotation):
+        """A span as the profiler sees it; `set()` matches `_Span.set`."""
+
+        __slots__ = ()
+
+        def set(self, **args):
+            self.set_metadata(**_scalars(args))
+            return self
+
+    return _Annotation
+
+
+def _scalars(args: Dict[str, Any]) -> Dict[str, Any]:
+    """The args an annotation can carry: TraceMe encodes them as `k=v`
+    pairs separated by commas after the event's name, so lists and dicts
+    (`requests=[...]`) stay in the ring."""
+    return {k: v for k, v in args.items()
+            if isinstance(v, (int, float, str))
+            or isinstance(v, numbers.Number)}
+
+
+def _annotation(name: str, args: Dict[str, Any]):
+    global _Annotation
+    if _Annotation is None:
+        _Annotation = _bind_annotation()
+    return _Annotation(name, **_scalars(args))
 
 
 # -- request context -------------------------------------------------------
@@ -193,28 +235,36 @@ class _Resume:
 
 
 class _Span:
-    __slots__ = ("_tracer", "name", "args", "_t0", "_ctx", "_token")
+    """A span while the ring is enabled: the ring's record plus the
+    profiler's annotation, opened and closed together."""
+
+    __slots__ = ("_tracer", "name", "args", "_t0", "_ctx", "_token",
+                 "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, args: Dict[str, Any],
-                 parent: Optional[TraceContext]):
+                 parent: Optional[TraceContext], annotation):
         self._tracer = tracer
         self.name = name
         self.args = args
         self._ctx = parent.child() if parent is not None else None
+        self._annotation = annotation
 
     def set(self, **args) -> "_Span":
         """Attach/override args mid-span (e.g. a result count discovered
         while the span is open)."""
         self.args.update(args)
+        self._annotation.set(**args)
         return self
 
     def __enter__(self):
+        self._annotation.__enter__()
         self._token = _CTX.set(self._ctx) if self._ctx is not None else None
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter_ns()
+        self._annotation.__exit__(exc_type, exc, tb)
         if self._token is not None:
             _CTX.reset(self._token)
         if exc_type is not None:
@@ -257,10 +307,20 @@ class Tracer:
 
     # -- recording --------------------------------------------------------
     def span(self, name: str, **args):
-        """Context manager timing a block. Near-zero cost when disabled."""
+        """Context manager timing a block: always a profiler annotation
+        (free outside a profiler session), and a ring record while
+        enabled. Arguments that cost something to build belong in
+        `.set()` under `if tracer.enabled`."""
+        annotation = _annotation(name, args)
         if not self.enabled:
-            return _NULL_SPAN
-        return _Span(self, name, args, _CTX.get())
+            return annotation
+        return _Span(self, name, args, _CTX.get(), annotation)
+
+    def step(self, name: str, step_num: int, **args):
+        """A span that is also a step marker of the profiler
+        (`jax.profiler.StepTraceAnnotation`: `_r=1` beside `step_num`),
+        so its step view groups device work by the program's steps."""
+        return self.span(name, step_num=step_num, _r=1, **args)
 
     def instant(self, name: str, **args) -> None:
         """A zero-duration marker (Chrome "i" event) — e.g. the moment a
@@ -450,13 +510,10 @@ def traced_dispatch(fn, name: str):
     """Wrap a jitted step function so each host-side dispatch becomes a
     span. The wall time is the DISPATCH (host call until the result's
     futures are returned), not device completion — jax dispatch is async;
-    the per-step wall clock lives in StepStats. Disabled tracing is one
-    attribute check per call."""
+    the per-step wall clock lives in StepStats."""
     tr = _TRACER
 
     def wrapper(*a, **k):
-        if not tr.enabled:
-            return fn(*a, **k)
         with tr.span(name):
             return fn(*a, **k)
 

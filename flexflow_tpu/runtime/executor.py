@@ -25,6 +25,12 @@ from ..ops.common import emit_dtype
 from .metrics import Metrics
 
 
+def _loss_scope(loss_fn) -> str:
+    """`loss:<name>`, the device name of the loss's ops."""
+    name = getattr(loss_fn, "__name__", "")
+    return "loss:" + (name if name.isidentifier() else "custom")
+
+
 class Executor:
     def __init__(self, graph: Graph, config, mesh=None,
                  reduction_plan=None):
@@ -304,10 +310,14 @@ class Executor:
                     p, state, inputs, rng, CompMode.COMP_MODE_TRAINING
                 )
                 pred = values[final_tensor.guid]
-                loss = loss_fn(pred, label) + aux
+                # what runs outside the graph gets a device name too
+                # (forward_values scopes every graph op)
+                with jax.named_scope(_loss_scope(loss_fn)):
+                    loss = loss_fn(pred, label) + aux
                 if reg_fn is not None:
                     loss = loss + reg_fn(p)
-                mvals = metrics.compute(pred, label) if metrics else {}
+                with jax.named_scope("metrics:compute"):
+                    mvals = metrics.compute(pred, label) if metrics else {}
                 return loss, (mvals, new_state)
 
             (loss, (mvals, new_state)), grads = jax.value_and_grad(
@@ -338,7 +348,9 @@ class Executor:
 
         def train_step(params, opt_state, state, inputs, label, rng):
             grads, mvals, new_state = gstep(params, state, inputs, label, rng)
-            new_params, new_opt_state = optimizer.update(params, grads, opt_state)
+            with jax.named_scope("optimizer:update"):
+                new_params, new_opt_state = optimizer.update(
+                    params, grads, opt_state)
             return new_params, new_opt_state, new_state, mvals
 
         # elastic retry re-dispatches the SAME arguments after a transient
@@ -373,8 +385,9 @@ class Executor:
             params, opt_state, state = carry
             inputs, label, rng = xs
             grads, mvals, new_state = gstep(params, state, inputs, label, rng)
-            new_params, new_opt_state = optimizer.update(
-                params, grads, opt_state)
+            with jax.named_scope("optimizer:update"):
+                new_params, new_opt_state = optimizer.update(
+                    params, grads, opt_state)
             return (new_params, new_opt_state, new_state), mvals
 
         def multi_step(params, opt_state, state, inputs_k, label_k, rng_k):
@@ -397,8 +410,10 @@ class Executor:
                 params, state, inputs, None, CompMode.COMP_MODE_INFERENCE
             )
             pred = values[final_tensor.guid]
-            mvals = metrics.compute(pred, label) if metrics else {}
-            mvals["loss"] = loss_fn(pred, label)
+            with jax.named_scope("metrics:compute"):
+                mvals = metrics.compute(pred, label) if metrics else {}
+            with jax.named_scope(_loss_scope(loss_fn)):
+                mvals["loss"] = loss_fn(pred, label)
             return mvals, pred
 
         self._eval_step = traced_dispatch(jax.jit(eval_step),
@@ -433,7 +448,8 @@ class Executor:
                     p, state, inputs, rng, CompMode.COMP_MODE_TRAINING,
                     seq_length=seq_length
                 )
-                return loss_fn(values[final_tensor.guid], label) + aux
+                with jax.named_scope(_loss_scope(loss_fn)):
+                    return loss_fn(values[final_tensor.guid], label) + aux
 
             return jax.grad(loss_of)(params)
 

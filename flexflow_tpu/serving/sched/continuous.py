@@ -600,6 +600,7 @@ class ContinuousBatcher:
         self._running = False
         self._thread: Optional[threading.Thread] = None
         self._completed = 0
+        self._retired = 0   # by this loop alone (scheduler thread only)
         self._failed = 0
         # fleet health signals (serving/fleet/health.py): the scheduler
         # stamps a heartbeat at the top of EVERY loop iteration (the idle
@@ -820,7 +821,8 @@ class ContinuousBatcher:
                 params, st, {input_name: toks[:, None]}, None,
                 CompMode.COMP_MODE_INFERENCE, decode_pos=pos)
             probs = values[final_guid][:, 0, :]  # (S, V)
-            next_tok = jax.vmap(pick_row)(probs, pos, keys)
+            with jax.named_scope("sample:pick"):
+                next_tok = jax.vmap(pick_row)(probs, pos, keys)
             new_caches = {
                 name: {"k_cache": new_state[name]["k_cache"],
                        "v_cache": new_state[name]["v_cache"]}
@@ -854,19 +856,18 @@ class ContinuousBatcher:
             batch-1 caches carry chunk-1 slack rows (see _zero_small)
             that must not spill into the pool slot."""
             out = {}
-            for name in attn_names_:
-                kc = pool_caches[name]["k_cache"]
-                vc = pool_caches[name]["v_cache"]
-                out[name] = {
-                    "k_cache": jax.lax.dynamic_update_slice(
-                        kc,
-                        small[name]["k_cache"][:, :max_len].astype(kc.dtype),
-                        (slot, 0, 0, 0)),
-                    "v_cache": jax.lax.dynamic_update_slice(
-                        vc,
-                        small[name]["v_cache"][:, :max_len].astype(vc.dtype),
-                        (slot, 0, 0, 0)),
-                }
+            with jax.named_scope("kv:scatter_span"):
+                for name in attn_names_:
+                    kc = pool_caches[name]["k_cache"]
+                    vc = pool_caches[name]["v_cache"]
+                    out[name] = {
+                        "k_cache": jax.lax.dynamic_update_slice(
+                            kc, small[name]["k_cache"][:, :max_len].astype(
+                                kc.dtype), (slot, 0, 0, 0)),
+                        "v_cache": jax.lax.dynamic_update_slice(
+                            vc, small[name]["v_cache"][:, :max_len].astype(
+                                vc.dtype), (slot, 0, 0, 0)),
+                    }
             return out
 
         def prefill_chunk(params, state, small, tokens, off):
@@ -879,9 +880,10 @@ class ContinuousBatcher:
 
         def _scatter_and_pick(caches, small, slot, probs, idx, pos, key):
             new_caches = scatter_span(caches, small, slot, attn_names)
-            row = jax.lax.dynamic_slice(
-                probs, (0, idx, 0), (1, 1, probs.shape[2]))[0, 0]  # (V,)
-            tok = pick_row(row, pos, key)
+            with jax.named_scope("sample:pick"):
+                row = jax.lax.dynamic_slice(
+                    probs, (0, idx, 0), (1, 1, probs.shape[2]))[0, 0]  # (V,)
+                tok = pick_row(row, pos, key)
             return tok, new_caches
 
         def prefill_last_chunk(params, state, caches, small, tokens, off,
@@ -905,21 +907,22 @@ class ContinuousBatcher:
             recomputing the prefix."""
             keep = (jnp.arange(max_len) < n_rows)[:, None, None]
             out = {}
-            for name in attn_names:
-                gk = band[name]["k_cache"][src_slot, src_row]  # (M, h, d)
-                gv = band[name]["v_cache"][src_slot, src_row]
-                sk = small[name]["k_cache"]  # (1, max_len + slack, h, d)
-                sv = small[name]["v_cache"]
-                out[name] = {
-                    # update the first max_len rows; the slack tail (see
-                    # _zero_small) passes through untouched
-                    "k_cache": jax.lax.dynamic_update_slice(
-                        sk, jnp.where(keep, gk, sk[0, :max_len])[None],
-                        (0, 0, 0, 0)),
-                    "v_cache": jax.lax.dynamic_update_slice(
-                        sv, jnp.where(keep, gv, sv[0, :max_len])[None],
-                        (0, 0, 0, 0)),
-                }
+            with jax.named_scope("kv:prefix"):
+                for name in attn_names:
+                    gk = band[name]["k_cache"][src_slot, src_row]  # (M, h, d)
+                    gv = band[name]["v_cache"][src_slot, src_row]
+                    sk = small[name]["k_cache"]  # (1, max_len + slack, h, d)
+                    sv = small[name]["v_cache"]
+                    out[name] = {
+                        # update the first max_len rows; the slack tail
+                        # (see _zero_small) passes through untouched
+                        "k_cache": jax.lax.dynamic_update_slice(
+                            sk, jnp.where(keep, gk, sk[0, :max_len])[None],
+                            (0, 0, 0, 0)),
+                        "v_cache": jax.lax.dynamic_update_slice(
+                            sv, jnp.where(keep, gv, sv[0, :max_len])[None],
+                            (0, 0, 0, 0)),
+                    }
             return out
 
         def insert_pages(band, caches, slot, src_rows, dst_slots, dst_rows):
@@ -931,15 +934,16 @@ class ContinuousBatcher:
             once. Band pages are written exactly once, before their
             entries become matchable — the immutability half of CoW."""
             new_band = {}
-            for name in attn_names:
-                rows_k = caches[name]["k_cache"][slot, src_rows]
-                rows_v = caches[name]["v_cache"][slot, src_rows]
-                new_band[name] = {
-                    "k_cache": band[name]["k_cache"].at[
-                        dst_slots, dst_rows].set(rows_k),
-                    "v_cache": band[name]["v_cache"].at[
-                        dst_slots, dst_rows].set(rows_v),
-                }
+            with jax.named_scope("kv:prefix"):
+                for name in attn_names:
+                    rows_k = caches[name]["k_cache"][slot, src_rows]
+                    rows_v = caches[name]["v_cache"][slot, src_rows]
+                    new_band[name] = {
+                        "k_cache": band[name]["k_cache"].at[
+                            dst_slots, dst_rows].set(rows_k),
+                        "v_cache": band[name]["v_cache"].at[
+                            dst_slots, dst_rows].set(rows_v),
+                    }
             return new_band
 
         # donate the pool caches: the scheduler always threads the newest
@@ -1747,123 +1751,119 @@ class ContinuousBatcher:
         return out
 
     # -- scheduler loop ----------------------------------------------------
-    def _loop(self) -> None:
-        import jax.numpy as jnp
+    def _idle_locked(self) -> bool:
+        """Nothing to schedule (caller holds _cv). PARKED slots hold KV
+        for the fleet handoff plane but schedule nothing — they must not
+        keep the loop spinning hot, nor block a clean stop (stop() fails
+        them after the join)."""
+        return (self._running and not self._queue
+                and not self._runnable_locked()
+                and self._pending_resize is None
+                and not self._pending_handoffs)
 
+    def _loop(self) -> None:
         from ...obs.tracing import get_tracer
 
         tracer = get_tracer()
         tracer.set_thread_name(self.trace_label)
         params = self.model.params
         state = self.model.state
+        n_iter = 0
         try:
             while True:
                 with self._cv:
-                    # PARKED slots hold KV for the fleet handoff plane
-                    # but schedule nothing — they must not keep the loop
-                    # spinning hot, nor block a clean stop (stop() fails
-                    # them after the join)
-                    while (self._running and not self._queue
-                           and not self._runnable_locked()
-                           and self._pending_resize is None
-                           and not self._pending_handoffs):
-                        # an idle loop is a HEALTHY loop: stamp the
-                        # heartbeat on every 0.1 s wake so the monitor
-                        # can tell "no work" from "hung dispatch"
-                        self._t_heartbeat = time.monotonic()
-                        self._cv.wait(timeout=0.1)
+                    if self._idle_locked():
+                        with tracer.span("serve.wait"):
+                            while self._idle_locked():
+                                # an idle loop is a HEALTHY loop: stamp
+                                # the heartbeat on every 0.1 s wake so the
+                                # monitor can tell "no work" from "hung
+                                # dispatch"
+                                self._t_heartbeat = time.monotonic()
+                                self._cv.wait(timeout=0.1)
                     if not self._running and not self._runnable_locked():
                         break
                     running = self._running
+                n_iter += 1
+                # one pass = one parent span; every phase inside is a
+                # child on this thread, so the parent's self time is the
+                # loop's own overhead (and a fault hook's stall)
+                with tracer.span("serve.iter", iter=n_iter) as it:
+                    self._iterate(params, state, tracer, it, running)
+        except BaseException as e:  # scheduler died: fail everything
+            self._fail_all(e)
+        finally:
+            self._g_active.set(0, pool=self.pool.label)
 
-                # health signals + chaos: stamp the heartbeat, sample
-                # the busy-gap step latency (gaps after an iteration
-                # that HAD work — so hook stalls and slow dispatches
-                # count, idle 0.1 s waits do not), then run the fault
-                # hook: a raise kills the loop like any scheduler bug,
-                # a sleep registers as a hang/straggle.
-                now = time.monotonic()
-                self._t_heartbeat = now
-                if self._iter_had_work and self._t_iter_prev is not None:
-                    self._observe_step_gap(now - self._t_iter_prev)
-                self._t_iter_prev = now
-                self._iter_had_work = bool(self._queue) or any(self._slots)
-                hook = self.fault_hook
-                if hook is not None:
-                    hook(self)
+    def _iterate(self, params, state, tracer, it, running: bool) -> None:
+        """One pass of the scheduler loop under its `serve.iter` span
+        `it`, which leaves with the pass's counters as args."""
+        # health signals + chaos: stamp the heartbeat, sample the
+        # busy-gap step latency (gaps after an iteration that HAD work —
+        # so hook stalls and slow dispatches count, idle 0.1 s waits do
+        # not), then run the fault hook: a raise kills the loop like any
+        # scheduler bug, a sleep registers as a hang/straggle.
+        now = time.monotonic()
+        self._t_heartbeat = now
+        if self._iter_had_work and self._t_iter_prev is not None:
+            self._observe_step_gap(now - self._t_iter_prev)
+        self._t_iter_prev = now
+        self._iter_had_work = bool(self._queue) or any(self._slots)
+        hook = self.fault_hook
+        if hook is not None:
+            hook(self)
+        queue_depth = len(self._queue)
+        emitted0, retired0 = self.tokens_emitted, self._retired
 
-                # 0) apply a pending mesh resize (a shrink defers until
-                #    live sequences fit; admissions are held meanwhile)
-                if self._pending_resize is not None:
-                    self._maybe_resize(tracer)
+        # 0) apply a pending mesh resize (a shrink defers until live
+        #    sequences fit; admissions are held meanwhile)
+        if self._pending_resize is not None:
+            self._maybe_resize(tracer)
 
-                # 0b) disaggregated KV handoff steps (export parked
-                #     rows / import shipped ones) — scheduler thread
-                #     only, same donated-cache rule as the resize
-                if self._pending_handoffs:
-                    self._process_handoffs(tracer)
+        # 0b) disaggregated KV handoff steps (export parked rows / import
+        #     shipped ones) — scheduler thread only, same donated-cache
+        #     rule as the resize
+        if self._pending_handoffs:
+            self._process_handoffs(tracer)
 
-                # 1) move queued requests into free slots (skipped once
-                #    stopping: queued requests fail fast in stop()). In
-                #    one-shot mode this runs the whole prefill; in chunked
-                #    mode it only installs any cached prefix and arms the
-                #    resumable PREFILL state.
-                if running:
-                    self._admit_new(params, state, tracer)
+        # 1) move queued requests into free slots (skipped once stopping:
+        #    queued requests fail fast in stop()). In one-shot mode this
+        #    runs the whole prefill; in chunked mode it only installs any
+        #    cached prefix and arms the resumable PREFILL state.
+        admitted = 0
+        if running:
+            with tracer.span("serve.schedule") as sp:
+                admitted = self._admit_new(params, state, tracer)
+                sp.set(admitted=admitted)
 
-                # 2) one prefill chunk per PREFILLING slot — interleaved
-                #    with decode so a long prompt costs in-flight decodes
-                #    one chunk of latency per iteration, not its whole
-                #    prefill
-                self._step_prefills(params, state, tracer)
+        # 2) one prefill chunk per PREFILLING slot — interleaved with
+        #    decode so a long prompt costs in-flight decodes one chunk of
+        #    latency per iteration, not its whole prefill
+        prefill_slots, prefill_chunks, prefill_tokens = \
+            self._step_prefills(params, state, tracer)
 
-                # 3) one decode iteration over all DECODING slots
-                active = [s for s in self._slots if s is not None
-                          and s.req.state is RequestState.DECODE]
-                if not active:
-                    continue
-                toks = np.zeros(self.num_slots, np.int32)
-                pos = np.zeros(self.num_slots, np.int32)
-                keys = np.zeros((self.num_slots, 2), np.uint32)
-                for s in self._slots:
-                    if s is not None \
-                            and s.req.state is not RequestState.DECODE:
-                        # the decode dispatch writes one KV row at `pos`
-                        # for EVERY slot, active or not. An owned but
-                        # non-decoding slot (PARKED awaiting handoff,
-                        # mid-chunk PREFILL) must not take that dummy
-                        # write at row 0 of its live pages — aim it at
-                        # the slot's own next-write row instead: beyond
-                        # `filled`, never attended, and overwritten by
-                        # the slot's next real fill
-                        pos[s.slot] = min(int(s.pos),
-                                          self.pool.max_len - 1)
-                for s in active:
-                    if s.shared and s.pos < s.shared:
-                        # copy-on-write break: this decode writes inside
-                        # pages the sequence still shares. Its slot rows
-                        # are already the private copy, so only the share
-                        # is severed — unreachable with page-aligned
-                        # matching (decode writes at pos >= plen >=
-                        # shared), but enforced, not assumed.
-                        self.pool.prefix.cow_break(s.req.id, s.pos)
-                        s.shared = (s.pos // self.pool.page_size
-                                    ) * self.pool.page_size
-                    toks[s.slot] = s.last_tok
-                    pos[s.slot] = s.pos
-                    keys[s.slot] = s.key
-                if self.spec_tokens:
-                    self._spec_iterate(params, state, tracer, active,
-                                       toks, pos)
-                    continue
-                with tracer.span("serve.decode", slots=len(active),
-                                 requests=[s.req.id for s in active]):
-                    t0 = time.monotonic()
+        # 3) one decode iteration over all DECODING slots
+        with tracer.span("serve.decode_stage") as sp:
+            active = [s for s in self._slots if s is not None
+                      and s.req.state is RequestState.DECODE]
+            sp.set(slots=len(active))
+            if active:
+                toks, pos, keys = self._stage_decode(active)
+        if active and self.spec_tokens:
+            self._spec_iterate(params, state, tracer, active, toks, pos)
+        elif active:
+            with tracer.span("serve.decode", slots=len(active)) as sp:
+                if tracer.enabled:
+                    sp.set(requests=[s.req.id for s in active])
+                t0 = time.monotonic()
+                with tracer.span("serve.decode_dispatch"):
                     next_tok, self._caches = self._decode_fn(
-                        params, state, self._caches, jnp.asarray(toks),
-                        jnp.asarray(pos), jnp.asarray(keys))
+                        params, state, self._caches, toks, pos, keys)
+                with tracer.span("serve.decode_fetch"):
                     next_tok = np.asarray(next_tok)  # sync
-                    self._observe_decode_iter(time.monotonic() - t0)
+                self._observe_decode_iter(time.monotonic() - t0)
+            with tracer.span("serve.emit") as sp:
+                e0, r0 = self.tokens_emitted, self._retired
                 now = time.monotonic()
                 for s in active:
                     self._h_itl.observe((now - s.t_last_emit) * 1e3)
@@ -1871,10 +1871,48 @@ class ContinuousBatcher:
                     self.pool.extend(s.req.id, 1)
                     s.pos += 1
                     self._emit_token(s, int(next_tok[s.slot]))
-        except BaseException as e:  # scheduler died: fail everything
-            self._fail_all(e)
-        finally:
-            self._g_active.set(0, pool=self.pool.label)
+                sp.set(emitted=self.tokens_emitted - e0,
+                       retired=self._retired - r0)
+        it.set(queue_depth=queue_depth, decode_slots=len(active),
+               prefill_slots=prefill_slots, admitted=admitted,
+               prefill_chunks=prefill_chunks, prefill_tokens=prefill_tokens,
+               emitted=self.tokens_emitted - emitted0,
+               retired=self._retired - retired0)
+
+    def _stage_decode(self, active):
+        """The decode dispatch's operands, placed on the device: per slot
+        the last token, the write position and the PRNG key (which the
+        speculative step, greedy, does not take)."""
+        import jax.numpy as jnp
+
+        toks = np.zeros(self.num_slots, np.int32)
+        pos = np.zeros(self.num_slots, np.int32)
+        keys = np.zeros((self.num_slots, 2), np.uint32)
+        for s in self._slots:
+            if s is not None and s.req.state is not RequestState.DECODE:
+                # the decode dispatch writes one KV row at `pos` for EVERY
+                # slot, active or not. An owned but non-decoding slot
+                # (PARKED awaiting handoff, mid-chunk PREFILL) must not
+                # take that dummy write at row 0 of its live pages — aim
+                # it at the slot's own next-write row instead: beyond
+                # `filled`, never attended, and overwritten by the slot's
+                # next real fill
+                pos[s.slot] = min(int(s.pos), self.pool.max_len - 1)
+        for s in active:
+            if s.shared and s.pos < s.shared:
+                # copy-on-write break: this decode writes inside pages
+                # the sequence still shares. Its slot rows are already
+                # the private copy, so only the share is severed —
+                # unreachable with page-aligned matching (decode writes
+                # at pos >= plen >= shared), but enforced, not assumed.
+                self.pool.prefix.cow_break(s.req.id, s.pos)
+                s.shared = (s.pos // self.pool.page_size
+                            ) * self.pool.page_size
+            toks[s.slot] = s.last_tok
+            pos[s.slot] = s.pos
+            keys[s.slot] = s.key
+        return (jnp.asarray(toks), jnp.asarray(pos),
+                None if self.spec_tokens else jnp.asarray(keys))
 
     def _spec_iterate(self, params, state, tracer, active, toks,
                       pos) -> None:
@@ -1885,59 +1923,64 @@ class ContinuousBatcher:
         over accepted tokens — a rejected suffix is rolled back by NOT
         advancing it, never by touching the cache (its rows are masked
         out and rewritten before any later query can attend them), so
-        other slots' pages are never involved."""
-        import jax.numpy as jnp
-
+        other slots' pages are never involved. `toks` / `pos` are the
+        staged device operands."""
         draft = self.draft_model
         with tracer.span("serve.spec_verify", slots=len(active),
                          k=self.spec_tokens):
             t0 = time.monotonic()
-            emitted, counts, n_acc, self._caches, self._draft_caches = \
-                self._spec_fn(params, state, self._caches, draft.params,
-                              draft.state, self._draft_caches,
-                              jnp.asarray(toks), jnp.asarray(pos))
-            emitted = np.asarray(emitted)
-            counts = np.asarray(counts)
-            n_acc = np.asarray(n_acc)  # sync
+            with tracer.span("serve.decode_dispatch"):
+                emitted, counts, n_acc, self._caches, self._draft_caches = \
+                    self._spec_fn(params, state, self._caches, draft.params,
+                                  draft.state, self._draft_caches, toks, pos)
+            with tracer.span("serve.decode_fetch"):
+                emitted = np.asarray(emitted)
+                counts = np.asarray(counts)
+                n_acc = np.asarray(n_acc)  # sync
             dt = time.monotonic() - t0
-        # acceptance counts RAW verify matches (draft quality, not the
-        # emission cap's m-1 — a perfect draft reads 1.0, not (k-1)/k),
-        # but only proposals that could still MATTER: a slot with r
-        # budget tokens left can use at most r-1 proposals, and queries
-        # past the budget (which is also the cache edge, plen+max_new <=
-        # max_len) are garbage whose argmax matches mean nothing
-        proposed = accepted = 0
-        for s in active:
-            useful = min(self.spec_tokens,
-                         s.req.max_new_tokens - s.emitted - 1)
-            if useful <= 0:
-                continue
-            proposed += useful
-            accepted += min(int(n_acc[s.slot]), useful)
-        self._spec_proposed += proposed
-        self._spec_accepted += accepted
-        self._c_spec_proposed.inc(proposed)
-        self._c_spec_accepted.inc(accepted)
-        if proposed:
-            rate = accepted / proposed
-            old = self._ewma_spec_accept
-            self._ewma_spec_accept = rate if old is None else \
-                (1 - self._EWMA_ALPHA) * old + self._EWMA_ALPHA * rate
-            self._g_spec_accept.set(self._ewma_spec_accept,
-                                    pool=self.pool.label)
-        self._observe_decode_iter(dt)
-        now = time.monotonic()
-        for s in active:
-            m = int(counts[s.slot])
-            for i in range(m):
-                self._h_itl.observe((now - s.t_last_emit) * 1e3)
-                s.t_last_emit = now
-                self.pool.extend(s.req.id, 1)
-                s.pos += 1
-                self._emit_token(s, int(emitted[s.slot, i]))
-                if s.req.state is not RequestState.DECODE:
-                    break  # retired (EOS/budget): the rest of the
-                    #        window is garbage past the sequence end
+        with tracer.span("serve.emit") as sp:
+            e0, r0 = self.tokens_emitted, self._retired
+            # acceptance counts RAW verify matches (draft quality, not
+            # the emission cap's m-1 — a perfect draft reads 1.0, not
+            # (k-1)/k), but only proposals that could still MATTER: a
+            # slot with r budget tokens left can use at most r-1
+            # proposals, and queries past the budget (which is also the
+            # cache edge, plen+max_new <= max_len) are garbage whose
+            # argmax matches mean nothing
+            proposed = accepted = 0
+            for s in active:
+                useful = min(self.spec_tokens,
+                             s.req.max_new_tokens - s.emitted - 1)
+                if useful <= 0:
+                    continue
+                proposed += useful
+                accepted += min(int(n_acc[s.slot]), useful)
+            self._spec_proposed += proposed
+            self._spec_accepted += accepted
+            self._c_spec_proposed.inc(proposed)
+            self._c_spec_accepted.inc(accepted)
+            if proposed:
+                rate = accepted / proposed
+                old = self._ewma_spec_accept
+                self._ewma_spec_accept = rate if old is None else \
+                    (1 - self._EWMA_ALPHA) * old + self._EWMA_ALPHA * rate
+                self._g_spec_accept.set(self._ewma_spec_accept,
+                                        pool=self.pool.label)
+            self._observe_decode_iter(dt)
+            now = time.monotonic()
+            for s in active:
+                m = int(counts[s.slot])
+                for i in range(m):
+                    self._h_itl.observe((now - s.t_last_emit) * 1e3)
+                    s.t_last_emit = now
+                    self.pool.extend(s.req.id, 1)
+                    s.pos += 1
+                    self._emit_token(s, int(emitted[s.slot, i]))
+                    if s.req.state is not RequestState.DECODE:
+                        break  # retired (EOS/budget): the rest of the
+                        #        window is garbage past the sequence end
+            sp.set(emitted=self.tokens_emitted - e0,
+                   retired=self._retired - r0)
 
     def _maybe_resize(self, tracer) -> None:
         """Apply the pending resize (scheduler thread only). The
@@ -2103,24 +2146,26 @@ class ContinuousBatcher:
                                      pool=self.pool.label)
         return req
 
-    def _admit_new(self, params, state, tracer) -> None:
-        """Move queued requests into free slots. One-shot mode runs the
-        whole prefill here (the pre-chunking behavior); chunked mode pins +
-        installs any cached prefix and leaves the slot in the resumable
-        PREFILL state for `_step_prefills`."""
+    def _admit_new(self, params, state, tracer) -> int:
+        """Move queued requests into free slots; returns how many. One-shot
+        mode runs the whole prefill here (the pre-chunking behavior);
+        chunked mode pins + installs any cached prefix and leaves the slot
+        in the resumable PREFILL state for `_step_prefills`."""
         import jax
         import jax.numpy as jnp
 
+        admitted = 0
         while True:
             with self._cv:
                 if self._pending_resize is not None:
                     # hold admissions while a resize is pending: a shrink
                     # is waiting for live sequences to drain, and filling
                     # freed slots would starve it
-                    return
+                    return admitted
                 if not self._queue or self.pool.free_slot_count() == 0:
-                    return
+                    return admitted
                 req = self._pop_next_locked()
+            admitted += 1
             req.state = RequestState.PREFILL
             req.queue_wait_s = self.admission.on_scheduled(req.id)
             plen = req.prompt.size
@@ -2183,21 +2228,22 @@ class ContinuousBatcher:
                     req.prefix_tokens = matched
                     req.cache_hit = True
 
-    def _step_prefills(self, params, state, tracer) -> None:
+    def _step_prefills(self, params, state, tracer):
         """One prefill chunk for every slot in the PREFILL state; a slot
         whose prompt completes scatters its cache span into the pool,
-        emits its first token, and joins this iteration's decode."""
+        emits its first token, and joins this iteration's decode. Returns
+        (slots prefilling, chunks run, prompt tokens in them)."""
         import jax.numpy as jnp
 
         chunk = self.prefill_chunk_tokens
-        for s in [x for x in self._slots
-                  if x is not None and x.req.state is RequestState.PREFILL]:
+        prefilling = [x for x in self._slots
+                      if x is not None and x.req.state is RequestState.PREFILL]
+        chunks = chunk_tokens = 0
+        for s in prefilling:
             if self.draft_model is not None and s.draft_filled < s.plen:
                 self._step_draft_prefill(s, tracer)
             off = s.filled
             n = min(chunk, s.plen - off)
-            tokens = np.zeros((1, chunk), np.int32)
-            tokens[0, :n] = s.req.prompt[off:off + n]
             last = off + n >= s.plen
             if (last and self.draft_model is not None
                     and s.draft_filled < s.plen):
@@ -2206,9 +2252,13 @@ class ContinuousBatcher:
                 # has the full prompt — the next spec iteration needs
                 # both sides of the sequence
                 continue
+            chunks += 1
+            chunk_tokens += n
             with tracer.resume(s.req.trace), \
                     tracer.span("serve.prefill", request=s.req.id,
                                 offset=off, tokens=n):
+                tokens = np.zeros((1, chunk), np.int32)
+                tokens[0, :n] = s.req.prompt[off:off + n]
                 if not last:
                     probs, s.small = self._chunk_fn(
                         params, state, s.small, jnp.asarray(tokens),
@@ -2231,7 +2281,10 @@ class ContinuousBatcher:
             s.filled = s.pos = s.plen
             s.last_tok = tok
             self._insert_prefix(s, tracer)
-            self._first_token(s, tok)
+            with tracer.resume(s.req.trace), \
+                    tracer.span("serve.emit", request=s.req.id, emitted=1):
+                self._first_token(s, tok)
+        return len(prefilling), chunks, chunk_tokens
 
     def _step_draft_prefill(self, s: _Slot, tracer) -> None:
         """One DRAFT prefill chunk for a speculative slot (scheduler
@@ -2349,6 +2402,7 @@ class ContinuousBatcher:
             self._retire(s)
 
     def _retire(self, s: _Slot) -> None:
+        self._retired += 1
         self._slots[s.slot] = None
         with self._cv:
             self._parked.pop(s.req.id, None)

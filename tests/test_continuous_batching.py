@@ -237,6 +237,83 @@ def test_continuous_sampling_deterministic_and_traffic_independent(lm):
     assert not np.array_equal(alone, other)
 
 
+def test_scheduler_iteration_is_covered_by_spans(lm):
+    """Every pass of the loop is one `serve.iter` span whose children name
+    each phase: with a decode slowed to the scale of a real step (50 ms)
+    they cover >= 95 % of every pass that decodes, and the passes' counters
+    add up to exactly what was served."""
+    import time
+
+    from flexflow_tpu import obs
+
+    prompts = _prompts([4, 7, 3, 6, 5], seed=2)
+    tr = obs.enable_tracing()
+    with ContinuousBatcher(lm, max_len=12, num_slots=2, page_size=4,
+                           max_queue=8, queue_pages_budget=64) as cb:
+        decode = cb._decode_fn
+
+        def slow_decode(*args):
+            time.sleep(0.05)
+            return decode(*args)
+
+        cb._decode_fn = slow_decode
+        outs = [r.result(timeout=300)
+                for r in [cb.submit(p, 5) for p in prompts]]
+    evs = [e for e in tr.events() if e["ph"] == "X"
+           and e["name"].startswith("serve.")]
+    iters = [e for e in evs if e["name"] == "serve.iter"]
+    assert [it["args"]["iter"] for it in iters] == \
+        list(range(1, len(iters) + 1))
+
+    def inside(e, it):
+        return (e is not it and e["tid"] == it["tid"] and it["ts"] <= e["ts"]
+                and e["ts"] + e["dur"] <= it["ts"] + it["dur"] + 1e-3)
+
+    seen = set()
+    for it in iters:
+        kids = sorted((e["ts"], e["ts"] + e["dur"], e["name"])
+                      for e in evs if inside(e, it))
+        seen.update(k[2] for k in kids)
+        covered, end = 0.0, it["ts"]
+        for lo, hi, _name in kids:       # nested children count once
+            covered += max(0.0, hi - max(lo, end))
+            end = max(end, hi)
+        if it["args"]["decode_slots"]:
+            assert covered >= 0.95 * it["dur"], (it, kids, covered)
+    assert {"serve.schedule", "serve.prefill", "serve.decode_stage",
+            "serve.decode", "serve.decode_dispatch", "serve.decode_fetch",
+            "serve.emit"} <= seen
+    total = {k: sum(it["args"][k] for it in iters)
+             for k in ("admitted", "emitted", "retired", "prefill_tokens")}
+    assert total == {"admitted": 5, "retired": 5,
+                     "emitted": sum(len(o) for o in outs),
+                     "prefill_tokens": sum(len(p) for p in prompts)}
+    # the decode span kept its meaning: dispatch + fetch, nothing else
+    for d in tr.events("serve.decode"):
+        assert d["dur"] >= 50e3 and "requests" in d["args"]
+
+
+def test_serving_steps_name_what_runs_outside_the_graph(lm):
+    """Sampling and the cache-span scatter carry device names in the
+    lowered decode and fused-finish programs."""
+    import jax.numpy as jnp
+
+    cb = ContinuousBatcher(lm, max_len=12, num_slots=2, page_size=4)
+    s = cb.num_slots
+    text = cb._decode_fn.lower(
+        lm.params, lm.state, cb._caches, jnp.zeros(s, jnp.int32),
+        jnp.zeros(s, jnp.int32), jnp.zeros((s, 2), jnp.uint32)
+    ).as_text(debug_info=True)
+    assert "sample:pick" in text and "multihead_attention:" in text
+    chunk = cb.prefill_chunk_tokens
+    text = cb._last_chunk_fn.lower(
+        lm.params, lm.state, cb._caches, cb._zero_small(),
+        jnp.zeros((1, chunk), jnp.int32), jnp.asarray(0, jnp.int32), 0,
+        jnp.asarray(0, jnp.int32), jnp.asarray(0, jnp.int32),
+        jnp.zeros(2, jnp.uint32)).as_text(debug_info=True)
+    assert "sample:pick" in text and "kv:scatter_span" in text
+
+
 def test_continuous_admission_rejections(lm):
     with ContinuousBatcher(lm, max_len=12, num_slots=1, page_size=4,
                            max_queue=2) as cb:

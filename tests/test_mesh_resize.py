@@ -255,6 +255,10 @@ def test_concurrent_admissions_during_deferred_shrink_queue_not_429(lm):
 
     b = ContinuousBatcher(lm, max_len=96, num_slots=3, page_size=4,
                           max_queue=16)
+    # a pass of the loop takes at least 10 ms, so the two decoders stay
+    # live for most of a second however fast the toy step is (with a warm
+    # compile cache 80 tokens used to finish inside the 0.2 s below)
+    b.fault_hook = lambda _b: time.sleep(0.01)
     with b:
         # long enough that the deferred window is seconds wide — the
         # mid-shrink asserts below must run while both are still live

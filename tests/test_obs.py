@@ -246,28 +246,88 @@ def test_span_records_exception_and_args():
 
 
 def test_disabled_tracing_is_effectively_free():
-    """ISSUE acceptance: spans compile to no-ops when disabled; the
-    enabled path is bounded. Min-of-repeats de-noises a loaded CI host;
-    the bounds are deliberately loose — the property pinned is the ORDER
-    of the overhead, not the constant."""
+    """ISSUE acceptance: with the ring disabled a span is a profiler
+    annotation that nobody records and nothing more; the enabled path is
+    bounded. Min-of-repeats de-noises a loaded CI host; the bounds are
+    deliberately loose — the property pinned is the ORDER of the overhead,
+    not the constant."""
     t = Tracer(enabled=False)
 
-    def per_span_us(n=5_000, repeats=5):
+    def per_call_us(call, n=5_000, repeats=5):
         best = float("inf")
         for _ in range(repeats):
             t0 = time.perf_counter()
             for _ in range(n):
-                with t.span("hot"):
-                    pass
+                call()
             best = min(best, (time.perf_counter() - t0) / n * 1e6)
         return best
 
-    disabled_us = per_span_us()
+    def one_span():
+        with t.span("hot", request=7):
+            pass
+
+    disabled_us = per_call_us(one_span)
     assert disabled_us < 20.0, f"disabled span cost {disabled_us:.2f}us"
     assert t.events() == []  # and truly recorded nothing
+    # the executor's dispatch wrapper rides the process tracer (disabled
+    # by the autouse reset): the same order of cost per dispatch
+    dispatch_us = per_call_us(obs.traced_dispatch(lambda: None, "hot"))
+    assert dispatch_us < 20.0, f"disabled dispatch cost {dispatch_us:.2f}us"
+    assert obs.get_tracer().events() == []
     t.enable()
-    enabled_us = per_span_us()
+    enabled_us = per_call_us(one_span)
     assert enabled_us < 250.0, f"enabled span cost {enabled_us:.2f}us"
+
+
+def _host_events(trace_dir, name):
+    """The profiler trace's host events called `name`, with their args."""
+    import glob
+    import os
+
+    import jax
+
+    (path,) = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    profile = jax.profiler.ProfileData.from_file(path)
+    return [(e, dict(e.stats)) for plane in profile.planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events if e.name == name]
+
+
+@pytest.mark.parametrize("ring", [False, True])
+def test_span_is_a_profiler_annotation_on_its_own_thread(tmp_path, ring):
+    """One clock: under a profiler session a span opened on a worker
+    thread is in the trace's host plane with its name and scalar args —
+    whether or not the ring records it too — and a list stays in the ring."""
+    import threading
+
+    from flexflow_tpu.runtime.profiling import trace
+
+    t = Tracer(enabled=ring)
+
+    def work():
+        with t.span("x", request=7, requests=[7, 8]) as sp:
+            time.sleep(0.002)
+            with t.step("x.step", 3):
+                pass
+            sp.set(emitted=2)
+
+    with trace(str(tmp_path)):
+        th = threading.Thread(target=work)
+        th.start()
+        th.join(timeout=60)
+        assert not th.is_alive()
+    ((ev, args),) = _host_events(str(tmp_path), "x")
+    assert args == {"request": 7, "emitted": 2}
+    assert ev.duration_ns >= 2e6
+    ((_, step_args),) = _host_events(str(tmp_path), "x.step")
+    assert step_args == {"step_num": 3, "_r": 1}   # a profiler step marker
+    if ring:
+        (rec,) = t.events("x")
+        assert rec["args"] == {"request": 7, "requests": [7, 8],
+                               "emitted": 2}
+    else:
+        assert t.events() == []
 
 
 def test_tracer_ring_bounds_memory():
@@ -562,13 +622,59 @@ def test_fit_records_step_stats_and_keeps_history_schema():
     assert obs.REGISTRY.counter("ff_train_steps_total", "").value() == 8
 
 
+def _inside(child, parent):
+    return (child["tid"] == parent["tid"] and parent["ts"] <= child["ts"]
+            and child["ts"] + child["dur"] <= parent["ts"] + parent["dur"])
+
+
 def test_fit_chunked_path_records_per_dispatch():
+    """Per K-step dispatch: one StepStats record that keeps the K single
+    losses beside their mean, and one `fit.chunk` span holding the four
+    phases of the dispatch; the fetch after the loop is a `fit.absorb`
+    of its own."""
+    tr = obs.enable_tracing()
     m = _small_model()
     x, y = _data(32)
+    tr.clear()
     m.fit(x, y, epochs=1, steps_per_execution=2)
     # 2 chunks of K=2: two records carrying 2 steps each
     assert m.step_stats.total_steps == 4
-    assert [r["steps"] for r in m.step_stats.records()] == [2.0, 2.0]
+    recs = m.step_stats.records()
+    assert [r["steps"] for r in recs] == [2.0, 2.0]
+    for r in recs:
+        assert len(r["losses"]) == 2
+        assert np.mean(r["losses"]) == pytest.approx(r["loss"], rel=1e-6)
+    assert recs[0]["losses"][0] != recs[0]["losses"][1]
+    chunks = tr.events("fit.chunk")
+    assert [c["args"]["chunk"] for c in chunks] == [0, 1]
+    assert [c["args"]["step_num"] for c in chunks] == [0, 2]
+    for c in chunks:
+        assert c["args"]["steps"] == 2 and c["args"]["samples"] == 16
+        for phase in ("fit.load", "fit.stage", "executor.multi_step"):
+            (ev,) = [e for e in tr.events(phase) if _inside(e, c)]
+            assert ev["dur"] > 0
+    absorbs = tr.events("fit.absorb")
+    assert len(absorbs) == 2           # one behind: the second chunk holds
+    assert not any(_inside(a, chunks[0]) for a in absorbs)   # the first's
+    assert _inside(absorbs[0], chunks[1])
+    assert absorbs[1]["ts"] >= chunks[1]["ts"] + chunks[1]["dur"]
+
+
+def test_train_steps_name_what_runs_outside_the_graph():
+    """The device names of the loss, the metrics and the optimizer update
+    are in the lowered multi-step program, beside the graph ops' own."""
+    import jax
+
+    m = _small_model()
+    x, y = _data(16)
+    step = m._get_multi_step().__wrapped__
+    text = step.lower(
+        m.params, m.opt_state, m.state,
+        {m.input_ops[0].name: x.reshape(2, 8, 16)}, y.reshape(2, 8, 1),
+        jax.random.split(jax.random.PRNGKey(0), 2)).as_text(debug_info=True)
+    for scope in ("loss:sparse_categorical_crossentropy", "metrics:compute",
+                  "optimizer:update", "linear:"):
+        assert scope in text, scope
 
 
 def test_fit_with_tracing_emits_dispatch_spans():
