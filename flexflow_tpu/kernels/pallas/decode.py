@@ -25,8 +25,9 @@ Two entry points over ONE kernel body:
 
 Inference-only, so no VJP. Layout is packed (heads iterated over lane
 slices inside the body, like kernels/flash_attention.py's packed
-variant): q (B, C, heads*d), caches (B, M, heads*d) — free trailing-dim
-reshapes of the attention op's [B, M, h, d] caches, no transposes.
+variant): q (B, C, heads*d), caches (B, M, heads*d) — the KV cache as the
+pool stores it (serving/sched/kvpool.py `kv_cache_spec`), taken in place;
+only the small per-head (B, C, h, d) query is reshaped.
 
 Token parity: when the whole cache fits one block the kernel computes
 max/exp/sum/divide in exactly the reference einsum path's order and
@@ -118,14 +119,16 @@ def _call_decode(q, k_cache, v_cache, pos, *, scale, block_k, interpret):
     b, c, heads, head_dim = q.shape
     m = k_cache.shape[1]
     e = heads * head_dim
+    if k_cache.shape != (b, m, e) or v_cache.shape != (b, m, e):
+        raise ValueError(
+            f"decode kernels take the packed (B, M, heads*head_dim) caches"
+            f" {(b, m, e)}, got k {k_cache.shape} v {v_cache.shape}")
     qp = q.reshape(b, c, e)
-    kp = k_cache.reshape(b, m, e)
-    vp = v_cache.reshape(b, m, e)
     block_k = max(1, min(block_k, m))
     m_pad = -(-m // block_k) * block_k
     if m_pad != m:
-        kp = jnp.pad(kp, ((0, 0), (0, m_pad - m), (0, 0)))
-        vp = jnp.pad(vp, ((0, 0), (0, m_pad - m), (0, 0)))
+        k_cache = jnp.pad(k_cache, ((0, 0), (0, m_pad - m), (0, 0)))
+        v_cache = jnp.pad(v_cache, ((0, 0), (0, m_pad - m), (0, 0)))
     n_kb = m_pad // block_k
 
     out = pl.pallas_call(
@@ -149,14 +152,14 @@ def _call_decode(q, k_cache, v_cache, pos, *, scale, block_k, interpret):
         ),
         out_shape=jax.ShapeDtypeStruct((b, c, e), q.dtype),
         interpret=interpret,
-    )(pos.astype(jnp.int32), qp, kp, vp)
+    )(pos.astype(jnp.int32), qp, k_cache, v_cache)
     return out.reshape(b, c, heads, head_dim)
 
 
 def fused_decode_attention(q, k_cache, v_cache, pos, *, scale: float,
                            block_k: int = 512, interpret: bool = False):
     """One decode step for every slot: q (B, 1, h, d) new-token
-    projections, caches (B, M, h, d) ALREADY updated at pos, pos (B,)
+    projections, caches (B, M, h*d) ALREADY updated at pos, pos (B,)
     per-slot positions. Returns the context (B, 1, h, d) in q.dtype —
     the output projection stays outside (a plain matmul XLA handles)."""
     if q.shape[1] != 1:
@@ -172,7 +175,7 @@ def fused_multiquery_decode_attention(q, k_cache, v_cache, pos, *,
                                       interpret: bool = False):
     """C query tokens per slot in one dispatch: q (B, C, h, d)
     projections of the tokens at absolute positions pos[b] + j, caches
-    (B, M, h, d) ALREADY updated at those rows, pos (B,) per-slot base
+    (B, M, h*d) ALREADY updated at those rows, pos (B,) per-slot base
     positions. Query j attends rows `k_pos <= pos[b] + j` — causal over
     prefix + query window. Returns the context (B, C, h, d) in q.dtype.
 

@@ -23,6 +23,64 @@ from ..runtime.platform import pallas_interpret
 from .common import emit_dtype, matmul_dtype
 
 
+def _packed(x):
+    """(b, l, h, d) -> (b, l, h*d), the shape the KV cache stores; an
+    already packed projection passes through."""
+    return x.reshape(x.shape[0], x.shape[1], -1)
+
+
+# rows of one MXU pass (v5e: 128 x 128)
+_MXU_ROWS = 128
+
+
+def _contract_heads_together(c, heads):
+    """Whether the decode step contracts ALL heads at once over the packed
+    (B, M, h*d) cache (`_scores` / `_context`). Yes while the C queries of
+    every head together are at most one MXU pass of rows: the whole
+    h*d-wide cache row is then read once, lane-dense and in place, and the
+    h-fold products a block-diagonal query adds hide behind that read (the
+    decode iteration and speculative verify are HBM-bound; measured on the
+    v5e at 48 x 2,048 x 16 x 64, PERF.md section 6 PR 26). Beyond that (a
+    prefill chunk, on a batch-1 cache) the added products are paid for in
+    MXU passes — a 512-query chunk takes 7x as long — and one head at a
+    time on a reshaped view costs a relayout of a 10 MB cache instead."""
+    return c * heads <= _MXU_ROWS
+
+
+# The decode step's two contractions over the cache AS STORED: q
+# (B, C, h, d), caches (B, M, h*d), scores (B, h, C, M) in float32, context
+# (B, C, h, d). `together`: head j's query sits in lanes [j*d, (j+1)*d) of
+# its own h*d-wide row and zeros elsewhere, so one contraction over the
+# packed row is every head's QK^T (the added products are exact zeros);
+# of the h*d context lanes a head's probabilities give, its own d are kept.
+# Otherwise the plain per-head einsums on a (B, M, h, d) view.
+
+def _own_lanes(heads):
+    return jnp.eye(heads, dtype=bool)[:, :, None]  # (j, j', 1)
+
+
+def _scores(q, kc, together):
+    b, c, heads, d = q.shape
+    if not together:
+        return jnp.einsum("bqhd,bkhd->bhqk", q, kc.reshape(b, -1, heads, d),
+                          preferred_element_type=jnp.float32)
+    qb = jnp.where(_own_lanes(heads), q[:, :, :, None, :], 0)
+    logits = jnp.einsum("bxe,bme->bxm", qb.reshape(b, c * heads, heads * d),
+                        kc, preferred_element_type=jnp.float32)
+    return logits.reshape(b, c, heads, -1).transpose(0, 2, 1, 3)
+
+
+def _context(probs, vc, together):
+    b, heads, c, m = probs.shape
+    if not together:
+        return jnp.einsum("bhqk,bkhd->bqhd", probs,
+                          vc.reshape(b, m, heads, -1))
+    wide = jnp.einsum("bxm,bme->bxe",
+                      probs.transpose(0, 2, 1, 3).reshape(b, c * heads, m),
+                      vc).reshape(b, c, heads, heads, -1)
+    return jnp.sum(jnp.where(_own_lanes(heads), wide, 0), axis=3)
+
+
 @register_op
 class MultiHeadAttentionOp(Op):
     op_type = OpType.MULTIHEAD_ATTENTION
@@ -127,8 +185,9 @@ class MultiHeadAttentionOp(Op):
         # layout change, so the [b,h,l,d] kernels cost real transposes
         # between projection and kernel (~5 ms/step, 13%, at the BERT bench
         # config in the r4 xprof trace); the packed path has none. Every
-        # other consumer (ring / ulysses shard_map, KV-cache fill/decode,
-        # einsum core) keeps the logical [b, l, h, d].
+        # other consumer (ring / ulysses shard_map, the decode step's
+        # queries, einsum core) keeps the logical [b, l, h, d]. The KV
+        # cache itself is stored packed, (rows, max_len, heads*head_dim).
         flash_selected = (
             self._use_flash(ctx) and not dropout_active and kdim == vdim
             and not seq_parallel_active
@@ -143,9 +202,9 @@ class MultiHeadAttentionOp(Op):
         # (e, h, d) -> (e, h*d) weight reshape merges the 'model'-sharded
         # heads axis into lanes, which would force GSPMD to all-gather the
         # projections — TP meshes stay on the blhd kernels. KV-cache
-        # prefill works packed (the cache's [b, l, h, d] view is a free
-        # trailing-dim reshape); the single-token decode step stays on the
-        # einsum path it always used.
+        # prefill works packed (the cache stores exactly the (b, l, h*d)
+        # projection); the decode step projects per head and packs the
+        # new rows itself.
         tp = 1
         if ctx.mesh is not None:
             tp = dict(getattr(ctx.mesh, "shape", {})).get("model", 1)
@@ -188,19 +247,14 @@ class MultiHeadAttentionOp(Op):
         if decode_active:
             return [self._decode_step(ctx, q, k, v, weights, scale)]
         if fill_active:
-            # the cache stores [b, l, h, d]; the packed (b, l, h*d)
-            # projections view into it with a free trailing-dim reshape
-            k4 = (k.reshape(k.shape[0], k.shape[1], heads, kdim)
-                  if use_packed else k)
-            v4 = (v.reshape(v.shape[0], v.shape[1], heads, vdim)
-                  if use_packed else v)
+            # the cache stores the packed (b, l, h*d) projection as it is
             vc = ctx.state[(self.name, "v_cache")]
             ctx.state_updates[(self.name, "k_cache")] = (
                 jax.lax.dynamic_update_slice(
-                    kc, k4.astype(kc.dtype), (0, 0, 0, 0)))
+                    kc, _packed(k).astype(kc.dtype), (0, 0, 0)))
             ctx.state_updates[(self.name, "v_cache")] = (
                 jax.lax.dynamic_update_slice(
-                    vc, v4.astype(vc.dtype), (0, 0, 0, 0)))
+                    vc, _packed(v).astype(vc.dtype), (0, 0, 0)))
 
         if seq_parallel_active:
             # sequence/context parallelism over the 'seq' mesh axis — two
@@ -336,8 +390,12 @@ class MultiHeadAttentionOp(Op):
 
     def _decode_step(self, ctx, q, k, v, weights, scale):
         """One incremental-decoding step: q/k/v are projections of the new
-        token(s) (B, C, h, d); the K/V caches (B, M, h, d) are updated at
-        decode_pos and attended with a causal <= position mask.
+        token(s) (B, C, h, d); the K/V caches are STORED PACKED,
+        (B, M, h*d) — lane-dense on the chip at every width whose h*d is a
+        multiple of 128, where a (…, h, 64) cache is relaid out (and
+        padded to 128 lanes) on every read and write. The new rows are
+        written at decode_pos as (B, C, h*d) and the caches are attended
+        with a causal <= position mask, in place.
 
         decode_pos may be a traced SCALAR (every row at the same position —
         the lockstep GenerativeSession path) or a traced (B,) VECTOR of
@@ -373,30 +431,39 @@ class MultiHeadAttentionOp(Op):
         multi-query kernel over the paged cache
         (kernels/pallas/decode.py) instead of materializing the
         (B, h, C, M) logits/probs in HBM; the einsum chain below is the
-        reference/parity oracle for both families."""
+        reference/parity oracle for both families.
+
+        The reference chain's CONTRACTION follows what it can see in its
+        operands (`_contract_heads_together`). One query a row (the decode
+        iteration, the lockstep session) and speculative verify contract
+        ALL heads at once on the cache as stored, the queries spread
+        block-diagonally over the h*d lanes: no (…, h, d) layout of a
+        whole cache is ever asked for. A prefill chunk (hundreds of
+        queries on a batch-1 cache) and heads GSPMD shards over a mesh
+        axis contract head by head on a reshaped view."""
         pos = ctx.decode_pos
         kc = ctx.state[(self.name, "k_cache")]
         vc = ctx.state[(self.name, "v_cache")]
         vector = getattr(pos, "ndim", 0) == 1
         c = q.shape[1]
+        k_rows = _packed(k).astype(kc.dtype)  # (B, C, h*d)
+        v_rows = _packed(v).astype(vc.dtype)
         if vector:
             rows = jnp.arange(kc.shape[0])
             if c == 1:
-                kc = kc.at[rows, pos].set(k[:, 0].astype(kc.dtype))
-                vc = vc.at[rows, pos].set(v[:, 0].astype(vc.dtype))
+                kc = kc.at[rows, pos].set(k_rows[:, 0])
+                vc = vc.at[rows, pos].set(v_rows[:, 0])
             else:
                 # slot i's C candidate rows land at [pos[i], pos[i]+C);
                 # rows past max_len (speculation at the cache edge) are
                 # DROPPED by the scatter — those queries' outputs are
                 # never accepted, so the dropped writes are unreachable
                 cols = pos[:, None] + jnp.arange(c)[None, :]  # (B, C)
-                kc = kc.at[rows[:, None], cols].set(k.astype(kc.dtype))
-                vc = vc.at[rows[:, None], cols].set(v.astype(vc.dtype))
+                kc = kc.at[rows[:, None], cols].set(k_rows)
+                vc = vc.at[rows[:, None], cols].set(v_rows)
         else:
-            kc = jax.lax.dynamic_update_slice(
-                kc, k.astype(kc.dtype), (0, pos, 0, 0))
-            vc = jax.lax.dynamic_update_slice(
-                vc, v.astype(vc.dtype), (0, pos, 0, 0))
+            kc = jax.lax.dynamic_update_slice(kc, k_rows, (0, pos, 0))
+            vc = jax.lax.dynamic_update_slice(vc, v_rows, (0, pos, 0))
         ctx.state_updates[(self.name, "k_cache")] = kc
         ctx.state_updates[(self.name, "v_cache")] = vc
 
@@ -430,15 +497,25 @@ class MultiHeadAttentionOp(Op):
             qpos = pos + jnp.arange(c)  # (C,) absolute positions
             mask = (jnp.arange(kc.shape[1])[None, :]
                     <= qpos[:, None])[None, None, :, :]  # (1, 1, C, M)
-        logits = jnp.einsum(
-            "bqhd,bkhd->bhqk", q, kc.astype(q.dtype),
-            preferred_element_type=jnp.float32,
-        ) * scale  # (B, h, C, M)
-        logits = jnp.where(mask, logits, -1e30)
+        # contracted as one row, a head-sharded last dimension would have
+        # GSPMD gather the cache: tensor-parallel heads contract head by head
+        together = (not self._heads_sharded(ctx)
+                    and _contract_heads_together(c, q.shape[2]))
+        logits = _scores(q, kc.astype(q.dtype), together) * scale
+        logits = jnp.where(mask, logits, -1e30)  # (B, h, C, M)
         probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-        ctxv = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(q.dtype),
-                          vc.astype(q.dtype))
+        ctxv = _context(probs.astype(q.dtype), vc.astype(q.dtype), together)
         return self._decode_project(ctxv, q.dtype, weights)
+
+    def _heads_sharded(self, ctx) -> bool:
+        """True where GSPMD partitions this op's heads over a mesh axis
+        (the spec `_on_mesh` hands its kernels); a mesh that partitions
+        nothing, or the batch alone, leaves every head on every device."""
+        if not ctx.gspmd_partitioned():
+            return False
+        wq = next(w for w in self.weights
+                  if w._weight_spec.name == "wq").parallel_shape
+        return wq is not None and wq.partition_spec()[1] is not None
 
     def _decode_project(self, ctxv, cdt, weights):
         """Output projection shared by the fused and reference decode

@@ -44,7 +44,6 @@ class GenerativeSession:
 
     def __init__(self, model, max_len: int):
         import jax
-        import jax.numpy as jnp
 
         self.model = model
         self.max_len = int(max_len)
@@ -61,16 +60,10 @@ class GenerativeSession:
         # ONE cache-geometry definition (heads/kdim/vdim + compute dtype —
         # bf16 under mixed precision, the dominant serving memory) shared
         # with the continuous batcher and the pool's HBM sizing
-        from .sched.kvpool import kv_cache_spec
+        from .sched.kvpool import zero_kv_caches
 
-        b = model.config.batch_size
-        self._caches: Dict[str, Dict[str, object]] = {
-            name: {
-                "k_cache": jnp.zeros((b, self.max_len, heads, kdim), cdt),
-                "v_cache": jnp.zeros((b, self.max_len, heads, vdim), cdt),
-            }
-            for name, heads, kdim, vdim, cdt in kv_cache_spec(model)
-        }
+        self._caches: Dict[str, Dict[str, object]] = zero_kv_caches(
+            model, model.config.batch_size, self.max_len)
 
         executor = model.executor
         final_guid = model.final_tensor.guid

@@ -36,7 +36,8 @@ from .continuous import (BatcherStopped, ContinuousBatcher, GenRequest,
                          RequestCancelled, RequestState, ResizeTicket)
 from .kvpool import (PagedKVPool, PoolExhausted, PrefixCache,
                      derive_num_slots, kv_bytes_per_token, kv_cache_spec,
-                     prefix_route_chain, prefix_route_key)
+                     prefix_route_chain, prefix_route_key, write_slot_span,
+                     zero_kv_caches)
 
 __all__ = [
     "AdmissionController", "AdmissionError", "QueueFull", "PoolSaturated",
@@ -44,5 +45,5 @@ __all__ = [
     "RequestCancelled", "RequestState", "ResizeTicket", "PagedKVPool",
     "PoolExhausted", "PrefixCache", "SLOExceeded", "derive_num_slots",
     "kv_bytes_per_token", "kv_cache_spec", "prefix_route_chain",
-    "prefix_route_key",
+    "prefix_route_key", "write_slot_span", "zero_kv_caches",
 ]

@@ -67,7 +67,8 @@ import numpy as np
 from ...ffconst import CompMode, OpType
 from ..batcher import BatcherStopped
 from .admission import AdmissionController
-from .kvpool import PagedKVPool, derive_num_slots, kv_cache_spec
+from .kvpool import (PagedKVPool, derive_num_slots, write_slot_span,
+                     zero_kv_caches)
 
 
 class RequestCancelled(RuntimeError):
@@ -676,57 +677,27 @@ class ContinuousBatcher:
 
     # -- jitted device functions ------------------------------------------
     def _zero_caches(self):
-        import jax.numpy as jnp
-
-        # kv_cache_spec is the SAME geometry derive_num_slots sized the
-        # pool with — allocation can never drift from the HBM estimate
-        return {
-            name: {
-                "k_cache": jnp.zeros(
-                    (self.num_slots, self.max_len, heads, kdim), cdt),
-                "v_cache": jnp.zeros(
-                    (self.num_slots, self.max_len, heads, vdim), cdt),
-            }
-            for name, heads, kdim, vdim, cdt in kv_cache_spec(self.model)
-        }
+        # zero_kv_caches allocates the SAME geometry (kv_cache_spec)
+        # derive_num_slots sized the pool with — allocation can never
+        # drift from the HBM estimate
+        return zero_kv_caches(self.model, self.num_slots, self.max_len)
 
     def _zero_band(self):
         """The prefix cache's device-side page store: slot-shaped rows
         SEPARATE from the decode caches, so decode dispatches never carry
         (or attend over) the band. None when prefix reuse is off."""
-        import jax.numpy as jnp
-
         band_slots = self.pool.band_slots
         if band_slots == 0:
             return None
-        return {
-            name: {
-                "k_cache": jnp.zeros(
-                    (band_slots, self.max_len, heads, kdim), cdt),
-                "v_cache": jnp.zeros(
-                    (band_slots, self.max_len, heads, vdim), cdt),
-            }
-            for name, heads, kdim, vdim, cdt in kv_cache_spec(self.model)
-        }
+        return zero_kv_caches(self.model, band_slots, self.max_len)
 
     def _zero_draft_caches(self):
         """The draft model's slot-dense KV caches, mirroring the target's
         geometry slot-for-slot (row p of slot i holds the draft's K/V of
         sequence i's token at position p). None without speculation."""
-        import jax.numpy as jnp
-
         if self.draft_model is None:
             return None
-        return {
-            name: {
-                "k_cache": jnp.zeros(
-                    (self.num_slots, self.max_len, heads, kdim), cdt),
-                "v_cache": jnp.zeros(
-                    (self.num_slots, self.max_len, heads, vdim), cdt),
-            }
-            for name, heads, kdim, vdim, cdt in kv_cache_spec(
-                self.draft_model)
-        }
+        return zero_kv_caches(self.draft_model, self.num_slots, self.max_len)
 
     def _zero_small(self, model=None):
         """Fresh batch-1 caches for one chunked prefill (of `model`,
@@ -739,17 +710,9 @@ class ContinuousBatcher:
         `dynamic_update_slice` would CLAMP that write at the array edge,
         silently shifting real prompt K/V rows (pinned by
         tests/test_prefix_cache.py::test_chunked_prefill_last_chunk_never_clamps)."""
-        import jax.numpy as jnp
-
         rows = self.max_len + max(0, self.prefill_chunk_tokens - 1)
-        return {
-            name: {
-                "k_cache": jnp.zeros((1, rows, heads, kdim), cdt),
-                "v_cache": jnp.zeros((1, rows, heads, vdim), cdt),
-            }
-            for name, heads, kdim, vdim, cdt in kv_cache_spec(
-                model if model is not None else self.model)
-        }
+        return zero_kv_caches(
+            model if model is not None else self.model, 1, rows)
 
     def _build_fns(self):
         import jax
@@ -858,15 +821,11 @@ class ContinuousBatcher:
             out = {}
             with jax.named_scope("kv:scatter_span"):
                 for name in attn_names_:
-                    kc = pool_caches[name]["k_cache"]
-                    vc = pool_caches[name]["v_cache"]
                     out[name] = {
-                        "k_cache": jax.lax.dynamic_update_slice(
-                            kc, small[name]["k_cache"][:, :max_len].astype(
-                                kc.dtype), (slot, 0, 0, 0)),
-                        "v_cache": jax.lax.dynamic_update_slice(
-                            vc, small[name]["v_cache"][:, :max_len].astype(
-                                vc.dtype), (slot, 0, 0, 0)),
+                        part: write_slot_span(
+                            pool_caches[name][part],
+                            small[name][part][:, :max_len], slot)
+                        for part in ("k_cache", "v_cache")
                     }
             return out
 
@@ -905,23 +864,18 @@ class ContinuousBatcher:
             real for rows < n_rows) into the leading rows of a fresh
             batch-1 prefill cache — the device-side copy that replaces
             recomputing the prefix."""
-            keep = (jnp.arange(max_len) < n_rows)[:, None, None]
+            keep = (jnp.arange(max_len) < n_rows)[:, None]
             out = {}
             with jax.named_scope("kv:prefix"):
                 for name in attn_names:
-                    gk = band[name]["k_cache"][src_slot, src_row]  # (M, h, d)
-                    gv = band[name]["v_cache"][src_slot, src_row]
-                    sk = small[name]["k_cache"]  # (1, max_len + slack, h, d)
-                    sv = small[name]["v_cache"]
+                    # band rows gathered: (M, e); sm: (1, max_len + slack,
+                    # e). Update the first max_len rows; the slack tail
+                    # (see _zero_small) passes through untouched
                     out[name] = {
-                        # update the first max_len rows; the slack tail
-                        # (see _zero_small) passes through untouched
-                        "k_cache": jax.lax.dynamic_update_slice(
-                            sk, jnp.where(keep, gk, sk[0, :max_len])[None],
-                            (0, 0, 0, 0)),
-                        "v_cache": jax.lax.dynamic_update_slice(
-                            sv, jnp.where(keep, gv, sv[0, :max_len])[None],
-                            (0, 0, 0, 0)),
+                        part: write_slot_span(sm, jnp.where(
+                            keep, band[name][part][src_slot, src_row],
+                            sm[0, :max_len])[None], 0)
+                        for part, sm in small[name].items()
                     }
             return out
 
@@ -1256,7 +1210,7 @@ class ContinuousBatcher:
         rows. Resolves with {"desc", "rows", "plen", "last_tok",
         "bytes"}: `desc` is the pool's geometry-checked page descriptor
         (`PagedKVPool.export_sequence`), `rows` maps "op/part" to the
-        (plen, heads, dim) host array of exactly the rows the page table
+        (plen, heads*dim) host array of exactly the rows the page table
         owns. The request STAYS parked — a failed ship can still
         resume_parked with nothing lost."""
         ticket = HandoffTicket()

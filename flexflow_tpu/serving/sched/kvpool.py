@@ -3,8 +3,14 @@
 Physical layout vs logical pages
 --------------------------------
 The device arrays backing the pool are slot-dense: per attention op one
-``(num_slots, max_len, heads, head_dim)`` K and V cache, exactly the layout
-the incremental-decoding kernels already consume (ops/attention.py). A
+``(num_slots, max_len, heads * head_dim)`` K and V cache — a token's heads
+PACKED into one row, so the last dimension is a multiple of the chip's 128
+lanes at every published width and the decode step scatters into it and
+contracts on it in place (ops/attention.py `_decode_step`,
+kernels/pallas/decode.py; a ``(…, heads, 64)`` cache was relaid out and
+lane-padded twice per layer per iteration). `kv_cache_spec` is the
+geometry, `zero_kv_caches` the one allocation and `write_slot_span` the
+one span write every holder of such arrays uses. A
 *page* is a fixed span of ``page_size`` consecutive token positions inside
 one slot, so page id ``slot * pages_per_slot + block`` names physical rows
 ``[block*page_size, (block+1)*page_size)`` of that slot. The per-sequence
@@ -732,10 +738,13 @@ class PagedKVPool:
 
 def kv_cache_spec(model) -> List[tuple]:
     """[(op_name, heads, kdim, vdim, jnp cache dtype)] for every attention
-    op — THE cache geometry. Shared by pool sizing (`kv_bytes_per_token`),
-    the ContinuousBatcher's slot caches, and GenerativeSession's lockstep
-    caches, so the HBM estimate can never drift from what actually gets
-    allocated. The dtype is the attention compute dtype (bf16 under mixed
+    op — THE cache geometry: op `name` stores a K cache of
+    (rows, max_len, heads*kdim) and a V cache of (rows, max_len,
+    heads*vdim). Shared by pool sizing (`kv_bytes_per_token`) and the one
+    allocation (`zero_kv_caches`: the ContinuousBatcher's slot, band,
+    draft and batch-1 caches, GenerativeSession's lockstep caches), so the
+    HBM estimate can never drift from what actually gets allocated. The
+    dtype is the attention compute dtype (bf16 under mixed
     precision — the KV cache is the dominant serving memory)."""
     from ...ops.common import matmul_dtype
 
@@ -752,6 +761,33 @@ def kv_cache_spec(model) -> List[tuple]:
         raise ValueError(
             "model has no multihead_attention ops: nothing to cache")
     return out
+
+
+def zero_kv_caches(model, rows: int, max_len: int) -> Dict[str, Dict]:
+    """{op_name: {"k_cache", "v_cache"}} of zeros AS STORED: `rows`
+    sequences (pool slots, band rows, or 1) of `max_len` token rows, each
+    token's heads packed into one (heads*dim,) row. The only place that
+    writes the stored shape out."""
+    import jax.numpy as jnp
+
+    return {
+        name: {
+            "k_cache": jnp.zeros((rows, max_len, heads * kdim), cdt),
+            "v_cache": jnp.zeros((rows, max_len, heads * vdim), cdt),
+        }
+        for name, heads, kdim, vdim, cdt in kv_cache_spec(model)
+    }
+
+
+def write_slot_span(cache, span, slot):
+    """`cache` (rows, max_len, e) with `span` (1, n <= max_len, e) written
+    over the leading token rows of sequence `slot` (traced or static), in
+    the cache's dtype — the whole-sequence install that prefill finish,
+    prefix install and KV import share."""
+    import jax
+
+    return jax.lax.dynamic_update_slice(
+        cache, span.astype(cache.dtype), (slot, 0, 0))
 
 
 def kv_bytes_per_token(model) -> int:

@@ -226,7 +226,8 @@ def _fake_rep(num_slots, max_len):
 def test_cross_pool_pricing_over_dcn_and_chunk_cap():
     machine = HierarchicalMachineModel.from_json(
         load_machine_spec(SPEC_PATH))
-    kv_shapes = {f"kv/l{i}_attn/{p}": ((4, 256, 4, 8), 4)
+    # the stored shape: (slots, max_len, heads*head_dim), 4 heads of 8
+    kv_shapes = {f"kv/l{i}_attn/{p}": ((4, 256, 4 * 8), 4)
                  for i in range(2) for p in ("k_cache", "v_cache")}
     cross = plan_slot_migration(kv_shapes, 4, 4, 128,
                                 device_ids=tuple(range(16)))
@@ -238,7 +239,7 @@ def test_cross_pool_pricing_over_dcn_and_chunk_cap():
     # latency), not the innermost p2p links (2x45 GB/s)
     assert cost_cross > cost_inner > 0.0
 
-    rows = {f"l{i}/k": np.zeros((2048, 64, 64), np.float32)
+    rows = {f"l{i}/k": np.zeros((2048, 64 * 64), np.float32)
             for i in range(3)}  # ~100 MB total
     total = sum(r.nbytes for r in rows.values())
     assert total > TRANSFER_TIER_CHUNK_BYTES

@@ -13,7 +13,7 @@ _xla_cache = pytest.fixture(scope="module", autouse=True)(module_xla_cache)
 
 
 def _build_lm(batch, window, vocab=50, hidden=32, heads=4, layers=2,
-              use_flash=None):
+              use_flash=None, parallel_axes=None):
     config = ff.FFConfig()
     config.batch_size = batch
     config.allow_mixed_precision = False
@@ -32,7 +32,8 @@ def _build_lm(batch, window, vocab=50, hidden=32, heads=4, layers=2,
         t = model.layer_norm(model.add(t, h), [-1], name=f"l{i}_ln2")
     model.softmax(model.dense(t, vocab, name="lm_head"))
     model.compile(optimizer=ff.SGDOptimizer(model, lr=0.0),
-                  loss_type=ff.LossType.LOSS_SPARSE_CATEGORICAL_CROSSENTROPY)
+                  loss_type=ff.LossType.LOSS_SPARSE_CATEGORICAL_CROSSENTROPY,
+                  parallel_axes=parallel_axes)
     return model
 
 
@@ -163,8 +164,8 @@ def test_generate_zero_tokens_returns_empty():
 
 
 def test_kv_cache_generate_flash_prefill_matches_naive_loop():
-    """use_flash=True prefill: the packed kernel fills the KV cache (its
-    [b,l,h,d] view is a reshape of the packed projections) and decode steps
+    """use_flash=True prefill: the packed kernel fills the KV cache (which
+    stores exactly the packed (b, l, h*d) projections) and decode steps
     attend against it — same tokens as the naive full-recompute loop."""
     b, window, n_new = 2, 12, 5
     model = _build_lm(b, window, use_flash=True)
@@ -172,8 +173,32 @@ def test_kv_cache_generate_flash_prefill_matches_naive_loop():
 
     ref = _naive_generate(model, prompt, n_new, window)
     session = GenerativeSession(model, max_len=window)
+    # the lockstep session holds its caches as the pool stores them:
+    # (batch, max_len, heads*head_dim), hidden 32 = 4 heads of 8
+    assert {c.shape for pair in session._caches.values()
+            for c in pair.values()} == {(b, window, 32)}
     got = session.generate(prompt, n_new)
     np.testing.assert_array_equal(got, ref)
+
+
+def test_kv_cache_generate_with_tensor_parallel_heads_matches_one_device():
+    """Heads sharded over a 'model' axis: the packed cache's last dimension
+    is then sharded by head, and the decode step contracts head by head
+    instead of over the whole packed row — same tokens as one device."""
+    import jax
+
+    b, window, n_new = 2, 12, 5
+    ref = _build_lm(b, window)
+    tp = _build_lm(b, window, parallel_axes={"model": 2})
+    tp.params = jax.tree.map(
+        lambda a, like: jax.device_put(np.asarray(a), like.sharding),
+        ref.params, tp.params)
+    attn = next(op for op in tp.graph.ops.values() if op.name == "l0_attn")
+    assert attn.weights[0].parallel_shape.partition_spec()[1] == "model"
+    prompt = np.random.RandomState(5).randint(1, 50, size=(b, 4)).astype(np.int32)
+    np.testing.assert_array_equal(
+        GenerativeSession(tp, max_len=window).generate(prompt, n_new),
+        GenerativeSession(ref, max_len=window).generate(prompt, n_new))
 
 
 def test_generate_eos_early_stop():

@@ -159,7 +159,14 @@ def test_fused_reduce_tiny_and_empty():
 # ---------------------------------------------------------------------
 # fused decode step
 # ---------------------------------------------------------------------
+def _per_head(q, cache):
+    """The pool's packed (B, M, h*d) cache as the (B, M, h, d) the plain
+    references attend."""
+    return cache.reshape(cache.shape[:2] + q.shape[2:])
+
+
 def _ref_decode(q, kc, vc, pos, scale):
+    kc, vc = _per_head(q, kc), _per_head(q, vc)
     m = kc.shape[1]
     mask = (jnp.arange(m)[None, :] <= pos[:, None])[:, None, None, :]
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, kc.astype(q.dtype),
@@ -175,8 +182,8 @@ def test_fused_decode_ragged_positions(block_k):
     rng = np.random.RandomState(6)
     B, M, h, d = 5, 24, 3, 8
     q = _rand(rng, (B, 1, h, d))
-    kc = _rand(rng, (B, M, h, d))
-    vc = _rand(rng, (B, M, h, d))
+    kc = _rand(rng, (B, M, h * d))
+    vc = _rand(rng, (B, M, h * d))
     # ragged: includes pos 0 (one live row) and pos M-1 (the whole cache)
     pos = jnp.asarray([0, 3, 11, 23, 7], dtype=jnp.int32)
     scale = 1.0 / np.sqrt(d)
@@ -191,8 +198,8 @@ def test_fused_decode_bf16_cache():
     rng = np.random.RandomState(7)
     B, M, h, d = 2, 16, 2, 16
     q = _rand(rng, (B, 1, h, d))
-    kc = _rand(rng, (B, M, h, d), jnp.bfloat16)
-    vc = _rand(rng, (B, M, h, d), jnp.bfloat16)
+    kc = _rand(rng, (B, M, h * d), jnp.bfloat16)
+    vc = _rand(rng, (B, M, h * d), jnp.bfloat16)
     pos = jnp.asarray([5, 15], dtype=jnp.int32)
     scale = 1.0 / np.sqrt(d)
     out = fused_decode_attention(q, kc, vc, pos, scale=scale, interpret=True)
@@ -204,7 +211,7 @@ def test_fused_decode_bf16_cache():
 
 def test_fused_decode_rejects_multi_query():
     q = jnp.zeros((1, 2, 2, 4))
-    kc = vc = jnp.zeros((1, 8, 2, 4))
+    kc = vc = jnp.zeros((1, 8, 2 * 4))
     with pytest.raises(ValueError, match="one query token"):
         fused_decode_attention(q, kc, vc, jnp.zeros((1,), jnp.int32),
                                scale=1.0, interpret=True)
@@ -585,6 +592,7 @@ def test_refit_persists_family_residuals(tmp_path):
 # multi-query decode kernel (ISSUE 14)
 # ---------------------------------------------------------------------
 def _ref_mq_decode(q, kc, vc, pos, scale):
+    kc, vc = _per_head(q, kc), _per_head(q, vc)
     b, c = q.shape[0], q.shape[1]
     m = kc.shape[1]
     qpos = pos[:, None] + jnp.arange(c)[None, :]
@@ -606,8 +614,8 @@ def test_fused_multiquery_decode_parity(block_k):
     rng = np.random.RandomState(12)
     B, C, M, h, d = 5, 3, 24, 3, 8
     q = _rand(rng, (B, C, h, d))
-    kc = _rand(rng, (B, M, h, d))
-    vc = _rand(rng, (B, M, h, d))
+    kc = _rand(rng, (B, M, h * d))
+    vc = _rand(rng, (B, M, h * d))
     # ragged: pos 0 (the query window IS the live prefix) through M-C
     # (the window ends at the last cache row)
     pos = jnp.asarray([0, 3, 11, 21, 7], dtype=jnp.int32)
@@ -626,8 +634,8 @@ def test_fused_multiquery_decode_bf16_cache():
     rng = np.random.RandomState(13)
     B, C, M, h, d = 2, 4, 16, 2, 16
     q = _rand(rng, (B, C, h, d))
-    kc = _rand(rng, (B, M, h, d), jnp.bfloat16)
-    vc = _rand(rng, (B, M, h, d), jnp.bfloat16)
+    kc = _rand(rng, (B, M, h * d), jnp.bfloat16)
+    vc = _rand(rng, (B, M, h * d), jnp.bfloat16)
     pos = jnp.asarray([5, 12], dtype=jnp.int32)
     scale = 1.0 / np.sqrt(d)
     for block_k in (64, 8):
@@ -648,8 +656,8 @@ def test_fused_multiquery_c1_matches_single_query():
     rng = np.random.RandomState(14)
     B, M, h, d = 3, 24, 2, 8
     q = _rand(rng, (B, 1, h, d))
-    kc = _rand(rng, (B, M, h, d))
-    vc = _rand(rng, (B, M, h, d))
+    kc = _rand(rng, (B, M, h * d))
+    vc = _rand(rng, (B, M, h * d))
     pos = jnp.asarray([0, 9, 23], dtype=jnp.int32)
     for block_k in (64, 8):
         a = fused_multiquery_decode_attention(

@@ -18,6 +18,7 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")  # before libtpu starts
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
 from flexflow_tpu.kernels.flash_attention import (  # noqa: E402
@@ -69,7 +70,7 @@ def _decode(fn):
     return lambda q, k, v, pos: fn(q, k, v, pos, scale=HEAD_DIM ** -0.5)
 
 
-_CACHE = ((SLOTS, ROWS, HEADS, HEAD_DIM), BF16)
+_CACHE = ((SLOTS, ROWS, HEADS * HEAD_DIM), BF16)  # as the pool stores it
 # name -> (function, [(shape, dtype), ...])
 CASES = {
     "flash_packed_fwd_bwd": (
@@ -118,3 +119,49 @@ def test_kernel_compiles_for_v5e(v5e, name):
             for shape, dtype in specs]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_decode_all_copies_no_whole_cache_on_v5e(v5e):
+    """The continuous batcher's decode step over the PACKED cache
+    (slots, max_len, heads*head_dim), one layer at 8 slots x 256 rows x 16
+    heads of 64: the chip's compiler scatters into every cache and
+    contracts on it in place. A cache stored (…, 16, 64) arrives rows-minor
+    and is copied whole — relaid out, its 64 lanes padded to 128 — before
+    the scatter and again for the donated output: four `copy` ops a layer
+    in the entry computation (85 of the 119 ms decode iteration, ledger
+    PR 25)."""
+    import re
+    from unittest import mock
+
+    from flexflow_tpu.serving.sched.continuous import ContinuousBatcher
+    from tests.test_generate import _build_lm
+
+    slots, rows = 8, 256
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"), \
+            jax.default_matmul_precision("highest"):
+        model = _build_lm(1, 64, vocab=512, hidden=HEADS * HEAD_DIM,
+                          heads=HEADS, layers=1)
+        batcher = ContinuousBatcher(model, max_len=rows, num_slots=slots,
+                                    page_size=16, prefill_chunk_tokens=64)
+        on_chip = lambda tree: jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+            tree)
+        vec = lambda *shape, dtype=I32: jax.ShapeDtypeStruct(
+            shape, dtype, sharding=v5e)
+        compiled = batcher._decode_fn.lower(
+            on_chip(model.params), on_chip(model.state),
+            on_chip(batcher._caches), vec(slots), vec(slots),
+            vec(slots, 2, dtype=jnp.uint32)).compile()
+
+    cache = batcher._caches["l0_attn"]["k_cache"]
+    assert cache.shape == (slots, rows, HEADS * HEAD_DIM)
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    whole_cache_copies = [
+        line.strip()[:120] for line in entry.splitlines()
+        for m in [re.match(r"\s*(?:ROOT )?\S+ = \w+\[([\d,]+)\]\S* copy\(",
+                           line)]
+        if m and np.prod([int(d) for d in m.group(1).split(",")]) >= cache.size
+    ]
+    assert not whole_cache_copies, whole_cache_copies
