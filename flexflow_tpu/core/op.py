@@ -173,6 +173,18 @@ class Op:
         """Non-trainable per-op state (e.g. running statistics)."""
         return []
 
+    def kv_cache_arrays(self) -> Optional[Dict[str, int]]:
+        """{array name: values a token stores in it} for an op that keeps a
+        serving cache (`ctx.state[(op name, array name)]`, shaped (rows,
+        max_len, width)); None for every other op. This is the capability
+        the serving stack finds attention ops by (serving/sched/kvpool.py
+        `kv_cache_spec`)."""
+        return None
+
+    # state vars the continuous batcher threads from one decode iteration
+    # to the next and hands back (small counters, never caches)
+    serving_counters: Tuple[str, ...] = ()
+
     def lower(self, ctx: LoweringContext, inputs: List[Any], weights: Dict[str, Any]):
         """Emit jax ops; return list of output values (one per output tensor)."""
         raise NotImplementedError

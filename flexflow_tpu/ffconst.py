@@ -44,6 +44,7 @@ class ActiMode(enum.Enum):
     AC_MODE_SIGMOID = 2
     AC_MODE_TANH = 3
     AC_MODE_GELU = 4
+    AC_MODE_SILU = 5
 
 
 class PoolType(enum.Enum):
@@ -142,9 +143,16 @@ class OpType(enum.Enum):
     MEAN = "mean"
     CAST = "cast"
     MULTIHEAD_ATTENTION = "multihead_attention"
+    # latent attention (ops/latent_attention.py); the value is its device
+    # scope prefix, `mla:<name>`
+    LATENT_ATTENTION = "mla"
     TOPK = "topk"
     GROUP_BY = "group_by"
     EXPERTS = "experts"
+    # dropless gated experts, told which experts they hold (ops/moe.py);
+    # device scopes `moe_router:<name>` and `moe:<name>`
+    MOE_ROUTER = "moe_router"
+    GATED_EXPERTS = "moe"
     FUSED = "fused"
     LSTM = "lstm"
     # Parallel ops (reference: src/parallel_ops)

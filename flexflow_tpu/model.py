@@ -202,7 +202,10 @@ class FFModel:
         kernel_initializer=None,
         bias_initializer=None,
         name: str = "",
+        kernel_datatype: Optional[DataType] = None,
     ) -> Tensor:
+        """`datatype`: the output's (and, unless `kernel_datatype` says
+        otherwise, the kernel's) type; default the input's."""
         return self._add_op(
             OpType.LINEAR,
             [input],
@@ -211,6 +214,7 @@ class FFModel:
             activation=activation,
             use_bias=use_bias,
             dtype=datatype,
+            kernel_dtype=kernel_datatype,
             kernel_initializer=kernel_initializer,
             bias_initializer=bias_initializer,
         ).outputs[0]
@@ -524,6 +528,52 @@ class FFModel:
             alpha=alpha, lambda_bal=lambda_bal, activation=activation,
             kernel_initializer=kernel_initializer,
         ).outputs[0]
+
+    def moe_router(self, input: Tensor, num_exp: int, num_select: int,
+                   scale: float = 1.0, kernel_initializer=None,
+                   name: str = "") -> Tuple[Tensor, Tensor]:
+        """Router of a dropless expert layer (ops/moe.py MoERouterOp):
+        float32 logits over all `num_exp` experts, the `num_select`
+        largest, softmax over those. Returns (weights, expert ids)."""
+        outs = self._add_op(
+            OpType.MOE_ROUTER, [input], name, n=num_exp, k=num_select,
+            scale=scale, kernel_initializer=kernel_initializer).outputs
+        return outs[0], outs[1]
+
+    def gated_experts(self, input: Tensor, gate_weights: Tensor,
+                      assign: Tensor, experts_total: int,
+                      expert_hidden_size: int,
+                      local_experts: Optional[Tuple[int, int]] = None,
+                      kernel_initializer=None, name: str = "") -> Tensor:
+        """Routed part of a gated (SiLU) expert layer for the experts held
+        here, dropless (ops/moe.py GatedExpertsOp). `local_experts` =
+        (first, count) names the range of the `experts_total` this holder
+        has; None = all of them."""
+        local = (0, int(experts_total)) if local_experts is None \
+            else (int(local_experts[0]), int(local_experts[1]))
+        return self._add_op(
+            OpType.GATED_EXPERTS, [input, gate_weights, assign], name,
+            experts_total=int(experts_total),
+            expert_hidden_size=int(expert_hidden_size), local_experts=local,
+            kernel_initializer=kernel_initializer).outputs[0]
+
+    def latent_attention(self, input: Tensor, num_heads: int,
+                         q_lora_rank: int, kv_lora_rank: int,
+                         qk_nope_head_dim: int, qk_rope_head_dim: int,
+                         v_head_dim: int, rope_parameters=None,
+                         eps: float = 1e-6, kernel_initializer=None,
+                         name: str = "") -> Tensor:
+        """Causal multi-head latent self-attention with rotary positions
+        (ops/latent_attention.py): low-rank q and kv projections, one
+        rotary key shared by the heads, a latent serving cache.
+        `rope_parameters`: the model's published group (ops/rope.py)."""
+        return self._add_op(
+            OpType.LATENT_ATTENTION, [input], name, num_heads=num_heads,
+            q_lora_rank=q_lora_rank, kv_lora_rank=kv_lora_rank,
+            qk_nope_head_dim=qk_nope_head_dim,
+            qk_rope_head_dim=qk_rope_head_dim, v_head_dim=v_head_dim,
+            rope_parameters=dict(rope_parameters) if rope_parameters else None,
+            eps=eps, kernel_initializer=kernel_initializer).outputs[0]
 
     def moe(
         self,
@@ -1554,7 +1604,7 @@ class FFModel:
         """End-of-epoch MoE router health: mirror every EXPERTS op's
         dropped/load state into the ff_moe_* metric families
         (obs/moe.py). No-op (no registry touch) for expert-free graphs."""
-        if not any(op.op_type == OpType.EXPERTS
+        if not any(op.op_type in (OpType.EXPERTS, OpType.GATED_EXPERTS)
                    for op in self.graph.ops.values()):
             return
         from .obs.moe import publish_moe_metrics

@@ -17,6 +17,18 @@ mirrors it into the default registry as
 
 FFModel.fit publishes once per epoch; the serve-bench moe leg publishes
 after its run and asserts the dropped counter stayed at zero.
+
+The dropless GatedExpertsOp (an expert layer told which experts it holds)
+drops nothing by construction and exports no dropped family; its state —
+threaded through the continuous batcher's decode iterations and handed to
+`publish_moe_metrics(..., state=batcher.op_counters())` — is mirrored as
+
+ - ff_moe_local_assignments_total    Counter, labels=(op,): assignments
+   that fell on the experts held here
+ - ff_moe_experts_hit_total          Counter, labels=(op,): distinct local
+   experts that received a token, summed over steps
+ - ff_moe_expert_load_max_over_mean  Gauge,   labels=(op,): the last
+   step's fullest local expert over the mean one
 """
 from __future__ import annotations
 
@@ -44,16 +56,56 @@ def moe_router_families(registry: Optional[MetricsRegistry] = None):
     return c_dropped, g_load, g_imb
 
 
-# per (registry id, op) last published dropped total, so the counter
-# family only ever receives non-negative deltas
-_LAST_DROPPED: Dict[tuple, float] = {}
+def gated_experts_families(registry: Optional[MetricsRegistry] = None):
+    """(local assignments counter, experts hit counter, load max-over-mean
+    gauge) of the dropless GatedExpertsOp."""
+    reg = registry if registry is not None else REGISTRY
+    return (
+        reg.counter("ff_moe_local_assignments_total",
+                    "Router assignments that fell on the experts held here",
+                    labels=("op",)),
+        reg.counter("ff_moe_experts_hit_total",
+                    "Distinct local experts that received a token, summed"
+                    " over steps", labels=("op",)),
+        reg.gauge("ff_moe_expert_load_max_over_mean",
+                  "Fullest local expert over the mean one, last step",
+                  labels=("op",)))
 
 
-def publish_moe_metrics(model,
-                        registry: Optional[MetricsRegistry] = None) -> Dict:
-    """Mirror every EXPERTS op's router state into the registry. Returns
-    {op name: {"dropped": float, "load": [..]}} for callers that want the
-    raw numbers (the serve-bench moe leg's zero-drop assert)."""
+# per (registry id, op[, counter]) last published total, so the counter
+# families only ever receive non-negative deltas
+_LAST_PUBLISHED: Dict[tuple, float] = {}
+
+
+def _inc_to(counter, key: tuple, total: float, **labels) -> None:
+    delta = total - _LAST_PUBLISHED.get(key, 0.0)
+    if delta > 0:
+        counter.inc(delta, **labels)
+    _LAST_PUBLISHED[key] = total
+
+
+def _publish_gated(reg, op, vars_) -> Dict:
+    import numpy as np
+
+    c_assign, c_hit, g_mom = gated_experts_families(reg)
+    load = np.asarray(vars_["load"], dtype=np.float64)
+    got = {k: float(np.asarray(vars_[k]))
+           for k in ("assignments", "experts_hit", "steps")}
+    _inc_to(c_assign, (id(reg), op.name, "a"), got["assignments"],
+            op=op.name)
+    _inc_to(c_hit, (id(reg), op.name, "h"), got["experts_hit"], op=op.name)
+    mean = float(load.mean()) if load.size else 0.0
+    g_mom.set(float(load.max()) / mean if mean > 0 else 0.0, op=op.name)
+    return {**got, "dropped": 0.0, "load": load.tolist()}
+
+
+def publish_moe_metrics(model, registry: Optional[MetricsRegistry] = None,
+                        state: Optional[Dict] = None) -> Dict:
+    """Mirror every expert op's router state into the registry (`state`:
+    an op-state tree to read in place of `model.state`, e.g. the
+    continuous batcher's `op_counters()`). Returns {op name: {"dropped":
+    float, "load": [..], ...}} for callers that want the raw numbers (the
+    serve-bench moe leg's zero-drop assert)."""
     import numpy as np
 
     from ..ffconst import OpType
@@ -61,8 +113,11 @@ def publish_moe_metrics(model,
     reg = registry if registry is not None else REGISTRY
     c_dropped, g_load, g_imb = moe_router_families(reg)
     out: Dict[str, Dict] = {}
-    state = getattr(model, "state", None) or {}
+    state = state if state is not None else (
+        getattr(model, "state", None) or {})
     for op in model.graph.ops.values():
+        if op.op_type == OpType.GATED_EXPERTS and state.get(op.name):
+            out[op.name] = _publish_gated(reg, op, state[op.name])
         if op.op_type != OpType.EXPERTS:
             continue
         vars_ = state.get(op.name)
@@ -70,11 +125,7 @@ def publish_moe_metrics(model,
             continue
         dropped = float(np.asarray(vars_["dropped"]))
         load = np.asarray(vars_["load"], dtype=np.float64)
-        key = (id(reg), op.name)
-        delta = dropped - _LAST_DROPPED.get(key, 0.0)
-        if delta > 0:
-            c_dropped.inc(delta, op=op.name)
-        _LAST_DROPPED[key] = dropped
+        _inc_to(c_dropped, (id(reg), op.name), dropped, op=op.name)
         for e, frac in enumerate(load):
             g_load.set(float(frac), op=op.name, expert=str(e))
         mean = float(load.mean()) if load.size else 0.0
@@ -87,4 +138,4 @@ def publish_moe_metrics(model,
 def reset_moe_publisher() -> None:
     """Forget the per-op published baselines (test isolation: the autouse
     obs reset zeroes the registry, so the deltas must restart from 0)."""
-    _LAST_DROPPED.clear()
+    _LAST_PUBLISHED.clear()

@@ -12,5 +12,6 @@ from . import norm  # noqa: F401
 from . import tensor_ops  # noqa: F401
 from . import embedding  # noqa: F401
 from . import attention  # noqa: F401
+from . import latent_attention  # noqa: F401
 from . import moe  # noqa: F401
 from . import rnn  # noqa: F401

@@ -141,6 +141,11 @@ class MultiHeadAttentionOp(Op):
             ]
         return specs
 
+    def kv_cache_arrays(self):
+        """A token's keys and values, the heads packed into one row each."""
+        _, _, _, _, heads, kdim, vdim = self._dims()
+        return {"k_cache": heads * kdim, "v_cache": heads * vdim}
+
     def lower(self, ctx, inputs, weights):
         q_in, k_in, v_in = inputs[:3]
         p = self.params

@@ -19,6 +19,8 @@ def apply_activation(x, activation: ActiMode):
         return jnp.tanh(x)
     if activation == ActiMode.AC_MODE_GELU:
         return jax.nn.gelu(x)
+    if activation == ActiMode.AC_MODE_SILU:
+        return jax.nn.silu(x)
     raise ValueError(f"unknown activation {activation}")
 
 

@@ -36,7 +36,9 @@ class LinearOp(Op):
             WeightSpec(
                 "kernel",
                 (x.dims[-1], out_dim),
-                dtype,
+                # a kernel may be stored narrower than the output it
+                # feeds (a bf16 vocabulary head emitting float32 logits)
+                self.params.get("kernel_dtype") or dtype,
                 self.params.get("kernel_initializer") or DefaultInitializer(),
             )
         ]

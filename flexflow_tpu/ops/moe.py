@@ -353,3 +353,200 @@ class CacheOp(Op):
         ctx.state_updates[(self.name, "score")] = score
         ctx.state_updates[(self.name, "cached")] = x
         return [cached if use_cached else x]
+
+
+# ---------------------------------------------------------------------
+# Dropless gated experts, told which experts they hold
+# ---------------------------------------------------------------------
+@register_op
+class MoERouterOp(Op):
+    """x (..., E) -> (weights (..., k) float32, expert ids (..., k) int32):
+    logits over ALL `n` experts accumulated in float32, the k largest, and
+    the softmax over those k logits (= softmax over n, top-k, renormalised:
+    `norm_topk_prob`), times `scale` (`routed_scaling_factor`). No bias, no
+    correction term."""
+
+    op_type = OpType.MOE_ROUTER
+
+    def output_shapes(self):
+        (x,) = self.inputs
+        out = tuple(x.dims[:-1]) + (self.params["k"],)
+        return [out, out], [DataType.DT_FLOAT, DataType.DT_INT32]
+
+    def weight_specs(self):
+        from ..core.op import WeightSpec
+        from ..runtime.initializers import DefaultInitializer
+
+        (x,) = self.inputs
+        return [WeightSpec(
+            "kernel", (x.dims[-1], self.params["n"]), x.dtype,
+            self.params.get("kernel_initializer") or DefaultInitializer())]
+
+    def lower(self, ctx, inputs, weights):
+        from .common import matmul_dtype
+
+        x = inputs[0]
+        cdt = matmul_dtype(getattr(ctx, "config", None), x.dtype)
+        with jax.named_scope("moe:route"):
+            logits = jnp.dot(x.astype(cdt), weights["kernel"].astype(cdt),
+                             preferred_element_type=jnp.float32)
+            top, idx = jax.lax.top_k(logits, self.params["k"])
+            w = jax.nn.softmax(top, axis=-1) * self.params.get("scale", 1.0)
+        return [w, idx.astype(jnp.int32)]
+
+    def flops(self) -> float:
+        x = self.inputs[0]
+        return 2.0 * moe_tokens(x.dims) * x.dims[-1] * self.params["n"]
+
+
+def local_assignments(idx, first: int, count: int):
+    """idx (T, k) expert ids over all experts -> (local (T*k,) int32: the
+    id among the `count` experts held here, `count` for an assignment that
+    falls on an absent expert; mine (T*k,) bool)."""
+    flat = idx.reshape(-1).astype(jnp.int32) - first
+    mine = (flat >= 0) & (flat < count)
+    return jnp.where(mine, flat, count), mine
+
+
+def gated_experts_oracle(x, w, idx, weights, first: int, count: int):
+    """The test oracle of `GatedExpertsOp`: every local expert applied to
+    every token under a mask, one expert at a time, in float32."""
+    xf = x.astype(jnp.float32)
+    out = jnp.zeros(xf.shape[:-1] + (weights["w_down"].shape[-1],),
+                    jnp.float32)
+    for e in range(count):
+        gate = jnp.sum(jnp.where(idx == first + e, w, 0.0), axis=-1)
+        h = (jax.nn.silu(xf @ weights["w_gate"][e].astype(jnp.float32))
+             * (xf @ weights["w_up"][e].astype(jnp.float32)))
+        out = out + gate[..., None] * (
+            h @ weights["w_down"][e].astype(jnp.float32))
+    return out
+
+
+@register_op
+class GatedExpertsOp(Op):
+    """The routed part of a gated (SiLU) expert layer for the experts THIS
+    holder has: inputs x (..., E), router weights (..., k), expert ids
+    (..., k) over all `experts_total`; weights w_gate / w_up
+    (count, E, F) and w_down (count, F, E), no bias.
+
+        y = sum_i [first <= id_i < first + count] w_i E_{id_i}(x),
+        E(x) = (silu(x W_g) * x W_u) W_d
+
+    `local_experts = (first, count)` is the contract of expert
+    parallelism: the router ranks all `experts_total`, this op keeps the
+    assignments that fall on its own experts and computes every one of
+    them — nothing is dropped, no capacity. What the absent experts add is
+    the other holders' part of the sum; on one holder nothing stands in
+    for it.
+
+    Assignments are sorted by local expert (absent ones last), the token
+    rows gathered in that order, and the three products are grouped
+    matmuls over the sorted rows (`jax.lax.ragged_dot`: the chip's
+    compiler lowers it to a grouped-GEMM kernel that reads the weights of
+    the experts that were hit). Rows are brought back to token order and
+    the k weighted parts of a token summed in float32.
+
+    Router health, threaded by the continuous batcher from one decode
+    iteration to the next (`serving_counters`): `assignments` (local
+    assignments so far), `experts_hit` (distinct local experts that got a
+    token, summed over steps), `steps`, `load` (the last step's
+    assignments per local expert). Dropped tokens: none, by construction.
+    """
+
+    op_type = OpType.GATED_EXPERTS
+    serving_counters = ("assignments", "experts_hit", "steps", "load")
+
+    def _local(self):
+        first, count = self.params["local_experts"]
+        total = self.params["experts_total"]
+        if not (0 <= first and count > 0 and first + count <= total):
+            raise ValueError(
+                f"gated_experts {self.name}: local_experts=({first},"
+                f" {count}) is not a range of the {total} experts")
+        return int(first), int(count)
+
+    def output_shapes(self):
+        x = self.inputs[0]
+        self._local()
+        return [x.dims], [x.dtype]
+
+    def weight_specs(self):
+        from ..core.op import WeightSpec
+        from ..runtime.initializers import DefaultInitializer
+
+        x = self.inputs[0]
+        e, f = x.dims[-1], self.params["expert_hidden_size"]
+        _, count = self._local()
+        user = self.params.get("kernel_initializer")
+        up = user or DefaultInitializer(fan_in=e, fan_out=f)
+        down = user or DefaultInitializer(fan_in=f, fan_out=e)
+        return [WeightSpec("w_gate", (count, e, f), x.dtype, up),
+                WeightSpec("w_up", (count, e, f), x.dtype, up),
+                WeightSpec("w_down", (count, f, e), x.dtype, down)]
+
+    def state_specs(self):
+        from ..core.op import WeightSpec
+        from ..runtime.initializers import ZeroInitializer
+
+        _, count = self._local()
+        z = ZeroInitializer()
+        return [WeightSpec("assignments", (), DataType.DT_INT32, z),
+                WeightSpec("experts_hit", (), DataType.DT_INT32, z),
+                WeightSpec("steps", (), DataType.DT_INT32, z),
+                WeightSpec("load", (count,), DataType.DT_INT32, z)]
+
+    def lower(self, ctx, inputs, weights):
+        from .common import emit_dtype, matmul_dtype
+
+        x, w, idx = inputs[:3]
+        first, count = self._local()
+        lead = x.shape[:-1]
+        x = x.reshape((-1, x.shape[-1]))
+        k = idx.shape[-1]
+        cdt = matmul_dtype(getattr(ctx, "config", None), x.dtype)
+
+        with jax.named_scope("moe:sort"):
+            local, mine = local_assignments(idx.reshape(-1, k), first, count)
+            order = jnp.argsort(local, stable=True)     # absent ones last
+            sizes = jnp.bincount(local, length=count + 1)[:count].astype(
+                jnp.int32)
+            rows = x[order // k].astype(cdt)            # (T*k, E)
+            held = jnp.arange(rows.shape[0]) < jnp.sum(sizes)
+        prev = ctx.state.get((self.name, "assignments"))
+        if prev is not None:
+            upd = ctx.state_updates
+            upd[(self.name, "assignments")] = prev + jnp.sum(sizes)
+            upd[(self.name, "experts_hit")] = (
+                ctx.state[(self.name, "experts_hit")]
+                + jnp.sum((sizes > 0).astype(jnp.int32)))
+            upd[(self.name, "steps")] = ctx.state[(self.name, "steps")] + 1
+            upd[(self.name, "load")] = sizes
+
+        with jax.named_scope("moe:experts"):
+            grouped = lambda a, m: jax.lax.ragged_dot(
+                a, m.astype(cdt), sizes, preferred_element_type=jnp.float32)
+            h = (jax.nn.silu(grouped(rows, weights["w_gate"]))
+                 * grouped(rows, weights["w_up"])).astype(cdt)
+            # rows past the held assignments belong to no group: the
+            # grouped product leaves them unwritten
+            y = jnp.where(held[:, None], grouped(h, weights["w_down"]), 0.0)
+
+        with jax.named_scope("moe:combine"):
+            back = jnp.argsort(order)                   # sorted row of (t, i)
+            gate = jnp.where(mine, w.reshape(-1).astype(jnp.float32), 0.0)
+            out = jnp.sum((y[back] * gate[:, None]).reshape(
+                (-1, k, y.shape[-1])), axis=1)
+        out = out.reshape(lead + (out.shape[-1],))
+        return [out.astype(emit_dtype(getattr(ctx, "config", None),
+                                      self.outputs[0].dtype))]
+
+    def flops(self) -> float:
+        """Operations of the assignments that fall here under a uniform
+        router: tokens x k x count / experts_total of them, 2 per weight of
+        an expert's three matrices."""
+        x = self.inputs[0]
+        _, count = self._local()
+        share = count / float(self.params["experts_total"])
+        t = moe_tokens(x.dims) * self.inputs[2].dims[-1] * share
+        return 2.0 * t * 3 * x.dims[-1] * self.params["expert_hidden_size"]

@@ -9,7 +9,7 @@ from typing import Dict, Optional
 
 import numpy as np
 
-from ..ffconst import CompMode, OpType
+from ..ffconst import CompMode
 
 
 def sampling_logits(probs, temperature: float, top_k):
@@ -54,10 +54,11 @@ class GenerativeSession:
                 f"window ({window}); the cache must hold at least one "
                 "full prefill")
         self.attn_ops = [op for op in model.graph.ops.values()
-                         if op.op_type == OpType.MULTIHEAD_ATTENTION]
+                         if op.kv_cache_arrays()]
         if not self.attn_ops:
-            raise ValueError("generation needs multihead_attention ops")
-        # ONE cache-geometry definition (heads/kdim/vdim + compute dtype —
+            raise ValueError("generation needs an attention op that keeps"
+                             " a serving cache")
+        # ONE cache-geometry definition (arrays per op + compute dtype —
         # bf16 under mixed precision, the dominant serving memory) shared
         # with the continuous batcher and the pool's HBM sizing
         from .sched.kvpool import zero_kv_caches

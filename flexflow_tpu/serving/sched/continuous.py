@@ -64,11 +64,11 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from ...ffconst import CompMode, OpType
+from ...ffconst import CompMode
 from ..batcher import BatcherStopped
 from .admission import AdmissionController
-from .kvpool import (PagedKVPool, derive_num_slots, write_slot_span,
-                     zero_kv_caches)
+from .kvpool import (PagedKVPool, derive_num_slots, kv_bytes_per_token,
+                     op_states, write_slot_span, zero_kv_caches)
 
 
 class RequestCancelled(RuntimeError):
@@ -441,10 +441,14 @@ class ContinuousBatcher:
                 " decodes here, and the draft's caches do not ship in"
                 " the KV handoff")
         self.role = role
+        # the ops that keep a serving cache, found by capability
+        # (Op.kv_cache_arrays), not by one op type
         self.attn_ops = [op for op in model.graph.ops.values()
-                         if op.op_type == OpType.MULTIHEAD_ATTENTION]
+                         if op.kv_cache_arrays()]
         if not self.attn_ops:
-            raise ValueError("generation needs multihead_attention ops")
+            raise ValueError(
+                "generation needs an attention op that keeps a serving"
+                " cache (multihead_attention, latent_attention)")
 
         # speculative decoding (docs/serving.md): a draft model proposes
         # `spec_tokens` greedy candidates per slot per iteration, the
@@ -490,10 +494,11 @@ class ContinuousBatcher:
                     " draft prefills through the same chunk entry")
             self.draft_attn_ops = [
                 op for op in draft_model.graph.ops.values()
-                if op.op_type == OpType.MULTIHEAD_ATTENTION]
+                if op.kv_cache_arrays()]
             if not self.draft_attn_ops:
                 raise ValueError(
-                    "draft model needs multihead_attention ops")
+                    "draft model needs an attention op that keeps a"
+                    " serving cache")
             tvocab = model.final_tensor.dims[-1]
             dvocab = draft_model.final_tensor.dims[-1]
             if tvocab != dvocab:
@@ -541,8 +546,6 @@ class ContinuousBatcher:
                 # the draft's slot-dense caches live beside the target's:
                 # scale the derived capacity by the combined per-token
                 # cache cost so the HBM estimate stays honest
-                from .kvpool import kv_bytes_per_token
-
                 tb = kv_bytes_per_token(model)
                 db = kv_bytes_per_token(draft_model)
                 derived = max(1, int(derived * tb / max(1, tb + db)))
@@ -590,6 +593,19 @@ class ContinuousBatcher:
                 "EWMA expert-signature overlap of admitted requests with"
                 " the running batch", labels=("pool",))
 
+        # state of the ops that count (Op.serving_counters: an expert
+        # layer's assignments and hits), threaded through decode_all from
+        # one iteration to the next; read by `op_counters()`
+        self._op_counters: Dict[str, Dict[str, object]] = {
+            op.name: {v: model.state[op.name][v]
+                      for v in op.serving_counters}
+            for op in model.graph.ops.values()
+            if op.serving_counters and op.name in (model.state or {})}
+        registry.gauge(
+            "ff_kvpool_bytes_per_token",
+            "Bytes of serving cache one token position costs across all"
+            " caching ops", labels=("pool",)).set(
+                kv_bytes_per_token(model), pool=self.pool.label)
         self._build_fns()
         self._caches = self._zero_caches()
         self._band = self._zero_band()
@@ -724,6 +740,7 @@ class ContinuousBatcher:
         input_name = model.input_ops[0].name
         max_len = self.max_len
         attn_names = [op.name for op in self.attn_ops]
+        counter_names = sorted(self._op_counters)
         temperature, top_k = self.temperature, self.top_k
 
         from ..generate import sampling_logits
@@ -743,12 +760,8 @@ class ContinuousBatcher:
 
         def small_caches(big):
             return {
-                name: {
-                    "k_cache": jnp.zeros((1,) + big[name]["k_cache"].shape[1:],
-                                         big[name]["k_cache"].dtype),
-                    "v_cache": jnp.zeros((1,) + big[name]["v_cache"].shape[1:],
-                                         big[name]["v_cache"].dtype),
-                }
+                name: {part: jnp.zeros((1,) + arr.shape[1:], arr.dtype)
+                       for part, arr in big[name].items()}
                 for name in attn_names
             }
 
@@ -763,11 +776,7 @@ class ContinuousBatcher:
                 params, st, {input_name: tokens}, None,
                 CompMode.COMP_MODE_INFERENCE, fill_kv_cache=True)
             probs = values[final_guid]  # (1, window, V)
-            small = {
-                name: {"k_cache": new_state[name]["k_cache"],
-                       "v_cache": new_state[name]["v_cache"]}
-                for name in attn_names
-            }
+            small = op_states(new_state, attn_names)
             return _scatter_and_pick(caches, small, slot, probs, plen - 1,
                                      plen - 1, key)
 
@@ -775,23 +784,18 @@ class ContinuousBatcher:
             """One decode iteration over EVERY slot: toks (S,) last tokens,
             pos (S,) per-slot write positions, keys (S, 2) per-request PRNG
             keys. Inactive slots carry dummy operands; their outputs are
-            discarded host-side."""
-            flat = {}
-            for name in attn_names:
-                flat[name] = dict(caches[name])
-            st = {**state, **flat}
+            discarded host-side. Also handed back: the state of the ops
+            that count (`Op.serving_counters`), which the caller threads
+            into the next iteration's `state`."""
+            st = {**state, **op_states(caches, attn_names)}
             values, new_state, _ = executor.forward_values(
                 params, st, {input_name: toks[:, None]}, None,
                 CompMode.COMP_MODE_INFERENCE, decode_pos=pos)
             probs = values[final_guid][:, 0, :]  # (S, V)
             with jax.named_scope("sample:pick"):
                 next_tok = jax.vmap(pick_row)(probs, pos, keys)
-            new_caches = {
-                name: {"k_cache": new_state[name]["k_cache"],
-                       "v_cache": new_state[name]["v_cache"]}
-                for name in attn_names
-            }
-            return next_tok, new_caches
+            return (next_tok, op_states(new_state, attn_names),
+                    op_states(new_state, counter_names))
 
         def chunk_forward(executor_, input_name_, attn_names_, params,
                           state, small, tokens, off):
@@ -807,11 +811,7 @@ class ContinuousBatcher:
             values, new_state, _ = executor_.forward_values(
                 params, st, {input_name_: tokens}, None,
                 CompMode.COMP_MODE_INFERENCE, decode_pos=off)
-            return values, {
-                name: {"k_cache": new_state[name]["k_cache"],
-                       "v_cache": new_state[name]["v_cache"]}
-                for name in attn_names_
-            }
+            return values, op_states(new_state, attn_names_)
 
         def scatter_span(pool_caches, small, slot, attn_names_):
             """Batch-1 -> pool-slot cache-span scatter, shared by the
@@ -825,7 +825,7 @@ class ContinuousBatcher:
                         part: write_slot_span(
                             pool_caches[name][part],
                             small[name][part][:, :max_len], slot)
-                        for part in ("k_cache", "v_cache")
+                        for part in pool_caches[name]
                     }
             return out
 
@@ -890,13 +890,10 @@ class ContinuousBatcher:
             new_band = {}
             with jax.named_scope("kv:prefix"):
                 for name in attn_names:
-                    rows_k = caches[name]["k_cache"][slot, src_rows]
-                    rows_v = caches[name]["v_cache"][slot, src_rows]
                     new_band[name] = {
-                        "k_cache": band[name]["k_cache"].at[
-                            dst_slots, dst_rows].set(rows_k),
-                        "v_cache": band[name]["v_cache"].at[
-                            dst_slots, dst_rows].set(rows_v),
+                        part: arr.at[dst_slots, dst_rows].set(
+                            caches[name][part][slot, src_rows])
+                        for part, arr in band[name].items()
                     }
             return new_band
 
@@ -987,11 +984,7 @@ class ContinuousBatcher:
                 cur = jnp.argmax(values[dfinal_guid][:, 0, :],
                                  axis=-1).astype(jnp.int32)
                 props.append(cur)
-                dc = {
-                    name: {"k_cache": new_state[name]["k_cache"],
-                           "v_cache": new_state[name]["v_cache"]}
-                    for name in dattn_names
-                }
+                dc = op_states(new_state, dattn_names)
             props = jnp.stack(props, axis=1)                  # (S, k)
             qtoks = jnp.concatenate([toks[:, None], props], axis=1)
             st = {**state,
@@ -1006,11 +999,7 @@ class ContinuousBatcher:
             match = (props == tgt[:, :k_spec]).astype(jnp.int32)
             n_acc = jnp.sum(jnp.cumprod(match, axis=1), axis=1)
             counts = jnp.minimum(n_acc + 1, k_spec)
-            new_caches = {
-                name: {"k_cache": new_state[name]["k_cache"],
-                       "v_cache": new_state[name]["v_cache"]}
-                for name in attn_names
-            }
+            new_caches = op_states(new_state, attn_names)
             return tgt[:, :k_spec], counts, n_acc, new_caches, dc
 
         self._draft_chunk_fn = jax.jit(draft_chunk, donate_argnums=(2,))
@@ -1704,6 +1693,23 @@ class ContinuousBatcher:
             }
         return out
 
+    def op_counters(self) -> Dict[str, Dict[str, object]]:
+        """{op name: {counter: host value}} of the ops that count
+        (`Op.serving_counters`) as the last finished decode iteration left
+        them; fetches from the device, so it is for whoever asks (a
+        dashboard's scrape, the benchmark), not for the loop."""
+        import jax
+
+        return jax.device_get(self._op_counters)
+
+    def publish_op_counters(self) -> Dict:
+        """Mirror the expert layers' counters into the registry's
+        `ff_moe_*` families (obs/moe.py)."""
+        from ...obs.moe import publish_moe_metrics
+
+        return publish_moe_metrics(self.model, self.registry,
+                                   state=self.op_counters())
+
     # -- scheduler loop ----------------------------------------------------
     def _idle_locked(self) -> bool:
         """Nothing to schedule (caller holds _cv). PARKED slots hold KV
@@ -1811,8 +1817,10 @@ class ContinuousBatcher:
                     sp.set(requests=[s.req.id for s in active])
                 t0 = time.monotonic()
                 with tracer.span("serve.decode_dispatch"):
-                    next_tok, self._caches = self._decode_fn(
-                        params, state, self._caches, toks, pos, keys)
+                    next_tok, self._caches, self._op_counters = \
+                        self._decode_fn(
+                            params, {**state, **self._op_counters},
+                            self._caches, toks, pos, keys)
                 with tracer.span("serve.decode_fetch"):
                     next_tok = np.asarray(next_tok)  # sync
                 self._observe_decode_iter(time.monotonic() - t0)

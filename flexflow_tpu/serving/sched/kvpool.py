@@ -2,10 +2,11 @@
 
 Physical layout vs logical pages
 --------------------------------
-The device arrays backing the pool are slot-dense: per attention op one
-``(num_slots, max_len, heads * head_dim)`` K and V cache — a token's heads
-PACKED into one row, so the last dimension is a multiple of the chip's 128
-lanes at every published width and the decode step scatters into it and
+The device arrays backing the pool are slot-dense: per caching op the
+arrays its `kv_cache_arrays()` names (`kv_cache_spec`), each ``(num_slots,
+max_len, width)`` — for a `multihead_attention` a K and a V cache of
+``heads * head_dim``, a token's heads PACKED into one row, so the last
+dimension is a multiple of the chip's 128 lanes at every published width and the decode step scatters into it and
 contracts on it in place (ops/attention.py `_decode_step`,
 kernels/pallas/decode.py; a ``(…, heads, 64)`` cache was relaid out and
 lane-padded twice per layer per iteration). `kv_cache_spec` is the
@@ -54,7 +55,6 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from ...ffconst import OpType
 
 # distinguishes concurrent pools' gauge series on /metrics
 _POOL_IDS = itertools.count()
@@ -737,46 +737,53 @@ class PagedKVPool:
 
 
 def kv_cache_spec(model) -> List[tuple]:
-    """[(op_name, heads, kdim, vdim, jnp cache dtype)] for every attention
-    op — THE cache geometry: op `name` stores a K cache of
-    (rows, max_len, heads*kdim) and a V cache of (rows, max_len,
-    heads*vdim). Shared by pool sizing (`kv_bytes_per_token`) and the one
-    allocation (`zero_kv_caches`: the ContinuousBatcher's slot, band,
-    draft and batch-1 caches, GenerativeSession's lockstep caches), so the
-    HBM estimate can never drift from what actually gets allocated. The
-    dtype is the attention compute dtype (bf16 under mixed
-    precision — the KV cache is the dominant serving memory)."""
+    """[(op_name, {array name: values a token stores in it}, jnp cache
+    dtype)] for every op that keeps a serving cache (`Op.kv_cache_arrays`)
+    — THE cache geometry: op `name` stores each named array as (rows,
+    max_len, width). A `multihead_attention` stores `k_cache` and `v_cache`
+    of heads*kdim and heads*vdim; a latent attention `c_kv` of
+    kv_lora_rank and `k_rope`, the rotary key padded to a 128-lane tile
+    (ops/latent_attention.py says why). Shared by pool sizing
+    (`kv_bytes_per_token`) and the one allocation (`zero_kv_caches`: the
+    ContinuousBatcher's slot, band, draft and batch-1 caches,
+    GenerativeSession's lockstep caches), so the HBM estimate can never
+    drift from what actually gets allocated. The dtype is the attention
+    compute dtype (bf16 under mixed precision — the KV cache is the
+    dominant serving memory)."""
     from ...ops.common import matmul_dtype
 
     out = []
     for op in model.graph.ops.values():
-        if op.op_type != OpType.MULTIHEAD_ATTENTION:
+        arrays = op.kv_cache_arrays()
+        if not arrays:
             continue
-        heads = op.params["num_heads"]
-        kdim = op.params.get("kdim") or op.params["embed_dim"] // heads
-        vdim = op.params.get("vdim") or op.params["embed_dim"] // heads
         cdt = matmul_dtype(model.config, op.inputs[0].dtype.jnp_dtype)
-        out.append((op.name, heads, kdim, vdim, cdt))
+        out.append((op.name, dict(arrays), cdt))
     if not out:
         raise ValueError(
-            "model has no multihead_attention ops: nothing to cache")
+            "model has no op that keeps a serving cache (an attention op"
+            " declaring kv_cache_arrays): nothing to cache")
     return out
 
 
 def zero_kv_caches(model, rows: int, max_len: int) -> Dict[str, Dict]:
-    """{op_name: {"k_cache", "v_cache"}} of zeros AS STORED: `rows`
-    sequences (pool slots, band rows, or 1) of `max_len` token rows, each
-    token's heads packed into one (heads*dim,) row. The only place that
-    writes the stored shape out."""
+    """{op_name: {array name: zeros}} AS STORED: `rows` sequences (pool
+    slots, band rows, or 1) of `max_len` token rows, each of the width its
+    op declares. The only place that writes the stored shape out."""
     import jax.numpy as jnp
 
     return {
-        name: {
-            "k_cache": jnp.zeros((rows, max_len, heads * kdim), cdt),
-            "v_cache": jnp.zeros((rows, max_len, heads * vdim), cdt),
-        }
-        for name, heads, kdim, vdim, cdt in kv_cache_spec(model)
+        name: {part: jnp.zeros((rows, max_len, width), cdt)
+               for part, width in arrays.items()}
+        for name, arrays, cdt in kv_cache_spec(model)
     }
+
+
+def op_states(state, names) -> Dict[str, Dict]:
+    """The entries of ops `names` out of an op-state tree (a step's
+    `new_state`, the pool's caches): a caching op's entry is its cache
+    arrays under the names its spec gives, a counting op's its counters."""
+    return {name: dict(state[name]) for name in names}
 
 
 def write_slot_span(cache, span, slot):
@@ -791,12 +798,12 @@ def write_slot_span(cache, span, slot):
 
 
 def kv_bytes_per_token(model) -> int:
-    """Bytes of K+V cache one token position costs across every attention
-    op (see kv_cache_spec for the geometry/dtype contract)."""
+    """Bytes of cache one token position costs across every caching op
+    (see kv_cache_spec for the geometry/dtype contract)."""
     import jax.numpy as jnp
 
-    return sum(heads * (kdim + vdim) * jnp.dtype(cdt).itemsize
-               for _, heads, kdim, vdim, cdt in kv_cache_spec(model))
+    return sum(sum(arrays.values()) * jnp.dtype(cdt).itemsize
+               for _, arrays, cdt in kv_cache_spec(model))
 
 
 def derive_num_slots(model, max_len: int, machine=None,

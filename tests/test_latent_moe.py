@@ -1,0 +1,344 @@
+"""Latent attention (ops/latent_attention.py, ops/rope.py), the dropless
+gated experts told which experts they hold (ops/moe.py) and the latent
+serving cache (serving/sched/kvpool.py), against the plain reference the
+benchmark keeps (benchmark/reference/mla_moe_lm.py) at a small size:
+hidden 64, 4 heads, q 32 / kv 16 / nope 8 / rope 8 / v 16, 8 experts top-2
+plus a shared expert, 2 layers, vocabulary 128."""
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import flexflow_tpu as ff
+from benchmark import harness
+from benchmark.configs import mla_moe_lm as builder
+from benchmark.metrics import mla_moe_shapes as shapes
+from benchmark.reference import mla_moe_lm as ref
+from flexflow_tpu.core.op import LoweringContext
+from flexflow_tpu.ffconst import CompMode
+from flexflow_tpu.ops import rope
+from flexflow_tpu.ops.moe import gated_experts_oracle
+from flexflow_tpu.serving.sched import kvpool
+from flexflow_tpu.serving.sched.continuous import ContinuousBatcher
+from tests.conftest import module_xla_cache
+
+_xla_cache = pytest.fixture(scope="module", autouse=True)(module_xla_cache)
+
+SEED = 2**31 + 77
+REAL = harness.load_config("mistral_small4_ep4")
+ROPE = REAL["rope_parameters"]
+
+
+def tiny_cfg(**over):
+    cfg = dict(REAL, num_hidden_layers=2, hidden_size=64,
+               num_attention_heads=4, q_lora_rank=32, kv_lora_rank=16,
+               qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=16,
+               moe_intermediate_size=32, router_width=8, n_routed_experts=8,
+               first_local_expert=0, num_experts_per_tok=2, vocab_size=128,
+               tensor_dtype="float32")
+    cfg["deployment"] = dict(REAL["deployment"], declared_batch=1, window=32,
+                             num_slots=3, max_len=64, page_size=8,
+                             prefill_chunk_tokens=16, max_queue=64)
+    cfg.update(over)
+    return cfg
+
+
+@pytest.fixture(scope="module")
+def lm():
+    cfg = tiny_cfg()
+    return cfg, builder.build_model(cfg, SEED)
+
+
+def ref_logits(cfg, tokens):
+    """(T, V) float32 logits of the reference's one causal pass, padded to
+    its query block."""
+    n = len(tokens)
+    padded = np.zeros((ref.pad_length(n, n),), np.int32)
+    padded[:n] = tokens
+    logits, _ = ref.forward(lambda g: builder.make_group(cfg, SEED, g), cfg,
+                            [padded], ["float32"])
+    return np.asarray(logits["float32"][0])[:n]
+
+
+def test_forward_logits_match_the_reference(lm):
+    cfg, model = lm
+    toks = np.random.default_rng(0).integers(0, 128, 32, dtype=np.int32)
+    values, _, _ = model.executor.forward_values(
+        model.params, model.state, {model.input_ops[0].name: toks[None]},
+        None, CompMode.COMP_MODE_INFERENCE)
+    probs = np.asarray(values[model.final_tensor.guid])[0]
+    want = jax.nn.softmax(ref_logits(cfg, toks), axis=-1)
+    np.testing.assert_allclose(probs, want, rtol=2e-4, atol=1e-7)
+
+
+def test_batcher_prefill_and_decode_match_one_causal_pass(lm):
+    """Chunked prefill (prompts of one, two and three chunks, so chunk
+    offsets > 0), decode through the latent cache, slots that start and end
+    at different times: every served token is the reference's best at its
+    position, to a rounding error."""
+    cfg, model = lm
+    rng = np.random.default_rng(1)
+    prompts = [rng.integers(0, 128, n, dtype=np.int32)
+               for n in (5, 20, 37, 9, 16, 33)]
+    cb = builder.build_batcher(model, cfg)
+    with cb:
+        reqs = [cb.submit(p, n) for p, n in zip(prompts, (9, 6, 12, 3, 7, 5))]
+        outs = [r.result(timeout=300) for r in reqs]
+        counts = cb.publish_op_counters()
+    for p, out in zip(prompts, outs):
+        z = ref_logits(cfg, np.concatenate([p, out]))
+        rows = z[len(p) - 1:len(p) - 1 + len(out)]
+        gap = rows.max(-1) - rows[np.arange(len(out)), out]
+        assert gap.max() < 1e-4, gap
+    # the layers counted their decode iterations, and dropped nothing
+    assert all(c["dropped"] == 0.0 and c["steps"] > 0
+               and c["assignments"] == 2 * 3 * c["steps"]
+               for c in counts.values()), counts
+
+
+def _mla_op(cfg, batch=2, length=6):
+    config = ff.FFConfig()
+    config.allow_mixed_precision = False
+    m = ff.FFModel(config)
+    x = m.create_tensor([batch, length, cfg["hidden_size"]])
+    m.latent_attention(x, cfg["num_attention_heads"], cfg["q_lora_rank"],
+                       cfg["kv_lora_rank"], cfg["qk_nope_head_dim"],
+                       cfg["qk_rope_head_dim"], cfg["v_head_dim"],
+                       rope_parameters=ROPE, name="attn")
+    return m, m.ops[-1]
+
+
+def _weights_of(op, seed=0, scale=0.3):
+    key = jax.random.PRNGKey(seed)
+    return {w._weight_spec.name: scale * jax.random.normal(
+        jax.random.fold_in(key, i), w.dims, jnp.float32)
+        for i, w in enumerate(op.weights)}
+
+
+@pytest.mark.parametrize("start", [0, 8190])
+def test_absorbed_decode_equals_expanded(start):
+    """Token by token through the latent cache (absorbed, a vector of
+    positions) = one expanded causal pass; from position 8,190 on the
+    queries cross 8,192, where a(t) leaves 1."""
+    cfg = tiny_cfg()
+    b, n = 2, 6
+    m, op = _mla_op(cfg, b, n)
+    w = _weights_of(op)
+    x = jax.random.normal(jax.random.PRNGKey(3), (b, n, 64), jnp.float32)
+    rows = start + n
+
+    def lower(x_in, cache, pos):
+        ctx = LoweringContext(m.config, CompMode.COMP_MODE_INFERENCE)
+        ctx.state[("attn", "c_kv")], ctx.state[("attn", "k_rope")] = cache
+        ctx.decode_pos = pos
+        out = op.lower(ctx, [x_in], w)[0]
+        return out, (ctx.state_updates[("attn", "c_kv")],
+                     ctx.state_updates[("attn", "k_rope")])
+
+    # expanded: the chunk entry at offset `start` of an empty batch-1 cache
+    # per row (earlier rows are zeros and masked by nothing, so feed them)
+    zeros = lambda n: (jnp.zeros((n, rows, cfg["kv_lora_rank"])),
+                       jnp.zeros((n, rows, 128)))
+    want = []
+    for i in range(b):
+        out, _ = lower(x[i:i + 1], zeros(1), jnp.int32(start))
+        want.append(out[0])
+    # absorbed: one token a step, per-slot positions
+    cache = zeros(b)
+    got = []
+    for j in range(n):
+        out, cache = lower(x[:, j:j + 1], cache,
+                           jnp.full((b,), start + j, jnp.int32))
+        got.append(out[:, 0])
+    if start:   # rows < start hold zeros on both sides: same softmax mass
+        assert float(rope.position_scale(jnp.array([start + n - 1]), ROPE)[0]
+                     ) == pytest.approx(1 + 0.1 * math.log(2))
+    np.testing.assert_allclose(np.stack(got, 1), np.stack(want), rtol=2e-5,
+                               atol=2e-6)
+
+
+def test_rope_table_by_hand():
+    """YaRN's blended frequencies, the interleaved rotation and the
+    position scaling against numbers worked out by hand from the published
+    rope_parameters (theta 10,000, dim 64, factor 128, original 8,192,
+    beta_fast 32, beta_slow 1)."""
+    f = rope.inv_freq(64, ROPE)
+    # correction range: dim * ln(ctx / (turns 2 pi)) / (2 ln theta)
+    lo = math.floor(64 * math.log(8192 / (32 * 2 * math.pi)) / (2 * math.log(1e4)))
+    hi = math.ceil(64 * math.log(8192 / (1 * 2 * math.pi)) / (2 * math.log(1e4)))
+    assert (lo, hi) == (12, 25)
+    base = lambda j: 1e4 ** (-2 * j / 64)
+    assert f[0] == pytest.approx(1.0) and f[12] == pytest.approx(base(12))
+    assert f[31] == pytest.approx(base(31) / 128)
+    mid = (18 - lo) / (hi - lo)      # pair 18: part extrapolated, part not
+    assert f[18] == pytest.approx(base(18) * (1 - mid) + base(18) / 128 * mid)
+    assert rope.table_scale(ROPE) == pytest.approx(1.0)
+    m = 0.1 * math.log(128) + 1
+    assert rope.attention_scale(128, ROPE) == pytest.approx(128 ** -0.5 * m * m)
+    # a(t): 1 below 8,192; 1 + 0.1 ln 2 at 8,192..16,383; 1 + 0.1 ln 3 after
+    a = rope.position_scale(jnp.array([0, 8191, 8192, 16384]), ROPE)
+    np.testing.assert_allclose(a, [1, 1, 1 + 0.1 * math.log(2),
+                                   1 + 0.1 * math.log(3)], rtol=1e-6)
+    # position 9,000, pair 12 of an interleaved vector (x[24], x[25])
+    x = jnp.arange(64, dtype=jnp.float32)
+    cos, sin = rope.cos_sin(jnp.array([9000]), 64, ROPE)
+    y = np.asarray(rope.rotate_interleaved(x[None], cos, sin))[0]
+    ang = 9000 * base(12)
+    assert y[24] == pytest.approx(24 * math.cos(ang) - 25 * math.sin(ang), rel=1e-3)
+    assert y[25] == pytest.approx(24 * math.sin(ang) + 25 * math.cos(ang), rel=1e-3)
+    # and the reference's own table agrees
+    np.testing.assert_allclose(ref.inv_freq(64, ROPE), f, rtol=1e-12)
+
+
+def _experts_op(cfg, local, tokens=24):
+    config = ff.FFConfig()
+    config.allow_mixed_precision = False
+    m = ff.FFModel(config)
+    x = m.create_tensor([tokens, cfg["hidden_size"]])
+    w, idx = m.moe_router(x, cfg["router_width"], cfg["num_experts_per_tok"],
+                          name="router")
+    m.gated_experts(x, w, idx, cfg["router_width"],
+                    cfg["moe_intermediate_size"], local_experts=local,
+                    name="experts")
+    return m, m.ops[-2], m.ops[-1]
+
+
+def _lower(m, op, ins, weights):
+    ctx = LoweringContext(m.config, CompMode.COMP_MODE_INFERENCE)
+    return op.lower(ctx, ins, weights)
+
+
+def test_grouped_experts_equal_the_masked_oracle_and_drop_nothing():
+    cfg = tiny_cfg()
+    m, router, experts = _experts_op(cfg, (2, 4))
+    x = jax.random.normal(jax.random.PRNGKey(0), (24, 64), jnp.float32)
+    w, idx = _lower(m, router, [x], _weights_of(router, 1))
+    assert np.allclose(np.asarray(w).sum(-1), 1.0, atol=1e-6)
+    ew = _weights_of(experts, 2)
+    got = _lower(m, experts, [x, w, idx], ew)[0]
+    want = gated_experts_oracle(x, w, idx, ew, 2, 4)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+    # a router forced onto ONE expert: all 24 x 2 assignments of a step land
+    # on it (a capacity of ceil(alpha k T / n) would have dropped most)
+    forced = jnp.full((24, 2), 3, jnp.int32)
+    half = jnp.full((24, 2), 0.5, jnp.float32)
+    got = _lower(m, experts, [x, half, forced], ew)[0]
+    want = gated_experts_oracle(x, half, forced, ew, 2, 4)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+    assert float(jnp.abs(got).min(axis=-1).max()) > 0  # no token zeroed
+
+
+def test_four_shares_and_the_shared_expert_add_up_to_the_uncut_layer():
+    """The share test: each of 4 holders computes the routed part its own
+    2 of the 8 experts give; those four parts, plus the shared expert
+    counted once, are the reference's uncut layer."""
+    cfg = tiny_cfg()
+    lp = builder.make_group(cfg, SEED, "l0")
+    x = jax.random.normal(jax.random.PRNGKey(5), (24, 64), jnp.float32)
+    uncut, _ = ref.moe(x, lp, "l0", cfg, "float32")
+    m, router, _ = _experts_op(cfg, (0, 8))
+    w, idx = _lower(m, router, [x], lp["l0_router"])
+    total = ref.gated_mlp(x, lp["l0_shared_gate"]["kernel"],
+                          lp["l0_shared_up"]["kernel"],
+                          lp["l0_shared_down"]["kernel"], "float32")
+    for s in range(4):
+        ms, _, experts = _experts_op(cfg, (2 * s, 2))
+        mine = {k: v[2 * s:2 * s + 2] for k, v in lp["l0_experts"].items()}
+        total = total + _lower(ms, experts, [x, w, idx], mine)[0]
+    np.testing.assert_allclose(total, uncut, rtol=2e-5, atol=2e-6)
+
+
+def _real_width_graph(layers):
+    """The configuration at its published widths as a GRAPH (no weights are
+    made): what the cache spec, flops() and the memory gate read."""
+    from flexflow_tpu.core.graph import Graph
+
+    cfg = dict(REAL, num_hidden_layers=layers)
+    config = ff.FFConfig()
+    config.batch_size = 1
+    config.allow_mixed_precision = False
+    config.num_devices = 1
+    real_compile = ff.FFModel.compile
+    try:        # build_model's calls, stopped before compile()
+        ff.FFModel.compile = lambda self, **kw: (_ for _ in ()).throw(
+            StopIteration(self))
+        builder.build_model(cfg, 0)
+    except StopIteration as stop:
+        model = stop.args[0]
+    finally:
+        ff.FFModel.compile = real_compile
+    model.graph = Graph(model.ops)
+    return cfg, model
+
+
+def test_real_width_cache_bytes_flops_and_memory_gate():
+    from flexflow_tpu.analysis import plan_memory_bytes
+    from flexflow_tpu.search.machine_model import make_machine_model
+
+    cfg, model = _real_width_graph(6)
+    # a token stores its 256-wide latent and its 64-wide rotary key, the
+    # key padded to one 128-lane tile: 768 B a layer in bf16 as stored, of
+    # which the algorithm reads 640 (the benchmark's roofline counts those)
+    spec = kvpool.kv_cache_spec(model)
+    assert [a for _, a, _ in spec] == [{"c_kv": 256, "k_rope": 128}] * 6
+    assert kvpool.kv_bytes_per_token(model) == 768 * 6
+    d = shapes.dims(cfg)
+    assert (d["kvr"] + d["rope"]) * d["cache_bytes"] == 640
+    # flops() against the benchmark's hand counts (window 512, batch 1)
+    ops = {op.name: op for op in model.ops}
+    t = 512
+    assert shapes.attention_params(cfg) == 28_049_408
+    assert shapes.expert_params(cfg) == 25_165_824
+    core = 2.0 * 32 * t * t * (64 + 64 + 128)
+    assert ops["l0_attn"].flops() == 2.0 * t * shapes.attention_params(cfg) + core
+    # uniform router: t x 4 x 32/128 assignments fall here
+    assert ops["l0_experts"].flops() == 2.0 * t * shapes.expert_params(cfg)
+    assert ops["l0_router"].flops() == 2.0 * t * 4096 * 128
+    # the plan gate: 10.85 GB of bf16 weights fit the 16 GB chip at declared
+    # batch 1 as an inference deployment; 9 layers (16 GB) do not
+    machine = make_machine_model(model.config, 1)
+    fits, _, _ = plan_memory_bytes(model.graph, machine, model.config,
+                                   optimizer_state_factor=1.0)
+    weights = sum(w.num_elements() * 2 for op in model.ops for w in op.weights)
+    assert weights == pytest.approx(10.85e9, rel=0.01)
+    assert weights <= fits <= weights + 0.3e9
+    assert fits < machine.memory_budget_bytes()
+    _, big = _real_width_graph(9)
+    over, _, _ = plan_memory_bytes(big.graph, machine, big.config,
+                                   optimizer_state_factor=1.0)
+    assert over > machine.memory_budget_bytes()
+
+
+def test_prefix_install_and_kv_export_import_round_trip_the_latent_cache(lm):
+    """A prompt served again installs its pages from the prefix band (a
+    latent array like any other); a parked request's latent rows exported
+    from a prefill-role batcher and imported into a decode-role one
+    continue token for token."""
+    cfg, model = lm
+    rng = np.random.default_rng(7)
+    prompt = rng.integers(0, 128, 37, dtype=np.int32)
+    with builder.build_batcher(model, cfg) as cb:
+        first = cb.submit(prompt, 8).result(timeout=300)
+        again = cb.submit(prompt, 8)
+        assert np.array_equal(again.result(timeout=300), first)
+        assert again.prefix_tokens >= 32     # four pages of 8 were installed
+    dep = cfg["deployment"]
+    mk = lambda role: ContinuousBatcher(
+        model, max_len=dep["max_len"], num_slots=2, page_size=dep["page_size"],
+        prefill_chunk_tokens=dep["prefill_chunk_tokens"], role=role)
+    with mk("prefill") as pre, mk("decode") as dec:
+        h = pre.submit(prompt, 8)
+        while not pre.parked_requests():
+            assert not h.done()
+        exp = pre.request_export(h).wait(timeout=60)
+        assert set(exp["rows"]) == {f"l{i}_attn/{part}" for i in (0, 1)
+                                    for part in ("c_kv", "k_rope")}
+        assert exp["rows"]["l0_attn/c_kv"].shape == (37, 16)
+        assert exp["rows"]["l0_attn/k_rope"].shape == (37, 128)
+        got = dec.request_import(
+            exp["desc"], exp["rows"], prompt, exp["last_tok"], 7).wait(60)
+        rest = got.result(timeout=300)
+        pre.release_parked(h)
+    assert np.array_equal(np.concatenate([[exp["last_tok"]], rest]), first)

@@ -165,3 +165,56 @@ def test_decode_all_copies_no_whole_cache_on_v5e(v5e):
         if m and np.prod([int(d) for d in m.group(1).split(",")]) >= cache.size
     ]
     assert not whole_cache_copies, whole_cache_copies
+
+
+def test_decode_all_copies_no_latent_cache_on_v5e(v5e):
+    """The twin of the test above for the LATENT cache (ops/
+    latent_attention.py): one layer at the published widths (32 heads, q
+    1,024 / kv 256 / nope 64 / rope 64 / v 128) in bf16, 8 slots x 256
+    rows. `c_kv` (…, 256) and `k_rope` (…, 128: the 64-wide rotary key
+    padded to a lane tile) are scattered into and contracted on in place;
+    stored as one (…, 320) row or with a (…, 64) key the chip's compiler
+    hands them over rows-minor and copies each whole twice an iteration.
+    The expert layer's grouped matmuls have to compile for the chip too
+    (`jax.lax.ragged_dot` becomes a grouped-GEMM kernel)."""
+    import re
+    from unittest import mock
+
+    from benchmark import harness
+    from benchmark.configs import mla_moe_lm as builder
+
+    slots, rows = 8, 256
+    cfg = harness.load_config("mistral_small4_ep4")
+    cfg.update(num_hidden_layers=1, vocab_size=512, n_routed_experts=4,
+               moe_intermediate_size=256)
+    cfg["deployment"] = dict(cfg["deployment"], window=64, num_slots=slots,
+                             max_len=rows, prefill_chunk_tokens=64)
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        model = builder.build_model(cfg, 0)
+        batcher = builder.build_batcher(model, cfg)
+        on_chip = lambda tree: jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+            tree)
+        vec = lambda *shape, dtype=I32: jax.ShapeDtypeStruct(
+            shape, dtype, sharding=v5e)
+        compiled = batcher._decode_fn.lower(
+            on_chip(model.params), on_chip(model.state),
+            on_chip(batcher._caches), vec(slots), vec(slots),
+            vec(slots, 2, dtype=jnp.uint32)).compile()
+
+    caches = batcher._caches["l0_attn"]
+    assert {k: v.shape for k, v in caches.items()} == {
+        "c_kv": (slots, rows, 256), "k_rope": (slots, rows, 128)}
+    assert caches["c_kv"].dtype == jnp.bfloat16
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3     # the three grouped matmuls
+    entry = text[text.index("ENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    smallest = min(int(a.size) for a in caches.values())
+    whole_cache_copies = [
+        line.strip()[:120] for line in entry.splitlines()
+        for m in [re.match(r"\s*(?:ROOT )?\S+ = \w+\[([\d,]+)\]\S* copy\(",
+                           line)]
+        if m and np.prod([int(d) for d in m.group(1).split(",")]) >= smallest
+    ]
+    assert not whole_cache_copies, whole_cache_copies
