@@ -29,6 +29,9 @@ threaded through the continuous batcher's decode iterations and handed to
    experts that received a token, summed over steps
  - ff_moe_expert_load_max_over_mean  Gauge,   labels=(op,): the last
    step's fullest local expert over the mean one
+ - ff_moe_few_rows_steps_total       Counter, labels=(op,): the steps whose
+   routed product took the few-rows form (ops/moe.py `few_rows`); over the
+   op's `steps` it is the share of decode iterations on that path
 """
 from __future__ import annotations
 
@@ -58,7 +61,7 @@ def moe_router_families(registry: Optional[MetricsRegistry] = None):
 
 def gated_experts_families(registry: Optional[MetricsRegistry] = None):
     """(local assignments counter, experts hit counter, load max-over-mean
-    gauge) of the dropless GatedExpertsOp."""
+    gauge, few-rows steps counter) of the dropless GatedExpertsOp."""
     reg = registry if registry is not None else REGISTRY
     return (
         reg.counter("ff_moe_local_assignments_total",
@@ -69,7 +72,10 @@ def gated_experts_families(registry: Optional[MetricsRegistry] = None):
                     " over steps", labels=("op",)),
         reg.gauge("ff_moe_expert_load_max_over_mean",
                   "Fullest local expert over the mean one, last step",
-                  labels=("op",)))
+                  labels=("op",)),
+        reg.counter("ff_moe_few_rows_steps_total",
+                    "Steps whose routed product took the few-rows form",
+                    labels=("op",)))
 
 
 # per (registry id, op[, counter]) last published total, so the counter
@@ -87,13 +93,15 @@ def _inc_to(counter, key: tuple, total: float, **labels) -> None:
 def _publish_gated(reg, op, vars_) -> Dict:
     import numpy as np
 
-    c_assign, c_hit, g_mom = gated_experts_families(reg)
+    c_assign, c_hit, g_mom, c_few = gated_experts_families(reg)
     load = np.asarray(vars_["load"], dtype=np.float64)
     got = {k: float(np.asarray(vars_[k]))
-           for k in ("assignments", "experts_hit", "steps")}
+           for k in ("assignments", "experts_hit", "steps", "few_rows_steps")}
     _inc_to(c_assign, (id(reg), op.name, "a"), got["assignments"],
             op=op.name)
     _inc_to(c_hit, (id(reg), op.name, "h"), got["experts_hit"], op=op.name)
+    _inc_to(c_few, (id(reg), op.name, "f"), got["few_rows_steps"],
+            op=op.name)
     mean = float(load.mean()) if load.size else 0.0
     g_mom.set(float(load.max()) / mean if mean > 0 else 0.0, op=op.name)
     return {**got, "dropped": 0.0, "load": load.tolist()}

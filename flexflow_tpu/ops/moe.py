@@ -423,6 +423,79 @@ def gated_experts_oracle(x, w, idx, weights, first: int, count: int):
     return out
 
 
+# The most token rows for which `GatedExpertsOp` sends every row through
+# every held expert: where the two forms cross on a v5e at 32 held experts
+# of 4096 x 2048 (PERF.md section 6, PR 28). The chip's grouped-GEMM kernel
+# multiplies one 512-row tile of sorted rows through EVERY group the tile
+# spans, so its time does not fall with the rows (5.1-5.5 ms a layer from
+# 128 to 640 tokens, 7.9 at 2,048), while the dense product streams the
+# three stacks once at the memory's speed up to the chip's ridge (~240
+# rows, 2.2 ms) and then grows with the rows (5.4 ms at 640, 17.3 at 2,048).
+FEW_ROWS_MAX = 640
+
+
+def few_rows(tokens: int) -> bool:
+    """Whether a routed product over `tokens` (static) token rows takes the
+    few-rows form: a decode iteration's slots, a prefill chunk, a small
+    batch; a long prefill or a training batch is grouped."""
+    return tokens <= FEW_ROWS_MAX
+
+
+def _few_rows_product(x, w, idx, weights, first: int, count: int):
+    """x (T, E) in the matmul dtype, w / idx (T, k) -> (y (T, E) float32,
+    assignments per local expert (count,) int32). Every token row through
+    every held expert, the router's weight (0 where the token did not
+    choose the expert) applied before the sum over experts, which the down
+    product takes together with the hidden dimension: one contraction of
+    count x F, no (T, count, E) intermediate. The three stacks are read in
+    place, once; no sort, no gather."""
+    with jax.named_scope("moe:combine"):
+        held = first + jnp.arange(count, dtype=jnp.int32)
+        chose = idx.astype(jnp.int32)[:, :, None] == held       # (T, k, count)
+        gate = jnp.sum(jnp.where(chose, w.astype(jnp.float32)[:, :, None],
+                                 0.0), axis=1)                  # (T, count)
+        sizes = jnp.sum(chose, axis=(0, 1), dtype=jnp.int32)
+    with jax.named_scope("moe:experts"):
+        up = lambda m: jnp.einsum("td,edf->tef", x, m.astype(x.dtype),
+                                  preferred_element_type=jnp.float32)
+        h = (jax.nn.silu(up(weights["w_gate"])) * up(weights["w_up"])
+             * gate[:, :, None]).astype(x.dtype)
+        y = jnp.einsum("tef,efd->td", h, weights["w_down"].astype(x.dtype),
+                       preferred_element_type=jnp.float32)
+    return y, sizes
+
+
+def _grouped_product(x, w, idx, weights, first: int, count: int):
+    """The same (y, assignments per local expert) for many rows:
+    assignments sorted by local expert (absent ones last), the token rows
+    gathered in that order, three grouped matmuls over the sorted rows
+    (`jax.lax.ragged_dot`: the chip's compiler lowers it to a grouped-GEMM
+    kernel), rows brought back to token order and the k weighted parts of
+    a token summed in float32."""
+    k = idx.shape[-1]
+    with jax.named_scope("moe:sort"):
+        local, mine = local_assignments(idx, first, count)
+        order = jnp.argsort(local, stable=True)     # absent ones last
+        sizes = jnp.bincount(local, length=count + 1)[:count].astype(
+            jnp.int32)
+        rows = x[order // k]                        # (T*k, E)
+        held = jnp.arange(rows.shape[0]) < jnp.sum(sizes)
+    with jax.named_scope("moe:experts"):
+        grouped = lambda a, m: jax.lax.ragged_dot(
+            a, m.astype(x.dtype), sizes, preferred_element_type=jnp.float32)
+        h = (jax.nn.silu(grouped(rows, weights["w_gate"]))
+             * grouped(rows, weights["w_up"])).astype(x.dtype)
+        # rows past the held assignments belong to no group: the grouped
+        # product leaves them unwritten
+        y = jnp.where(held[:, None], grouped(h, weights["w_down"]), 0.0)
+    with jax.named_scope("moe:combine"):
+        back = jnp.argsort(order)                   # sorted row of (t, i)
+        gate = jnp.where(mine, w.reshape(-1).astype(jnp.float32), 0.0)
+        y = jnp.sum((y[back] * gate[:, None]).reshape((-1, k, y.shape[-1])),
+                    axis=1)
+    return y, sizes
+
+
 @register_op
 class GatedExpertsOp(Op):
     """The routed part of a gated (SiLU) expert layer for the experts THIS
@@ -440,22 +513,22 @@ class GatedExpertsOp(Op):
     the other holders' part of the sum; on one holder nothing stands in
     for it.
 
-    Assignments are sorted by local expert (absent ones last), the token
-    rows gathered in that order, and the three products are grouped
-    matmuls over the sorted rows (`jax.lax.ragged_dot`: the chip's
-    compiler lowers it to a grouped-GEMM kernel that reads the weights of
-    the experts that were hit). Rows are brought back to token order and
-    the k weighted parts of a token summed in float32.
+    The three products take one of two forms, chosen from the static
+    number of token rows (`few_rows`): for few rows every row goes through
+    every held expert (`_few_rows_product`), for many the assignments are
+    sorted by expert and multiplied group by group (`_grouped_product`).
 
     Router health, threaded by the continuous batcher from one decode
     iteration to the next (`serving_counters`): `assignments` (local
     assignments so far), `experts_hit` (distinct local experts that got a
     token, summed over steps), `steps`, `load` (the last step's
-    assignments per local expert). Dropped tokens: none, by construction.
+    assignments per local expert), `few_rows_steps` (the steps that took
+    the few-rows form). Dropped tokens: none, by construction.
     """
 
     op_type = OpType.GATED_EXPERTS
-    serving_counters = ("assignments", "experts_hit", "steps", "load")
+    serving_counters = ("assignments", "experts_hit", "steps", "load",
+                        "few_rows_steps")
 
     def _local(self):
         first, count = self.params["local_experts"]
@@ -494,7 +567,8 @@ class GatedExpertsOp(Op):
         return [WeightSpec("assignments", (), DataType.DT_INT32, z),
                 WeightSpec("experts_hit", (), DataType.DT_INT32, z),
                 WeightSpec("steps", (), DataType.DT_INT32, z),
-                WeightSpec("load", (count,), DataType.DT_INT32, z)]
+                WeightSpec("load", (count,), DataType.DT_INT32, z),
+                WeightSpec("few_rows_steps", (), DataType.DT_INT32, z)]
 
     def lower(self, ctx, inputs, weights):
         from .common import emit_dtype, matmul_dtype
@@ -505,38 +579,20 @@ class GatedExpertsOp(Op):
         x = x.reshape((-1, x.shape[-1]))
         k = idx.shape[-1]
         cdt = matmul_dtype(getattr(ctx, "config", None), x.dtype)
+        few = few_rows(x.shape[0])
+        product = _few_rows_product if few else _grouped_product
+        out, sizes = product(x.astype(cdt), w.reshape(-1, k),
+                             idx.reshape(-1, k), weights, first, count)
 
-        with jax.named_scope("moe:sort"):
-            local, mine = local_assignments(idx.reshape(-1, k), first, count)
-            order = jnp.argsort(local, stable=True)     # absent ones last
-            sizes = jnp.bincount(local, length=count + 1)[:count].astype(
-                jnp.int32)
-            rows = x[order // k].astype(cdt)            # (T*k, E)
-            held = jnp.arange(rows.shape[0]) < jnp.sum(sizes)
-        prev = ctx.state.get((self.name, "assignments"))
-        if prev is not None:
-            upd = ctx.state_updates
-            upd[(self.name, "assignments")] = prev + jnp.sum(sizes)
-            upd[(self.name, "experts_hit")] = (
-                ctx.state[(self.name, "experts_hit")]
-                + jnp.sum((sizes > 0).astype(jnp.int32)))
-            upd[(self.name, "steps")] = ctx.state[(self.name, "steps")] + 1
-            upd[(self.name, "load")] = sizes
+        if (self.name, "assignments") in ctx.state:
+            for var, by in (
+                    ("assignments", jnp.sum(sizes)),
+                    ("experts_hit", jnp.sum((sizes > 0).astype(jnp.int32))),
+                    ("steps", 1), ("few_rows_steps", int(few))):
+                ctx.state_updates[(self.name, var)] = (
+                    ctx.state[(self.name, var)] + by)
+            ctx.state_updates[(self.name, "load")] = sizes
 
-        with jax.named_scope("moe:experts"):
-            grouped = lambda a, m: jax.lax.ragged_dot(
-                a, m.astype(cdt), sizes, preferred_element_type=jnp.float32)
-            h = (jax.nn.silu(grouped(rows, weights["w_gate"]))
-                 * grouped(rows, weights["w_up"])).astype(cdt)
-            # rows past the held assignments belong to no group: the
-            # grouped product leaves them unwritten
-            y = jnp.where(held[:, None], grouped(h, weights["w_down"]), 0.0)
-
-        with jax.named_scope("moe:combine"):
-            back = jnp.argsort(order)                   # sorted row of (t, i)
-            gate = jnp.where(mine, w.reshape(-1).astype(jnp.float32), 0.0)
-            out = jnp.sum((y[back] * gate[:, None]).reshape(
-                (-1, k, y.shape[-1])), axis=1)
         out = out.reshape(lead + (out.shape[-1],))
         return [out.astype(emit_dtype(getattr(ctx, "config", None),
                                       self.outputs[0].dtype))]

@@ -18,8 +18,8 @@ from benchmark.metrics import mla_moe_shapes as shapes
 from benchmark.reference import mla_moe_lm as ref
 from flexflow_tpu.core.op import LoweringContext
 from flexflow_tpu.ffconst import CompMode
-from flexflow_tpu.ops import rope
-from flexflow_tpu.ops.moe import gated_experts_oracle
+from flexflow_tpu.ops import moe, rope
+from flexflow_tpu.ops.moe import GatedExpertsOp, gated_experts_oracle
 from flexflow_tpu.serving.sched import kvpool
 from flexflow_tpu.serving.sched.continuous import ContinuousBatcher
 from tests.conftest import module_xla_cache
@@ -96,6 +96,13 @@ def test_batcher_prefill_and_decode_match_one_causal_pass(lm):
     assert all(c["dropped"] == 0.0 and c["steps"] > 0
                and c["assignments"] == 2 * 3 * c["steps"]
                for c in counts.values()), counts
+    # every decode iteration of 3 slots took the few-rows form, and the
+    # registry carries that count
+    assert all(c["few_rows_steps"] == c["steps"] for c in counts.values())
+    text = cb.registry.render()
+    for name, c in counts.items():
+        assert (f'ff_moe_few_rows_steps_total{{op="{name}"}} '
+                f'{int(c["steps"])}\n') in text, text
 
 
 def _mla_op(cfg, batch=2, length=6):
@@ -210,24 +217,103 @@ def _lower(m, op, ins, weights):
     return op.lower(ctx, ins, weights)
 
 
-def test_grouped_experts_equal_the_masked_oracle_and_drop_nothing():
+# name -> (token rows, the (ids, weights) the experts are handed; None: the
+# router's own). The op holds experts 2..5 of 8, top-2.
+_SKEWS = {
+    # as the router assigns: about half of the assignments are absent
+    "router": (24, None),
+    # all 24 x 2 assignments of a step on ONE expert (a capacity of
+    # ceil(alpha k T / n) would have dropped most): the grouped form has one
+    # group of 48 rows, the dense form 3 experts whose weight is 0 everywhere
+    "one_expert": (24, lambda t: (np.full((t, 2), 3), np.full((t, 2), 0.5))),
+    # expert 4 gets no token: an empty group between two full ones
+    "empty_expert": (24, lambda t: (
+        np.stack([np.full(t, 3), np.where(np.arange(t) % 2, 5, 2)], 1),
+        np.full((t, 2), 0.5))),
+    # every assignment on an absent expert: the output is exactly 0
+    "all_absent": (24, lambda t: (
+        np.stack([np.arange(t) % 2, 6 + np.arange(t) % 2], 1),
+        np.full((t, 2), 0.5))),
+    "one_token": (1, None),
+    "decode_128": (128, None),
+}
+_COUNTERS = ("assignments", "experts_hit", "steps", "load", "few_rows_steps")
+
+
+def _experts_case(case, path, monkeypatch):
+    """(model, op, inputs [x, w, idx], weights) of one case, the op steered
+    down `path` ('few_rows' | 'grouped') through the predicate's threshold."""
+    tokens, forced = _SKEWS[case]
+    monkeypatch.setattr(moe, "FEW_ROWS_MAX",
+                        10**9 if path == "few_rows" else 0)
     cfg = tiny_cfg()
-    m, router, experts = _experts_op(cfg, (2, 4))
-    x = jax.random.normal(jax.random.PRNGKey(0), (24, 64), jnp.float32)
-    w, idx = _lower(m, router, [x], _weights_of(router, 1))
-    assert np.allclose(np.asarray(w).sum(-1), 1.0, atol=1e-6)
-    ew = _weights_of(experts, 2)
-    got = _lower(m, experts, [x, w, idx], ew)[0]
+    m, router, experts = _experts_op(cfg, (2, 4), tokens)
+    x = jax.random.normal(jax.random.PRNGKey(0), (tokens, 64), jnp.float32)
+    if forced is None:
+        w, idx = _lower(m, router, [x], _weights_of(router, 1))
+        assert np.allclose(np.asarray(w).sum(-1), 1.0, atol=1e-6)
+    else:
+        idx, w = forced(tokens)
+        idx, w = jnp.asarray(idx, jnp.int32), jnp.asarray(w, jnp.float32)
+    return m, experts, [x, w, idx], _weights_of(experts, 2)
+
+
+@pytest.mark.parametrize("path", ["few_rows", "grouped"])
+@pytest.mark.parametrize("case", list(_SKEWS))
+def test_grouped_experts_equal_the_masked_oracle_and_drop_nothing(
+        case, path, monkeypatch):
+    """Both forms of the routed product, on skews they treat differently,
+    equal the masked oracle; the four counters read the same on both, and
+    `few_rows_steps` counts the few-rows form alone."""
+    m, experts, ins, ew = _experts_case(case, path, monkeypatch)
+    x, w, idx = ins
+    ctx = LoweringContext(m.config, CompMode.COMP_MODE_INFERENCE)
+    before = {"assignments": 7, "experts_hit": 5, "steps": 3,
+              "load": np.zeros(4, np.int32), "few_rows_steps": 2}
+    for name, val in before.items():
+        ctx.state[("experts", name)] = jnp.asarray(val, jnp.int32)
+    got = experts.lower(ctx, ins, ew)[0]
     want = gated_experts_oracle(x, w, idx, ew, 2, 4)
-    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
-    # a router forced onto ONE expert: all 24 x 2 assignments of a step land
-    # on it (a capacity of ceil(alpha k T / n) would have dropped most)
-    forced = jnp.full((24, 2), 3, jnp.int32)
-    half = jnp.full((24, 2), 0.5, jnp.float32)
-    got = _lower(m, experts, [x, half, forced], ew)[0]
-    want = gated_experts_oracle(x, half, forced, ew, 2, 4)
-    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
-    assert float(jnp.abs(got).min(axis=-1).max()) > 0  # no token zeroed
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=1e-5)
+    local = np.asarray(idx).reshape(-1) - 2
+    load = np.bincount(local[(local >= 0) & (local < 4)], minlength=4)
+    after = {k: np.asarray(ctx.state_updates[("experts", k)])
+             for k in _COUNTERS}
+    assert after["assignments"] == 7 + load.sum()
+    assert after["experts_hit"] == 5 + (load > 0).sum()
+    assert after["steps"] == 4
+    np.testing.assert_array_equal(after["load"], load)
+    assert after["few_rows_steps"] == 2 + (path == "few_rows")
+    if case == "all_absent":
+        assert not np.asarray(got).any()
+    if case == "one_expert":
+        assert float(jnp.abs(got).min(axis=-1).max()) > 0  # no token zeroed
+
+
+@pytest.mark.parametrize("path", ["few_rows", "grouped"])
+@pytest.mark.parametrize("case", ["router", "empty_expert"])
+def test_experts_gradients_equal_the_oracles(case, path, monkeypatch):
+    """d/d(x, router weights, the three stacks) of a scalar of the output,
+    through either form, is the oracle's."""
+    m, experts, (x, w, idx), ew = _experts_case(case, path, monkeypatch)
+    probe = jax.random.normal(jax.random.PRNGKey(5), x.shape, jnp.float32)
+    got = jax.grad(lambda x, w, ew: jnp.sum(
+        probe * _lower(m, experts, [x, w, idx], ew)[0]), (0, 1, 2))(x, w, ew)
+    want = jax.grad(lambda x, w, ew: jnp.sum(
+        probe * gated_experts_oracle(x, w, idx, ew, 2, 4)), (0, 1, 2))(
+            x, w, ew)
+    for g, o in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(g, o, rtol=2e-4, atol=2e-5)
+
+
+def test_few_rows_is_chosen_from_the_static_rows_alone():
+    """A decode iteration's slots and a prefill chunk take the few-rows
+    form, a long prefill or a training batch the grouped one; the op's
+    counter says which ran."""
+    assert moe.few_rows(1) and moe.few_rows(128) and moe.few_rows(
+        moe.FEW_ROWS_MAX)
+    assert not moe.few_rows(moe.FEW_ROWS_MAX + 1) and not moe.few_rows(8192)
+    assert GatedExpertsOp.serving_counters == _COUNTERS
 
 
 def test_four_shares_and_the_shared_expert_add_up_to_the_uncut_layer():

@@ -174,9 +174,7 @@ def test_decode_all_copies_no_latent_cache_on_v5e(v5e):
     rows. `c_kv` (…, 256) and `k_rope` (…, 128: the 64-wide rotary key
     padded to a lane tile) are scattered into and contracted on in place;
     stored as one (…, 320) row or with a (…, 64) key the chip's compiler
-    hands them over rows-minor and copies each whole twice an iteration.
-    The expert layer's grouped matmuls have to compile for the chip too
-    (`jax.lax.ragged_dot` becomes a grouped-GEMM kernel)."""
+    hands them over rows-minor and copies each whole twice an iteration."""
     import re
     from unittest import mock
 
@@ -207,7 +205,6 @@ def test_decode_all_copies_no_latent_cache_on_v5e(v5e):
         "c_kv": (slots, rows, 256), "k_rope": (slots, rows, 128)}
     assert caches["c_kv"].dtype == jnp.bfloat16
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= 3     # the three grouped matmuls
     entry = text[text.index("ENTRY "):]
     entry = entry[:entry.index("\n}")]
     smallest = min(int(a.size) for a in caches.values())
@@ -218,3 +215,46 @@ def test_decode_all_copies_no_latent_cache_on_v5e(v5e):
         if m and np.prod([int(d) for d in m.group(1).split(",")]) >= smallest
     ]
     assert not whole_cache_copies, whole_cache_copies
+
+
+def test_decode_all_streams_the_expert_stacks_in_place_on_v5e(v5e):
+    """`mistral_small4_ep4`'s serving programs, one layer at the published
+    widths (32 held experts of 4,096 x 2,048, 128 slots x 4,096 rows) with
+    shapes for parameters. The decode iteration's 128 token rows take the
+    few-rows form of the routed product (ops/moe.py `few_rows`): no
+    grouped-GEMM kernel (at 4 rows a group its one 512-row tile is
+    multiplied through all 32 groups: 29.7 of the 42 ms decode iteration,
+    ledger PR 27) and no copy or transpose of a 0.5 GB expert stack. A
+    prefill chunk of more rows than `FEW_ROWS_MAX` still holds the grouped
+    product (`jax.lax.ragged_dot` becomes three grouped-GEMM kernels)."""
+    import re
+    from unittest import mock
+
+    from benchmark import harness
+    from benchmark.tests.compile_ms4_for_v5e import programs
+    from flexflow_tpu.ops.moe import FEW_ROWS_MAX
+
+    cfg = harness.load_config("mistral_small4_ep4")
+    cfg["num_hidden_layers"] = 1
+    chunk = 1024
+    cfg["deployment"] = dict(cfg["deployment"], prefill_chunk_tokens=chunk)
+    assert cfg["deployment"]["num_slots"] <= FEW_ROWS_MAX < chunk
+    stack = (int(cfg["n_routed_experts"]) * int(cfg["hidden_size"])
+             * int(cfg["moe_intermediate_size"]))
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        progs = programs(cfg, v5e)
+        jax.clear_caches()
+        text = {name: progs[name][0].lower(*progs[name][1]).compile()
+                .as_text() for name in ("decode_all", "prefill_chunk")}
+
+    moved = [
+        line.strip()[:120] for line in text["decode_all"].splitlines()
+        for m in [re.match(
+            r"\s*(?:ROOT )?\S+ = \w+\[([\d,]+)\]\S* (?:copy|transpose)\(",
+            line)]
+        if m and np.prod([int(d) for d in m.group(1).split(",")]) >= stack
+    ]
+    assert not moved, moved
+    assert "ragged-dot" not in text["decode_all"]
+    assert "tpu_custom_call" not in text["decode_all"]
+    assert text["prefill_chunk"].count("ragged-dot") >= 3
