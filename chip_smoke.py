@@ -44,7 +44,7 @@ class Sizes:
     batch: int = 8
     steps_per_execution: int = 4   # the K > 1 dispatch shape
     train_dispatches: int = 2      # K-step dispatches (K=1 run: K x this)
-    kernel_layers: int = 1         # depth of the per-family parity models
+    kernel_layers: int = 1         # depth of the flash parity model
     search_layers: int = 2         # identical layers share one measurement
     search_budget: int = 4
     search_devices: int = 4        # the machine the one-chip search plans for
@@ -153,7 +153,7 @@ def _compiled_text(jitted, *args) -> str:
 # models
 # ---------------------------------------------------------------------------
 def build_bert(sizes: Sizes, seed: int, *, layers: int = None,
-               num_devices: int = 1, kernel_impl: str = "auto",
+               num_devices: int = 1,
                optimizer: str = "adam", search_budget: int = 0,
                parallel_axes=None, compile_model: bool = True):
     """bench.py's model (same builder, bf16 Adam moments) at `layers`."""
@@ -166,7 +166,6 @@ def build_bert(sizes: Sizes, seed: int, *, layers: int = None,
     config.num_devices = num_devices
     config.batch_size = sizes.batch
     config.seed = seed
-    config.kernel_impl = kernel_impl
     config.search_budget = search_budget
     model = ff.FFModel(config)
     tokens = model.create_tensor([sizes.batch, sizes.seq],
@@ -290,76 +289,38 @@ def _update_error(got, want) -> float:
     return float(np.sqrt(num / max(den, 1e-30)))
 
 
-def _only(family: str) -> str:
-    """--kernel-impl spec: `family` on Pallas, every other family on its
-    reference lowering (the parity oracle, kernels/registry.py)."""
-    from flexflow_tpu.kernels.registry import FAMILIES
-
-    return ",".join(f"{f}={'pallas' if f == family else 'reference'}"
-                    for f in FAMILIES)
-
-
-def _build_rms(sizes: Sizes, seed: int, kernel_impl: str):
-    """RMSNorm has no user in the BERT graph: norm -> classifier."""
-    import flexflow_tpu as ff
-
-    config = ff.FFConfig()
-    config.num_devices = 1
-    config.batch_size = sizes.batch
-    config.seed = seed
-    config.kernel_impl = kernel_impl
-    model = ff.FFModel(config)
-    t = model.create_tensor([sizes.batch, sizes.seq, sizes.hidden])
-    t = model.rms_norm(t, [-1], name="rms")
-    model.softmax(model.dense(t, 2, name="cls"))
-    model.compile(
-        optimizer=ff.SGDOptimizer(model, lr=1.0, weight_decay=0.0),
-        loss_type=ff.LossType.LOSS_SPARSE_CATEGORICAL_CROSSENTROPY,
-        metrics=[])
-    return model
-
-
 def phase_kernels(sizes: Sizes, seed: int) -> Dict:
-    """Each Pallas family forced on alone through --kernel-impl, compiled
-    (not interpreted) on the chip, against the all-reference lowering of
-    the same model and seed: forward loss and the gradient-carrying SGD
-    update. (The two decode families have no training graph: the serve
-    phase forces them.)"""
+    """Flash attention forced on through KERNELS.override, compiled (not
+    interpreted) on the chip, against the reference lowering of the same
+    model and seed: forward loss and the gradient-carrying SGD update.
+    (The two decode families have no training graph: the serve phase
+    forces them.)"""
+    from flexflow_tpu.kernels.registry import KERNELS
     from flexflow_tpu.runtime.platform import pallas_interpret
 
     x, y = bert_data(sizes, seed, 1)
-    xr = np.random.RandomState(seed).randn(
-        sizes.batch, sizes.seq, sizes.hidden).astype(np.float32)
 
-    def bert_step(spec):
-        return _one_sgd_step(
-            build_bert(sizes, seed, layers=sizes.kernel_layers,
-                       kernel_impl=spec, optimizer="sgd"), x, y)
+    def bert_step(impl):
+        with KERNELS.override("attention", impl):
+            return _one_sgd_step(
+                build_bert(sizes, seed, layers=sizes.kernel_layers,
+                           optimizer="sgd"), x, y)
 
-    def rms_step(spec):
-        return _one_sgd_step(_build_rms(sizes, seed, spec), xr, y)
-
-    ref_loss, ref_update = bert_step("reference")
-    rms_ref_loss, rms_ref_update = rms_step("reference")
-    results = {}
-    for family in ("attention", "layernorm", "softmax", "reduction",
-                   "rmsnorm"):
-        step, want_loss, want_update = (
-            (rms_step, rms_ref_loss, rms_ref_update) if family == "rmsnorm"
-            else (bert_step, ref_loss, ref_update))
-        before = _selected(family)
-        loss, update = step(_only(family))
-        assert _selected(family, before)["pallas"] > 0, (
-            f"{family}: forced pallas but the lowering never selected it")
-        d_loss = _check_close(f"{family} pallas-vs-reference loss", loss,
-                              want_loss, KERNEL_LOSS_RTOL)
-        d_update = _update_error(update, want_update)
-        assert d_update <= KERNEL_UPDATE_RTOL, (
-            f"{family}: SGD update differs from the reference lowering's"
-            f" by rel L2 {d_update:.2e} > {KERNEL_UPDATE_RTOL:g}")
-        results[family] = {"loss_rel": float(f"{d_loss:.1e}"),
-                           "update_rel_l2": float(f"{d_update:.1e}")}
-    return {"interpret": pallas_interpret(), "families": results,
+    want_loss, want_update = bert_step("reference")
+    before = _selected("attention")
+    loss, update = bert_step("pallas")
+    assert _selected("attention", before)["pallas"] > 0, (
+        "attention: forced pallas but the lowering never selected it")
+    d_loss = _check_close("attention pallas-vs-reference loss", loss,
+                          want_loss, KERNEL_LOSS_RTOL)
+    d_update = _update_error(update, want_update)
+    assert d_update <= KERNEL_UPDATE_RTOL, (
+        "attention: SGD update differs from the reference lowering's"
+        f" by rel L2 {d_update:.2e} > {KERNEL_UPDATE_RTOL:g}")
+    return {"interpret": pallas_interpret(),
+            "families": {"attention": {
+                "loss_rel": float(f"{d_loss:.1e}"),
+                "update_rel_l2": float(f"{d_update:.1e}")}},
             "compared": f"forced-pallas vs reference: loss rel <="
                         f" {KERNEL_LOSS_RTOL:g}, lr-1 SGD update rel L2 <="
                         f" {KERNEL_UPDATE_RTOL:g}"}

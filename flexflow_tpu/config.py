@@ -41,13 +41,10 @@ class FFConfig:
     `--only-data-parallel`, `--enable-parameter-parallel`,
     `--enable-attribute-parallel`, `--search-overlap-backward-update`,
     `--base-optimize-threshold`, `--substitution-json`, `--export`/`--import`,
-    `--memory-search`, `--profiling`, `--fusion`.
+    `--memory-search`, `--profiling`.
 
-    TPU-native additions beyond the reference surface:
-    `--steps-per-execution` (K optimizer steps per jitted dispatch),
-    `--flash-block-q`/`--flash-block-k` (Pallas flash-attention tiling,
-    swept by scripts/sweep_flash.py), and `--kernel-impl` (fused-kernel
-    tier selection, kernels/registry.py).
+    TPU-native addition beyond the reference surface:
+    `--steps-per-execution` (K optimizer steps per jitted dispatch).
     """
 
     batch_size: int = 64
@@ -56,24 +53,6 @@ class FFConfig:
     # K optimizer steps per jitted device dispatch (tf.keras
     # steps_per_execution role; FFModel.fit flag of the same name)
     steps_per_execution: int = 1
-    # Pallas flash-attention block sizes (kernels/flash_attention.py).
-    # 512x512 measured best at the BERT bench config on v5e;
-    # scripts/sweep_flash.py sweeps these on the live chip.
-    flash_block_q: int = 512
-    flash_block_k: int = 512
-    # Kernel-tier selection knob (kernels/registry.py, docs/kernels.md):
-    # "auto" (backend capability + calibration residuals), a bare
-    # "pallas"/"reference" forcing every family, or a per-family list
-    # "attention=pallas,layernorm=reference,...". ONE knob for what used
-    # to be the ad-hoc use_flash heuristic plus per-callsite flags.
-    kernel_impl: str = "auto"
-    # Calibration-residual threshold for auto kernel selection
-    # (kernels/registry.py, docs/kernels.md): an op family whose measured
-    # cost runs >= this multiple of the roofline prediction is a fusion
-    # candidate. 1.10 is the hand-set default the registry shipped with;
-    # fit it from before/after kernel measurements on real TPU
-    # (--kernel-residual-threshold).
-    kernel_residual_threshold: float = 1.10
     # Collective lowering of the searched reduction plan
     # (runtime/collectives.py, docs/machine.md "Lowering"): "gspmd" lets
     # XLA synthesize the gradient-sync schedule (the historical path),
@@ -213,15 +192,12 @@ class FFConfig:
     import_strategy_file: Optional[str] = None
     export_strategy_computation_graph_file: Optional[str] = None
     export_strategy_task_graph_file: Optional[str] = None
-    include_costs_dot_graph: bool = False
     # Execution knobs
     computation_mode: CompMode = CompMode.COMP_MODE_TRAINING
     profiling: bool = False
-    perform_fusion: bool = False
     seed: int = 0
     # Numerics: compute dtype for matmul-heavy ops (MXU-friendly default).
     allow_mixed_precision: bool = True
-    simulator_work_space_size: int = 2 * 1024 * 1024 * 1024
     machine_model_version: int = 0
     machine_model_file: Optional[str] = None
     # Fitted machine profile (obs/refit.py): measured coefficient overlay
@@ -268,16 +244,6 @@ class FFConfig:
                 self.iterations = int(take())
             elif a == "--steps-per-execution":
                 self.steps_per_execution = int(take())
-            elif a == "--flash-block-q":
-                self.flash_block_q = int(take())
-            elif a == "--flash-block-k":
-                self.flash_block_k = int(take())
-            elif a == "--kernel-impl":
-                v = take()
-                from .kernels.registry import KernelRegistry
-
-                KernelRegistry.parse_spec(v)  # validate; raises on junk
-                self.kernel_impl = v
             elif a == "--collective-lowering":
                 v = take()
                 from .runtime.collectives import COLLECTIVE_LOWERINGS
@@ -294,13 +260,6 @@ class FFConfig:
                         "--grad-bucket-bytes must be >= 0 (bytes; 0 "
                         f"disables bucketing), got {v}")
                 self.grad_bucket_bytes = v
-            elif a == "--kernel-residual-threshold":
-                v = float(take())
-                if not v > 0:
-                    raise ValueError(
-                        "--kernel-residual-threshold must be > 0 "
-                        f"(a measured/predicted ratio), got {v}")
-                self.kernel_residual_threshold = v
             elif a in ("--lr", "--learning-rate"):
                 self.learning_rate = float(take())
             elif a in ("--wd", "--weight-decay"):
@@ -393,12 +352,8 @@ class FFConfig:
                 self.export_strategy_computation_graph_file = take()
             elif a == "--export-strategy-task-graph-file":
                 self.export_strategy_task_graph_file = take()
-            elif a == "--include-costs-dot-graph":
-                self.include_costs_dot_graph = True
             elif a == "--profiling":
                 self.profiling = True
-            elif a == "--fusion":
-                self.perform_fusion = True
             elif a == "--seed":
                 self.seed = int(take())
             elif a == "--nodes":
@@ -415,8 +370,6 @@ class FFConfig:
                 self.machine_model_file = take()
             elif a == "--fitted-profile":
                 self.fitted_profile_file = take()
-            elif a == "--simulator-workspace-size":
-                self.simulator_work_space_size = int(take())
             elif a == "--print-freq":
                 self.print_freq = int(take())
             else:

@@ -671,13 +671,6 @@ class FFModel:
         self.metrics = Metrics(self.loss.loss_type, list(metrics))
         self.comp_mode = comp_mode
 
-        # kernel tier (docs/kernels.md): adopt the --kernel-impl knob and
-        # the fitted profile's per-op-family residuals BEFORE the search,
-        # so the simulator prices the same selections the lowering makes
-        from .kernels.registry import KERNELS
-
-        KERNELS.configure(self.config)
-
         self.graph = Graph(self.ops)
         order = self.graph.topo_order()
         self.final_tensor = self.final_tensor or order[-1].outputs[0]

@@ -27,34 +27,19 @@ from typing import Any, Dict, List, Optional
 
 from .registry import REGISTRY
 
-def _tier_families() -> Dict[str, str]:
-    """op_type value -> kernel-tier family, DERIVED from the registry's
-    OPTYPE_FAMILY so the two layers cannot drift (a new tier op family
-    automatically accumulates residual evidence here)."""
-    from ..kernels.registry import OPTYPE_FAMILY
-
-    return {k.value: v for k, v in OPTYPE_FAMILY.items()}
-
-
-# materialized at import: rows store op_type as its string value
-KERNEL_TIER_FAMILIES = _tier_families()
-
 
 def op_family_residuals(rows) -> Dict[str, float]:
-    """Per-kernel-family residual: the MEDIAN measured/predicted ratio
-    over a family's calibrated ops (median, not mean — one bad
+    """Per-op-type residual: the MEDIAN measured/predicted ratio over an
+    op type's calibrated ops (median, not mean — one bad
     micro-measurement must not nominate a kernel). Only finite ratios
-    count; families with no measurable op are absent. This is the
-    evidence `refit` persists into the FittedProfile and the
-    KernelRegistry selects fused kernels from."""
+    count; op types with no measurable op are absent. `refit` persists
+    this into the FittedProfile as information: nothing selects from
+    it."""
     by_fam: Dict[str, List[float]] = {}
     for r in rows:
-        fam = KERNEL_TIER_FAMILIES.get(getattr(r, "op_type", None))
-        if fam is None:
-            continue
         ratio = r.ratio
         if math.isfinite(ratio):
-            by_fam.setdefault(fam, []).append(ratio)
+            by_fam.setdefault(r.op_type, []).append(ratio)
     out: Dict[str, float] = {}
     for fam, ratios in by_fam.items():
         ratios.sort()
@@ -179,29 +164,24 @@ class CalibrationReport:
         return json.dumps(self.to_dict(), indent=2)
 
     def kernel_candidates(self) -> List[Dict[str, Any]]:
-        """Ranked fused-kernel candidates: per kernel-tier family, the
-        median residual (measured/predicted) weighted by the family's
-        share of predicted step time — `score = max(0, residual - 1) *
-        share`. The family at the top is where a fused kernel buys the
-        most wall clock; `profile --kernel-report` renders this and the
-        KernelRegistry auto-selects from the same residuals once a refit
-        persists them (docs/kernels.md)."""
+        """Ranked fused-kernel candidates: per op type, the median
+        residual (measured/predicted) weighted by the type's share of
+        predicted step time — `score = max(0, residual - 1) * share`.
+        The type at the top is where a fused kernel would buy the most
+        wall clock; `profile --kernel-report` renders this for a reader
+        (docs/kernels.md) — no selection reads it."""
         residuals = op_family_residuals(self.ops)
         total_pred = sum(o.predicted_us for o in self.ops
                          if o.predicted_us > 0
                          and math.isfinite(o.predicted_us))
-        # every tier family present in the graph is listed — one with no
+        # every op type present in the graph is listed — one with no
         # measurable op shows residual NaN and score 0 rather than
         # disappearing (the reader should see it was considered)
-        present = {fam for o in self.ops
-                   for fam in [KERNEL_TIER_FAMILIES.get(o.op_type)]
-                   if fam is not None}
         out: List[Dict[str, Any]] = []
-        for fam in present:
+        for fam in {o.op_type for o in self.ops}:
             residual = residuals.get(fam, float("nan"))
             pred = sum(o.predicted_us for o in self.ops
-                       if KERNEL_TIER_FAMILIES.get(o.op_type) == fam
-                       and o.predicted_us > 0
+                       if o.op_type == fam and o.predicted_us > 0
                        and math.isfinite(o.predicted_us))
             share = pred / total_pred if total_pred > 0 else 0.0
             out.append({
@@ -210,13 +190,12 @@ class CalibrationReport:
                 "step_share": share,
                 "score": (max(0.0, residual - 1.0) * share
                           if math.isfinite(residual) else 0.0),
-                "ops": sum(
-                    1 for o in self.ops
-                    if KERNEL_TIER_FAMILIES.get(o.op_type) == fam),
+                "ops": sum(1 for o in self.ops if o.op_type == fam),
             })
         out.sort(key=lambda c: (
             -c["score"],
-            -(c["residual"] if math.isfinite(c["residual"]) else 0.0)))
+            -(c["residual"] if math.isfinite(c["residual"]) else 0.0),
+            c["family"]))
         return out
 
     def format_kernel_report(self) -> str:
@@ -224,14 +203,14 @@ class CalibrationReport:
         lines = [
             "kernel candidates (median calibration residual weighted by "
             "share of predicted step time; score>0 = fusion headroom)",
-            f"  {'family':<16} {'residual':>9} {'step share':>11} "
+            f"  {'family':<20} {'residual':>9} {'step share':>11} "
             f"{'score':>8} {'ops':>5}",
         ]
         if not cands:
-            lines.append("  (no kernel-tier op families measurable)")
+            lines.append("  (no op measurable)")
         for c in cands:
             lines.append(
-                f"  {c['family']:<16} {_r(c['residual']):>9} "
+                f"  {c['family']:<20} {_r(c['residual']):>9} "
                 f"{c['step_share']:>10.1%} {c['score']:>8.3f} "
                 f"{c['ops']:>5}")
         return "\n".join(lines)

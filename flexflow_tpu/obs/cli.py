@@ -17,24 +17,17 @@ emits the full observability bundle into --out (default ./profile_out):
                       so the perf trajectory resumes with every run
 
 `--kernel-report` additionally prints (and writes kernel_report.txt)
-the ranked fused-kernel candidates: per kernel-tier op family
-(docs/kernels.md), the median calibration residual weighted by the
-family's share of predicted step time — where a Pallas kernel buys the
-most. The same per-family residuals are persisted by `--refit` into the
-fitted profile, which is what lets the KernelRegistry auto-select the
-fused kernels on later runs.
+the ranked fused-kernel candidates: per op type, the median calibration
+residual weighted by the type's share of predicted step time — where a
+fused kernel would buy the most. `--refit` persists the same residuals
+into the fitted profile as information (docs/kernels.md).
 
 Refit mode (`--refit`, docs/observability.md "Closing the loop"): after
 training, fit the machine-model coefficients from the calibration data
 (obs/refit.py) until the re-simulated predicted step cost converges on
 the measured one (`--refit-rounds`, `--refit-tol`), and persist the
 fitted profile as `fitted_profile.json` — load it into any later run
-with `--fitted-profile`. `--refit --fit-kernel-thresholds` additionally
-rebuilds the same synthetic model with every fused Pallas impl FORCED,
-measures it, and persists per-family kernel-SELECTION thresholds
-(`kernel_residual_thresholds`, obs/refit.fit_kernel_thresholds) — the
-measured replacement for the hand-set 1.10 residual default
-(docs/kernels.md "Selection"; doubles the run, off by default).
+with `--fitted-profile`.
 `--miscalibrate flops=2.0,ici=0.5` seeds the
 run with deliberately wrong constants (the CI refit drill proves they
 converge anyway). `--drift-replan` runs the training under an
@@ -251,9 +244,6 @@ def run_profile(argv: Optional[List[str]] = None) -> int:
     kernel_report = "--kernel-report" in argv
     if kernel_report:
         argv.remove("--kernel-report")
-    fit_thresholds = "--fit-kernel-thresholds" in argv
-    if fit_thresholds:
-        argv.remove("--fit-kernel-thresholds")
     refit_rounds = _take(argv, "--refit-rounds", 3, cast=int)
     refit_tol = _take(argv, "--refit-tol", 0.15, cast=float)
     miscal_spec = _take(argv, "--miscalibrate", None)
@@ -264,11 +254,6 @@ def run_profile(argv: Optional[List[str]] = None) -> int:
     drift_threshold = _take(argv, "--drift-threshold", 0.5, cast=float)
     drift_warmup = _take(argv, "--drift-warmup", 2, cast=int)
     drift_patience = _take(argv, "--drift-patience", 2, cast=int)
-    if fit_thresholds and (not refit_mode or drift_replan):
-        raise SystemExit(
-            "--fit-kernel-thresholds needs --refit (and is not supported"
-            " under --drift-replan): the thresholds ride on the profile"
-            " the refit persists")
 
     from ..runtime.platform import cpu_mesh_from_env
 
@@ -355,43 +340,9 @@ def run_profile(argv: Optional[List[str]] = None) -> int:
             from .refit import FittedProfileError, refit
 
             try:
-                pallas_rows = None
-                if fit_thresholds:
-                    # the AFTER side of the before/after threshold fit
-                    # (docs/kernels.md "Selection"): calibrate the SAME
-                    # synthetic model with every fused impl forced — the
-                    # override must be live while calibrate's per-op
-                    # micro-functions LOWER (so the measured side is the
-                    # fused kernels), but the PREDICTED side must be
-                    # re-derived outside it, or the override's
-                    # PALLAS_COST_GAIN pricing discount would inflate
-                    # every fitted threshold by 1/gain
-                    import contextlib
-
-                    from ..kernels.registry import FAMILIES, KERNELS
-                    from .refit import (FittedCoefficients,
-                                        _predict_op_rows)
-
-                    with contextlib.ExitStack() as st:
-                        for fam in FAMILIES:
-                            st.enter_context(
-                                KERNELS.override(fam, "pallas"))
-                        fused, _, _ = _synthetic(model_name, config)
-                        fused.compile(
-                            optimizer=ff.SGDOptimizer(
-                                fused, lr=config.learning_rate),
-                            loss_type=ff.LossType
-                            .LOSS_SPARSE_CATEGORICAL_CROSSENTROPY,
-                            metrics=[ff.MetricsType.METRICS_ACCURACY])
-                        raw_rows = calibrate(fused, max_ops=max_ops).ops
-                    # un-discounted roofline, neutral coefficients: the
-                    # same baseline op_family_residuals compares against
-                    pallas_rows = _predict_op_rows(
-                        fused, FittedCoefficients(), raw_rows)
                 profile, history = refit(
                     model, report.measured_step_us, report.ops,
-                    prior=prior, rounds=refit_rounds, tol=refit_tol,
-                    pallas_rows=pallas_rows)
+                    prior=prior, rounds=refit_rounds, tol=refit_tol)
                 path = profile.save(
                     os.path.join(out_dir, "fitted_profile.json"))
                 refit_summary = {
@@ -400,8 +351,6 @@ def run_profile(argv: Optional[List[str]] = None) -> int:
                     "final_ratio": history[-1].ratio,
                     "replans": 0,
                     "profile": path,
-                    "kernel_thresholds": dict(
-                        profile.kernel_residual_thresholds),
                 }
             except FittedProfileError as e:
                 refit_summary = {"rounds": [], "converged": False,
@@ -453,13 +402,6 @@ def run_profile(argv: Optional[List[str]] = None) -> int:
                 "refit: did not converge within "
                 f"{refit_rounds} round(s) to ±{refit_tol:.0%} "
                 f"({(refit_summary or {}).get('error', 'see rounds')})")
-        if fit_thresholds and not (refit_summary or {}).get(
-                "kernel_thresholds"):
-            problems.append(
-                "fit-kernel-thresholds: the forced-pallas measurement"
-                " produced no per-family thresholds — no usable"
-                " calibration rows (see kernel_thresholds in the"
-                " summary)")
         if drift_replan:
             if replans != 1:
                 problems.append(

@@ -153,22 +153,10 @@ class FittedProfile:
     rounds: int = 0
     step_ratio: float = float("nan")
     num_chips: int = 0
-    # per-kernel-family calibration residuals (median measured/predicted
-    # at fit time, obs/calibration.op_family_residuals): the evidence the
-    # KernelRegistry auto-selects fused Pallas kernels from
-    # (kernels/registry.py, docs/kernels.md). Informational for the
-    # machine model itself — apply_to never touches it.
+    # per-op-type calibration residuals (median measured/predicted at fit
+    # time, obs/calibration.op_family_residuals). Informational: apply_to
+    # never touches it and nothing selects from it.
     op_family_residuals: Dict[str, float] = dataclasses.field(
-        default_factory=dict)
-    # per-family FITTED selection thresholds (fit_kernel_thresholds):
-    # derived from real before/after kernel measurements — a family's
-    # threshold is the residual the FUSED impl itself achieves at the
-    # profiled shapes (x a small safety margin), so reference evidence
-    # past it means switching genuinely pays. A family present here
-    # overrides the hand-set RESIDUAL_CANDIDATE_THRESHOLD /
-    # --kernel-residual-threshold default in the registry; absent
-    # families keep the knob. Informational for the machine model.
-    kernel_residual_thresholds: Dict[str, float] = dataclasses.field(
         default_factory=dict)
 
     def __post_init__(self):
@@ -243,11 +231,7 @@ class FittedProfile:
                    num_chips=int(d.get("num_chips", 0)),
                    op_family_residuals={
                        str(k): float(v) for k, v in dict(
-                           d.get("op_family_residuals", {})).items()},
-                   kernel_residual_thresholds={
-                       str(k): float(v) for k, v in dict(
-                           d.get("kernel_residual_thresholds",
-                                 {})).items()})
+                           d.get("op_family_residuals", {})).items()})
 
 
 # -- the coefficient fit ---------------------------------------------------
@@ -454,37 +438,9 @@ class RefitRound:
         return d
 
 
-def fit_kernel_thresholds(pallas_rows, margin: float = 1.02
-                          ) -> Dict[str, float]:
-    """Per-family kernel-selection thresholds from real BEFORE/AFTER
-    measurements, replacing the hand-set
-    `RESIDUAL_CANDIDATE_THRESHOLD = 1.10` guess (kernels/registry.py).
-
-    `pallas_rows` are calibration rows measured with the fused Pallas
-    impls FORCED (the "after" side; the ordinary profile run is the
-    "before" side whose residuals ride in `op_family_residuals`). The
-    registry selects pallas when the reference residual
-    (measured_ref/predicted) exceeds the threshold; switching genuinely
-    pays exactly when the reference runs slower than the fused kernel —
-    i.e. when the reference residual exceeds the residual the FUSED impl
-    itself achieves. So the fitted threshold per family is the fused
-    impl's own median measured/predicted at the profiled shapes, times a
-    small `margin` (switching for a sub-2% win is churn), floored at 1.0
-    (a fused impl beating the roofline still should not be selected on
-    noise-level reference evidence). Families without usable pallas rows
-    are omitted — they keep the knob/default."""
-    from .calibration import op_family_residuals
-
-    out: Dict[str, float] = {}
-    for fam, resid in op_family_residuals(usable_rows(pallas_rows)).items():
-        if math.isfinite(resid) and resid > 0:
-            out[fam] = max(1.0, float(resid)) * float(margin)
-    return out
-
-
 def refit(model, measured_step_us: float, op_rows,
           prior: Optional[FittedCoefficients] = None,
-          rounds: int = 3, tol: float = 0.15, pallas_rows=None,
+          rounds: int = 3, tol: float = 0.15,
           ) -> Tuple[FittedProfile, List[RefitRound]]:
     """Fit machine-model coefficients for `model`'s compiled plan until
     the re-simulated predicted step cost lands within `tol` of
@@ -593,16 +549,9 @@ def refit(model, measured_step_us: float, op_rows,
         rounds=len(history), step_ratio=history[-1].ratio,
         num_chips=max(1, model.config.total_devices),
         # residuals from the ORIGINAL rows (usable_rows(op_rows)), not
-        # the re-predicted ones: the registry wants the gap the backend
-        # showed against the un-refit roofline, which is what nominates
-        # a fused kernel
-        op_family_residuals=op_family_residuals(usable_rows(op_rows)),
-        # before/after threshold fit: rows measured with the fused impls
-        # forced turn the hand-set selection threshold into a measured
-        # per-family one (fit_kernel_thresholds); without them the
-        # profile carries none and the knob/default stays in charge
-        kernel_residual_thresholds=(
-            fit_kernel_thresholds(pallas_rows) if pallas_rows else {}))
+        # the re-predicted ones: the gap the backend showed against the
+        # un-refit roofline
+        op_family_residuals=op_family_residuals(usable_rows(op_rows)))
     REGISTRY.gauge(
         "ff_refit_step_ratio",
         "Measured/predicted step cost after the last refit "

@@ -280,8 +280,6 @@ class MultiHeadAttentionOp(Op):
                 ctxv = ulysses_attention_sharded(
                     q, k, v, ctx.mesh, axis_name="seq", causal=causal,
                     scale=scale, use_flash=local_flash,
-                    block_q=getattr(ctx.config, "flash_block_q", 512),
-                    block_k=getattr(ctx.config, "flash_block_k", 512),
                     interpret=local_flash and pallas_interpret(),
                 )
             elif mode == "ring":
@@ -304,8 +302,6 @@ class MultiHeadAttentionOp(Op):
             ctxv = self._on_mesh(ctx, functools.partial(
                 flash_attention_packed, num_heads=heads, scale=scale,
                 causal=causal,
-                block_q=getattr(ctx.config, "flash_block_q", 512),
-                block_k=getattr(ctx.config, "flash_block_k", 512),
                 interpret=pallas_interpret(),
             ), heads_dim=None)(q, k, v)
         elif flash_selected:
@@ -316,8 +312,6 @@ class MultiHeadAttentionOp(Op):
 
             ctxv = self._on_mesh(ctx, functools.partial(
                 flash_attention, scale=scale, causal=causal,
-                block_q=getattr(ctx.config, "flash_block_q", 512),
-                block_k=getattr(ctx.config, "flash_block_k", 512),
                 interpret=pallas_interpret(),
             ), heads_dim=2)(q, k, v)
         else:
@@ -407,8 +401,8 @@ class MultiHeadAttentionOp(Op):
         per-row positions (continuous batching, serving/sched/continuous.py:
         each slot decodes its own sequence, so slot i writes its K/V at
         pos[i] and masks to its own length). The vector form is the
-        continuous batcher's per-iteration hot loop, and a kernel-tier
-        family (`attention_decode`): when the registry selects pallas the
+        continuous batcher's per-iteration hot loop, and a kernel
+        family (`attention_decode`): under `KERNELS.override` the
         QK^T -> masked softmax -> V chain runs as ONE fused kernel over
         the paged cache (kernels/pallas/decode.py) instead of
         materializing the (B, h, 1, M) logits/probs in HBM; the einsum
@@ -432,7 +426,7 @@ class MultiHeadAttentionOp(Op):
         query can attend them.
 
         Every C > 1 entry (both forms) is the `attention_decode_mq`
-        kernel-tier family: selected, the chunk runs as ONE fused
+        kernel family: selected, the chunk runs as ONE fused
         multi-query kernel over the paged cache
         (kernels/pallas/decode.py) instead of materializing the
         (B, h, C, M) logits/probs in HBM; the einsum chain below is the
@@ -478,8 +472,7 @@ class MultiHeadAttentionOp(Op):
                   else "attention_decode_mq")
         # GSPMD cannot partition a Mosaic kernel (see _on_mesh): a decode
         # step jitted over a mesh keeps the reference chain below
-        if (not ctx.gspmd_partitioned()
-                and KERNELS.select(family, config=ctx.config)):
+        if not ctx.gspmd_partitioned() and KERNELS.select(family):
             from ..kernels.pallas import decode
 
             fused = (decode.fused_decode_attention
@@ -488,7 +481,6 @@ class MultiHeadAttentionOp(Op):
             posv = pos if vector else jnp.full(
                 (kc.shape[0],), pos, jnp.int32)
             ctxv = fused(q, kc, vc, posv, scale=scale,
-                         block_k=getattr(ctx.config, "flash_block_k", 512),
                          interpret=pallas_interpret())
             return self._decode_project(ctxv, q.dtype, weights)
 
@@ -533,33 +525,19 @@ class MultiHeadAttentionOp(Op):
         return out
 
     def _use_flash(self, ctx) -> bool:
-        """Flash/pallas vs einsum selection, routed through the ONE
-        KernelRegistry code path: an explicit use_flash=True/False param
-        is the per-op override lane (what the CPU tests use to force the
-        interpret-mode kernel — formerly a special case here), the
-        `--kernel-impl` knob and `KERNELS.override` sit above auto, and
-        the auto policy on TPU is the per-family calibration residual
-        first, then the v5e-measured crossover: since the kernel's
-        bf16-MXU-input fix (round 3) the Pallas flash path wins from seq
-        ~512 up (r4 ablation: 39.1 ms/step flash vs 44.0 einsum at the
-        BERT bench config, where the per-chip f32 score matrix is
-        134 MB); below that the blocks are too small to fill the grid
-        and XLA's fused einsum attention stays ahead. The threshold is
-        the score-matrix size at the measured crossover."""
-        from ..kernels.registry import KERNELS, flash_crossover
+        """Flash or the einsum core: `KERNELS.select` (kernels/registry.py)
+        given this op's explicit `use_flash` and its per-chip score shape
+        (the batch dim shards over the mesh's data axis)."""
+        from ..kernels.registry import KERNELS
 
-        def crossover() -> bool:
-            q, k = self.inputs[0], self.inputs[1]
-            # per-chip pressure: the batch dim shards over the data axis
-            dp = 1
-            if ctx is not None and ctx.mesh is not None:
-                dp = dict(getattr(ctx.mesh, "shape", {})).get("data", 1)
-            return flash_crossover(q.dims[0], self.params["num_heads"],
-                                   q.dims[1], k.dims[1], dp)
-
+        q, k = self.inputs[0], self.inputs[1]
+        dp = 1
+        if ctx is not None and ctx.mesh is not None:
+            dp = dict(getattr(ctx.mesh, "shape", {})).get("data", 1)
         return bool(KERNELS.select(
             "attention", param=self.params.get("use_flash"),
-            config=getattr(ctx, "config", None), heuristic=crossover))
+            scores=(q.dims[0], self.params["num_heads"], q.dims[1],
+                    k.dims[1], dp)))
 
     def flops(self) -> float:
         q, k, v, embed, heads, kdim, vdim = self._dims()

@@ -4,13 +4,8 @@ Reference: src/ops/layer_norm.cc (custom CUDA kernels), softmax.cc (cuDNN),
 dropout.cc (cuDNN dropout states). Dropout here uses jax PRNG threaded through
 the LoweringContext — functional replacement for cuDNN's stateful RNG.
 
-The norm/softmax ops are kernel-tier families (docs/kernels.md): when the
-KernelRegistry selects `pallas` — trailing-axis normalization only — the
-lowering emits the fused Pallas kernel from kernels/pallas/norm.py (one
-VMEM pass, f32 statistics, custom fwd+bwd); otherwise the unfused jnp
-reference below, which doubles as the parity oracle. On a mesh
-(`ctx.gspmd_partitioned()`) they always keep the reference lowering, which
-XLA partitions; CostModel.kernel_time_factor prices the same gate.
+The norm/softmax ops have one lowering each: plain jnp with f32 statistics,
+which XLA fuses and partitions on any backend and mesh.
 """
 from __future__ import annotations
 
@@ -22,14 +17,6 @@ import jax.numpy as jnp
 from ..core.op import Op, WeightSpec, register_op
 from ..ffconst import CompMode, OpType
 from ..runtime.initializers import ConstantInitializer, ZeroInitializer
-from ..runtime.platform import pallas_interpret
-
-
-def _trailing_axis_only(op: Op, axes) -> bool:
-    """The fused kernels normalize the trailing axis with leading dims
-    flattened; anything else stays on the reference lowering."""
-    nd = len(op.inputs[0].dims)
-    return tuple(axes) == (nd - 1,)
 
 
 @register_op
@@ -56,16 +43,6 @@ class LayerNormOp(Op):
         x = inputs[0]
         axes = tuple(self.params["axes"])
         eps = self.params.get("eps", 1e-5)
-        from ..kernels.registry import KERNELS
-
-        if (_trailing_axis_only(self, axes)
-                and not ctx.gspmd_partitioned()
-                and KERNELS.select("layernorm", config=ctx.config)):
-            from ..kernels.pallas.norm import fused_layernorm
-
-            return [fused_layernorm(x, weights.get("gamma"),
-                                    weights.get("beta"), eps=eps,
-                                    interpret=pallas_interpret())]
         # statistics in f32 even when activations flow bf16; the result is
         # stored back in the activation dtype
         xf = x.astype(jnp.float32)
@@ -85,8 +62,7 @@ class LayerNormOp(Op):
 @register_op
 class RMSNormOp(Op):
     """Root-mean-square norm (no mean-centering, no beta) — the
-    LayerNorm variant of LLaMA-family decoders, added with the kernel
-    tier so the serving models it matters for can use the fused path."""
+    LayerNorm variant of LLaMA-family decoders."""
 
     op_type = OpType.RMSNORM
 
@@ -107,15 +83,6 @@ class RMSNormOp(Op):
         x = inputs[0]
         axes = tuple(self.params["axes"])
         eps = self.params.get("eps", 1e-6)
-        from ..kernels.registry import KERNELS
-
-        if (_trailing_axis_only(self, axes)
-                and not ctx.gspmd_partitioned()
-                and KERNELS.select("rmsnorm", config=ctx.config)):
-            from ..kernels.pallas.norm import fused_rmsnorm
-
-            return [fused_rmsnorm(x, weights.get("gamma"), eps=eps,
-                                  interpret=pallas_interpret())]
         xf = x.astype(jnp.float32)
         y = xf * jax.lax.rsqrt(
             jnp.mean(jnp.square(xf), axis=axes, keepdims=True) + eps)
@@ -137,16 +104,6 @@ class SoftmaxOp(Op):
     def lower(self, ctx, inputs, weights):
         axis = self.params.get("axis", -1)
         x = inputs[0]
-        from ..kernels.pallas.norm import fused_softmax, softmax_block_rows
-        from ..kernels.registry import KERNELS
-
-        # the fused kernel keeps whole rows resident in VMEM: a row too
-        # wide for that stays on the reference lowering (the same gate
-        # CostModel.kernel_time_factor prices with)
-        if (axis in (-1, x.ndim - 1) and softmax_block_rows(x.shape[-1])
-                and not ctx.gspmd_partitioned()
-                and KERNELS.select("softmax", config=ctx.config)):
-            return [fused_softmax(x, interpret=pallas_interpret())]
         # f32 exp/sum even for bf16 activations
         return [jax.nn.softmax(x.astype(jnp.float32), axis=axis).astype(x.dtype)]
 

@@ -12,29 +12,13 @@ import jax.numpy as jnp
 from ..ffconst import LossType
 
 
-def reduce_scalar(x, kind: str = "mean"):
-    """Scalar reduction of a loss/metric term through the kernel tier's
-    `reduction` family (docs/kernels.md): the fused single-pass Pallas
-    reduction (kernels/pallas/reduction.py, exact-gradient VJP) when the
-    registry selects pallas, plain jnp otherwise. Always f32 out — the
-    jnp path matches by reducing in the input's (already f32) dtype."""
-    from ..kernels.registry import KERNELS
-
-    if kind in ("sum", "mean") and KERNELS.select("reduction"):
-        from ..kernels.pallas.reduction import fused_reduce
-        from .platform import pallas_interpret
-
-        return fused_reduce(x, kind, interpret=pallas_interpret())
-    return jnp.mean(x) if kind == "mean" else jnp.sum(x)
-
-
 def sparse_categorical_crossentropy(logits, labels):
     """labels: int class ids, shape logits.shape[:-1] or (..., 1)."""
     if labels.ndim == logits.ndim:
         labels = labels[..., 0]
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
     ll = jnp.take_along_axis(logp, labels.astype(jnp.int32)[..., None], axis=-1)
-    return -reduce_scalar(ll, "mean")
+    return -jnp.mean(ll)
 
 
 def categorical_crossentropy(probs_or_logits, labels, from_logits: bool = False):
@@ -43,20 +27,17 @@ def categorical_crossentropy(probs_or_logits, labels, from_logits: bool = False)
         logp = jax.nn.log_softmax(x, axis=-1)
     else:
         logp = jnp.log(jnp.clip(x, 1e-12, 1.0))
-    return -reduce_scalar(
-        jnp.sum(labels.astype(jnp.float32) * logp, axis=-1), "mean")
+    return -jnp.mean(jnp.sum(labels.astype(jnp.float32) * logp, axis=-1))
 
 
 def mean_squared_error(pred, target, reduce: str = "avg"):
     se = jnp.square(pred.astype(jnp.float32) - target.astype(jnp.float32))
     per_sample = jnp.sum(se.reshape(se.shape[0], -1), axis=-1)
-    if reduce == "avg":
-        return reduce_scalar(per_sample, "mean")
-    return reduce_scalar(per_sample, "sum")
+    return jnp.mean(per_sample) if reduce == "avg" else jnp.sum(per_sample)
 
 
 def identity_loss(pred, target=None):
-    return reduce_scalar(pred.astype(jnp.float32), "mean")
+    return jnp.mean(pred.astype(jnp.float32))
 
 
 def loss_fn_for(loss_type: LossType):

@@ -78,9 +78,7 @@ class Metrics:
                     tgt = jnp.argmax(sparse_label, axis=-1)
                 else:
                     tgt = sparse_label
-                from .losses import reduce_scalar
-
-                out["accuracy"] = reduce_scalar(
+                out["accuracy"] = jnp.mean(
                     (jnp.argmax(pred, axis=-1) == tgt.astype(jnp.int32)).astype(jnp.float32)
                 )
             elif m == MetricsType.METRICS_SPARSE_CATEGORICAL_CROSSENTROPY:
@@ -92,20 +90,13 @@ class Metrics:
 
                 out["cce"] = categorical_crossentropy(pred, label)
             elif m == MetricsType.METRICS_MEAN_SQUARED_ERROR:
-                from .losses import reduce_scalar
-
-                # f32 BEFORE the reduction: reduce_scalar's two impls
-                # must agree, and a bf16-accumulated mean would not
-                out["mse"] = reduce_scalar(jnp.square(
+                # f32 BEFORE the reduction: a bf16-accumulated mean drifts
+                out["mse"] = jnp.mean(jnp.square(
                     pred - label.astype(pred.dtype)).astype(jnp.float32))
             elif m == MetricsType.METRICS_ROOT_MEAN_SQUARED_ERROR:
-                from .losses import reduce_scalar
-
-                out["rmse"] = jnp.sqrt(reduce_scalar(jnp.square(
+                out["rmse"] = jnp.sqrt(jnp.mean(jnp.square(
                     pred - label.astype(pred.dtype)).astype(jnp.float32)))
             elif m == MetricsType.METRICS_MEAN_ABSOLUTE_ERROR:
-                from .losses import reduce_scalar
-
-                out["mae"] = reduce_scalar(jnp.abs(
+                out["mae"] = jnp.mean(jnp.abs(
                     pred - label.astype(pred.dtype)).astype(jnp.float32))
         return out

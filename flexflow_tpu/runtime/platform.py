@@ -89,8 +89,8 @@ def pallas_interpret() -> bool:
         return True
     raise RuntimeError(
         f"Pallas kernels here target the TPU; backend {backend!r} can"
-        " neither compile nor (by policy) interpret them — select the"
-        " reference lowering (--kernel-impl reference)")
+        " neither compile nor (by policy) interpret them — keep the"
+        " reference lowering")
 
 
 def compile_cache_dir() -> str:

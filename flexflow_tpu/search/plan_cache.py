@@ -13,7 +13,7 @@ moved by one pod. This module makes the search incremental:
    replaces chip constants and latency terms, so the post-overlay
    fingerprint changes when a refit lands), the batch size, the device
    count, and the search knobs (budget/alpha/axis flags/memory
-   search/kernel tier/substitution file content).
+   search/substitution file content).
  - `PlanCache` — an in-memory LRU of serialized SearchResults keyed by
    that hash, with optional disk persistence (`--plan-cache-dir`). A
    hit skips enumeration entirely (`candidates_simulated == 0`); the
@@ -142,9 +142,9 @@ SEARCH_KNOB_FIELDS = (
     "enable_inplace_optimizations", "search_overlap_backward_update",
     "analysis_prune", "memory_search", "memory_budget_mb",
     "optimizer_state_factor", "allow_mixed_precision",
-    "grad_bucket_bytes", "kernel_impl", "kernel_residual_threshold",
-    "use_native_search", "measure_op_costs", "search_warm_start",
-    "warm_fallback_tolerance", "replan_distance_weight",
+    "grad_bucket_bytes", "use_native_search", "measure_op_costs",
+    "search_warm_start", "warm_fallback_tolerance",
+    "replan_distance_weight",
 )
 
 

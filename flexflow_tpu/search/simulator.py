@@ -272,51 +272,16 @@ class CostModel:
         return t * self.kernel_time_factor(op, s)
 
     def kernel_time_factor(self, op: Op, s: OpStrategy) -> float:
-        """Fused-kernel tier pricing (docs/kernels.md): ops whose family
-        the KernelRegistry would select pallas for cost PALLAS_COST_GAIN
-        of their roofline estimate, so the Unity search ranks strategies
-        against the kernels the lowering will actually emit. The
-        structural gates mirror the lowerings exactly — a norm/softmax
-        the op would NOT fuse (non-trailing axes) is never discounted.
-        1.0 for reference selections and non-tier ops — on CPU
-        (reference everywhere by default) this is an exact no-op."""
-        from ..kernels.registry import (KERNELS, OPTYPE_FAMILY,
-                                        flash_crossover)
+        """An attention op whose lowering emits flash costs
+        FLASH_COST_GAIN of its roofline estimate, so the search ranks
+        strategies against the kernel the lowering will actually emit:
+        the same `KERNELS.select` (kernels/registry.py) behind the same
+        structural gates as ops/attention.py. 1.0 for every other op and
+        off a TPU."""
+        from ..kernels.registry import FLASH_COST_GAIN, KERNELS
 
-        family = OPTYPE_FAMILY.get(op.op_type)
-        if family is None:
+        if op.op_type != OpType.MULTIHEAD_ATTENTION:
             return 1.0
-        # memoized per selection-relevant key: the registry resolves the
-        # fitted profile's residuals per call (an os.stat for freshness),
-        # and this sits on the search's per-op-per-strategy hot path.
-        # Assumes selection policy is stable for this CostModel's
-        # lifetime — construct a fresh Simulator after changing the
-        # config knob or entering a KERNELS.override
-        memo = getattr(self, "_kernel_factor_memo", None)
-        if memo is None:
-            memo = self._kernel_factor_memo = {}
-        nd = len(op.inputs[0].dims) if op.inputs else 0
-        if family in ("layernorm", "rmsnorm", "softmax"):
-            # ops/norm.py gates: never fused inside a step jitted over a
-            # mesh (a Mosaic kernel has no GSPMD partitioning rule)
-            if self.machine.num_chips > 1:
-                return 1.0
-            if family == "softmax":
-                # ops/norm.py gates: fused only on the trailing axis, and
-                # only rows narrow enough to stay resident in VMEM
-                from ..kernels.pallas.norm import softmax_block_rows
-
-                if (op.params.get("axis", -1) not in (-1, nd - 1)
-                        or not softmax_block_rows(op.inputs[0].dims[-1])):
-                    return 1.0
-            elif tuple(op.params.get("axes", ())) != (nd - 1,):
-                return 1.0
-            hit = memo.get(family)
-            if hit is None:
-                hit = memo[family] = KERNELS.cost_factor(
-                    family, config=self.config)
-            return hit
-
         # the lowering's structural flash gates (ops/attention.py):
         # attention-prob dropout, kdim != vdim, and the sequence-parallel
         # ring all keep the einsum core regardless of selection
@@ -326,37 +291,22 @@ class CostModel:
         if (op.params.get("dropout", 0.0) > 0 or kdim != vdim
                 or (op.params.get("sequence_parallel") and s.sp > 1)):
             return 1.0
-
-        # the attention lowering's measured score-bytes policy (the
-        # SHARED registry helper) at this STRATEGY's data-parallel
-        # degree (ops/attention.py _use_flash consults the live mesh;
-        # costing has s.dp)
+        # ops/attention.py _use_flash consults the live mesh's data axis;
+        # costing has this STRATEGY's data-parallel degree
         q, k = op.inputs[0], op.inputs[1]
-        param = op.params.get("use_flash")
-        key = ("attention", param,
-               flash_crossover(q.dims[0], op.params["num_heads"],
-                               q.dims[1], k.dims[1], s.dp))
-        hit = memo.get(key)
-        if hit is None:
-            hit = memo[key] = KERNELS.cost_factor(
-                "attention", param=param, config=self.config,
-                heuristic=lambda: key[2])
-        return hit
+        flash = KERNELS.select(
+            "attention", param=op.params.get("use_flash"),
+            scores=(q.dims[0], heads, q.dims[1], k.dims[1], s.dp),
+            record=False)
+        return FLASH_COST_GAIN if flash else 1.0
 
     def decode_step_time_us(self, op: Op, batch: int, cache_len: int,
                             c_queries: int = 1) -> float:
         """Price ONE continuous-batching decode dispatch of attention op
         `op`: `c_queries` query tokens per slot against a `cache_len`-row
         paged KV cache — the serving hot path, which never appears as a
-        graph op so `forward_time_us` cannot see it. Kernel-tier priced
-        like the rest of the Pallas tier: the registry's selection for
-        `attention_decode` (C = 1) / `attention_decode_mq` (C > 1,
-        chunked prefill and the speculative verify) multiplies the
-        roofline by PALLAS_COST_GAIN, so serving-rate predictions
-        (serve-bench's predicted speculative win, fleet sizing) rank
-        against the kernels the batcher will actually dispatch."""
-        from ..kernels.registry import KERNELS
-
+        graph op so `forward_time_us` cannot see it. Priced at the
+        roofline of the reference chain the batcher dispatches."""
         heads = op.params.get("num_heads", 1)
         embed = op.params.get("embed_dim", op.inputs[0].dims[-1])
         kdim = op.params.get("kdim") or embed // heads
@@ -371,14 +321,9 @@ class CostModel:
                                       + vdim * embed)
         core = 2.0 * b * c * heads * m * (kdim + vdim)
         dt_bytes = self.op_dtype_bytes(op)
-        # HBM traffic is the cache stream (the decode bottleneck); the
-        # reference path additionally round-trips the (b, h, c, m)
-        # logits+probs, which is exactly what the fused kernels save —
-        # modeled by the family's PALLAS_COST_GAIN, not double-counted
+        # HBM traffic is the cache stream (the decode bottleneck)
         bytes_ = float(b) * m * heads * (kdim + vdim) * dt_bytes
-        t = self.machine.compute_time_us(proj + core, bytes_, dt_bytes)
-        fam = "attention_decode_mq" if c > 1 else "attention_decode"
-        return t * KERNELS.cost_factor(fam, config=self.config)
+        return self.machine.compute_time_us(proj + core, bytes_, dt_bytes)
 
     def backward_time_us(self, op: Op, s: OpStrategy) -> float:
         if op.op_type in (OpType.INPUT, OpType.NOOP, OpType.WEIGHT):

@@ -1523,8 +1523,8 @@ def unity_optimize(graph: Graph, config, machine: MachineModel,
         # the native core prices from the chip scalars alone — the
         # fitted latency/step-scale coefficients a profile overlay
         # sets (obs/refit.py) don't cross the line protocol, and
-        # neither does the kernel tier's PALLAS_COST_GAIN pricing
-        # (docs/kernels.md). When either is active, re-price the
+        # neither does the flash-attention factor
+        # (CostModel.kernel_time_factor). When either is active, re-price the
         # CHOSEN plan with the fully-overlaid Python simulator so
         # predicted_step_us (what calibration and the drift detector
         # compare against) reflects them; the native ranking stands
@@ -1542,7 +1542,7 @@ def unity_optimize(graph: Graph, config, machine: MachineModel,
                 or tier_active):
             repriced = sim.simulate(graph, result.strategies)
             result.log.append(
-                f"{'kernel-tier' if tier_active else 'fitted-profile'}"
+                f"{'flash-kernel' if tier_active else 'fitted-profile'}"
                 f" reprice: native {result.cost_us:.1f}"
                 f"us -> {repriced:.1f}us predicted")
             result.predicted_step_us = repriced

@@ -43,9 +43,7 @@ and drives one of three workloads (``--workload``):
    greedy tokens identical to plain decode, nonzero draft acceptance,
    tokens/s-per-chip >= ``--spec-speedup`` over plain, a short rerun
    with the fused multi-query kernel FORCED (interpret mode on CPU)
-   still token-identical, and the CostModel pricing the
-   ``attention_decode_mq`` family (its fused/reference dispatch-price
-   ratio == PALLAS_COST_GAIN). On the CPU twin the measured win is
+   still token-identical. On the CPU twin the measured win is
    dispatch amortization (k tokens per fused dispatch vs one per plain
    dispatch); the real draft-vs-target compute ratio needs hardware.
 
@@ -572,10 +570,9 @@ def run_speculative_once(model, draft, workload, max_len: int, slots: int,
 def _spec_pricing(model, spec_tokens: int, max_len: int,
                   slots: int) -> Dict:
     """The CostModel's view of the two hot dispatches: one plain decode
-    step vs one C = k+1 multi-query verify, with and without the fused
-    tier selected — the predicted side of the speculative win."""
+    step vs one C = k+1 multi-query verify — the predicted side of the
+    speculative win."""
     from ...ffconst import OpType
-    from ...kernels.registry import KERNELS, PALLAS_COST_GAIN
     from ...search.machine_model import make_machine_model
     from ...search.simulator import CostModel
 
@@ -584,20 +581,11 @@ def _spec_pricing(model, spec_tokens: int, max_len: int,
     machine = make_machine_model(model.config,
                                  max(1, model.config.total_devices))
     cost = CostModel(machine, model.config)
-    c = spec_tokens + 1
-    ref_plain = cost.decode_step_time_us(attn, slots, max_len, 1)
-    ref_mq = cost.decode_step_time_us(attn, slots, max_len, c)
-    with KERNELS.override("attention_decode", "pallas"), \
-            KERNELS.override("attention_decode_mq", "pallas"):
-        fused_plain = cost.decode_step_time_us(attn, slots, max_len, 1)
-        fused_mq = cost.decode_step_time_us(attn, slots, max_len, c)
     return {
-        "decode_us_reference": round(ref_plain, 3),
-        "decode_us_fused": round(fused_plain, 3),
-        "verify_us_reference": round(ref_mq, 3),
-        "verify_us_fused": round(fused_mq, 3),
-        "mq_gain_priced": round(fused_mq / ref_mq, 4) if ref_mq else 0.0,
-        "mq_gain_expected": PALLAS_COST_GAIN["attention_decode_mq"],
+        "decode_us": round(cost.decode_step_time_us(
+            attn, slots, max_len, 1), 3),
+        "verify_us": round(cost.decode_step_time_us(
+            attn, slots, max_len, spec_tokens + 1), 3),
     }
 
 
@@ -605,8 +593,8 @@ def _run_speculative_cli(args) -> int:
     """Speculative vs plain greedy decode (ISSUE 14 acceptance:
     token-identical output, nonzero acceptance, >= --spec-speedup
     tokens/s per chip, fused multi-query kernel parity in interpret
-    mode, CostModel pricing the new family)."""
-    from ...kernels.registry import KERNELS, PALLAS_COST_GAIN
+    mode)."""
+    from ...kernels.registry import KERNELS
 
     window = args.prompt_max
     max_len = args.prompt_max + args.out_max
@@ -683,9 +671,8 @@ def _run_speculative_cli(args) -> int:
     pricing = _spec_pricing(model, args.spec_tokens, max_len, args.slots)
     print(f"[serve-bench] fused mq leg: parity mismatches"
           f" {fused_parity_bad} ({len(fused_workload)} requests,"
-          " interpret mode) | CostModel mq gain"
-          f" {pricing['mq_gain_priced']} (expected"
-          f" {pricing['mq_gain_expected']})")
+          f" interpret mode) | CostModel decode {pricing['decode_us']}us"
+          f" verify {pricing['verify_us']}us")
 
     failures = []
     if plain["dropped"] or spec["dropped"]:
@@ -706,12 +693,6 @@ def _run_speculative_cli(args) -> int:
         failures.append(
             f"fused multi-query leg: {fused_parity_bad} parity"
             f" mismatches, {fused['dropped']} dropped")
-    if abs(pricing["mq_gain_priced"]
-           - PALLAS_COST_GAIN["attention_decode_mq"]) > 1e-6:
-        failures.append(
-            "CostModel does not price the attention_decode_mq family:"
-            f" gain {pricing['mq_gain_priced']}, expected"
-            f" {pricing['mq_gain_expected']}")
     _check_exposition(failures, extra_required=(
         "ff_spec_decode_proposed_total", "ff_spec_decode_accepted_total",
         "ff_spec_decode_acceptance"))

@@ -58,8 +58,8 @@ def main():
         if bq > L or bk > L:
             continue
 
-        # packed layout: the production path (ops/attention.py use_packed;
-        # block sizes reachable via FFConfig flash_block_q/k)
+        # packed layout: the production path (ops/attention.py use_packed,
+        # which runs the kernel's own 512x512 default)
         def fp(q_, k_, v_, bq=bq, bk=bk):
             return flash_attention_packed(q_, k_, v_, H, block_q=bq,
                                           block_k=bk, interpret=interpret)

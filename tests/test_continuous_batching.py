@@ -240,8 +240,12 @@ def test_continuous_sampling_deterministic_and_traffic_independent(lm):
 def test_scheduler_iteration_is_covered_by_spans(lm):
     """Every pass of the loop is one `serve.iter` span whose children name
     each phase: with a decode slowed to the scale of a real step (50 ms)
-    they cover >= 95 % of every pass that decodes, and the passes' counters
-    add up to exactly what was served."""
+    they cover >= 95 % of the time of the passes that decode, and the
+    passes' counters add up to exactly what was served. The share is of
+    the decoding passes' summed duration, and of the median pass, not of
+    each pass (PERF.md section 6: checks on the loop hold on means): one
+    preemption between two spans, under six busy test workers, is 5 % of
+    a 60 ms pass but not of the run's."""
     import time
 
     from flexflow_tpu import obs
@@ -270,6 +274,8 @@ def test_scheduler_iteration_is_covered_by_spans(lm):
                 and e["ts"] + e["dur"] <= it["ts"] + it["dur"] + 1e-3)
 
     seen = set()
+    uncovered = decoding = 0.0
+    shares = []
     for it in iters:
         kids = sorted((e["ts"], e["ts"] + e["dur"], e["name"])
                       for e in evs if inside(e, it))
@@ -279,7 +285,14 @@ def test_scheduler_iteration_is_covered_by_spans(lm):
             covered += max(0.0, hi - max(lo, end))
             end = max(end, hi)
         if it["args"]["decode_slots"]:
-            assert covered >= 0.95 * it["dur"], (it, kids, covered)
+            uncovered += it["dur"] - covered
+            decoding += it["dur"]
+            shares.append(covered / it["dur"])
+    assert decoding > 0 and uncovered <= 0.05 * decoding, (
+        uncovered, decoding)
+    # the first pass compiles and is ten passes long: the median keeps a
+    # phase that lost its span in EVERY pass from hiding behind it
+    assert np.median(shares) >= 0.95, shares
     assert {"serve.schedule", "serve.prefill", "serve.decode_stage",
             "serve.decode", "serve.decode_dispatch", "serve.decode_fetch",
             "serve.emit"} <= seen

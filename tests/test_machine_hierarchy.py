@@ -1,7 +1,7 @@
 """Hierarchical machine model (docs/machine.md): tier-aware collective
 pricing, per-tier reduction synthesis, one-tier degeneracy vs the flat
 TpuPodModel, fitted-profile overlay round-trips, the FFTA07x cross-tier
-legality family, and the --kernel-residual-threshold satellite."""
+legality family."""
 import dataclasses
 import json
 
@@ -521,48 +521,6 @@ def test_shrink_topology_spec_preserves_tiers_on_whole_pod_loss():
     out2 = shrink_topology_spec(spec, [3])
     assert "tiers" not in out2 and out2["num_chips"] == 15
     assert all(g == 45.0 for _, _, g in out2["links"])
-
-
-# -- kernel residual threshold knob (satellite) -----------------------------
-
-def test_kernel_residual_threshold_flag_parses():
-    cfg = ff.FFConfig()
-    assert cfg.kernel_residual_threshold == 1.10
-    cfg.parse_args(["--kernel-residual-threshold", "1.5"])
-    assert cfg.kernel_residual_threshold == 1.5
-    with pytest.raises(ValueError, match="must be > 0"):
-        ff.FFConfig().parse_args(["--kernel-residual-threshold", "-1"])
-
-
-def test_kernel_residual_threshold_gates_selection(tmp_path):
-    from flexflow_tpu.kernels.registry import KERNELS
-
-    prof = FittedProfile(chip="tpu-v5e", backend="cpu",
-                         coefficients=FittedCoefficients(),
-                         op_family_residuals={"layernorm": 1.3})
-    path = str(tmp_path / "prof.json")
-    prof.save(path)
-    cfg = ff.FFConfig()
-    cfg.fitted_profile_file = path
-    # default threshold 1.10: the 1.3 residual nominates the fused kernel
-    sel = KERNELS.select("layernorm", config=cfg, backend="tpu",
-                         record=False)
-    assert sel.impl == "pallas" and sel.reason == "residual"
-    # a raised threshold rejects the same evidence
-    cfg.kernel_residual_threshold = 1.5
-    sel = KERNELS.select("layernorm", config=cfg, backend="tpu",
-                         record=False)
-    assert sel.impl == "reference"
-    # configure() adopts the knob as the process default too
-    cfg2 = ff.FFConfig()
-    cfg2.kernel_residual_threshold = 1.5
-    cfg2.fitted_profile_file = path
-    KERNELS.configure(cfg2)
-    try:
-        sel = KERNELS.select("layernorm", backend="tpu", record=False)
-        assert sel.impl == "reference"
-    finally:
-        KERNELS.configure(ff.FFConfig())
 
 
 # -- tier-aware pipeline placement + overlap (docs/machine.md "Overlap") ---

@@ -1,25 +1,24 @@
-"""Pallas fused-kernel tier (ISSUE 9): interpret-mode fwd+bwd parity of
-every fused kernel against its jnp reference, the KernelRegistry's
-selection semantics, calibration-driven candidacy, simulator pricing,
-and token-identical greedy decode through the continuous batcher with
-the fused decode kernel forced.
+"""Kernels and the one decision that picks them (kernels/registry.py):
+the norm/softmax reference lowerings against a float64 numpy oracle,
+interpret-mode parity of the Pallas decode kernels against their jnp
+reference, `KERNELS.select`'s decision table, simulator pricing, and
+token-identical greedy decode through the continuous batcher with the
+decode kernels forced.
 
-Tolerances: f32 kernels must match the reference to float-roundoff
-(1e-5); bf16 I/O kernels accumulate in f32 and are compared at bf16
-resolution (2e-2 on normalized outputs).
+Tolerances: f32 must match the oracle to float-roundoff (1e-5); bf16 I/O
+accumulates in f32 and is compared at bf16 resolution (2e-2 on
+normalized outputs).
 """
 import contextlib
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from flexflow_tpu.kernels.pallas import (fused_decode_attention,
-                                         fused_layernorm, fused_reduce,
-                                         fused_rmsnorm, fused_softmax)
-from flexflow_tpu.kernels.registry import (KERNELS, PALLAS_COST_GAIN,
-                                           KernelRegistry)
+from flexflow_tpu.kernels.pallas import fused_decode_attention
+from flexflow_tpu.kernels.registry import FLASH_COST_GAIN, KERNELS
 
 F32_TOL = dict(rtol=1e-5, atol=1e-5)
 BF16_TOL = dict(rtol=2e-2, atol=2e-2)
@@ -38,18 +37,8 @@ def force_pallas(*families):
 
 
 # ---------------------------------------------------------------------
-# norm kernels: fwd + bwd parity
+# norm / softmax reference lowerings: fwd + bwd against a float64 oracle
 # ---------------------------------------------------------------------
-def _ref_layernorm(x, g=None, b=None, eps=1e-5):
-    xf = x.astype(jnp.float32)
-    mean = jnp.mean(xf, -1, keepdims=True)
-    var = jnp.var(xf, -1, keepdims=True)
-    y = (xf - mean) * jax.lax.rsqrt(var + eps)
-    if g is not None:
-        y = y * g.astype(jnp.float32) + b.astype(jnp.float32)
-    return y.astype(x.dtype)
-
-
 def _ref_rmsnorm(x, g=None, eps=1e-6):
     xf = x.astype(jnp.float32)
     y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
@@ -58,40 +47,84 @@ def _ref_rmsnorm(x, g=None, eps=1e-6):
     return y.astype(x.dtype)
 
 
+def _lowering(kind, shape, affine=True):
+    """The op's own `lower` (ops/norm.py) as a function of (x, *weights)."""
+    import flexflow_tpu as ff
+
+    m = ff.FFModel(ff.FFConfig())
+    inp = m.create_tensor(list(shape))
+    if kind == "softmax":
+        m.softmax(inp)
+    else:
+        getattr(m, kind)(inp, [-1], elementwise_affine=affine)
+    op = m.ops[-1]
+    names = [w.name for w in op.weight_specs()]
+    return lambda x, *ws: op.lower(None, [x], dict(zip(names, ws)))[0]
+
+
+def _f64(*arrays):
+    return [np.asarray(a.astype(jnp.float32), np.float64) for a in arrays]
+
+
+def _oracle_layernorm(dy, x, g=None, b=None, eps=1e-5):
+    """float64 numpy: y, and the cotangent dy pulled back to (x, g, b)."""
+    mean = x.mean(-1, keepdims=True)
+    rstd = 1.0 / np.sqrt(x.var(-1, keepdims=True) + eps)
+    xhat = (x - mean) * rstd
+    y = xhat if g is None else xhat * g + b
+    dxhat = dy if g is None else dy * g
+    dx = rstd * (dxhat - dxhat.mean(-1, keepdims=True)
+                 - xhat * (dxhat * xhat).mean(-1, keepdims=True))
+    lead = tuple(range(x.ndim - 1))
+    return y, (dx, (dy * xhat).sum(lead), dy.sum(lead))
+
+
+def _oracle_rmsnorm(dy, x, g, eps=1e-6):
+    r = 1.0 / np.sqrt((x * x).mean(-1, keepdims=True) + eps)
+    y = x * r * g
+    dyg = dy * g
+    dx = r * (dyg - x * r * r * (dyg * x).mean(-1, keepdims=True))
+    return y, (dx, (dy * x * r).sum(tuple(range(x.ndim - 1))))
+
+
+def _oracle_softmax(dy, x):
+    e = np.exp(x - x.max(-1, keepdims=True))
+    y = e / e.sum(-1, keepdims=True)
+    return y, (y * (dy - (dy * y).sum(-1, keepdims=True)),)
+
+
+def _check_fwd_bwd(fn, oracle, args, tol):
+    """The lowering's output and its VJP of one random cotangent (in the
+    I/O dtype, like the inputs) against the oracle's, in float64 from the
+    same stored values."""
+    dy = _rand(np.random.RandomState(99), args[0].shape, args[0].dtype)
+    want_y, want_grads = oracle(*_f64(dy, *args))
+    y, pull = jax.vjp(fn, *args)
+    np.testing.assert_allclose(np.asarray(y, np.float32), want_y, **tol)
+    for a, r in zip(pull(dy), want_grads):
+        np.testing.assert_allclose(np.asarray(a, np.float32), r, **tol)
+
+
 @pytest.mark.parametrize("dtype,tol", [(np.float32, F32_TOL),
                                        (jnp.bfloat16, BF16_TOL)])
 def test_layernorm_fwd_bwd_parity(dtype, tol):
     rng = np.random.RandomState(0)
-    x = _rand(rng, (3, 9, 48), dtype)  # 9 rows: exercises row padding
+    x = _rand(rng, (3, 9, 48), dtype)
     g = _rand(rng, (48,), dtype)
     b = _rand(rng, (48,), dtype)
-    y = fused_layernorm(x, g, b, interpret=True, block_rows=4)
-    np.testing.assert_allclose(np.asarray(y, np.float32),
-                               np.asarray(_ref_layernorm(x, g, b),
-                                          np.float32), **tol)
-
-    def loss(fn):
-        return lambda x, g, b: jnp.sum(jnp.sin(
-            fn(x, g, b).astype(jnp.float32)))
-
-    gf = jax.grad(loss(lambda x, g, b: fused_layernorm(
-        x, g, b, interpret=True, block_rows=4)), argnums=(0, 1, 2))(x, g, b)
-    gr = jax.grad(loss(_ref_layernorm), argnums=(0, 1, 2))(x, g, b)
-    for a, r in zip(gf, gr):
-        np.testing.assert_allclose(np.asarray(a, np.float32),
-                                   np.asarray(r, np.float32), **tol)
+    _check_fwd_bwd(_lowering("layer_norm", x.shape), _oracle_layernorm,
+                   (x, g, b), tol)
 
 
 def test_layernorm_no_affine_parity():
-    rng = np.random.RandomState(1)
-    x = _rand(rng, (4, 5, 32))
-    y = fused_layernorm(x, interpret=True)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(_ref_layernorm(x)),
-                               **F32_TOL)
-    gf = jax.grad(lambda x: jnp.sum(jnp.sin(
-        fused_layernorm(x, interpret=True))))(x)
-    gr = jax.grad(lambda x: jnp.sum(jnp.sin(_ref_layernorm(x))))(x)
-    np.testing.assert_allclose(np.asarray(gf), np.asarray(gr), **F32_TOL)
+    x = _rand(np.random.RandomState(1), (4, 5, 32))
+
+    def oracle(dy, x):
+        y, (dx, _, _) = _oracle_layernorm(dy, x)
+        return y, (dx,)
+
+    _check_fwd_bwd(_lowering("layer_norm", x.shape, affine=False), oracle,
+                   (x,), F32_TOL)
 
 
 @pytest.mark.parametrize("dtype,tol", [(np.float32, F32_TOL),
@@ -100,60 +133,16 @@ def test_rmsnorm_fwd_bwd_parity(dtype, tol):
     rng = np.random.RandomState(2)
     x = _rand(rng, (2, 7, 64), dtype)
     g = _rand(rng, (64,), dtype)
-    y = fused_rmsnorm(x, g, interpret=True, block_rows=4)
-    np.testing.assert_allclose(np.asarray(y, np.float32),
-                               np.asarray(_ref_rmsnorm(x, g), np.float32),
-                               **tol)
-    gf = jax.grad(lambda x, g: jnp.sum(jnp.sin(fused_rmsnorm(
-        x, g, interpret=True, block_rows=4).astype(jnp.float32))),
-        argnums=(0, 1))(x, g)
-    gr = jax.grad(lambda x, g: jnp.sum(jnp.sin(
-        _ref_rmsnorm(x, g).astype(jnp.float32))), argnums=(0, 1))(x, g)
-    for a, r in zip(gf, gr):
-        np.testing.assert_allclose(np.asarray(a, np.float32),
-                                   np.asarray(r, np.float32), **tol)
+    _check_fwd_bwd(_lowering("rms_norm", x.shape), _oracle_rmsnorm,
+                   (x, g), tol)
 
 
 @pytest.mark.parametrize("dtype,tol", [(np.float32, F32_TOL),
                                        (jnp.bfloat16, BF16_TOL)])
 def test_softmax_fwd_bwd_parity(dtype, tol):
-    rng = np.random.RandomState(3)
-    x = _rand(rng, (5, 11, 40), dtype)
-    ref = jax.nn.softmax(x.astype(jnp.float32), -1).astype(x.dtype)
-    y = fused_softmax(x, interpret=True, block_rows=4)
-    np.testing.assert_allclose(np.asarray(y, np.float32),
-                               np.asarray(ref, np.float32), **tol)
-    gf = jax.grad(lambda x: jnp.sum(jnp.sin(fused_softmax(
-        x, interpret=True, block_rows=4).astype(jnp.float32))))(x)
-    gr = jax.grad(lambda x: jnp.sum(jnp.sin(jax.nn.softmax(
-        x.astype(jnp.float32), -1))))(x)
-    np.testing.assert_allclose(np.asarray(gf, np.float32),
-                               np.asarray(gr, np.float32), **tol)
-
-
-# ---------------------------------------------------------------------
-# reduction / scan
-# ---------------------------------------------------------------------
-def test_fused_reduce_parity_and_grads():
-    rng = np.random.RandomState(4)
-    x = _rand(rng, (7, 33))  # 231 elements: lane + row padding
-    np.testing.assert_allclose(float(fused_reduce(x, "sum", interpret=True)),
-                               float(jnp.sum(x)), rtol=1e-5)
-    np.testing.assert_allclose(float(fused_reduce(x, "mean", interpret=True)),
-                               float(jnp.mean(x)), rtol=1e-5)
-    assert float(fused_reduce(x, "max", interpret=True)) == float(jnp.max(x))
-    for kind, ref in (("sum", jnp.sum), ("mean", jnp.mean)):
-        gf = jax.grad(lambda x: fused_reduce(x, kind, interpret=True))(x)  # noqa: B023
-        gr = jax.grad(lambda x: ref(x))(x)  # noqa: B023
-        np.testing.assert_allclose(np.asarray(gf), np.asarray(gr), **F32_TOL)
-    with pytest.raises(TypeError, match="forward-only"):
-        jax.grad(lambda x: fused_reduce(x, "max", interpret=True))(x)
-
-
-def test_fused_reduce_tiny_and_empty():
-    assert float(fused_reduce(jnp.asarray([3.0]), "sum",
-                              interpret=True)) == 3.0
-    assert float(fused_reduce(jnp.zeros((0,)), "sum", interpret=True)) == 0.0
+    x = _rand(np.random.RandomState(3), (5, 11, 40), dtype)
+    _check_fwd_bwd(_lowering("softmax", x.shape), _oracle_softmax,
+                   (x,), tol)
 
 
 # ---------------------------------------------------------------------
@@ -218,177 +207,181 @@ def test_fused_decode_rejects_multi_query():
 
 
 # ---------------------------------------------------------------------
-# KernelRegistry semantics
+# KERNELS.select: param > override > platform > shape
 # ---------------------------------------------------------------------
 def test_registry_selection_order():
-    # CPU backend: auto is always reference
-    assert KERNELS.select("layernorm", record=False).reason == "backend"
-    assert not KERNELS.select("layernorm", record=False)
+    # CPU backend: no param, no override -> always reference
+    c = KERNELS.select("attention", scores=(64, 16, 512, 512, 1),
+                       record=False)
+    assert not c and c.reason == "backend"
     # param beats everything, both ways
     with KERNELS.override("attention", "reference"):
-        assert KERNELS.select("attention", param=True, record=False)
-        assert KERNELS.select("attention", param=True,
-                              record=False).reason == "param"
-    # override beats config; restores the previous override on exit
-    with KERNELS.override("softmax", "pallas"):
-        c = KERNELS.select("softmax", record=False)
+        c = KERNELS.select("attention", param=True, record=False)
+        assert c and c.reason == "param"
+    with KERNELS.override("attention", "pallas"):
+        assert not KERNELS.select("attention", param=False, record=False)
+    # override beats platform; restores the previous override on exit
+    with KERNELS.override("attention_decode", "pallas"):
+        c = KERNELS.select("attention_decode", record=False)
         assert c and c.reason == "override"
-        with KERNELS.override("softmax", "reference"):
-            assert not KERNELS.select("softmax", record=False)
-        assert KERNELS.select("softmax", record=False)
-    assert KERNELS.select("softmax", record=False).reason == "backend"
-    with pytest.raises(KeyError):
-        KERNELS.select("not_a_family")
+        with KERNELS.override("attention_decode", "reference"):
+            assert not KERNELS.select("attention_decode", record=False)
+        assert KERNELS.select("attention_decode", record=False)
+    assert KERNELS.select("attention_decode",
+                          record=False).reason == "backend"
+    for gone in ("not_a_family", "layernorm", "reduction"):
+        with pytest.raises(KeyError):
+            KERNELS.select(gone)
+        with pytest.raises(KeyError):
+            with KERNELS.override(gone, "pallas"):
+                pass
+    with pytest.raises(ValueError):
+        with KERNELS.override("attention", "fused"):
+            pass
 
 
-def test_registry_config_knob_and_parse_spec():
-    assert KernelRegistry.parse_spec("auto") == {}
-    assert KernelRegistry.parse_spec("pallas")["layernorm"] == "pallas"
-    assert KernelRegistry.parse_spec(
-        "attention=pallas,softmax=reference") == {
-            "attention": "pallas", "softmax": "reference"}
-    for bad in ("nope", "attention=fused", "zzz=pallas"):
-        with pytest.raises(ValueError, match="kernel-impl"):
-            KernelRegistry.parse_spec(bad)
+# the three configurations' own attention shapes at published widths:
+# (declared batch, heads, q_len, k_len, data-parallel degree)
+BERT_1CHIP = (64, 16, 512, 512, 1)      # bert_osdi22, 64 rows a chip
+BERT_DATA4 = (256, 16, 512, 512, 4)     # the same at global 256 over data: 4
+LM_WINDOW = (1, 16, 1024, 1024, 1)      # lm_osdi22w's lockstep window
+SEQ_128 = (64, 16, 128, 128, 1)
+
+# (platform, family, use_flash, override, scores) -> (impl, reason)
+DECISIONS = {
+    "cpu-bert": ("cpu", "attention", None, None, BERT_1CHIP,
+                 "reference", "backend"),
+    "cpu-decode": ("cpu", "attention_decode", None, None, None,
+                   "reference", "backend"),
+    "cpu-mq": ("cpu", "attention_decode_mq", None, None, None,
+               "reference", "backend"),
+    "cpu-use_flash-forces": ("cpu", "attention", True, None, SEQ_128,
+                             "pallas", "param"),
+    "cpu-override-forces": ("cpu", "attention", None, "pallas", SEQ_128,
+                            "pallas", "override"),
+    "cpu-decode-override": ("cpu", "attention_decode", None, "pallas", None,
+                            "pallas", "override"),
+    "tpu-bert_osdi22": ("tpu", "attention", None, None, BERT_1CHIP,
+                        "pallas", "shape"),
+    "tpu-bert_osdi22-data4": ("tpu", "attention", None, None, BERT_DATA4,
+                              "pallas", "shape"),
+    "tpu-bert-global256-one-chip": ("tpu", "attention", None, None,
+                                    (256, 16, 512, 512, 1),
+                                    "pallas", "shape"),
+    "tpu-lm_osdi22w-window": ("tpu", "attention", None, None, LM_WINDOW,
+                              "reference", "shape"),
+    "tpu-seq128": ("tpu", "attention", None, None, SEQ_128,
+                   "reference", "shape"),
+    "tpu-seq128-data4": ("tpu", "attention", None, None,
+                         (256, 16, 128, 128, 4), "reference", "shape"),
+    "tpu-no-shape": ("tpu", "attention", None, None, None,
+                     "reference", "shape"),
+    "tpu-decode": ("tpu", "attention_decode", None, None, None,
+                   "reference", "shape"),
+    "tpu-mq": ("tpu", "attention_decode_mq", None, None, None,
+               "reference", "shape"),
+    "tpu-decode-ignores-shape": ("tpu", "attention_decode", None, None,
+                                 BERT_1CHIP, "reference", "shape"),
+    "tpu-decode-override": ("tpu", "attention_decode", None, "pallas", None,
+                            "pallas", "override"),
+    "tpu-mq-override": ("tpu", "attention_decode_mq", None, "pallas", None,
+                        "pallas", "override"),
+    "tpu-bert-override-reference": ("tpu", "attention", None, "reference",
+                                    BERT_1CHIP, "reference", "override"),
+    "tpu-seq128-override-pallas": ("tpu", "attention", None, "pallas",
+                                   SEQ_128, "pallas", "override"),
+    "tpu-bert-use_flash-false": ("tpu", "attention", False, None,
+                                 BERT_1CHIP, "reference", "param"),
+    "tpu-seq128-use_flash-true": ("tpu", "attention", True, None, SEQ_128,
+                                  "pallas", "param"),
+    "tpu-param-beats-override": ("tpu", "attention", False, "pallas",
+                                 BERT_1CHIP, "reference", "param"),
+    "tpu-param-true-beats-override": ("tpu", "attention", True, "reference",
+                                      SEQ_128, "pallas", "param"),
+    "cpu-param-beats-override": ("cpu", "attention", False, "pallas",
+                                 BERT_1CHIP, "reference", "param"),
+    "gpu-is-not-a-tpu": ("gpu", "attention", None, None, BERT_1CHIP,
+                         "reference", "backend"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DECISIONS))
+def test_select_decision_table(case):
+    platform, family, use_flash, override, scores, impl, reason = \
+        DECISIONS[case]
+    forced = (KERNELS.override(family, override) if override
+              else contextlib.nullcontext())
+    with mock.patch.object(jax, "default_backend", lambda: platform), forced:
+        choice = KERNELS.select(family, param=use_flash, scores=scores,
+                                record=False)
+    assert (choice.impl, choice.reason) == (impl, reason)
+    assert bool(choice) == (impl == "pallas")
+
+
+def _attention_ops(batch, seq, **attn):
     import flexflow_tpu as ff
-
-    cfg = ff.FFConfig()
-    cfg.parse_args(["--kernel-impl", "layernorm=pallas"])
-    assert cfg.kernel_impl == "layernorm=pallas"
-    reg = KernelRegistry()
-    reg.configure(cfg)
-    c = reg.select("layernorm", record=False)
-    assert c and c.reason == "config"
-    # reconfiguring back to auto clears it
-    cfg.kernel_impl = "auto"
-    reg.configure(cfg)
-    assert not reg.select("layernorm", record=False)
-    with pytest.raises(ValueError, match="kernel-impl"):
-        ff.FFConfig().parse_args(["--kernel-impl", "bogus"])
-
-
-def test_registry_residual_driven_selection(tmp_path):
-    """A fitted profile whose residuals mark layernorm as underpriced
-    makes auto select pallas on a TPU backend — the calibration-driven
-    loop — while a calibrated family stays on reference."""
-    import flexflow_tpu as ff
-    from flexflow_tpu.obs.refit import FittedCoefficients, FittedProfile
-
-    path = str(tmp_path / "prof.json")
-    FittedProfile(
-        chip="cpu-host", backend="cpu",
-        coefficients=FittedCoefficients(),
-        op_family_residuals={"layernorm": 1.8, "softmax": 1.01},
-    ).save(path)
-    cfg = ff.FFConfig()
-    cfg.fitted_profile_file = path
-    reg = KernelRegistry()
-    reg.configure(cfg)
-    assert reg.residual("layernorm") == 1.8
-    c = reg.select("layernorm", backend="tpu", record=False)
-    assert c and c.reason == "residual"
-    # residual below threshold: falls through to the family default
-    assert not reg.select("softmax", backend="tpu", record=False)
-    # and on CPU the backend gate still wins
-    assert not reg.select("layernorm", backend="cpu", record=False)
-
-
-def test_registry_decode_inherits_attention_residual_and_defaults(tmp_path):
-    """attention_decode never appears as a calibratable graph op: its
-    auto selection on TPU rides the attention family's residual.
-    reduction (same situation, but with no related family and no SPMD
-    partitioning rule for its pallas_call) stays knob-opt-in: reference
-    on every backend under auto."""
-    import flexflow_tpu as ff
-    from flexflow_tpu.obs.refit import FittedCoefficients, FittedProfile
-
-    reg = KernelRegistry()
-    assert not reg.select("attention_decode", backend="tpu", record=False)
-    assert not reg.select("reduction", backend="tpu", record=False)
-    assert not reg.select("reduction", backend="cpu", record=False)
-    path = str(tmp_path / "prof.json")
-    FittedProfile(chip="x", backend="cpu",
-                  coefficients=FittedCoefficients(),
-                  op_family_residuals={"attention": 2.0}).save(path)
-    cfg = ff.FFConfig()
-    cfg.fitted_profile_file = path
-    reg.configure(cfg)
-    d = reg.select("attention_decode", backend="tpu", record=False)
-    assert d and d.reason == "residual"
-
-
-def test_registry_residual_respects_size_heuristic():
-    """Under attention residual evidence, the measured score-bytes
-    crossover still gates per instance: a small-context op stays on the
-    einsum path even when the profiled model's residual nominated the
-    family."""
-    from flexflow_tpu.kernels.registry import flash_crossover
-
-    reg = KernelRegistry()
-    reg._residuals = {"attention": 2.0}
-    big = reg.select("attention", backend="tpu",
-                     heuristic=lambda: True, record=False)
-    assert big and big.reason == "residual"
-    small = reg.select("attention", backend="tpu",
-                       heuristic=lambda: False, record=False)
-    assert not small and small.reason == "heuristic"
-    # the shared helper itself: bert-bench scale crosses, tiny does not
-    assert flash_crossover(64, 16, 512, 512, dp=1)
-    assert not flash_crossover(2, 4, 64, 64, dp=1)
-
-
-def test_registry_per_call_config_isolation(tmp_path):
-    """Two models with different --kernel-impl knobs in one process:
-    select(config=...) resolves each model's own knob regardless of
-    which one configure()d the process default last (the retrace-after-
-    another-compile hazard)."""
-    import flexflow_tpu as ff
-
-    cfg_a = ff.FFConfig()
-    cfg_a.kernel_impl = "layernorm=pallas"
-    cfg_b = ff.FFConfig()  # auto
-    reg = KernelRegistry()
-    reg.configure(cfg_b)  # B compiled LAST — the process default
-    a = reg.select("layernorm", config=cfg_a, record=False)
-    assert a and a.reason == "config"
-    assert not reg.select("layernorm", config=cfg_b, record=False)
-    # and a config-carrying call ignores the global default entirely
-    reg.configure(cfg_a)
-    assert not reg.select("layernorm", config=cfg_b, record=False)
-
-
-def test_cost_model_gates_match_lowering():
-    """The simulator never discounts an op the lowering would not fuse:
-    non-trailing-axis norms and non-last-axis softmax price at 1.0 even
-    with pallas forced."""
-    import flexflow_tpu as ff
-    from flexflow_tpu.search.machine_model import make_machine_model
-    from flexflow_tpu.search.simulator import CostModel, OpStrategy
 
     cfg = ff.FFConfig()
     cfg.num_devices = 1
     m = ff.FFModel(cfg)
-    inp = m.create_tensor([4, 16, 32])
-    m.layer_norm(inp, [1], name="ln_axis1")       # NOT trailing
-    m.softmax(inp, axis=0, name="sm_axis0")       # NOT last
-    ops = {op.name: op for op in m.ops}
-    cost = CostModel(make_machine_model(cfg, 1), cfg)
-    s = OpStrategy()
-    with force_pallas("layernorm", "softmax"):
-        assert cost.kernel_time_factor(ops["ln_axis1"], s) == 1.0
-        assert cost.kernel_time_factor(ops["sm_axis0"], s) == 1.0
+    inp = m.create_tensor([batch, seq, 1024])
+    m.multihead_attention(inp, inp, inp, 1024, 16, name="attn", **attn)
+    m.layer_norm(inp, [-1], name="ln")
+    return cfg, {op.name: op for op in m.ops}
+
+
+def test_cost_model_gates_match_lowering():
+    """The simulator never discounts an op the lowering would not run as
+    flash, and answers as the lowering does for the same op and strategy:
+    attention-prob dropout and an explicit use_flash=False price at 1.0
+    even past the crossover on a TPU; a data-parallel degree that takes
+    the per-chip scores under the crossover does too; no other op type is
+    ever discounted."""
+    from flexflow_tpu.search.machine_model import make_machine_model
+    from flexflow_tpu.search.simulator import CostModel, OpStrategy
+
+    class Ctx:  # what _use_flash reads of a LoweringContext
+        mesh = None
+
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        for attn, dp, want in (({}, 1, FLASH_COST_GAIN),
+                               ({"dropout": 0.1}, 1, 1.0),
+                               ({"use_flash": False}, 1, 1.0),
+                               ({}, 2, 1.0)):
+            cfg, ops = _attention_ops(8, 512, **attn)
+            cost = CostModel(make_machine_model(cfg, dp), cfg)
+            s = OpStrategy(dp=dp)
+            assert cost.kernel_time_factor(ops["attn"], s) == want, attn
+            assert cost.kernel_time_factor(ops["ln"], s) == 1.0
+            if dp == 1 and not attn.get("dropout"):
+                # dropout is a gate of lower() itself, past _use_flash
+                assert ops["attn"]._use_flash(Ctx()) == (
+                    want == FLASH_COST_GAIN)
 
 
 def test_registry_profile_roundtrip_residuals(tmp_path):
+    import json
+
     from flexflow_tpu.obs.refit import FittedCoefficients, FittedProfile
 
     path = str(tmp_path / "p.json")
     FittedProfile(chip="x", backend="cpu",
                   coefficients=FittedCoefficients(),
-                  op_family_residuals={"attention": 2.5}).save(path)
+                  op_family_residuals={"multihead_attention": 2.5}
+                  ).save(path)
     loaded = FittedProfile.load(path, expect_chip="x",
                                 expect_backend="cpu")
-    assert loaded.op_family_residuals == {"attention": 2.5}
+    assert loaded.op_family_residuals == {"multihead_attention": 2.5}
+    # a profile written by an older tree (it carried per-family selection
+    # thresholds) still loads: keys the dataclass no longer has are ignored
+    with open(path) as f:
+        d = json.load(f)
+    d["thresholds_of_an_older_tree"] = {"attention": 1.07}
+    with open(path, "w") as f:
+        json.dump(d, f)
+    again = FittedProfile.load(path, expect_chip="x", expect_backend="cpu")
+    assert again.op_family_residuals == loaded.op_family_residuals
+    assert "thresholds_of_an_older_tree" not in again.to_dict()
 
 
 def test_registry_selection_counter():
@@ -397,42 +390,36 @@ def test_registry_selection_counter():
     fam = REGISTRY.counter("ff_kernel_selected_total",
                            "Kernel-tier selections by op family and "
                            "implementation", labels=("op", "impl"))
-    before = fam.value(op="rmsnorm", impl="pallas")
-    with KERNELS.override("rmsnorm", "pallas"):
-        KERNELS.select("rmsnorm")
-        KERNELS.select("rmsnorm", record=False)  # peeks never count
-    assert fam.value(op="rmsnorm", impl="pallas") == before + 1
+    before = fam.value(op="attention_decode_mq", impl="pallas")
+    with KERNELS.override("attention_decode_mq", "pallas"):
+        KERNELS.select("attention_decode_mq")
+        KERNELS.select("attention_decode_mq", record=False)  # never counts
+    assert fam.value(op="attention_decode_mq", impl="pallas") == before + 1
 
 
 # ---------------------------------------------------------------------
-# simulator pricing: the search sees the kernel tier
+# simulator pricing: the search sees the kernel the lowering emits
 # ---------------------------------------------------------------------
 def test_cost_model_prices_pallas_selection():
-    import flexflow_tpu as ff
+    """Flash priced at the measured ratio, the einsum core at 1.0."""
     from flexflow_tpu.search.machine_model import make_machine_model
     from flexflow_tpu.search.simulator import CostModel, OpStrategy
 
-    cfg = ff.FFConfig()
-    cfg.num_devices = 1
-    m = ff.FFModel(cfg)
-    inp = m.create_tensor([4, 16, 32])
-    m.layer_norm(inp, [-1], name="ln")
-    ln_op = [op for op in m.ops if op.op_type.value == "layernorm"][0]
+    cfg, ops = _attention_ops(4, 16)
     s = OpStrategy(dp=1, tp=1)
-    # fresh CostModel per selection regime: the factor memo assumes the
-    # policy is stable for one model's lifetime
-    t_ref = CostModel(make_machine_model(cfg, 1), cfg).forward_time_us(
-        ln_op, s)
-    with KERNELS.override("layernorm", "pallas"):
-        t_pallas = CostModel(make_machine_model(cfg, 1),
-                             cfg).forward_time_us(ln_op, s)
-    assert t_pallas == pytest.approx(
-        t_ref * PALLAS_COST_GAIN["layernorm"], rel=1e-6)
-    assert t_pallas < t_ref
+    cost = CostModel(make_machine_model(cfg, 1), cfg)
+    t_ref = cost.forward_time_us(ops["attn"], s)
+    assert cost.kernel_time_factor(ops["attn"], s) == 1.0  # CPU: einsum
+    with KERNELS.override("attention", "pallas"):
+        t_pallas = cost.forward_time_us(ops["attn"], s)
+        t_ln = cost.forward_time_us(ops["ln"], s)
+    assert t_pallas == pytest.approx(t_ref * FLASH_COST_GAIN, rel=1e-6)
+    assert FLASH_COST_GAIN == 0.89 and t_pallas < t_ref
+    assert t_ln == cost.forward_time_us(ops["ln"], s)
 
 
 # ---------------------------------------------------------------------
-# op lowerings: forced-pallas model matches the reference model
+# op lowerings through a whole model
 # ---------------------------------------------------------------------
 def _tiny_model(seed=0):
     import flexflow_tpu as ff
@@ -450,21 +437,6 @@ def _tiny_model(seed=0):
               loss_type=ff.LossType.LOSS_SPARSE_CATEGORICAL_CROSSENTROPY,
               metrics=[ff.MetricsType.METRICS_ACCURACY])
     return m
-
-
-def test_training_parity_reference_vs_forced_pallas():
-    """Same data, same seed: a full fit() through the fused layernorm/
-    rmsnorm/softmax/reduction kernels lands on the reference run's loss
-    to float tolerance — fwd AND bwd exercised end-to-end."""
-    rng = np.random.RandomState(8)
-    x = rng.randn(8, 6, 32).astype(np.float32)
-    y = rng.randint(0, 10, size=(8, 6, 1)).astype(np.int32)
-    h_ref = _tiny_model().fit([x], y, batch_size=4, epochs=2)
-    with force_pallas("layernorm", "rmsnorm", "softmax", "reduction"):
-        h_fused = _tiny_model().fit([x], y, batch_size=4, epochs=2)
-    assert h_fused[-1]["loss"] == pytest.approx(h_ref[-1]["loss"],
-                                               rel=1e-4, abs=1e-5)
-    assert h_fused[-1]["accuracy"] == h_ref[-1]["accuracy"]
 
 
 def test_rms_norm_op_reference_lowering_correct():
@@ -515,9 +487,9 @@ def test_continuous_batcher_fused_decode_token_parity():
 
 
 def test_calibration_kernel_candidates_ranking():
-    """Synthetic calibration rows: the candidates section ranks by
-    residual weighted by predicted-step share, and op_family_residuals
-    takes the per-family MEDIAN."""
+    """Synthetic calibration rows: the candidates section ranks op types,
+    by their own names, by residual weighted by predicted-step share, and
+    op_family_residuals takes the per-type MEDIAN."""
     from flexflow_tpu.obs.calibration import (CalibrationReport,
                                               OpCalibration,
                                               op_family_residuals)
@@ -529,39 +501,41 @@ def test_calibration_kernel_candidates_ranking():
         OpCalibration("ln3", "layernorm", "dp=1", 10.0, 30.0),
         # attention: modest residual (x1.5) on most of the step
         OpCalibration("attn", "multihead_attention", "dp=1", 400.0, 600.0),
-        # linear: not a kernel-tier family — never a candidate
-        OpCalibration("fc", "linear", "dp=1", 100.0, 500.0),
+        # linear: faster than predicted — listed, no headroom
+        OpCalibration("fc", "linear", "dp=1", 100.0, 50.0),
         # failed measurement: excluded from residuals
         OpCalibration("sm", "softmax", "dp=1", 5.0, float("nan"),
                       error="x"),
     ]
     fams = op_family_residuals(rows)
-    assert fams["layernorm"] == 3.0  # median of [3, 5, 3]
-    assert fams["attention"] == 1.5
-    assert "softmax" not in fams and "linear" not in fams
+    assert fams == {"layernorm": 3.0,  # median of [3, 5, 3]
+                    "multihead_attention": 1.5, "linear": 0.5}
 
     rep = CalibrationReport(backend="cpu", predicted_step_us=1000.0,
                             measured_step_us=1500.0, measured_steps=3,
                             ops=rows)
     cands = rep.kernel_candidates()
     by_fam = {c["family"]: c for c in cands}
-    assert set(by_fam) == {"layernorm", "attention", "softmax"}
+    assert set(by_fam) == {"layernorm", "multihead_attention", "linear",
+                           "softmax"}
     # attention: 0.5 residual excess * (400/535) share beats layernorm's
     # 2.0 excess * (30/535)
-    assert cands[0]["family"] == "attention"
+    assert [c["family"] for c in cands] == [
+        "multihead_attention", "layernorm", "linear", "softmax"]
     assert by_fam["softmax"]["score"] == 0.0  # unmeasurable -> no score
+    assert by_fam["linear"]["score"] == 0.0   # under the roofline
     assert by_fam["layernorm"]["score"] == pytest.approx(
         2.0 * 30.0 / 535.0)
     # the report renders and serializes with the section included
     assert "kernel candidates" in rep.format_kernel_report()
-    assert rep.to_dict()["kernel_candidates"][0]["family"] == "attention"
+    assert (rep.to_dict()["kernel_candidates"][0]["family"]
+            == "multihead_attention")
 
 
 def test_refit_persists_family_residuals(tmp_path):
-    """A real refit run records the per-family residuals into the saved
-    profile, and a fresh registry configured with that profile sees
-    them."""
-    import flexflow_tpu as ff
+    """A real refit run records the per-op-type residuals into the saved
+    profile, and they survive the round trip; selecting reads none of
+    it."""
     from flexflow_tpu.obs import calibrate
     from flexflow_tpu.obs.refit import FittedProfile, refit
 
@@ -573,20 +547,16 @@ def test_refit_persists_family_residuals(tmp_path):
     rep = calibrate(m)
     measured = rep.measured_step_us or 5000.0
     profile, _ = refit(m, measured, rep.ops, rounds=1, tol=0.15)
-    # the tiny model has layernorm+rmsnorm+softmax rows; at least one
-    # family must have produced evidence
+    # the tiny model has layernorm+rmsnorm+linear+softmax rows; at least
+    # one type must have produced evidence, under its own name
     assert profile.op_family_residuals
+    assert set(profile.op_family_residuals) <= {
+        "layernorm", "rmsnorm", "linear", "softmax"}
     path = str(tmp_path / "fitted.json")
     profile.save(path)
     assert (FittedProfile.load(path).op_family_residuals
             == profile.op_family_residuals)
-    cfg = ff.FFConfig()
-    cfg.fitted_profile_file = path
-    reg = KernelRegistry()
-    reg.configure(cfg)
-    assert reg.residual_source == path
-    for fam, r in profile.op_family_residuals.items():
-        assert reg.residual(fam) == r
+
 
 # ---------------------------------------------------------------------
 # multi-query decode kernel (ISSUE 14)
@@ -670,7 +640,7 @@ def test_fused_multiquery_c1_matches_single_query():
 def test_continuous_batcher_fused_decode_multiblock_token_parity():
     """Satellite 3 (lifts the PR 9 docs caveat): greedy decode through
     the continuous batcher with BOTH fused decode kernels forced and
-    flash_block_k SMALLER than the cache span — every decode streams
+    the kernels' block_k SMALLER than the cache span — every decode streams
     multiple KV blocks through the online softmax — stays
     token-identical to the pure-reference run. Ragged prompts, slot
     reuse (4 requests through 2 slots), chunked prefill through the
@@ -682,13 +652,21 @@ def test_continuous_batcher_fused_decode_multiblock_token_parity():
     prompts = [rng.randint(1, 50, size=(n,)).astype(np.int32)
                for n in (4, 9, 3, 7)]
 
+    import functools
+
+    from flexflow_tpu.kernels.pallas import decode
+
     def run(forced):
         lm = _build_lm(2, 12)
-        lm.config.flash_block_k = 8  # cache span 24 -> 3 KV blocks
-        import contextlib
         with contextlib.ExitStack() as st:
             for fam in forced:
                 st.enter_context(KERNELS.override(fam, "pallas"))
+            for fn in ("fused_decode_attention",
+                       "fused_multiquery_decode_attention"):
+                # cache span 24 -> 3 KV blocks
+                st.enter_context(mock.patch.object(
+                    decode, fn,
+                    functools.partial(getattr(decode, fn), block_k=8)))
             with ContinuousBatcher(lm, max_len=24, num_slots=2,
                                    page_size=4, max_queue=8) as cb:
                 return [r.result(timeout=300).tolist()
@@ -712,7 +690,6 @@ def test_chunk_offset_prefill_lowers_through_mq_kernel():
         1, 50, size=(9,)).astype(np.int32)
 
     def run(force):
-        import contextlib
         with contextlib.ExitStack() as st:
             if force:
                 st.enter_context(KERNELS.override("attention_decode_mq",
@@ -726,112 +703,13 @@ def test_chunk_offset_prefill_lowers_through_mq_kernel():
 
 
 # ---------------------------------------------------------------------
-# registry: mq family, fitted thresholds, decode pricing
+# decode pricing
 # ---------------------------------------------------------------------
-def test_registry_mq_family_aliases_attention_residual(tmp_path):
-    import json
-
-    from flexflow_tpu.obs.refit import FittedCoefficients, FittedProfile
-
-    prof = FittedProfile(chip="c", backend="cpu",
-                         coefficients=FittedCoefficients(),
-                         op_family_residuals={"attention": 1.5})
-    path = str(tmp_path / "p.json")
-    prof.save(path)
-    assert "attention" in json.load(open(path))["op_family_residuals"]
-    reg = KernelRegistry()
-
-    class Cfg:
-        kernel_impl = "auto"
-        fitted_profile_file = path
-        kernel_residual_threshold = 1.10
-
-    d = reg.select("attention_decode_mq", backend="tpu", config=Cfg(),
-                   record=False)
-    assert d and d.reason == "residual"
-    # no evidence -> reference
-    assert not reg.select("attention_decode_mq", backend="tpu",
-                          record=False)
-
-
-def test_registry_fitted_threshold_overrides_knob(tmp_path):
-    """A profile carrying kernel_residual_thresholds wins over the
-    hand-set --kernel-residual-threshold default: evidence below the
-    knob but above the FITTED threshold selects pallas, and a fitted
-    threshold ABOVE the knob demands the stronger evidence."""
-    from flexflow_tpu.obs.refit import FittedCoefficients, FittedProfile
-
-    def mk(residual, fitted):
-        prof = FittedProfile(
-            chip="c", backend="cpu", coefficients=FittedCoefficients(),
-            op_family_residuals={"attention": residual},
-            kernel_residual_thresholds=(
-                {"attention": fitted} if fitted else {}))
-        path = str(tmp_path / f"p_{residual}_{fitted}.json")
-        prof.save(path)
-
-        class Cfg:
-            kernel_impl = "auto"
-            fitted_profile_file = path
-            kernel_residual_threshold = 1.10
-
-        return Cfg()
-
-    reg = KernelRegistry()
-    # residual 1.05 < knob 1.10: reference without a fitted threshold...
-    assert not reg.select("attention_decode", backend="tpu",
-                          config=mk(1.05, None), record=False)
-    # ...but pallas when the PALLAS impl measured at 1.02
-    assert reg.select("attention_decode", backend="tpu",
-                      config=mk(1.05, 1.03), record=False)
-    # a fitted threshold above the knob demands more evidence
-    assert not reg.select("attention_decode", backend="tpu",
-                          config=mk(1.15, 1.30), record=False)
-    assert reg.select("attention_decode", backend="tpu",
-                      config=mk(1.35, 1.30), record=False)
-
-
-def test_fit_kernel_thresholds_from_pallas_rows():
-    """The fitted threshold is the fused impl's own median residual x
-    margin, floored at 1.0 — derived from before/after measurement rows,
-    replacing the hand-set 1.10 constant."""
-    from flexflow_tpu.obs.calibration import OpCalibration
-    from flexflow_tpu.obs.refit import fit_kernel_thresholds
-
-    rows = [
-        OpCalibration("a1", "multihead_attention", "dp=1", 10.0, 10.4),
-        OpCalibration("a2", "multihead_attention", "dp=1", 10.0, 10.6),
-        OpCalibration("a3", "multihead_attention", "dp=1", 10.0, 10.4),
-        # a fused impl BEATING the roofline still floors at 1.0
-        OpCalibration("ln", "layernorm", "dp=1", 10.0, 7.0),
-        # degenerate rows are excluded
-        OpCalibration("sm", "softmax", "dp=1", 5.0, float("nan"),
-                      error="x"),
-    ]
-    th = fit_kernel_thresholds(rows, margin=1.02)
-    assert th["attention"] == pytest.approx(1.04 * 1.02)
-    assert th["layernorm"] == pytest.approx(1.02)
-    assert "softmax" not in th
-
-
-def test_fitted_thresholds_profile_roundtrip(tmp_path):
-    from flexflow_tpu.obs.refit import (FittedCoefficients, FittedProfile)
-
-    prof = FittedProfile(
-        chip="c", backend="cpu", coefficients=FittedCoefficients(),
-        kernel_residual_thresholds={"attention": 1.07, "layernorm": 1.0})
-    path = str(tmp_path / "p.json")
-    prof.save(path)
-    assert (FittedProfile.load(path, expect_backend="cpu")
-            .kernel_residual_thresholds
-            == {"attention": 1.07, "layernorm": 1.0})
-
-
 def test_cost_model_prices_decode_dispatches():
-    """decode_step_time_us prices the serving hot dispatches through the
-    kernel tier: fused/reference ratio is exactly the family's
-    PALLAS_COST_GAIN, the multi-query dispatch costs more than the
-    single-query one, and C rides through the mq family."""
+    """decode_step_time_us prices the serving hot dispatches at the
+    roofline of the reference chain: the multi-query dispatch costs no
+    less than the single-query one, and forcing the decode kernels (which
+    no cell has timed) changes no price."""
     from flexflow_tpu.ffconst import OpType
     from flexflow_tpu.search.machine_model import make_machine_model
     from flexflow_tpu.search.simulator import CostModel
@@ -849,8 +727,5 @@ def test_cost_model_prices_decode_dispatches():
     # that amortization is the whole speculative-decoding win
     assert ref4 >= ref1 > 0
     with force_pallas("attention_decode", "attention_decode_mq"):
-        cost2 = CostModel(machine, lm.config)
-        assert cost2.decode_step_time_us(attn, 4, 64, 1) / ref1 == \
-            pytest.approx(PALLAS_COST_GAIN["attention_decode"])
-        assert cost2.decode_step_time_us(attn, 4, 64, 4) / ref4 == \
-            pytest.approx(PALLAS_COST_GAIN["attention_decode_mq"])
+        assert cost.decode_step_time_us(attn, 4, 64, 1) == ref1
+        assert cost.decode_step_time_us(attn, 4, 64, 4) == ref4

@@ -1,5 +1,6 @@
 """The chip's compiler, without the chip: every Pallas entry point a
-registry family can select is compiled for a DESCRIBED TPU v5e at the
+registry family can select, and every program a benchmark cell times, is
+compiled for a DESCRIBED TPU v5e — the kernels at the
 widths the main paths use (BERT bench: batch 8, seq 512, hidden 1024, 16
 heads; serving: 16 slots x 2048 cache rows).
 
@@ -24,17 +25,12 @@ import pytest  # noqa: E402
 from flexflow_tpu.kernels.flash_attention import (  # noqa: E402
     flash_attention, flash_attention_packed)
 from flexflow_tpu.kernels.pallas import (  # noqa: E402
-    fused_decode_attention, fused_layernorm,
-    fused_multiquery_decode_attention, fused_reduce, fused_rmsnorm,
-    fused_softmax)
-from flexflow_tpu.kernels.pallas.norm import softmax_block_rows  # noqa: E402
+    fused_decode_attention, fused_multiquery_decode_attention)
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 BERT = (8, 512, 1024)   # batch, seq, hidden of the bench config
 HEADS = 16
 SLOTS, ROWS, HEAD_DIM = 16, 2048, 64
-# the widest softmax row the selector admits (ops/norm.py gate)
-WIDEST_ROW = max(n for n in range(39000, 40000) if softmax_block_rows(n))
 
 
 @pytest.fixture(scope="module")
@@ -95,20 +91,6 @@ CASES = {
         _decode(fused_multiquery_decode_attention),
         [((SLOTS, 16, HEADS, HEAD_DIM), F32),
          (_CACHE[0], F32), (_CACHE[0], F32), ((SLOTS,), I32)]),
-    "layernorm_fwd_bwd": (
-        _sum_grad(lambda x, g, b: fused_layernorm(x, g, b), 3),
-        [(BERT, BF16), ((1024,), BF16), ((1024,), BF16)]),
-    "rmsnorm_fwd_bwd": (
-        _sum_grad(lambda x, g: fused_rmsnorm(x, g), 2),
-        [(BERT, BF16), ((1024,), BF16)]),
-    "softmax_widest_row_fwd_bwd": (
-        _sum_grad(fused_softmax, 1), [((64, WIDEST_ROW), F32)]),
-    "softmax_vocab_fwd_bwd": (
-        _sum_grad(fused_softmax, 1), [((8, 512, 30522), BF16)]),
-    "reduce_mean_1d": (
-        lambda x: fused_reduce(x, "mean"), [((4096,), F32)]),
-    "reduce_max": (
-        lambda x: fused_reduce(x, "max"), [((8, 512, 1), F32)]),
 }
 
 
@@ -258,3 +240,92 @@ def test_decode_all_streams_the_expert_stacks_in_place_on_v5e(v5e):
     assert "ragged-dot" not in text["decode_all"]
     assert "tpu_custom_call" not in text["decode_all"]
     assert text["prefill_chunk"].count("ragged-dot") >= 3
+
+
+# the programs the benchmark's cells time, each with the kernel choice the
+# chip makes for it at the parent of PR 29 (compile_for_v5e.py at the real
+# sizes: 36 custom calls in 12 layers of multi_step, none in serving)
+CELL_PROGRAMS = [
+    ("bert_osdi22", "multi_step", 3),          # flash fwd + dq + dkv a layer
+    ("lm_osdi22w", "decode_all", 0),
+    ("lm_osdi22w", "prefill_chunk", 0),
+    ("lm_osdi22w", "prefill_last_chunk", 0),
+    ("mistral_small4_ep4", "decode_all", 0),
+    ("mistral_small4_ep4", "prefill_chunk", 0),
+    ("mistral_small4_ep4", "prefill_last_chunk", 0),
+]
+
+
+@pytest.fixture(scope="module")
+def cell_programs(v5e):
+    """config name -> {program: (jitted fn, argument shapes on the chip)},
+    each configuration at its published widths, one layer, a small
+    vocabulary and deployment, with shapes for parameters; built once."""
+    from unittest import mock
+
+    from benchmark import harness, traffic
+    from benchmark.tests.compile_ms4_for_v5e import programs
+    from flexflow_tpu.runtime.executor import Executor
+
+    small = dict(num_slots=8, max_len=256, prefill_chunk_tokens=64)
+    built = {}
+
+    def shapes(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=v5e), tree)
+
+    def bert(cfg):
+        # 8 rows of 512 tokens a chip: past the crossover, as the cell's 64
+        batch, k, seq = 8, 2, int(cfg["sequence_length"])
+        cfg["deployment"].update(per_chip_batch=batch, steps_per_execution=k)
+        builder = harness.module_of("configs", cfg["builder"])
+        real_init = Executor.init_params
+        with mock.patch.object(builder, "install_weights",
+                               lambda *a: None), \
+                mock.patch.object(
+                    Executor, "init_params",
+                    lambda self, key: jax.eval_shape(
+                        lambda k_: real_init(self, k_), key)):
+            model = builder.build_program(
+                cfg, traffic.load_traffic("train_packed_512"), 1, 0)
+        i32 = lambda *s: jax.ShapeDtypeStruct(s, I32, sharding=v5e)
+        return {"multi_step": (model._get_multi_step().__wrapped__, (
+            shapes(model.params), shapes(model.opt_state),
+            shapes(model.state), {model.input_ops[0].name: i32(k, batch, seq)},
+            i32(k, batch, seq, 1),
+            jax.ShapeDtypeStruct((k, 2), jnp.uint32, sharding=v5e)))}
+
+    def get(name):
+        if name not in built:
+            cfg = harness.load_config(name)
+            cfg.update(num_hidden_layers=1, vocab_size=512)
+            with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+                if cfg["runner"] == "train_fit":
+                    built[name] = bert(cfg)
+                else:
+                    if "n_routed_experts" in cfg:
+                        cfg.update(n_routed_experts=4,
+                                   moe_intermediate_size=256)
+                    cfg["deployment"] = dict(cfg["deployment"], **small)
+                    built[name] = programs(cfg, v5e)
+        return built[name]
+
+    return get
+
+
+@pytest.mark.parametrize("config,program,custom_calls_a_layer",
+                         CELL_PROGRAMS)
+def test_cell_program_holds_the_cells_kernel_choice(
+        cell_programs, config, program, custom_calls_a_layer):
+    """With default settings on a TPU every cell's program lowers to what
+    it lowered to before the selection was cut to one rule: flash's three
+    custom calls a layer in the training step, no Pallas call at all in
+    the serving programs (their attention is the reference chain; the
+    decode kernels wait behind `KERNELS.override`)."""
+    from unittest import mock
+
+    fn, args = cell_programs(config)[program]
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        jax.clear_caches()
+        text = fn.lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == custom_calls_a_layer
