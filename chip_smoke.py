@@ -7,7 +7,8 @@ heads, seq 512, vocab 30,522, batch 8) and a causal LM of the same width
 (serve-bench's own builder). Weights are random, made from --seed; step and
 request counts are a handful — the widths are what is full-size.
 
-    python chip_smoke.py              # one chip: train, kernels, search, serve
+    python chip_smoke.py              # one chip: train, kernels, search,
+                                      # serve, latent
     python chip_smoke.py --chips 4    # ONLY the mesh phase + its 1-device twin
 
 It needs a TPU: with none it exits non-zero before doing any work. There is
@@ -55,6 +56,9 @@ class Sizes:
     page_size: int = 16
     prompts: Tuple[int, ...] = (24, 200, 520, 75)
     new_tokens: int = 8
+    # the latent layer: Mistral-Small-4's heads, q / kv ranks, nope / rope /
+    # value head sizes
+    latent: Tuple[int, ...] = (32, 1024, 256, 64, 64, 128)
     # --chips 4: a global batch at which every plan's per-chip share is
     # past the flash crossover, so the kernels run inside the mesh step
     mesh_batch: int = 32
@@ -390,6 +394,35 @@ def _near_tie(model, prompt, ref_tokens, i, tok_a, tok_b) -> float:
     return gap
 
 
+def _count_identical(label, model, outs, refs, prompts, near_ties) -> int:
+    """How many of `outs` equal `refs` token for token; a first mismatch
+    must be a near-tie under one reference forward, and is appended to
+    `near_ties`."""
+    identical = 0
+    for r, (out, ref, prompt) in enumerate(zip(outs, refs, prompts)):
+        assert len(out) == len(ref), (label, r, out)
+        i = _first_mismatch(out, ref)
+        if i < 0:
+            identical += 1
+            continue
+        gap = _near_tie(model, prompt, ref, i, int(out[i]), int(ref[i]))
+        near_ties.append({"run": label, "request": r, "position": i,
+                          "tokens": [int(out[i]), int(ref[i])],
+                          "logprob_gap": round(gap, 5)})
+        assert gap <= NEAR_TIE_LOGPROB, (
+            f"{label}: request {r} token {i} is {int(out[i])}, the reference"
+            f" says {int(ref[i])}, and it is no near-tie: log-prob gap"
+            f" to the top {gap:.4f} > {NEAR_TIE_LOGPROB:g}")
+    return identical
+
+
+def _print_near_ties(near_ties: List[Dict]) -> None:
+    if near_ties:
+        print("chip_smoke: greedy near-tie flipped (matmul precision);"
+              f" accepted within log-prob {NEAR_TIE_LOGPROB:g}:"
+              f" {json.dumps(near_ties)}", flush=True)
+
+
 def phase_serve(sizes: Sizes, seed: int) -> Dict:
     """serve-bench's causal LM at the BERT width through ContinuousBatcher,
     greedy, against the lockstep GenerativeSession: once with the
@@ -435,26 +468,10 @@ def phase_serve(sizes: Sizes, seed: int) -> Dict:
         for fam in forced:
             assert _selected(fam, before[fam])["pallas"] > 0, (
                 f"{fam}: forced pallas but the decode step never selected it")
-        identical = 0
-        for r, (out, ref, prompt) in enumerate(zip(outs, refs, prompts)):
-            assert len(out) == sizes.new_tokens, (label, r, out)
-            i = _first_mismatch(out, ref)
-            if i < 0:
-                identical += 1
-                continue
-            gap = _near_tie(model, prompt, ref, i, int(out[i]), int(ref[i]))
-            near_ties.append({"run": label, "request": r, "position": i,
-                              "tokens": [int(out[i]), int(ref[i])],
-                              "logprob_gap": round(gap, 5)})
-            assert gap <= NEAR_TIE_LOGPROB, (
-                f"{label}: request {r} token {i} is {int(out[i])}, lockstep"
-                f" says {int(ref[i])}, and it is no near-tie: log-prob gap"
-                f" to the top {gap:.4f} > {NEAR_TIE_LOGPROB:g}")
+        identical = _count_identical(label, model, outs, refs, prompts,
+                                     near_ties)
         runs[label] = f"{identical}/{len(prompts)} identical"
-    if near_ties:
-        print("chip_smoke: greedy near-tie flipped (matmul precision);"
-              f" accepted within log-prob {NEAR_TIE_LOGPROB:g}:"
-              f" {json.dumps(near_ties)}", flush=True)
+    _print_near_ties(near_ties)
     return {"model": f"causal lm {sizes.layers}L/{sizes.hidden}/"
                      f"{sizes.heads}h/vocab{sizes.vocab}, {sizes.slots} slots,"
                      f" window {sizes.window}, f32",
@@ -464,6 +481,75 @@ def phase_serve(sizes: Sizes, seed: int) -> Dict:
             "compared": "greedy tokens vs lockstep GenerativeSession"
                         f" (near-tie log-prob tolerance {NEAR_TIE_LOGPROB:g})",
             "token_parity": runs, "near_ties": len(near_ties)}
+
+
+# ---------------------------------------------------------------------------
+# phase: latent
+# ---------------------------------------------------------------------------
+def phase_latent(sizes: Sizes, seed: int) -> Dict:
+    """One latent attention layer (ops/latent_attention.py) under a causal
+    LM head, through ContinuousBatcher, greedy: the `latent_decode` family
+    forced to its reference and to its kernel, token against token. The
+    prompts' positions span several of the kernel's row blocks."""
+    import flexflow_tpu as ff
+    from flexflow_tpu.kernels.registry import KERNELS
+    from flexflow_tpu.obs.latent_attention import (
+        publish_latent_attention_metrics)
+    from flexflow_tpu.serving.sched import ContinuousBatcher
+
+    heads, q_rank, kv_rank, nope, rope, v_dim = sizes.latent
+    config = ff.FFConfig()
+    config.batch_size = 1
+    config.allow_mixed_precision = False
+    config.num_devices = 1
+    model = ff.FFModel(config)
+    tokens = model.create_tensor([1, sizes.window], ff.DataType.DT_INT32)
+    t = model.embedding(tokens, sizes.vocab, sizes.hidden,
+                        ff.AggrMode.AGGR_MODE_NONE, name="emb")
+    attn = model.latent_attention(t, heads, q_rank, kv_rank, nope, rope,
+                                  v_dim, name="attn")
+    t = model.layer_norm(model.add(t, attn), [-1], name="ln")
+    model.softmax(model.dense(t, sizes.vocab, name="lm_head"))
+    model.compile(optimizer=ff.SGDOptimizer(model, lr=0.0),
+                  loss_type=ff.LossType.LOSS_SPARSE_CATEGORICAL_CROSSENTROPY)
+    rng = np.random.RandomState(seed)
+    prompts = [rng.randint(1, sizes.vocab, size=(n,)).astype(np.int32)
+               for n in sizes.prompts]
+
+    outs, rows = {}, {}
+    for impl in ("reference", "pallas"):
+        before = _selected("latent_decode")
+        with KERNELS.override("latent_decode", impl), ContinuousBatcher(
+                model, max_len=sizes.max_len, num_slots=sizes.slots,
+                page_size=sizes.page_size,
+                max_queue=len(prompts)) as batcher:
+            handles = [batcher.submit(p, sizes.new_tokens) for p in prompts]
+            outs[impl] = [np.asarray(h.result(timeout=900.0))
+                          for h in handles]
+            rows[impl] = publish_latent_attention_metrics(
+                model, batcher.registry, state=batcher.op_counters())["attn"]
+        selected = _selected("latent_decode", before)
+        assert selected[impl] > 0, (
+            f"latent_decode forced to {impl}, but the decode step never"
+            f" selected it: {selected}")
+    near_ties: List[Dict] = []
+    identical = _count_identical("latent_decode_forced", model,
+                                 outs["pallas"], outs["reference"], prompts,
+                                 near_ties)
+    _print_near_ties(near_ties)
+    return {"model": f"latent attention {heads}h q{q_rank}/kv{kv_rank}/"
+                     f"nope{nope}/rope{rope}/v{v_dim} at hidden"
+                     f" {sizes.hidden}, {sizes.slots} slots x"
+                     f" {sizes.max_len} rows, f32",
+            "prompt_lengths": list(sizes.prompts),
+            "new_tokens": sizes.new_tokens,
+            "compared": "greedy tokens, kernel vs reference (near-tie"
+                        f" log-prob tolerance {NEAR_TIE_LOGPROB:g})",
+            "token_parity": f"{identical}/{len(prompts)} identical",
+            "near_ties": len(near_ties),
+            "rows_read_over_filled": {
+                impl: round(r["rows_read"] / r["rows_filled"], 3)
+                for impl, r in rows.items()}}
 
 
 # ---------------------------------------------------------------------------
@@ -588,6 +674,7 @@ def main(argv=None) -> int:
         run_phase("kernels", clock, phase_kernels, sizes, args.seed)
         run_phase("search", clock, phase_search, sizes, args.seed)
         run_phase("serve", clock, phase_serve, sizes, args.seed)
+        run_phase("latent", clock, phase_latent, sizes, args.seed)
     print(json.dumps({"ok": True, "device": device}))
     return 0
 

@@ -9,13 +9,18 @@ observe, first match wins:
     chip_smoke.py force one side;
  3. platform — Pallas compiles only on a TPU; any other backend gets the
     reference (`jax.default_backend()`, looked up at call time);
- 4. shape — `attention`: `flash_crossover`; the two decode families:
-    reference (kernels/pallas/decode.py is reachable by override alone
-    until it has a timed row on a serving cell).
+ 4. shape — `attention`: `flash_crossover`; `latent_decode`: pallas (its
+    call site asks for one query a slot alone, and there the kernel reads
+    the filled rows where the reference reads the allocated ones: 1.9
+    against 8.0 ms a decode iteration of `ms4_decode_sat`, PERF.md
+    section 6, PR 30); the two dense decode families: reference
+    (kernels/pallas/decode.py is reachable by override alone until it has
+    a timed row on a serving cell).
 
 The mesh is the call sites' business (GSPMD cannot partition a Mosaic
 kernel): ops/attention.py runs flash under shard_map (`_on_mesh`) and
-keeps the decode reference chain where `ctx.gspmd_partitioned()`.
+keeps the decode reference chain where `ctx.gspmd_partitioned()`, as
+ops/latent_attention.py does.
 
 Every recorded selection bumps `ff_kernel_selected_total{op,impl}`;
 `CostModel.kernel_time_factor` asks the same `select` so the search
@@ -29,7 +34,8 @@ from typing import Dict, Optional, Tuple
 
 import jax
 
-FAMILIES = ("attention", "attention_decode", "attention_decode_mq")
+FAMILIES = ("attention", "attention_decode", "attention_decode_mq",
+            "latent_decode")
 
 # per-chip f32 score-matrix bytes at the v5e-measured crossover: flash wins
 # from seq ~512 up; below that the blocks are too small to fill the grid and
@@ -91,9 +97,11 @@ class KernelRegistry:
         """Pick the impl for one op instance. `param` is the op's own
         explicit setting (attention's use_flash); `scores` the attention
         instance's `flash_crossover` arguments (batch, heads, q_len,
-        k_len, dp) — the decode families have no shape predicate and stay
-        on the reference; `record=False` skips the selection counter (the
-        cost simulator asks thousands of times per search)."""
+        k_len, dp) — the dense decode families have no shape predicate and
+        stay on the reference, `latent_decode` is asked for one query a
+        slot alone and takes the kernel; `record=False` skips the
+        selection counter (the cost simulator asks thousands of times per
+        search)."""
         _known(family)
         if param is not None:
             choice = KernelChoice(
@@ -103,8 +111,9 @@ class KernelRegistry:
         elif jax.default_backend() != "tpu":
             choice = KernelChoice(family, "reference", "backend")
         else:
-            wins = (family == "attention" and scores is not None
-                    and flash_crossover(*scores))
+            wins = family == "latent_decode" or (
+                family == "attention" and scores is not None
+                and flash_crossover(*scores))
             choice = KernelChoice(
                 family, "pallas" if wins else "reference", "shape")
         if record:

@@ -21,6 +21,11 @@ Two paths compute that, chosen from what the step sees:
             AS STORED — all heads share them, so the heads of one slot are
             the rows of one matrix product — and W_kvb[V,h] is applied to
             the context. No per-head key or value of a cached row exists.
+            With one query a slot, off a GSPMD mesh and where the kernel
+            registry says so (family `latent_decode`: a TPU), the core is
+            kernels/pallas/latent_decode.py: one pass over the rows each
+            slot has FILLED; everywhere else two contractions over all the
+            rows the pool allocated, under a mask.
 
 The cache is two arrays a layer: `c_kv` (rows, max_len, kv_lora_rank), the
 latent after its norm, and `k_rope` (rows, max_len, 128), the shared key
@@ -41,8 +46,10 @@ import jax
 import jax.numpy as jnp
 
 from ..core.op import Op, WeightSpec, register_op
-from ..ffconst import OpType
-from ..runtime.initializers import ConstantInitializer, DefaultInitializer
+from ..ffconst import DataType, OpType
+from ..runtime.initializers import (ConstantInitializer, DefaultInitializer,
+                                    ZeroInitializer)
+from ..runtime.platform import pallas_interpret
 from . import rope as rope_mod
 from .common import emit_dtype, matmul_dtype
 
@@ -69,9 +76,37 @@ def _mm(spec, a, b, cdt):
                       preferred_element_type=jnp.float32).astype(cdt)
 
 
+# A row counter is (2,) int32, [count >> 20, count & (2**20 - 1)]: 128 slots
+# of ~1,000 filled rows add 10**5 a decode iteration, and a plain int32 would
+# wrap within ten minutes of serving.
+_WIDE_BITS = 20
+
+
+def _wide_add(counter, by):
+    low = counter[1] + by
+    return jnp.stack([counter[0] + (low >> _WIDE_BITS),
+                      low & ((1 << _WIDE_BITS) - 1)])
+
+
+def wide_count(counter) -> int:
+    """The host's reading of a row counter of `serving_counters`."""
+    return (int(counter[0]) << _WIDE_BITS) + int(counter[1])
+
+
 @register_op
 class LatentAttentionOp(Op):
+    """The op of the module's docstring. Its state says how far the decode
+    core's read is from the rows the sequences hold, threaded by the
+    continuous batcher from one decode iteration to the next
+    (`serving_counters`): `attn_steps` (decode and verify steps),
+    `rows_filled` (cache rows at or before each slot's position, summed
+    over slots and steps: what the algorithm needs) and `rows_read` (the
+    rows the core that ran fetched: whole blocks up to the position under
+    the kernel, slots x max_len under the reference). The two row counts
+    are wide (`wide_count`)."""
+
     op_type = OpType.LATENT_ATTENTION
+    serving_counters = ("attn_steps", "rows_filled", "rows_read")
 
     def _dims(self):
         p = self.params
@@ -102,6 +137,12 @@ class LatentAttentionOp(Op):
                        init(kvr, heads * (nope + vd))),
             WeightSpec("wo", (heads, vd, e), dt, init(heads * vd, e)),
         ]
+
+    def state_specs(self):
+        z = ZeroInitializer()
+        return [WeightSpec("attn_steps", (), DataType.DT_INT32, z),
+                WeightSpec("rows_filled", (2,), DataType.DT_INT32, z),
+                WeightSpec("rows_read", (2,), DataType.DT_INT32, z)]
 
     def kv_cache_arrays(self):
         """What a token leaves in the cache: its normalised kv latent, and
@@ -165,8 +206,28 @@ class LatentAttentionOp(Op):
                     new.append(c.at[rows[:, None], qpos].set(part))
             for n, c in zip(names, new):
                 ctx.state_updates[(self.name, n)] = c
-            o = self._absorbed(q_nope, q_rope, new[0].astype(cdt),
-                               new[1].astype(cdt), qpos, qscale, weights, cdt)
+            from ..kernels.pallas import latent_decode
+            from ..kernels.registry import KERNELS
+
+            max_len = new[0].shape[1]
+            # GSPMD cannot partition a Mosaic kernel: a decode step jitted
+            # over a mesh keeps the reference contractions
+            fused = bool(length == 1 and not ctx.gspmd_partitioned()
+                         and latent_decode.block_rows(max_len) is not None
+                         and KERNELS.select("latent_decode"))
+            o = self._absorbed(q_nope, q_rope, new[0], new[1], qpos, qscale,
+                               weights, cdt, fused)
+            if (self.name, "rows_read") in ctx.state:
+                held = lambda var: ctx.state[(self.name, var)]
+                last = jnp.minimum(qpos[:, -1], max_len - 1)
+                read = (latent_decode.rows_read(last, max_len) if fused
+                        else b * max_len)
+                ctx.state_updates[(self.name, "attn_steps")] = (
+                    held("attn_steps") + 1)
+                ctx.state_updates[(self.name, "rows_filled")] = _wide_add(
+                    held("rows_filled"), jnp.sum(last + 1))
+                ctx.state_updates[(self.name, "rows_read")] = _wide_add(
+                    held("rows_read"), read)
         else:
             keys = parts
             if pos is not None:     # a chunk at offset `pos` of a batch-1 cache
@@ -214,17 +275,30 @@ class LatentAttentionOp(Op):
             return _mm("bhqm,bmhv->bqhv", probs, v, cdt)
 
     def _absorbed(self, q_nope, q_rope, c_kv, k_rope, qpos, qscale, weights,
-                  cdt):
+                  cdt, fused):
         """c_kv (B, M, kvr), k_rope (B, M, rope) as stored. All heads share
         the latent rows, so the C queries of all heads of a slot are the
         rows of ONE product over the slot's rows: q~ against c_kv plus
         q_rope against k_rope for the scores, the probabilities against
-        c_kv for the context."""
+        c_kv for the context. `fused` (C = 1): the kernel makes that one
+        pass over the rows each slot has filled, under the scope the
+        reference's scores run under."""
         b, c, heads, _ = q_nope.shape
         wk, wv = self._split_kvb(weights)
         flat = lambda t: t.reshape(b, c * heads, t.shape[-1])
         with jax.named_scope("mla:absorb"):
             q_lat = _mm("bqhn,chn->bqhc", q_nope, wk, cdt)
+        if fused:
+            from ..kernels.pallas.latent_decode import latent_decode_attention
+
+            with jax.named_scope("mla:scores"):
+                ctx_lat = latent_decode_attention(
+                    q_lat[:, 0], q_rope[:, 0].astype(cdt), c_kv, k_rope,
+                    qpos[:, 0], jnp.reshape(qscale, (-1,)),
+                    interpret=pallas_interpret())[:, None]
+            with jax.named_scope("mla:context"):
+                return _mm("bqhc,chv->bqhv", ctx_lat, wv, cdt)
+        c_kv, k_rope = c_kv.astype(cdt), k_rope.astype(cdt)
         with jax.named_scope("mla:scores"):
             s = (jnp.einsum("bxc,bmc->bxm", flat(q_lat), c_kv,
                             preferred_element_type=jnp.float32)

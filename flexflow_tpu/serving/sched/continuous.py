@@ -594,8 +594,9 @@ class ContinuousBatcher:
                 " the running batch", labels=("pool",))
 
         # state of the ops that count (Op.serving_counters: an expert
-        # layer's assignments and hits), threaded through decode_all from
-        # one iteration to the next; read by `op_counters()`
+        # layer's assignments and hits, a latent attention's rows filled
+        # and read), threaded through decode_all from one iteration to the
+        # next; read by `op_counters()`
         self._op_counters: Dict[str, Dict[str, object]] = {
             op.name: {v: model.state[op.name][v]
                       for v in op.serving_counters}
@@ -741,6 +742,8 @@ class ContinuousBatcher:
         max_len = self.max_len
         attn_names = [op.name for op in self.attn_ops]
         counter_names = sorted(self._op_counters)
+        counter_vars = {name: tuple(self._op_counters[name])
+                        for name in counter_names}
         temperature, top_k = self.temperature, self.top_k
 
         from ..generate import sampling_logits
@@ -786,16 +789,23 @@ class ContinuousBatcher:
             keys. Inactive slots carry dummy operands; their outputs are
             discarded host-side. Also handed back: the state of the ops
             that count (`Op.serving_counters`), which the caller threads
-            into the next iteration's `state`."""
-            st = {**state, **op_states(caches, attn_names)}
+            into the next iteration's `state`. A caching op that also
+            counts keeps its counters beside its cache arrays."""
+            st = {**state, **{name: {**state.get(name, {}), **caches[name]}
+                              for name in attn_names}}
             values, new_state, _ = executor.forward_values(
                 params, st, {input_name: toks[:, None]}, None,
                 CompMode.COMP_MODE_INFERENCE, decode_pos=pos)
             probs = values[final_guid][:, 0, :]  # (S, V)
             with jax.named_scope("sample:pick"):
                 next_tok = jax.vmap(pick_row)(probs, pos, keys)
-            return (next_tok, op_states(new_state, attn_names),
-                    op_states(new_state, counter_names))
+            return (next_tok,
+                    {name: {part: new_state[name][part]
+                            for part in caches[name]}
+                     for name in attn_names},
+                    {name: {var: new_state[name][var]
+                            for var in counter_vars[name]}
+                     for name in counter_names})
 
         def chunk_forward(executor_, input_name_, attn_names_, params,
                           state, small, tokens, off):
@@ -1703,12 +1713,17 @@ class ContinuousBatcher:
         return jax.device_get(self._op_counters)
 
     def publish_op_counters(self) -> Dict:
-        """Mirror the expert layers' counters into the registry's
-        `ff_moe_*` families (obs/moe.py)."""
+        """Mirror the counting ops' state into the registry: the expert
+        layers' into the `ff_moe_*` families (obs/moe.py), whose numbers
+        are returned, and the latent attentions' rows into `ff_mla_*`
+        (obs/latent_attention.py)."""
+        from ...obs.latent_attention import publish_latent_attention_metrics
         from ...obs.moe import publish_moe_metrics
 
-        return publish_moe_metrics(self.model, self.registry,
-                                   state=self.op_counters())
+        state = self.op_counters()
+        publish_latent_attention_metrics(self.model, self.registry,
+                                         state=state)
+        return publish_moe_metrics(self.model, self.registry, state=state)
 
     # -- scheduler loop ----------------------------------------------------
     def _idle_locked(self) -> bool:

@@ -25,7 +25,8 @@ TOY = chip_smoke.Sizes(
     steps_per_execution=2, train_dispatches=2, kernel_layers=1,
     search_layers=1, search_budget=2, search_devices=2,
     slots=2, window=24, max_len=32, page_size=8, prompts=(5, 20, 9),
-    new_tokens=3, mesh_batch=4, mesh_layers=1, mesh_steps=3)
+    new_tokens=3, latent=(4, 32, 16, 8, 8, 16), mesh_batch=4, mesh_layers=1,
+    mesh_steps=3)
 
 
 @pytest.fixture(scope="module")
@@ -34,7 +35,7 @@ def clock():
 
 
 @pytest.mark.parametrize("phase", ["train", "kernels", "search", "serve",
-                                   "mesh"])
+                                   "latent", "mesh"])
 def test_phase_at_toy_width(phase, clock, capsys):
     """Each phase runs end to end and prints its one JSON line. On this
     backend the kernels run interpreted and the search measures CPU op
@@ -66,6 +67,11 @@ def test_phase_at_toy_width(phase, clock, capsys):
         assert printed["token_parity"] == {
             "default": "3/3 identical",
             "decode_kernels_forced": "3/3 identical"}
+    elif phase == "latent":
+        assert printed["token_parity"] == "3/3 identical"
+        # a cache of one 32-row block: both sides read every allocated row
+        assert (printed["rows_read_over_filled"]["pallas"]
+                == printed["rows_read_over_filled"]["reference"] > 1)
     else:
         assert printed["dp_x_tp"]["mesh_devices"] == 4
         assert printed["dp_x_tp"]["params"]["devices"] == [0, 1, 2, 3]
@@ -76,7 +82,7 @@ def test_phase_at_toy_width(phase, clock, capsys):
 
 
 @pytest.mark.parametrize("argv,phases", [
-    ([], ["train", "kernels", "search", "serve"]),
+    ([], ["train", "kernels", "search", "serve", "latent"]),
     (["--chips", "4", "--seed", "3"], ["mesh"]),
 ])
 def test_main_runs_the_right_phases_and_ends_with_the_ok_line(

@@ -18,7 +18,11 @@ from benchmark.metrics import mla_moe_shapes as shapes
 from benchmark.reference import mla_moe_lm as ref
 from flexflow_tpu.core.op import LoweringContext
 from flexflow_tpu.ffconst import CompMode
+from flexflow_tpu.kernels.pallas import latent_decode
+from flexflow_tpu.kernels.registry import KERNELS
 from flexflow_tpu.ops import moe, rope
+from flexflow_tpu.ops.latent_attention import (LatentAttentionOp, _wide_add,
+                                               wide_count)
 from flexflow_tpu.ops.moe import GatedExpertsOp, gated_experts_oracle
 from flexflow_tpu.serving.sched import kvpool
 from flexflow_tpu.serving.sched.continuous import ContinuousBatcher
@@ -164,6 +168,138 @@ def test_absorbed_decode_equals_expanded(start):
                      ) == pytest.approx(1 + 0.1 * math.log(2))
     np.testing.assert_allclose(np.stack(got, 1), np.stack(want), rtol=2e-5,
                                atol=2e-6)
+
+
+# positions of 8 slots over a cache of 4 blocks of 32 rows: the first row, a
+# block's last and the next block's first, the cache's last, an idle slot's
+# dummy position (0 again), the middle of a block, and a block boundary
+_POS = (0, 31, 32, 127, 0, 77, 63, 64)
+
+
+@pytest.mark.parametrize("compute,stored,tol", [
+    ("float32", "float32", 2e-5), ("bfloat16", "bfloat16", 2e-2),
+    ("float32", "bfloat16", 2e-5)])
+def test_latent_decode_kernel_equals_the_absorbed_reference(
+        compute, stored, tol, monkeypatch):
+    """The kernel (interpret mode) against `_absorbed`'s two contractions at
+    the published widths of one layer (32 heads, kv 256, rope 64 in 128
+    lanes, nope 64, v 128), ragged positions over several blocks. The
+    kernel's caches hold NaN in EVERY row past a slot's position: none
+    reaches the output, so the blocks past it are not computed and the
+    block that holds it is masked in scores and in context."""
+    monkeypatch.setattr(latent_decode, "BLOCK_ROWS", 32)
+    cfg = dict(REAL, hidden_size=64)          # the core does not see it
+    _, op = _mla_op(cfg, len(_POS), 1)
+    cdt, sdt = jnp.dtype(compute), jnp.dtype(stored)
+    b, m, heads = len(_POS), 128, 32
+    ks = jax.random.split(jax.random.PRNGKey(11), 5)
+    q_nope = jax.random.normal(ks[0], (b, 1, heads, 64), cdt)
+    q_rope = jnp.pad(jax.random.normal(ks[1], (b, 1, heads, 64), cdt),
+                     ((0, 0),) * 3 + ((0, 64),))
+    c_kv = jax.random.normal(ks[2], (b, m, 256), sdt)
+    k_rope = jax.random.normal(ks[3], (b, m, 128), sdt)
+    w = {"wkv_b": 0.1 * jax.random.normal(ks[4], (256, heads, 192), cdt)}
+    pos = jnp.asarray(_POS, jnp.int32)
+    qscale = 0.07 * (1.0 + 0.1 * jnp.arange(b, dtype=jnp.float32)
+                     )[:, None, None, None]
+    want = op._absorbed(q_nope, q_rope, c_kv, k_rope, pos[:, None], qscale,
+                        w, cdt, False)
+    past = jnp.arange(m)[None, :, None] > pos[:, None, None]
+    got = op._absorbed(q_nope, q_rope, jnp.where(past, jnp.nan, c_kv),
+                       jnp.where(past, jnp.nan, k_rope), pos[:, None],
+                       qscale, w, cdt, True)
+    assert got.shape == want.shape == (b, 1, heads, 128)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), rtol=tol,
+                               atol=tol)
+
+
+def test_latent_decode_blocks_divide_the_cache():
+    """One block for a short cache, else the largest block of whole 16-row
+    tiles that divides it; none, and so the reference, where there is no
+    such block."""
+    rows = latent_decode.block_rows
+    assert [rows(n) for n in (64, 512, 4096, 1536, 4096 + 16)] == [
+        64, 512, 512, 512, 16]
+    assert rows(1000) is None and rows(520) is None
+    pos = jnp.asarray([0, 511, 512, 4095], jnp.int32)
+    assert int(latent_decode.rows_read(pos, 4096)) == 512 * (1 + 1 + 2 + 8)
+
+
+@pytest.mark.parametrize("impl", ["pallas", "reference"])
+def test_latent_decode_counts_rows_filled_and_rows_read(impl, monkeypatch):
+    """One decode step through `lower()` on either side of the family:
+    `rows_filled` is what the sequences hold, `rows_read` what the core
+    that ran fetched (whole blocks up to the position; every allocated
+    row), and the wide counters carry past 2**20."""
+    monkeypatch.setattr(latent_decode, "BLOCK_ROWS", 16)
+    cfg = tiny_cfg()
+    b, m = 4, 64
+    mdl, op = _mla_op(cfg, b, 1)
+    w = _weights_of(op)
+    x = jax.random.normal(jax.random.PRNGKey(3), (b, 1, 64), jnp.float32)
+    pos = jnp.asarray([0, 15, 16, 63], jnp.int32)
+    near = jnp.asarray([3, 2**20 - 5], jnp.int32)    # 3 * 2**20 + 2**20 - 5
+    ctx = LoweringContext(mdl.config, CompMode.COMP_MODE_INFERENCE)
+    ctx.state[("attn", "c_kv")] = jnp.zeros((b, m, 16))
+    ctx.state[("attn", "k_rope")] = jnp.zeros((b, m, 128))
+    ctx.state[("attn", "attn_steps")] = jnp.int32(7)
+    ctx.state[("attn", "rows_filled")] = near
+    ctx.state[("attn", "rows_read")] = near
+    ctx.decode_pos = pos
+    with KERNELS.override("latent_decode", impl):
+        op.lower(ctx, [x], w)
+    start = wide_count(near)
+    assert start == 4 * 2**20 - 5
+    assert int(ctx.state_updates[("attn", "attn_steps")]) == 8
+    filled = wide_count(ctx.state_updates[("attn", "rows_filled")])
+    read = wide_count(ctx.state_updates[("attn", "rows_read")])
+    assert filled - start == 1 + 16 + 17 + 64
+    assert read - start == (16 * (1 + 1 + 2 + 4) if impl == "pallas"
+                            else b * m)
+    assert LatentAttentionOp.serving_counters == (
+        "attn_steps", "rows_filled", "rows_read")
+    big = _wide_add(jnp.asarray([2**30, 0], jnp.int32), 2**30)
+    assert wide_count(big) == 2**50 + 2**30     # far past what int32 holds
+
+
+def test_batcher_decodes_the_same_tokens_on_both_sides_of_latent_decode(
+        lm, monkeypatch):
+    """`ContinuousBatcher` in float32 with the family forced each way:
+    the same greedy tokens, and counters that add up — one request at a
+    time, so every decode step has one live slot and two idle ones at
+    their dummy position 0."""
+    monkeypatch.setattr(latent_decode, "BLOCK_ROWS", 16)
+    cfg, model = lm
+    rng = np.random.default_rng(5)
+    jobs = [(rng.integers(0, 128, 21, dtype=np.int32), 14),
+            (rng.integers(0, 128, 9, dtype=np.int32), 30)]
+    slots, max_len = 3, cfg["deployment"]["max_len"]
+    steps = sum(n - 1 for _, n in jobs)     # the first token is prefill's
+    filled = sum(len(p) + k + 1 for p, n in jobs for k in range(n - 1)) \
+        + (slots - 1) * steps
+    blocks = sum((len(p) + k) // 16 + 1 for p, n in jobs
+                 for k in range(n - 1)) + (slots - 1) * steps
+    tokens = {}
+    for impl in ("pallas", "reference"):
+        with KERNELS.override("latent_decode", impl), \
+                builder.build_batcher(model, cfg) as cb:
+            tokens[impl] = [cb.submit(p, n).result(timeout=300)
+                            for p, n in jobs]
+            cb.publish_op_counters()
+            got = cb.op_counters()
+            text = cb.registry.render()
+        for name in ("l0_attn", "l1_attn"):
+            c = got[name]
+            read = (16 * blocks if impl == "pallas"
+                    else steps * slots * max_len)
+            assert (int(c["attn_steps"]), wide_count(c["rows_filled"]),
+                    wide_count(c["rows_read"])) == (steps, filled, read)
+            assert f'ff_mla_rows_filled_total{{op="{name}"}} {filled}\n' \
+                in text, text
+            assert f'ff_mla_rows_read_total{{op="{name}"}} {read}\n' in text
+    for a, b in zip(tokens["pallas"], tokens["reference"]):
+        assert np.array_equal(a, b)
 
 
 def test_rope_table_by_hand():

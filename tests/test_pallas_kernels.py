@@ -302,6 +302,18 @@ DECISIONS = {
                                  BERT_1CHIP, "reference", "param"),
     "gpu-is-not-a-tpu": ("gpu", "attention", None, None, BERT_1CHIP,
                          "reference", "backend"),
+    # the latent decode core (one query a slot, asked by its call site)
+    "cpu-latent": ("cpu", "latent_decode", None, None, None,
+                   "reference", "backend"),
+    "cpu-latent-override": ("cpu", "latent_decode", None, "pallas", None,
+                            "pallas", "override"),
+    "tpu-latent": ("tpu", "latent_decode", None, None, None,
+                   "pallas", "shape"),
+    "tpu-latent-override-reference": ("tpu", "latent_decode", None,
+                                      "reference", None,
+                                      "reference", "override"),
+    "gpu-latent": ("gpu", "latent_decode", None, None, None,
+                   "reference", "backend"),
 }
 
 

@@ -25,7 +25,8 @@ import pytest  # noqa: E402
 from flexflow_tpu.kernels.flash_attention import (  # noqa: E402
     flash_attention, flash_attention_packed)
 from flexflow_tpu.kernels.pallas import (  # noqa: E402
-    fused_decode_attention, fused_multiquery_decode_attention)
+    fused_decode_attention, fused_multiquery_decode_attention,
+    latent_decode_attention)
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 BERT = (8, 512, 1024)   # batch, seq, hidden of the bench config
@@ -91,6 +92,17 @@ CASES = {
         _decode(fused_multiquery_decode_attention),
         [((SLOTS, 16, HEADS, HEAD_DIM), F32),
          (_CACHE[0], F32), (_CACHE[0], F32), ((SLOTS,), I32)]),
+    # ms4_decode_sat's decode step: 128 slots x 4,096 rows, 32 heads on a
+    # 256-wide latent and a 64-wide rotary key in 128 lanes, bf16
+    "latent_decode": (
+        latent_decode_attention,
+        [((128, 32, 256), BF16), ((128, 32, 128), BF16),
+         ((128, 4096, 256), BF16), ((128, 4096, 128), BF16),
+         ((128,), I32), ((128,), F32)]),
+    "latent_decode_f32_one_block": (
+        latent_decode_attention,
+        [((8, 32, 256), F32), ((8, 32, 128), F32), ((8, 256, 256), F32),
+         ((8, 256, 128), F32), ((8,), I32), ((8,), F32)]),
 }
 
 
@@ -197,6 +209,13 @@ def test_decode_all_copies_no_latent_cache_on_v5e(v5e):
         if m and np.prod([int(d) for d in m.group(1).split(",")]) >= smallest
     ]
     assert not whole_cache_copies, whole_cache_copies
+    # and the core is the kernel's one pass: no (slots, heads, max_len) f32
+    # scores are left in the step
+    heads = int(cfg["num_attention_heads"])
+    assert text.count("tpu_custom_call") == 1
+    assert not re.search(rf"f32\[{slots},{heads},{rows}\]", text)
+    assert not re.search(rf"f32\[{slots},1,{heads},{rows}\]", text)
+    assert not re.search(rf"f32\[{slots},{heads},1,{rows}\]", text)
 
 
 def test_decode_all_streams_the_expert_stacks_in_place_on_v5e(v5e):
@@ -238,19 +257,22 @@ def test_decode_all_streams_the_expert_stacks_in_place_on_v5e(v5e):
     ]
     assert not moved, moved
     assert "ragged-dot" not in text["decode_all"]
-    assert "tpu_custom_call" not in text["decode_all"]
+    calls = [line for line in text["decode_all"].splitlines()
+             if "tpu_custom_call" in line]
+    assert len(calls) == 1 and "mla:scores" in calls[0], calls
     assert text["prefill_chunk"].count("ragged-dot") >= 3
 
 
 # the programs the benchmark's cells time, each with the kernel choice the
-# chip makes for it at the parent of PR 29 (compile_for_v5e.py at the real
-# sizes: 36 custom calls in 12 layers of multi_step, none in serving)
+# chip makes for it (compile_for_v5e.py at the real sizes: 36 custom calls
+# in 12 layers of multi_step; in serving the latent decode kernel alone,
+# one a layer of mistral_small4_ep4's decode step, since PR 30)
 CELL_PROGRAMS = [
     ("bert_osdi22", "multi_step", 3),          # flash fwd + dq + dkv a layer
     ("lm_osdi22w", "decode_all", 0),
     ("lm_osdi22w", "prefill_chunk", 0),
     ("lm_osdi22w", "prefill_last_chunk", 0),
-    ("mistral_small4_ep4", "decode_all", 0),
+    ("mistral_small4_ep4", "decode_all", 1),     # the latent decode core
     ("mistral_small4_ep4", "prefill_chunk", 0),
     ("mistral_small4_ep4", "prefill_last_chunk", 0),
 ]
@@ -317,11 +339,12 @@ def cell_programs(v5e):
                          CELL_PROGRAMS)
 def test_cell_program_holds_the_cells_kernel_choice(
         cell_programs, config, program, custom_calls_a_layer):
-    """With default settings on a TPU every cell's program lowers to what
-    it lowered to before the selection was cut to one rule: flash's three
-    custom calls a layer in the training step, no Pallas call at all in
-    the serving programs (their attention is the reference chain; the
-    decode kernels wait behind `KERNELS.override`)."""
+    """With default settings on a TPU every cell's program lowers to the
+    registry's one rule: flash's three custom calls a layer in the
+    training step; in the serving programs the latent decode kernel in
+    `mistral_small4_ep4`'s decode step and no Pallas call anywhere else
+    (dense attention is the reference chain, its decode kernels wait
+    behind `KERNELS.override`; prefill keeps the expanded path)."""
     from unittest import mock
 
     fn, args = cell_programs(config)[program]
