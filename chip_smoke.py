@@ -59,6 +59,9 @@ class Sizes:
     # the latent layer: Mistral-Small-4's heads, q / kv ranks, nope / rope /
     # value head sizes
     latent: Tuple[int, ...] = (32, 1024, 256, 64, 64, 128)
+    # the hybrid block: Falcon-H1-34B's 20 query heads on 4 KV heads of 128,
+    # and its mixer: inner width, heads, state, groups
+    hybrid: Tuple[int, ...] = (20, 4, 128, 4096, 32, 256, 2)
     # --chips 4: a global batch at which every plan's per-chip share is
     # past the flash crossover, so the kernels run inside the mesh step
     mesh_batch: int = 32
@@ -553,6 +556,78 @@ def phase_latent(sizes: Sizes, seed: int) -> Dict:
 
 
 # ---------------------------------------------------------------------------
+# phase: hybrid
+# ---------------------------------------------------------------------------
+def phase_hybrid(sizes: Sizes, seed: int) -> Dict:
+    """One block of a state-space mixer beside grouped-KV rotary attention
+    (ops/ssm.py, ops/attention.py) under a causal LM head, through
+    ContinuousBatcher, greedy, against the lockstep GenerativeSession on
+    the same weights: chunked prefill that carries the recurrent state
+    (chunks that do not divide the scan's block), one prompt more than
+    there are slots, so one slot is REUSED and its state reset."""
+    import flexflow_tpu as ff
+    from flexflow_tpu.serving.generate import GenerativeSession
+    from flexflow_tpu.serving.sched import ContinuousBatcher
+
+    heads, kv_heads, head_dim, d_ssm, ssm_heads, d_state, groups = \
+        sizes.hybrid
+    config = ff.FFConfig()
+    config.batch_size = 1
+    config.allow_mixed_precision = False
+    config.num_devices = 1
+    model = ff.FFModel(config)
+    tokens = model.create_tensor([1, sizes.window], ff.DataType.DT_INT32)
+    t = model.embedding(tokens, sizes.vocab, sizes.hidden,
+                        ff.AggrMode.AGGR_MODE_NONE, name="emb")
+    h = model.rms_norm(t, [-1], eps=1e-5, name="ln1")
+    mixed = model.ssm_mixer(h, d_ssm, ssm_heads, d_state, n_groups=groups,
+                            name="mixer")
+    attn = model.multihead_attention(
+        h, h, h, sizes.hidden, heads, kdim=head_dim, vdim=head_dim,
+        bias=False, causal=True, kv_heads=kv_heads,
+        rope_parameters={"rope_theta": 1e11}, name="attn")
+    t = model.layer_norm(model.add(t, model.add(mixed, attn)), [-1],
+                         name="ln")
+    model.softmax(model.dense(t, sizes.vocab, name="lm_head"))
+    model.compile(optimizer=ff.SGDOptimizer(model, lr=0.0),
+                  loss_type=ff.LossType.LOSS_SPARSE_CATEGORICAL_CROSSENTROPY)
+    rng = np.random.RandomState(seed)
+    lengths = tuple(sizes.prompts) + (sizes.prompts[0] + 7,) * max(
+        0, sizes.slots + 1 - len(sizes.prompts))
+    prompts = [rng.randint(1, sizes.vocab, size=(n,)).astype(np.int32)
+               for n in lengths]
+    session = GenerativeSession(model, max_len=sizes.max_len)
+    refs = [np.asarray(session.generate(p[None, :], sizes.new_tokens)[0])
+            for p in prompts]
+    del session
+    chunk = 7 * sizes.page_size      # no multiple of the scan's block
+    with ContinuousBatcher(
+            model, max_len=sizes.max_len, num_slots=sizes.slots,
+            page_size=sizes.page_size, max_queue=len(prompts),
+            prefill_chunk_tokens=chunk) as batcher:
+        handles = [batcher.submit(p, sizes.new_tokens) for p in prompts]
+        outs = [np.asarray(h.result(timeout=900.0)) for h in handles]
+        counts = batcher.op_counters()["mixer"]
+    assert counts["state_resets"] == len(prompts) > sizes.slots
+    near_ties: List[Dict] = []
+    identical = _count_identical("hybrid", model, outs, refs, prompts,
+                                 near_ties)
+    _print_near_ties(near_ties)
+    return {"model": f"ssm mixer {ssm_heads}h x {d_ssm // ssm_heads} x"
+                     f" {d_state} beside attention {heads}/{kv_heads}h of"
+                     f" {head_dim} (rope) at hidden {sizes.hidden},"
+                     f" {sizes.slots} slots x {sizes.max_len} rows, f32",
+            "prompt_lengths": list(lengths), "new_tokens": sizes.new_tokens,
+            "prefill_chunk_tokens": chunk,
+            "compared": "greedy tokens vs lockstep GenerativeSession"
+                        f" (near-tie log-prob tolerance {NEAR_TIE_LOGPROB:g})",
+            "token_parity": f"{identical}/{len(prompts)} identical",
+            "near_ties": len(near_ties),
+            "state_resets": int(counts["state_resets"]),
+            "ssm_steps": int(counts["ssm_steps"])}
+
+
+# ---------------------------------------------------------------------------
 # phase: mesh (--chips 4)
 # ---------------------------------------------------------------------------
 _COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
@@ -675,6 +750,7 @@ def main(argv=None) -> int:
         run_phase("search", clock, phase_search, sizes, args.seed)
         run_phase("serve", clock, phase_serve, sizes, args.seed)
         run_phase("latent", clock, phase_latent, sizes, args.seed)
+        run_phase("hybrid", clock, phase_hybrid, sizes, args.seed)
     print(json.dumps({"ok": True, "device": device}))
     return 0
 

@@ -173,12 +173,29 @@ class Op:
         """Non-trainable per-op state (e.g. running statistics)."""
         return []
 
+    # The two serving-cache capabilities. An op may have either or both;
+    # serving/sched/kvpool.py `kv_cache_spec` finds caching ops by them and
+    # is the one home of how each kind is shaped, allocated and installed.
     def kv_cache_arrays(self) -> Optional[Dict[str, int]]:
-        """{array name: values a token stores in it} for an op that keeps a
-        serving cache (`ctx.state[(op name, array name)]`, shaped (rows,
-        max_len, width)); None for every other op. This is the capability
-        the serving stack finds attention ops by (serving/sched/kvpool.py
-        `kv_cache_spec`)."""
+        """What the op keeps PER TOKEN: {array name: values a token stores
+        in it} (`ctx.state[(op name, array name)]`, shaped (rows, max_len,
+        width): an attention's keys and values, a latent attention's
+        latent rows). Rows past a sequence's position are hidden by the
+        op's own `<= position` mask, so a reused slot needs no reset. None
+        for an op that keeps nothing per token."""
+        return None
+
+    def sequence_state_arrays(self) -> Optional[Dict[str, tuple]]:
+        """What the op keeps PER SEQUENCE, whatever the sequence's length:
+        {array name: (shape after the row axis, DataType or None for the
+        op's cache type)} (`ctx.state[(op name, array name)]`, shaped
+        (rows,) + shape: a state-space mixer's recurrent state and
+        convolution tail). No mask hides a previous tenant's state: whoever
+        admits a sequence into a row starts it from zeros and OVERWRITES
+        the row (the continuous batcher's batch-1 prefill state does), and
+        nothing addresses such state by token position — no page of it can
+        be shared, rolled back or shipped by rows. None for every other
+        op."""
         return None
 
     # state vars the continuous batcher threads from one decode iteration

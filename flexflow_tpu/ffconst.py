@@ -155,6 +155,8 @@ class OpType(enum.Enum):
     GATED_EXPERTS = "moe"
     FUSED = "fused"
     LSTM = "lstm"
+    # Mamba-2 state-space mixer (ops/ssm.py); device scope `ssm:<name>`
+    SSM = "ssm"
     # Parallel ops (reference: src/parallel_ops)
     REPARTITION = "repartition"
     COMBINE = "combine"

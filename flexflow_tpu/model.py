@@ -364,7 +364,25 @@ class FFModel:
         use_flash: Optional[bool] = None,
         kernel_initializer=None,
         name: str = "",
+        kv_heads: int = 0,
+        rope_parameters=None,
+        key_multiplier: float = 1.0,
     ) -> Tensor:
+        """`kv_heads` (0 = `num_heads`): grouped KV heads, query head j
+        reading KV head j // (num_heads / kv_heads); `rope_parameters`: the
+        model's published rotary group (ops/rope.py), queries and keys
+        rotated at their positions before the cache write (half-split
+        pairs); `key_multiplier` scales the projected keys."""
+        # only what departs from plain multi-head attention becomes an op
+        # parameter: the keys of the cost caches and of stored strategies
+        # for every model without them stay what they were
+        extra = {}
+        if kv_heads and kv_heads != num_heads:
+            extra["kv_heads"] = int(kv_heads)
+        if rope_parameters:
+            extra["rope_parameters"] = dict(rope_parameters)
+        if float(key_multiplier) != 1.0:
+            extra["key_multiplier"] = float(key_multiplier)
         return self._add_op(
             OpType.MULTIHEAD_ATTENTION,
             [query, key, value],
@@ -382,6 +400,7 @@ class FFModel:
             sequence_parallel_mode=sequence_parallel_mode,
             use_flash=use_flash,
             kernel_initializer=kernel_initializer,
+            **extra,
         ).outputs[0]
 
     # -- shape ops -------------------------------------------------------
@@ -574,6 +593,32 @@ class FFModel:
             qk_rope_head_dim=qk_rope_head_dim, v_head_dim=v_head_dim,
             rope_parameters=dict(rope_parameters) if rope_parameters else None,
             eps=eps, kernel_initializer=kernel_initializer).outputs[0]
+
+    def ssm_mixer(self, input: Tensor, d_ssm: int, n_heads: int,
+                  d_state: int, n_groups: int = 1, d_conv: int = 4,
+                  chunk_size: int = 128, slice_multipliers=None,
+                  conv_bias: bool = True, eps: float = 1e-5,
+                  state_dtype: DataType = DataType.DT_FLOAT,
+                  kernel_initializer=None, name: str = "") -> Tensor:
+        """Mamba-2 state-space mixer (ops/ssm.py): a gated selective
+        recurrence over `n_heads` heads of `d_ssm / n_heads` channels with a
+        `d_state`-wide state each, B and C shared inside each of `n_groups`
+        groups, behind a causal depthwise convolution of `d_conv` taps; the
+        multi-token entries scan in blocks of `chunk_size`.
+        `slice_multipliers`: five scalars on the input projection's
+        [z | x | B | C | dt] slices. Serving keeps its state per SEQUENCE
+        (`Op.sequence_state_arrays`), the recurrent state stored in
+        `state_dtype` and stepped in float32."""
+        return self._add_op(
+            OpType.SSM, [input], name, d_ssm=int(d_ssm),
+            n_heads=int(n_heads), d_state=int(d_state),
+            n_groups=int(n_groups), d_conv=int(d_conv),
+            chunk_size=int(chunk_size),
+            slice_multipliers=(tuple(float(m) for m in slice_multipliers)
+                               if slice_multipliers is not None else None),
+            conv_bias=bool(conv_bias), eps=float(eps),
+            state_dtype=state_dtype,
+            kernel_initializer=kernel_initializer).outputs[0]
 
     def moe(
         self,

@@ -15,3 +15,4 @@ from . import attention  # noqa: F401
 from . import latent_attention  # noqa: F401
 from . import moe  # noqa: F401
 from . import rnn  # noqa: F401
+from . import ssm  # noqa: F401

@@ -47,38 +47,67 @@ def _contract_heads_together(c, heads):
     return c * heads <= _MXU_ROWS
 
 
-# The decode step's two contractions over the cache AS STORED: q
-# (B, C, h, d), caches (B, M, h*d), scores (B, h, C, M) in float32, context
-# (B, C, h, d). `together`: head j's query sits in lanes [j*d, (j+1)*d) of
-# its own h*d-wide row and zeros elsewhere, so one contraction over the
-# packed row is every head's QK^T (the added products are exact zeros);
-# of the h*d context lanes a head's probabilities give, its own d are kept.
-# Otherwise the plain per-head einsums on a (B, M, h, d) view.
+# Grouped heads (`kv_heads` n < heads h): query head j reads KV head
+# j // (h/n). The two contractions of the core on the logical layouts, q
+# (B, Q, h, d) against k / v (B, K, n, d): the group's queries are a second
+# batch axis of their KV head, so no key or value is ever repeated.
 
-def _own_lanes(heads):
-    return jnp.eye(heads, dtype=bool)[:, :, None]  # (j, j', 1)
+def _grouped_scores(q, k):
+    b, lq, heads, d = q.shape
+    n = k.shape[2]
+    if n == heads:
+        return jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                          preferred_element_type=jnp.float32)
+    s = jnp.einsum("bqngd,bknd->bngqk", q.reshape(b, lq, n, heads // n, d),
+                   k, preferred_element_type=jnp.float32)
+    return s.reshape(b, heads, lq, -1)
+
+
+def _grouped_context(probs, v):
+    b, heads, lq, lk = probs.shape
+    n = v.shape[2]
+    if n == heads:
+        return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    o = jnp.einsum("bngqk,bknd->bqngd",
+                   probs.reshape(b, n, heads // n, lq, lk), v)
+    return o.reshape(b, lq, heads, -1)
+
+
+# The decode step's two contractions over the cache AS STORED: q
+# (B, C, h, d), caches (B, M, n*d) for n KV heads, scores (B, h, C, M) in
+# float32, context (B, C, h, d). `together`: head j's query sits in the
+# lanes of ITS KV head, [(j // g)*d, (j // g + 1)*d) with g = h/n, of its
+# own n*d-wide row and zeros elsewhere, so one contraction over the packed
+# row is every head's QK^T (the added products are exact zeros); of the
+# n*d context lanes a head's probabilities give, its KV head's d are kept.
+# Otherwise the per-head einsums on a (B, M, n, d) view.
+
+def _own_lanes(heads, kv_heads):
+    if kv_heads == heads:
+        return jnp.eye(heads, dtype=bool)[:, :, None]  # (j, j', 1)
+    return (jnp.arange(heads)[:, None] // (heads // kv_heads)
+            == jnp.arange(kv_heads)[None, :])[:, :, None]
 
 
 def _scores(q, kc, together):
     b, c, heads, d = q.shape
+    n = kc.shape[-1] // d
     if not together:
-        return jnp.einsum("bqhd,bkhd->bhqk", q, kc.reshape(b, -1, heads, d),
-                          preferred_element_type=jnp.float32)
-    qb = jnp.where(_own_lanes(heads), q[:, :, :, None, :], 0)
-    logits = jnp.einsum("bxe,bme->bxm", qb.reshape(b, c * heads, heads * d),
+        return _grouped_scores(q, kc.reshape(b, -1, n, d))
+    qb = jnp.where(_own_lanes(heads, n), q[:, :, :, None, :], 0)
+    logits = jnp.einsum("bxe,bme->bxm", qb.reshape(b, c * heads, n * d),
                         kc, preferred_element_type=jnp.float32)
     return logits.reshape(b, c, heads, -1).transpose(0, 2, 1, 3)
 
 
-def _context(probs, vc, together):
+def _context(probs, vc, together, kv_heads):
     b, heads, c, m = probs.shape
     if not together:
-        return jnp.einsum("bhqk,bkhd->bqhd", probs,
-                          vc.reshape(b, m, heads, -1))
+        return _grouped_context(probs, vc.reshape(b, m, kv_heads, -1))
     wide = jnp.einsum("bxm,bme->bxe",
                       probs.transpose(0, 2, 1, 3).reshape(b, c * heads, m),
-                      vc).reshape(b, c, heads, heads, -1)
-    return jnp.sum(jnp.where(_own_lanes(heads), wide, 0), axis=3)
+                      vc).reshape(b, c, heads, kv_heads, -1)
+    return jnp.sum(jnp.where(_own_lanes(heads, kv_heads), wide, 0), axis=3)
 
 
 @register_op
@@ -94,8 +123,31 @@ class MultiHeadAttentionOp(Op):
         vdim = p.get("vdim") or embed // heads
         return q, k, v, embed, heads, kdim, vdim
 
+    def _kv_heads(self) -> int:
+        """KV heads (`kv_heads`; the query heads where none are given):
+        query head j reads KV head j // (heads / kv_heads)."""
+        return self.params.get("kv_heads") or self.params["num_heads"]
+
     def output_shapes(self):
         q, k, v, embed, heads, kdim, vdim = self._dims()
+        kv_heads = self._kv_heads()
+        rope = self.params.get("rope_parameters")
+        if heads % kv_heads:
+            raise ValueError(
+                f"multihead_attention: num_heads={heads} is no multiple of"
+                f" kv_heads={kv_heads}")
+        if rope is not None and kdim % 2:
+            raise ValueError("multihead_attention: rotary positions need an"
+                             f" even kdim, got {kdim}")
+        if kv_heads != heads or rope is not None:
+            # the flash, ring and ulysses kernels take one K/V head a query
+            # head and unrotated projections (ROADMAP Reach M1)
+            for opt in ("use_flash", "sequence_parallel"):
+                if self.params.get(opt):
+                    raise ValueError(
+                        f"multihead_attention: {opt}=True takes neither"
+                        " grouped KV heads nor rotary positions; the"
+                        " einsum core serves them")
         if self.params.get("sequence_parallel") and self.params.get("dropout", 0.0) > 0:
             # the ring kernel has no attention-probability dropout; fail loudly
             # rather than silently train with different regularization
@@ -121,6 +173,7 @@ class MultiHeadAttentionOp(Op):
     def weight_specs(self) -> List[WeightSpec]:
         q, k, v, embed, heads, kdim, vdim = self._dims()
         user_init = self.params.get("kernel_initializer")
+        kvh = self._kv_heads()
 
         def init(fan_in, fan_out):
             return user_init or DefaultInitializer(fan_in=fan_in, fan_out=fan_out)
@@ -128,23 +181,25 @@ class MultiHeadAttentionOp(Op):
         dt = q.dtype
         specs = [
             WeightSpec("wq", (q.dims[-1], heads, kdim), dt, init(q.dims[-1], heads * kdim)),
-            WeightSpec("wk", (k.dims[-1], heads, kdim), dt, init(k.dims[-1], heads * kdim)),
-            WeightSpec("wv", (v.dims[-1], heads, vdim), dt, init(v.dims[-1], heads * vdim)),
+            WeightSpec("wk", (k.dims[-1], kvh, kdim), dt, init(k.dims[-1], kvh * kdim)),
+            WeightSpec("wv", (v.dims[-1], kvh, vdim), dt, init(v.dims[-1], kvh * vdim)),
             WeightSpec("wo", (heads, vdim, embed), dt, init(heads * vdim, embed)),
         ]
         if self.params.get("bias", True):
             specs += [
                 WeightSpec("bq", (heads, kdim), dt, ZeroInitializer()),
-                WeightSpec("bk", (heads, kdim), dt, ZeroInitializer()),
-                WeightSpec("bv", (heads, vdim), dt, ZeroInitializer()),
+                WeightSpec("bk", (kvh, kdim), dt, ZeroInitializer()),
+                WeightSpec("bv", (kvh, vdim), dt, ZeroInitializer()),
                 WeightSpec("bo", (embed,), dt, ZeroInitializer()),
             ]
         return specs
 
     def kv_cache_arrays(self):
-        """A token's keys and values, the heads packed into one row each."""
-        _, _, _, _, heads, kdim, vdim = self._dims()
-        return {"k_cache": heads * kdim, "v_cache": heads * vdim}
+        """A token's keys (after their rotation, where the op rotates) and
+        values, the KV heads packed into one row each."""
+        _, _, _, _, _, kdim, vdim = self._dims()
+        kvh = self._kv_heads()
+        return {"k_cache": kvh * kdim, "v_cache": kvh * vdim}
 
     def lower(self, ctx, inputs, weights):
         q_in, k_in, v_in = inputs[:3]
@@ -154,6 +209,8 @@ class MultiHeadAttentionOp(Op):
         # heads/tp when the search's op-cost measurement hands the op its
         # tensor-parallel weight shard (search/simulator.py OpCostCache)
         heads = weights["wq"].shape[1]
+        kv_heads = weights["wk"].shape[1]
+        rope = p.get("rope_parameters")
         cdt = matmul_dtype(ctx.config, q_in.dtype)
 
         # iteration seq_length truncation (reference: FFIterationConfig
@@ -194,7 +251,8 @@ class MultiHeadAttentionOp(Op):
         # queries, einsum core) keeps the logical [b, l, h, d]. The KV
         # cache itself is stored packed, (rows, max_len, heads*head_dim).
         flash_selected = (
-            self._use_flash(ctx) and not dropout_active and kdim == vdim
+            kv_heads == heads and rope is None
+            and self._use_flash(ctx) and not dropout_active and kdim == vdim
             and not seq_parallel_active
         )
         kc = (ctx.state.get((self.name, "k_cache"))
@@ -243,6 +301,12 @@ class MultiHeadAttentionOp(Op):
                 q = q + weights["bq"].astype(cdt)
                 k = k + weights["bk"].astype(cdt)
                 v = v + weights["bv"].astype(cdt)
+        if p.get("key_multiplier", 1.0) != 1.0:
+            k = k * jnp.asarray(p["key_multiplier"], cdt)
+        if rope is not None:
+            # rotated BEFORE the cache write: a cached key carries its own
+            # position, a query the position it is asked at
+            q, k = self._rotate(ctx, q, k, decode_active)
 
         # KV-cache paths for autoregressive serving (serving/generate.py;
         # reference role: the incremental-decoding half of the Triton
@@ -318,10 +382,7 @@ class MultiHeadAttentionOp(Op):
             drop_key = ctx.next_rng() if dropout_active else None
 
             def attn_core(q, k, v, drop_key):
-                logits = jnp.einsum(
-                    "bqhd,bkhd->bhqk", q, k,
-                    preferred_element_type=jnp.float32
-                ) * scale
+                logits = _grouped_scores(q, k) * scale
                 if causal:
                     lq, lk = logits.shape[-2], logits.shape[-1]
                     mask = jnp.tril(jnp.ones((lq, lk), dtype=bool), lk - lq)
@@ -335,7 +396,7 @@ class MultiHeadAttentionOp(Op):
                 # emits the compute dtype — the MXU accumulates f32
                 # internally either way, and a bf16 output halves the HBM
                 # write
-                return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(cdt), v)
+                return _grouped_context(probs.astype(cdt), v)
 
             if ctx.mode == CompMode.COMP_MODE_TRAINING:
                 # rematerialize in backward: recomputing logits+softmax
@@ -470,9 +531,12 @@ class MultiHeadAttentionOp(Op):
 
         family = ("attention_decode" if vector and c == 1
                   else "attention_decode_mq")
+        kv_heads = k.shape[2]
         # GSPMD cannot partition a Mosaic kernel (see _on_mesh): a decode
-        # step jitted over a mesh keeps the reference chain below
-        if not ctx.gspmd_partitioned() and KERNELS.select(family):
+        # step jitted over a mesh keeps the reference chain below, as do
+        # grouped KV heads (the kernels take one K/V head a query head)
+        if (kv_heads == q.shape[2] and not ctx.gspmd_partitioned()
+                and KERNELS.select(family)):
             from ..kernels.pallas import decode
 
             fused = (decode.fused_decode_attention
@@ -501,8 +565,29 @@ class MultiHeadAttentionOp(Op):
         logits = _scores(q, kc.astype(q.dtype), together) * scale
         logits = jnp.where(mask, logits, -1e30)  # (B, h, C, M)
         probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-        ctxv = _context(probs.astype(q.dtype), vc.astype(q.dtype), together)
+        ctxv = _context(probs.astype(q.dtype), vc.astype(q.dtype), together,
+                        kv_heads)
         return self._decode_project(ctxv, q.dtype, weights)
+
+    def _rotate(self, ctx, q, k, decode_active):
+        """q (B, L, h, d) and k (B, L, n, d) rotated to their positions
+        (ops/rope.py, half-split pairs): 0..L-1 for a whole sequence,
+        `decode_pos` + 0..L-1 in the decode step's three forms."""
+        from . import rope as rope_mod
+
+        steps = jnp.arange(q.shape[1])
+        pos = ctx.decode_pos if decode_active else None
+        if pos is None:
+            qpos = steps[None, :]
+        elif getattr(pos, "ndim", 0) == 1:
+            qpos = pos[:, None] + steps[None, :]        # (B, C)
+        else:
+            qpos = (pos + steps)[None, :]
+        cos, sin = rope_mod.cos_sin(qpos, q.shape[-1],
+                                    self.params["rope_parameters"])
+        cos, sin = cos[:, :, None, :], sin[:, :, None, :]
+        return (rope_mod.rotate_half_split(q, cos, sin),
+                rope_mod.rotate_half_split(k, cos, sin))
 
     def _heads_sharded(self, ctx) -> bool:
         """True where GSPMD partitions this op's heads over a mesh axis
@@ -543,11 +628,12 @@ class MultiHeadAttentionOp(Op):
         q, k, v, embed, heads, kdim, vdim = self._dims()
         b, lq = q.dims[0], q.dims[1]
         lk = k.dims[1]
-        proj = 2.0 * b * heads * (
-            lq * q.dims[-1] * kdim
-            + lk * k.dims[-1] * kdim
-            + lk * v.dims[-1] * vdim
-            + lq * vdim * embed
+        kvh = self._kv_heads()
+        proj = 2.0 * b * (
+            heads * lq * q.dims[-1] * kdim
+            + kvh * lk * k.dims[-1] * kdim
+            + kvh * lk * v.dims[-1] * vdim
+            + heads * lq * vdim * embed
         )
         core = 2.0 * b * heads * lq * lk * (kdim + vdim)
         return proj + core

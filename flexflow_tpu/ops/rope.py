@@ -1,10 +1,14 @@
-"""Rotary position embedding: the YaRN frequency table and the rotation of
-interleaved pairs at given positions.
+"""Rotary position embedding: the default and the YaRN frequency table, and
+the rotation of interleaved or half-split pairs at given positions.
 
 `rope_parameters` is the published group of a model's `config.json`
 (`rope_theta`, and for `rope_type: "yarn"`: `factor`,
 `original_max_position_embeddings`, `beta_fast`, `beta_slow`, `mscale`,
-`mscale_all_dim`; Ministral's `llama_4_scaling_beta`). Conventions are the
+`mscale_all_dim`; Ministral's `llama_4_scaling_beta`); with no `rope_type`
+(or "default") the table is plain theta^(-2j/dim) and nothing is scaled.
+Pairs are (x[2j], x[2j+1]) where a model says `rope_interleave`
+(`rotate_interleaved`) and (x[j], x[j + dim/2]) otherwise
+(`rotate_half_split`). YaRN's conventions are the
 DeepSeek-V2/V3 ones: the frequencies of the dimensions that turn more than
 `beta_fast` times inside the original context are kept, those that turn
 fewer than `beta_slow` times are divided by `factor`, a linear ramp in
@@ -107,3 +111,16 @@ def rotate_interleaved(x, cos, sin):
     a, b = pairs[..., 0], pairs[..., 1]
     out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
     return out.reshape(xf.shape).astype(x.dtype)
+
+
+def rotate_half_split(x, cos, sin):
+    """x (..., dim) whose pairs are (x[j], x[j + dim/2]) (the default
+    layout of the published rotary models), rotated by the angles of cos /
+    sin (..., dim/2) — broadcast against x's leading dims by the caller.
+    Computed in float32, returned in x's type; the halves stay where they
+    were."""
+    xf = x.astype(jnp.float32)
+    half = xf.shape[-1] // 2
+    a, b = xf[..., :half], xf[..., half:]
+    return jnp.concatenate([a * cos - b * sin, a * sin + b * cos],
+                           axis=-1).astype(x.dtype)

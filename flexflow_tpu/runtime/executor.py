@@ -234,16 +234,23 @@ class Executor:
         seq_length: Optional[int] = None,
         decode_pos=None,
         fill_kv_cache: bool = False,
+        valid_len=None,
     ) -> Tuple[Dict[int, Any], Dict, Any]:
         """Returns (tensor guid -> value, new state, aux loss sum).
         seq_length: iteration
         truncation (FFIterationConfig) — static per distinct length.
         decode_pos / fill_kv_cache: KV-cache serving paths (a traced scalar
-        position for incremental decoding / prefill cache capture)."""
+        position for incremental decoding / prefill cache capture).
+        valid_len: a traced count of the leading REAL tokens of a padded
+        prefill dispatch (None = all): an attention hides what follows
+        behind its position mask and ignores it, an op that keeps state
+        per sequence (Op.sequence_state_arrays) must not let the padding
+        into the state."""
         ctx = LoweringContext(self.config, mode, self.mesh, rng,
                               iter_seq_length=seq_length)
         ctx.decode_pos = decode_pos
         ctx.fill_kv_cache = fill_kv_cache
+        ctx.valid_len = valid_len
         if self._manual_axes:
             # tracing inside the explicit grad-sync shard_map body: the
             # manual axes' constraints must not reach XLA (core/op.py)

@@ -54,13 +54,15 @@ class GenerativeSession:
                 f"window ({window}); the cache must hold at least one "
                 "full prefill")
         self.attn_ops = [op for op in model.graph.ops.values()
-                         if op.kv_cache_arrays()]
+                         if op.kv_cache_arrays()
+                         or op.sequence_state_arrays()]
         if not self.attn_ops:
-            raise ValueError("generation needs an attention op that keeps"
-                             " a serving cache")
-        # ONE cache-geometry definition (arrays per op + compute dtype —
-        # bf16 under mixed precision, the dominant serving memory) shared
-        # with the continuous batcher and the pool's HBM sizing
+            raise ValueError("generation needs an op that keeps a serving"
+                             " cache")
+        # ONE cache-geometry definition (per-token and per-sequence arrays
+        # per op + compute dtype — bf16 under mixed precision, the dominant
+        # serving memory) shared with the continuous batcher and the pool's
+        # HBM sizing
         from .sched.kvpool import zero_kv_caches
 
         self._caches: Dict[str, Dict[str, object]] = zero_kv_caches(
@@ -70,10 +72,13 @@ class GenerativeSession:
         final_guid = model.final_tensor.guid
         input_name = model.input_ops[0].name
 
-        def prefill(params, state, tokens):
+        def prefill(params, state, tokens, prompt_len):
+            # prompt_len: the prompts' real tokens inside the padded window,
+            # so that per-sequence state stops where they do
             values, new_state, _ = executor.forward_values(
                 params, state, {input_name: tokens}, None,
-                CompMode.COMP_MODE_INFERENCE, fill_kv_cache=True)
+                CompMode.COMP_MODE_INFERENCE, fill_kv_cache=True,
+                valid_len=prompt_len)
             return values[final_guid], new_state
 
         def decode(params, state, token, pos):
@@ -198,7 +203,8 @@ class GenerativeSession:
         import jax
 
         base_key = jax.random.PRNGKey(seed)
-        probs, state = self._prefill(model.params, state, jnp.asarray(padded))
+        probs, state = self._prefill(model.params, state, jnp.asarray(padded),
+                                     jnp.asarray(prompt_len, jnp.int32))
         # next token from the last REAL prompt position
         tok = self._pick(probs[:, prompt_len - 1, :],
                          jnp.asarray(prompt_len - 1, jnp.int32), base_key,

@@ -67,8 +67,9 @@ import numpy as np
 from ...ffconst import CompMode
 from ..batcher import BatcherStopped
 from .admission import AdmissionController
-from .kvpool import (PagedKVPool, derive_num_slots, kv_bytes_per_token,
-                     op_states, write_slot_span, zero_kv_caches)
+from .kvpool import (PagedKVPool, derive_num_slots, install_slot,
+                     kv_bytes_per_token, op_states, refuse_sequence_state,
+                     state_bytes_per_slot, write_slot_span, zero_kv_caches)
 
 
 class RequestCancelled(RuntimeError):
@@ -441,14 +442,29 @@ class ContinuousBatcher:
                 " decodes here, and the draft's caches do not ship in"
                 " the KV handoff")
         self.role = role
-        # the ops that keep a serving cache, found by capability
-        # (Op.kv_cache_arrays), not by one op type
+        # the ops that keep a serving cache of either kind, found by
+        # capability (Op.kv_cache_arrays per token, Op.sequence_state_arrays
+        # per sequence), not by one op type
         self.attn_ops = [op for op in model.graph.ops.values()
-                         if op.kv_cache_arrays()]
+                         if op.kv_cache_arrays()
+                         or op.sequence_state_arrays()]
         if not self.attn_ops:
             raise ValueError(
-                "generation needs an attention op that keeps a serving"
-                " cache (multihead_attention, latent_attention)")
+                "generation needs an op that keeps a serving cache"
+                " (multihead_attention, latent_attention, ssm_mixer)")
+        # per-sequence state (kvpool.py's second kind) is not addressable
+        # by token position: what would need it AT a position is refused
+        # here, typed, by the capability and never by an option
+        self._seq_parts = {
+            op.name: frozenset(op.sequence_state_arrays() or ())
+            for op in self.attn_ops}
+        self._stateful = any(self._seq_parts.values())
+        if role != "unified":
+            refuse_sequence_state(
+                model, f"role={role!r} (KV export/import between replicas)")
+        if draft_model is not None:
+            refuse_sequence_state(model, "speculative decoding")
+            refuse_sequence_state(draft_model, "speculative decoding")
 
         # speculative decoding (docs/serving.md): a draft model proposes
         # `spec_tokens` greedy candidates per slot per iteration, the
@@ -524,10 +540,12 @@ class ContinuousBatcher:
         pages_per_slot = _math.ceil(self.max_len / int(page_size))
         full_pages_per_slot = self.max_len // int(page_size)
         if prefix_cache_pages is None:
-            prefix_pages = 2 * pages_per_slot if self.prefill_chunk_tokens \
-                else 0
+            prefix_pages = 2 * pages_per_slot if (
+                self.prefill_chunk_tokens and not self._stateful) else 0
         else:
             prefix_pages = int(prefix_cache_pages)
+            if prefix_pages:
+                refuse_sequence_state(model, "the prefix cache")
         if prefix_pages and not self.prefill_chunk_tokens:
             raise ValueError(
                 "prefix caching requires chunked prefill"
@@ -607,6 +625,15 @@ class ContinuousBatcher:
             "Bytes of serving cache one token position costs across all"
             " caching ops", labels=("pool",)).set(
                 kv_bytes_per_token(model), pool=self.pool.label)
+        registry.gauge(
+            "ff_kvpool_state_bytes_per_slot",
+            "Bytes of per-sequence state one slot costs across all caching"
+            " ops, whatever its sequence's length", labels=("pool",)).set(
+                state_bytes_per_slot(model), pool=self.pool.label)
+        # admissions of a model that keeps per-sequence state: each starts
+        # its sequence from a zeroed batch-1 state that later overwrites
+        # the slot's (`_admit_new`); `op_counters` reports it per op
+        self._state_resets = 0
         self._build_fns()
         self._caches = self._zero_caches()
         self._band = self._zero_band()
@@ -741,6 +768,7 @@ class ContinuousBatcher:
         input_name = model.input_ops[0].name
         max_len = self.max_len
         attn_names = [op.name for op in self.attn_ops]
+        seq_parts = self._seq_parts
         counter_names = sorted(self._op_counters)
         counter_vars = {name: tuple(self._op_counters[name])
                         for name in counter_names}
@@ -777,7 +805,8 @@ class ContinuousBatcher:
             st = {**state, **small_caches(caches)}
             values, new_state, _ = executor.forward_values(
                 params, st, {input_name: tokens}, None,
-                CompMode.COMP_MODE_INFERENCE, fill_kv_cache=True)
+                CompMode.COMP_MODE_INFERENCE, fill_kv_cache=True,
+                valid_len=plen)
             probs = values[final_guid]  # (1, window, V)
             small = op_states(new_state, attn_names)
             return _scatter_and_pick(caches, small, slot, probs, plen - 1,
@@ -808,35 +837,37 @@ class ContinuousBatcher:
                      for name in counter_names})
 
         def chunk_forward(executor_, input_name_, attn_names_, params,
-                          state, small, tokens, off):
+                          state, small, tokens, off, valid=None):
             """The chunk-offset forward shared by TARGET and DRAFT
             prefill: run C tokens at prompt offset `off` through the
             chunk-offset decode entry (ops/attention.py _decode_step,
             scalar pos, C queries) against batch-1 caches; returns
             (final-tensor values, updated caches). Padded tail positions
             of the last chunk write garbage rows at positions >= plen —
-            harmless, because decode overwrites row p before any query
-            can attend it."""
+            harmless to a per-token cache, because decode overwrites row p
+            before any query can attend it; per-sequence state is kept
+            clear of them by `valid`, the chunk's count of real tokens
+            (None: all of them)."""
             st = {**state, **small}
             values, new_state, _ = executor_.forward_values(
                 params, st, {input_name_: tokens}, None,
-                CompMode.COMP_MODE_INFERENCE, decode_pos=off)
+                CompMode.COMP_MODE_INFERENCE, decode_pos=off,
+                valid_len=valid)
             return values, op_states(new_state, attn_names_)
 
         def scatter_span(pool_caches, small, slot, attn_names_):
-            """Batch-1 -> pool-slot cache-span scatter, shared by the
-            target's fused finish AND the draft's. [:max_len]: the
-            batch-1 caches carry chunk-1 slack rows (see _zero_small)
-            that must not spill into the pool slot."""
+            """Batch-1 -> pool-slot install, shared by the target's
+            fused finish AND the draft's: each per-token array's first
+            max_len rows (the batch-1 caches carry chunk-1 slack rows, see
+            _zero_small, that must not spill into the pool slot) and each
+            per-sequence array whole — which is what resets a reused
+            slot's state."""
             out = {}
             with jax.named_scope("kv:scatter_span"):
                 for name in attn_names_:
-                    out[name] = {
-                        part: write_slot_span(
-                            pool_caches[name][part],
-                            small[name][part][:, :max_len], slot)
-                        for part in pool_caches[name]
-                    }
+                    out[name] = install_slot(
+                        pool_caches[name], small[name], slot, max_len,
+                        seq_parts.get(name, ()))
             return out
 
         def prefill_chunk(params, state, small, tokens, off):
@@ -864,7 +895,7 @@ class ContinuousBatcher:
             path did."""
             values, new_small = chunk_forward(
                 executor, input_name, attn_names, params, state, small,
-                tokens, off)
+                tokens, off, valid=idx + 1)
             return _scatter_and_pick(caches, new_small, slot,
                                      values[final_guid], idx, pos, key)
 
@@ -1172,6 +1203,7 @@ class ContinuousBatcher:
         migrating every live sequence's OWNED cache rows into the new
         arrays, so in-flight requests keep decoding token-identically.
         Returns a ResizeTicket; `.wait()` blocks until applied."""
+        refuse_sequence_state(self.model, "a live resize")
         if num_slots is None and machine is None:
             raise ValueError("give num_slots or a machine spec")
         if num_slots is None:
@@ -1212,6 +1244,7 @@ class ContinuousBatcher:
         (plen, heads*dim) host array of exactly the rows the page table
         owns. The request STAYS parked — a failed ship can still
         resume_parked with nothing lost."""
+        refuse_sequence_state(self.model, "KV export")
         ticket = HandoffTicket()
         with self._cv:
             if not self._running:
@@ -1235,6 +1268,7 @@ class ContinuousBatcher:
         the new GenRequest; fails typed — AdmissionError subclasses when
         this replica sheds, `KVGeometryMismatch` when the exporter's
         page regime differs (kvpool.py)."""
+        refuse_sequence_state(self.model, "KV import")
         ticket = HandoffTicket()
         payload = {"desc": desc, "rows": rows,
                    "prompt": np.asarray(prompt, np.int32),
@@ -1710,19 +1744,26 @@ class ContinuousBatcher:
         dashboard's scrape, the benchmark), not for the loop."""
         import jax
 
-        return jax.device_get(self._op_counters)
+        got = jax.device_get(self._op_counters)
+        for name, parts in self._seq_parts.items():
+            if parts:
+                got.setdefault(name, {})["state_resets"] = self._state_resets
+        return got
 
     def publish_op_counters(self) -> Dict:
         """Mirror the counting ops' state into the registry: the expert
         layers' into the `ff_moe_*` families (obs/moe.py), whose numbers
-        are returned, and the latent attentions' rows into `ff_mla_*`
-        (obs/latent_attention.py)."""
+        are returned, the latent attentions' rows into `ff_mla_*`
+        (obs/latent_attention.py) and the state-space mixers' state
+        traffic into `ff_ssm_*` (obs/ssm.py)."""
         from ...obs.latent_attention import publish_latent_attention_metrics
         from ...obs.moe import publish_moe_metrics
+        from ...obs.ssm import publish_ssm_metrics
 
         state = self.op_counters()
         publish_latent_attention_metrics(self.model, self.registry,
                                          state=state)
+        publish_ssm_metrics(self.model, self.registry, state=state)
         return publish_moe_metrics(self.model, self.registry, state=state)
 
     # -- scheduler loop ----------------------------------------------------
@@ -2152,6 +2193,11 @@ class ContinuousBatcher:
             s.plen = plen
             self._slots[slot_idx] = s
             self._sync_active_gauge()
+            if self._stateful:
+                # the sequence starts from the zeroed batch-1 state below
+                # (one-shot: `prefill_one`'s own), which overwrites the
+                # slot's previous tenant's at the install
+                self._state_resets += 1
 
             if self.prefill_chunk_tokens == 0:
                 padded = np.zeros((1, self.window), np.int32)

@@ -2,17 +2,31 @@
 
 Physical layout vs logical pages
 --------------------------------
-The device arrays backing the pool are slot-dense: per caching op the
-arrays its `kv_cache_arrays()` names (`kv_cache_spec`), each ``(num_slots,
-max_len, width)`` — for a `multihead_attention` a K and a V cache of
-``heads * head_dim``, a token's heads PACKED into one row, so the last
-dimension is a multiple of the chip's 128 lanes at every published width and the decode step scatters into it and
-contracts on it in place (ops/attention.py `_decode_step`,
-kernels/pallas/decode.py; a ``(…, heads, 64)`` cache was relaid out and
-lane-padded twice per layer per iteration). `kv_cache_spec` is the
-geometry, `zero_kv_caches` the one allocation and `write_slot_span` the
-one span write every holder of such arrays uses. A
-*page* is a fixed span of ``page_size`` consecutive token positions inside
+The device arrays backing the pool are slot-dense, and of TWO kinds
+(`kv_cache_spec` is the geometry of both, `zero_kv_caches` the one
+allocation):
+
+ - PER-TOKEN arrays, those a caching op's `kv_cache_arrays()` names, each
+   ``(num_slots, max_len, width)`` — for a `multihead_attention` a K and a
+   V cache of ``kv_heads * head_dim``, a token's heads PACKED into one row,
+   so the last dimension is a multiple of the chip's 128 lanes at every
+   published width and the decode step scatters into it and contracts on it
+   in place (ops/attention.py `_decode_step`, kernels/pallas/decode.py; a
+   ``(…, heads, 64)`` cache was relaid out and lane-padded twice per layer
+   per iteration). `write_slot_span` is the one span write every holder of
+   such arrays uses. Pages, the prefix cache, speculation's rollback,
+   export/import and resize all address these rows by token position.
+ - PER-SEQUENCE arrays, those `sequence_state_arrays()` names, each
+   ``(num_slots,) + shape`` — a state-space mixer's recurrent state and
+   convolution tail: a fixed cost a slot, whatever the sequence's length
+   (`state_bytes_per_slot`). `write_slot_state` installs a sequence's state
+   over a slot's. Nothing masks a previous tenant's state, so admission
+   starts a sequence from zeros (a fresh batch-1 holder) and the install
+   overwrites; and no token position addresses it, so a model that has any
+   runs without the prefix cache, speculation, export/import and resize
+   (`refuse_sequence_state`).
+
+A *page* is a fixed span of ``page_size`` consecutive token positions inside
 one slot, so page id ``slot * pages_per_slot + block`` names physical rows
 ``[block*page_size, (block+1)*page_size)`` of that slot. The per-sequence
 page table therefore maps a sequence's logical token blocks to real cache
@@ -42,8 +56,9 @@ compute and TTFT, tracked by `ff_kvpool_pages_saved`.
 
 Capacity comes from the machine spec's HBM through the SAME memory model
 the plan sanitizer gates compiles with (`analysis.plan_memory_bytes`):
-HBM minus the model's inference footprint, divided by KV bytes per token
-times ``max_len`` per slot (`derive_num_slots`).
+HBM minus the model's inference footprint, divided by what a slot costs:
+KV bytes per token times ``max_len``, plus the per-sequence state's fixed
+bytes (`derive_num_slots`).
 """
 from __future__ import annotations
 
@@ -51,7 +66,7 @@ import hashlib
 import itertools
 import math
 import threading
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -82,6 +97,23 @@ class KVGeometryMismatch(ValueError):
         super().__init__(
             f"kv import geometry mismatch on {field!r}: exporter has"
             f" {exporter}, importing pool has {importer}")
+
+
+class SequenceStateUnsupported(ValueError):
+    """A serving feature that addresses cache rows by token position was
+    asked of a model one of whose ops keeps state per SEQUENCE
+    (`Op.sequence_state_arrays`): a prefix hit would need the state at the
+    page boundary, a rejected draft a rollback, an export, import or
+    resize the state beside the rows. Typed and raised where the feature
+    is asked for, never a silently wrong answer."""
+
+    def __init__(self, feature: str, op_name: str):
+        self.feature = feature
+        self.op_name = op_name
+        super().__init__(
+            f"{feature} is not available: op {op_name!r} keeps state per"
+            " sequence (a recurrent state no token position addresses),"
+            " and nothing snapshots, rolls back or ships it yet")
 
 
 def _chain_key(parent: bytes, block: np.ndarray) -> bytes:
@@ -736,46 +768,76 @@ class PagedKVPool:
         self._g_used.set(self.pages_used(), pool=self.label)
 
 
-def kv_cache_spec(model) -> List[tuple]:
-    """[(op_name, {array name: values a token stores in it}, jnp cache
-    dtype)] for every op that keeps a serving cache (`Op.kv_cache_arrays`)
-    — THE cache geometry: op `name` stores each named array as (rows,
-    max_len, width). A `multihead_attention` stores `k_cache` and `v_cache`
-    of heads*kdim and heads*vdim; a latent attention `c_kv` of
-    kv_lora_rank and `k_rope`, the rotary key padded to a 128-lane tile
-    (ops/latent_attention.py says why). Shared by pool sizing
-    (`kv_bytes_per_token`) and the one allocation (`zero_kv_caches`: the
-    ContinuousBatcher's slot, band, draft and batch-1 caches,
-    GenerativeSession's lockstep caches), so the HBM estimate can never
-    drift from what actually gets allocated. The dtype is the attention
+class OpCache(NamedTuple):
+    """One caching op's geometry: what it stores per token ({array: values
+    a token stores}, each array (rows, max_len, width) of `dtype`) and per
+    sequence ({array: (shape after the row axis, jnp dtype)})."""
+    op: str
+    per_token: Dict[str, int]
+    dtype: object
+    per_sequence: Dict[str, tuple]
+
+
+def kv_cache_spec(model) -> List[OpCache]:
+    """An `OpCache` for every op that keeps a serving cache of either kind
+    (`Op.kv_cache_arrays`, `Op.sequence_state_arrays`) — THE cache geometry.
+    Per token, op `name` stores each named array as (rows, max_len, width):
+    a `multihead_attention` `k_cache` and `v_cache` of kv_heads*kdim and
+    kv_heads*vdim; a latent attention `c_kv` of kv_lora_rank and `k_rope`,
+    the rotary key padded to a 128-lane tile (ops/latent_attention.py says
+    why). Per sequence, (rows,) + shape: a state-space mixer's `ssm_state`
+    in the op's `state_dtype` (float32 unless the model states bfloat16;
+    stepped in float32 either way) and `conv_tail` (ops/ssm.py). Shared by
+    pool sizing (`kv_bytes_per_token`, `state_bytes_per_slot`) and the one
+    allocation
+    (`zero_kv_caches`: the ContinuousBatcher's slot, band, draft and batch-1
+    caches, GenerativeSession's lockstep caches), so the HBM estimate can
+    never drift from what actually gets allocated. `dtype` is the op's
     compute dtype (bf16 under mixed precision — the KV cache is the
-    dominant serving memory)."""
+    dominant serving memory); a per-sequence array that names its own type
+    keeps it."""
     from ...ops.common import matmul_dtype
 
     out = []
     for op in model.graph.ops.values():
-        arrays = op.kv_cache_arrays()
-        if not arrays:
+        arrays = op.kv_cache_arrays() or {}
+        held = op.sequence_state_arrays() or {}
+        if not arrays and not held:
             continue
         cdt = matmul_dtype(model.config, op.inputs[0].dtype.jnp_dtype)
-        out.append((op.name, dict(arrays), cdt))
+        out.append(OpCache(
+            op.name, dict(arrays), cdt,
+            {part: (tuple(shape), cdt if dt is None else dt.jnp_dtype)
+             for part, (shape, dt) in held.items()}))
     if not out:
         raise ValueError(
             "model has no op that keeps a serving cache (an attention op"
-            " declaring kv_cache_arrays): nothing to cache")
+            " declaring kv_cache_arrays, a state-space mixer declaring"
+            " sequence_state_arrays): nothing to cache")
     return out
 
 
+def refuse_sequence_state(model, feature: str) -> None:
+    """Raise `SequenceStateUnsupported` naming the first op of `model`
+    that keeps state per sequence; a no-op for every other model."""
+    for op in model.graph.ops.values():
+        if op.sequence_state_arrays():
+            raise SequenceStateUnsupported(feature, op.name)
+
+
 def zero_kv_caches(model, rows: int, max_len: int) -> Dict[str, Dict]:
-    """{op_name: {array name: zeros}} AS STORED: `rows` sequences (pool
-    slots, band rows, or 1) of `max_len` token rows, each of the width its
-    op declares. The only place that writes the stored shape out."""
+    """{op_name: {array name: zeros}} AS STORED, both kinds: `rows`
+    sequences (pool slots, band rows, or 1), per-token arrays of `max_len`
+    token rows of the width their op declares, per-sequence arrays of their
+    declared shape. The only place that writes the stored shapes out."""
     import jax.numpy as jnp
 
     return {
-        name: {part: jnp.zeros((rows, max_len, width), cdt)
-               for part, width in arrays.items()}
-        for name, arrays, cdt in kv_cache_spec(model)
+        c.op: {**{part: jnp.zeros((rows, max_len, width), c.dtype)
+                  for part, width in c.per_token.items()},
+               **{part: jnp.zeros((rows,) + shape, dt)
+                  for part, (shape, dt) in c.per_sequence.items()}}
+        for c in kv_cache_spec(model)
     }
 
 
@@ -797,19 +859,52 @@ def write_slot_span(cache, span, slot):
         cache, span.astype(cache.dtype), (slot, 0, 0))
 
 
+def write_slot_state(cache, state, slot):
+    """`cache` (rows,) + shape with `state` (1,) + shape written over
+    sequence `slot`'s (traced or static), in the cache's dtype:
+    `write_slot_span`'s twin for a per-sequence array, the whole of it —
+    which is what resets a reused slot."""
+    import jax
+
+    return jax.lax.dynamic_update_slice(
+        cache, state.astype(cache.dtype), (slot,) + (0,) * (cache.ndim - 1))
+
+
+def install_slot(pool, small, slot, max_len: int, per_sequence=()):
+    """One op's pool arrays with a batch-1 holder's written over slot
+    `slot`: the leading `max_len` token rows of each per-token array (the
+    holder may carry slack rows past them), each array named in
+    `per_sequence` whole."""
+    return {part: (write_slot_state(arr, small[part], slot)
+                   if part in per_sequence
+                   else write_slot_span(arr, small[part][:, :max_len], slot))
+            for part, arr in pool.items()}
+
+
 def kv_bytes_per_token(model) -> int:
     """Bytes of cache one token position costs across every caching op
     (see kv_cache_spec for the geometry/dtype contract)."""
     import jax.numpy as jnp
 
-    return sum(sum(arrays.values()) * jnp.dtype(cdt).itemsize
-               for _, arrays, cdt in kv_cache_spec(model))
+    return sum(sum(c.per_token.values()) * jnp.dtype(c.dtype).itemsize
+               for c in kv_cache_spec(model))
+
+
+def state_bytes_per_slot(model) -> int:
+    """Bytes of per-sequence state one slot costs across every caching op,
+    whatever its sequence's length (0 for a model of attentions alone)."""
+    import jax.numpy as jnp
+
+    return sum(int(np.prod(shape)) * jnp.dtype(dt).itemsize
+               for c in kv_cache_spec(model)
+               for shape, dt in c.per_sequence.values())
 
 
 def derive_num_slots(model, max_len: int, machine=None,
                      max_slots: int = 64, min_slots: int = 1) -> int:
     """Slots the machine's HBM can hold: (HBM - model inference footprint)
-    / (KV bytes per token x max_len). The model footprint comes from the
+    / (KV bytes per token x max_len + per-sequence state bytes a slot). The
+    model footprint comes from the
     SAME memory model the plan sanitizer's FFTA010 fit gate uses
     (`analysis.plan_memory_bytes`, optimizer_state_factor=1 — serving
     keeps weights, not optimizer moments). Clamped to [min_slots,
@@ -825,6 +920,7 @@ def derive_num_slots(model, max_len: int, machine=None,
     model_bytes, _, _ = plan_memory_bytes(
         model.graph, machine, model.config, optimizer_state_factor=1.0)
     free = machine.memory_budget_bytes() - model_bytes
-    per_slot = kv_bytes_per_token(model) * int(max_len)
+    per_slot = (kv_bytes_per_token(model) * int(max_len)
+                + state_bytes_per_slot(model))
     slots = int(free // per_slot) if per_slot > 0 else min_slots
     return max(int(min_slots), min(int(max_slots), slots))

@@ -25,8 +25,8 @@ TOY = chip_smoke.Sizes(
     steps_per_execution=2, train_dispatches=2, kernel_layers=1,
     search_layers=1, search_budget=2, search_devices=2,
     slots=2, window=24, max_len=32, page_size=8, prompts=(5, 20, 9),
-    new_tokens=3, latent=(4, 32, 16, 8, 8, 16), mesh_batch=4, mesh_layers=1,
-    mesh_steps=3)
+    new_tokens=3, latent=(4, 32, 16, 8, 8, 16),
+    hybrid=(4, 2, 8, 32, 4, 8, 2), mesh_batch=4, mesh_layers=1, mesh_steps=3)
 
 
 @pytest.fixture(scope="module")
@@ -35,7 +35,7 @@ def clock():
 
 
 @pytest.mark.parametrize("phase", ["train", "kernels", "search", "serve",
-                                   "latent", "mesh"])
+                                   "latent", "hybrid", "mesh"])
 def test_phase_at_toy_width(phase, clock, capsys):
     """Each phase runs end to end and prints its one JSON line. On this
     backend the kernels run interpreted and the search measures CPU op
@@ -72,6 +72,10 @@ def test_phase_at_toy_width(phase, clock, capsys):
         # a cache of one 32-row block: both sides read every allocated row
         assert (printed["rows_read_over_filled"]["pallas"]
                 == printed["rows_read_over_filled"]["reference"] > 1)
+    elif phase == "hybrid":
+        # 3 prompts through 2 slots: one slot reused, its state reset
+        assert printed["token_parity"] == "3/3 identical"
+        assert printed["state_resets"] == 3
     else:
         assert printed["dp_x_tp"]["mesh_devices"] == 4
         assert printed["dp_x_tp"]["params"]["devices"] == [0, 1, 2, 3]
@@ -82,7 +86,7 @@ def test_phase_at_toy_width(phase, clock, capsys):
 
 
 @pytest.mark.parametrize("argv,phases", [
-    ([], ["train", "kernels", "search", "serve", "latent"]),
+    ([], ["train", "kernels", "search", "serve", "latent", "hybrid"]),
     (["--chips", "4", "--seed", "3"], ["mesh"]),
 ])
 def test_main_runs_the_right_phases_and_ends_with_the_ok_line(
@@ -93,7 +97,7 @@ def test_main_runs_the_right_phases_and_ends_with_the_ok_line(
     devices = jax.devices()
     monkeypatch.setattr(chip_smoke, "require_tpu",
                         lambda what, chips: devices)
-    for name in ("train", "kernels", "search", "serve", "mesh"):
+    for name in ("train", "kernels", "search", "serve", "hybrid", "mesh"):
         monkeypatch.setattr(chip_smoke, f"phase_{name}",
                             lambda *a, _n=name: {"stub": _n})
     assert chip_smoke.main(argv) == 0

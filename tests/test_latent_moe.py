@@ -504,7 +504,7 @@ def test_real_width_cache_bytes_flops_and_memory_gate():
     # key padded to one 128-lane tile: 768 B a layer in bf16 as stored, of
     # which the algorithm reads 640 (the benchmark's roofline counts those)
     spec = kvpool.kv_cache_spec(model)
-    assert [a for _, a, _ in spec] == [{"c_kv": 256, "k_rope": 128}] * 6
+    assert [c.per_token for c in spec] == [{"c_kv": 256, "k_rope": 128}] * 6
     assert kvpool.kv_bytes_per_token(model) == 768 * 6
     d = shapes.dims(cfg)
     assert (d["kvr"] + d["rope"]) * d["cache_bytes"] == 640

@@ -275,6 +275,11 @@ CELL_PROGRAMS = [
     ("mistral_small4_ep4", "decode_all", 1),     # the latent decode core
     ("mistral_small4_ep4", "prefill_chunk", 0),
     ("mistral_small4_ep4", "prefill_last_chunk", 0),
+    # grouped-KV rotary attention and the state-space mixer are XLA's: the
+    # recurrent state is donated beside the K/V, no Pallas call
+    ("falcon_h1_34b_1chip", "decode_all", 0),
+    ("falcon_h1_34b_1chip", "prefill_chunk", 0),
+    ("falcon_h1_34b_1chip", "prefill_last_chunk", 0),
 ]
 
 
