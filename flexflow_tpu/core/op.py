@@ -198,6 +198,22 @@ class Op:
         op."""
         return None
 
+    def acts_per_position(self) -> bool:
+        """Whether position p of axis 1 (the token axis of a (rows, tokens,
+        ...) value) of every output is computed from position p of the
+        inputs alone, so that the op gives the same row whether it is
+        handed every position or that one. A serving step that feeds one
+        token a sequence relies on it for every op that keeps no cache;
+        `Executor.row_cut` checks it. An op that does not say is taken to
+        read across positions."""
+        return False
+
+    def _off_token_axis(self, axes) -> bool:
+        """None of `axes` (negative ones counted from the end) is axis 1
+        of the first input."""
+        ndim = len(self.inputs[0].dims)
+        return all(a % ndim != 1 for a in axes)
+
     # state vars the continuous batcher threads from one decode iteration
     # to the next and hands back (small counters, never caches)
     serving_counters: Tuple[str, ...] = ()

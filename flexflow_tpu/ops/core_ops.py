@@ -63,6 +63,9 @@ class NoOp(Op):
     def output_shapes(self):
         return [self.inputs[0].dims], [self.inputs[0].dtype]
 
+    def acts_per_position(self):
+        return True
+
     def lower(self, ctx, inputs, weights):
         return [inputs[0]]
 
@@ -73,6 +76,9 @@ class IdentityOp(Op):
 
     def output_shapes(self):
         return [self.inputs[0].dims], [self.inputs[0].dtype]
+
+    def acts_per_position(self):
+        return True
 
     def lower(self, ctx, inputs, weights):
         return [inputs[0]]

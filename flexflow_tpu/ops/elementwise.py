@@ -54,6 +54,7 @@ def _make_unary(op_type):
 
     _Unary.output_shapes = output_shapes
     _Unary.lower = lower
+    _Unary.acts_per_position = lambda self: True
     return register_op(_Unary)
 
 
@@ -108,6 +109,7 @@ def _make_binary(op_type):
 
     _Binary.output_shapes = output_shapes
     _Binary.lower = lower
+    _Binary.acts_per_position = lambda self: True
     return register_op(_Binary)
 
 
@@ -128,6 +130,9 @@ class CastOp(Op):
 
     def output_shapes(self):
         return [self.inputs[0].dims], [self.params["dtype"]]
+
+    def acts_per_position(self):
+        return True
 
     def lower(self, ctx, inputs, weights):
         return [inputs[0].astype(self.params["dtype"].jnp_dtype)]

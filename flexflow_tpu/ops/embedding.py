@@ -29,6 +29,11 @@ class EmbeddingOp(Op):
             return [ids.dims + (out_dim,)], [dtype]
         return [ids.dims[:-1] + (out_dim,)], [dtype]
 
+    def acts_per_position(self):
+        # SUM / AVG reduce over the ids' last axis
+        return self.params.get("aggr", AggrMode.AGGR_MODE_NONE) \
+            == AggrMode.AGGR_MODE_NONE or self._off_token_axis([-1])
+
     def weight_specs(self) -> List[WeightSpec]:
         return [
             WeightSpec(

@@ -28,6 +28,9 @@ class LinearOp(Op):
         dtype = self.params.get("dtype") or x.dtype
         return [x.dims[:-1] + (out_dim,)], [dtype]
 
+    def acts_per_position(self):
+        return self._off_token_axis([-1])   # the contracted axis
+
     def weight_specs(self) -> List[WeightSpec]:
         (x,) = self.inputs
         out_dim = self.params["out_dim"]

@@ -188,6 +188,13 @@ class ExpertsOp(Op):
         x, n, cap, out_dim = self._shape()
         return [tuple(x.dims[:-1]) + (out_dim,)], [x.dtype]
 
+    def acts_per_position(self):
+        # while no assignment overflows an expert's capacity: what
+        # overflows is dropped in arrival order, across positions. A decode
+        # step (one token a sequence) serves this op under that condition
+        # already; one position alone (capacity >= k) never overflows
+        return self._off_token_axis([-1])
+
     def weight_specs(self):
         from ..core.op import WeightSpec
         from ..runtime.initializers import DefaultInitializer, ZeroInitializer
@@ -373,6 +380,9 @@ class MoERouterOp(Op):
         out = tuple(x.dims[:-1]) + (self.params["k"],)
         return [out, out], [DataType.DT_FLOAT, DataType.DT_INT32]
 
+    def acts_per_position(self):
+        return self._off_token_axis([-1])   # the contracted axis
+
     def weight_specs(self):
         from ..core.op import WeightSpec
         from ..runtime.initializers import DefaultInitializer
@@ -543,6 +553,11 @@ class GatedExpertsOp(Op):
         x = self.inputs[0]
         self._local()
         return [x.dims], [x.dtype]
+
+    def acts_per_position(self):
+        # a token's own k assignments through its own experts; the
+        # counters sum over whatever rows the step was given
+        return self._off_token_axis([-1])
 
     def weight_specs(self):
         from ..core.op import WeightSpec

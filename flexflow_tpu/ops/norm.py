@@ -30,6 +30,9 @@ class LayerNormOp(Op):
         axes = self.params["axes"]
         return tuple(self.inputs[0].dims[a] for a in axes)
 
+    def acts_per_position(self):
+        return self._off_token_axis(self.params["axes"])
+
     def weight_specs(self) -> List[WeightSpec]:
         if not self.params.get("elementwise_affine", True):
             return []
@@ -73,6 +76,9 @@ class RMSNormOp(Op):
         axes = self.params["axes"]
         return tuple(self.inputs[0].dims[a] for a in axes)
 
+    def acts_per_position(self):
+        return self._off_token_axis(self.params["axes"])
+
     def weight_specs(self) -> List[WeightSpec]:
         if not self.params.get("elementwise_affine", True):
             return []
@@ -101,6 +107,9 @@ class SoftmaxOp(Op):
     def output_shapes(self):
         return [self.inputs[0].dims], [self.inputs[0].dtype]
 
+    def acts_per_position(self):
+        return self._off_token_axis([self.params.get("axis", -1)])
+
     def lower(self, ctx, inputs, weights):
         axis = self.params.get("axis", -1)
         x = inputs[0]
@@ -114,6 +123,9 @@ class DropoutOp(Op):
 
     def output_shapes(self):
         return [self.inputs[0].dims], [self.inputs[0].dtype]
+
+    def acts_per_position(self):
+        return True
 
     def lower(self, ctx, inputs, weights):
         x = inputs[0]

@@ -66,6 +66,9 @@ class ConcatOp(Op):
         base[axis] = sum(t.dims[axis] for t in self.inputs)
         return [tuple(base)], [self.inputs[0].dtype]
 
+    def acts_per_position(self):
+        return self._off_token_axis([self.params["axis"]])
+
     def lower(self, ctx, inputs, weights):
         return [jnp.concatenate(inputs, axis=self.params["axis"])]
 
@@ -85,6 +88,9 @@ class SplitOp(Op):
             d[axis] = s
             outs.append(tuple(d))
         return outs, [x.dtype] * len(sizes)
+
+    def acts_per_position(self):
+        return self._off_token_axis([self.params["axis"]])
 
     def lower(self, ctx, inputs, weights):
         axis = self.params["axis"]
@@ -179,6 +185,9 @@ class TopKOp(Op):
         k = self.params["k"]
         out = x.dims[:-1] + (k,)
         return [out, out], [x.dtype, DataType.DT_INT32]
+
+    def acts_per_position(self):
+        return self._off_token_axis([-1])
 
     def lower(self, ctx, inputs, weights):
         values, indices = jax.lax.top_k(inputs[0], self.params["k"])

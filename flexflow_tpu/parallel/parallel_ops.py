@@ -69,6 +69,9 @@ class ParallelOpBase(Op):
     def output_shapes(self):
         return [self.inputs[0].dims], [self.inputs[0].dtype]
 
+    def acts_per_position(self):
+        return True
+
     def lower(self, ctx, inputs, weights):
         # identity on the value; the executor's constrain() on the output
         # tensor (whose parallel_shape this op changed) triggers the reshard
@@ -161,6 +164,9 @@ class AllReduceOp(Op):
 
     def output_shapes(self):
         return [self.inputs[0].dims], [self.inputs[0].dtype]
+
+    def acts_per_position(self):
+        return True
 
     def lower(self, ctx, inputs, weights):
         axis = self.params.get("axis_name")

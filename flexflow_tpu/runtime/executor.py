@@ -25,6 +25,17 @@ from ..ops.common import emit_dtype
 from .metrics import Metrics
 
 
+class RowCutError(ValueError):
+    """A graph `forward_values` cannot run for one wanted row: the tail
+    after the last op that keeps a serving cache holds an op (named) that
+    does not act on each token position alone, or reads a value whose
+    axis 1 is not the token axis."""
+
+
+# `forward_values(final_row=NO_ROW)`: no row of the final value is wanted
+NO_ROW = object()
+
+
 def _loss_scope(loss_fn) -> str:
     """`loss:<name>`, the device name of the loss's ops."""
     name = getattr(loss_fn, "__name__", "")
@@ -42,6 +53,7 @@ class Executor:
         self._multi_step = None
         self._eval_step = None
         self._forward_jit = None
+        self._row_cut_plan = None
         # per-tier reduction decomposition of each synced tensor on a
         # hierarchical machine ({op name: {strategy, tiers, ...}},
         # docs/machine.md) — compile() threads the SAME plan the search
@@ -224,6 +236,40 @@ class Executor:
         return params, state
 
     # -- forward walk ------------------------------------------------------
+    def row_cut(self) -> Tuple[int, frozenset]:
+        """Where a forward that wants ONE row of the final value (or none)
+        stops running every row: (index in `self.topo` of the last op that
+        keeps a serving cache or per-sequence state, guids of the tensors
+        that descend from a graph input and so carry the token axis).
+        Every prompt token has to go through the caching ops, which read
+        across positions; what follows them is fed one token a sequence by
+        every decode step already, which is sound only if it acts on each
+        position alone. Here that is checked (`Op.acts_per_position`), and
+        a graph whose tail does not is refused, by the op's name."""
+        if self._row_cut_plan is not None:
+            return self._row_cut_plan
+        caching = [i for i, op in enumerate(self.topo)
+                   if op.kv_cache_arrays() or op.sequence_state_arrays()]
+        if not caching or self.pipeline_plan is not None:
+            raise RowCutError(
+                "one row of the final value can be asked of a graph with an"
+                " op that keeps a serving cache, outside a pipeline")
+        fed = set()
+        for op in self.topo:
+            if op.op_type == OpType.INPUT or any(
+                    t.guid in fed for t in op.inputs):
+                fed.update(t.guid for t in op.outputs)
+        for op in self.topo[caching[-1] + 1:]:
+            if op.op_type != OpType.INPUT and not op.acts_per_position() \
+                    and any(t.guid in fed for t in op.inputs):
+                raise RowCutError(
+                    f"{op!r} follows the last op that keeps a serving cache"
+                    f" ({self.topo[caching[-1]].name}) and does not act on"
+                    " each token position alone: its output for one"
+                    " position cannot be computed from that position")
+        self._row_cut_plan = (caching[-1], frozenset(fed))
+        return self._row_cut_plan
+
     def forward_values(
         self,
         params: Dict,
@@ -235,6 +281,7 @@ class Executor:
         decode_pos=None,
         fill_kv_cache: bool = False,
         valid_len=None,
+        final_row=None,
     ) -> Tuple[Dict[int, Any], Dict, Any]:
         """Returns (tensor guid -> value, new state, aux loss sum).
         seq_length: iteration
@@ -245,7 +292,14 @@ class Executor:
         prefill dispatch (None = all): an attention hides what follows
         behind its position mask and ignores it, an op that keeps state
         per sequence (Op.sequence_state_arrays) must not let the padding
-        into the state."""
+        into the state.
+        final_row: which positions of the token axis (axis 1) the caller
+        reads of the values AFTER the last op that keeps a serving cache
+        (`row_cut`). None = all of them, and the walk is what it always
+        was. A traced index = that one: every value the later ops read is
+        cut to it, they run on (rows, 1, ...), and so the final value is
+        (rows, 1, ...). `NO_ROW` = none: the walk ends at the cut and the
+        later tensors have no value."""
         ctx = LoweringContext(self.config, mode, self.mesh, rng,
                               iter_seq_length=seq_length)
         ctx.decode_pos = decode_pos
@@ -261,7 +315,32 @@ class Executor:
             for var, val in vars_.items():
                 ctx.state[(op_name, var)] = val
         plan = self.pipeline_plan
-        for op in self.topo:
+        cut, fed = (len(self.topo), ()) if final_row is None \
+            else self.row_cut()
+        rows: Dict[int, Any] = {}   # guid -> the value at `final_row`
+
+        def one_row(op, t):
+            """What `op`, past the cut, reads of tensor `t`."""
+            if t.guid not in fed:
+                return ctx.values[t.guid]   # a constant: no token axis
+            if t.guid not in rows:
+                v = ctx.values[t.guid]
+                # the token axis is axis 1 of what the last caching op
+                # hands on
+                handed = ctx.values[self.topo[cut].outputs[0].guid]
+                if v.ndim < 2 or v.shape[1] != handed.shape[1]:
+                    raise RowCutError(
+                        f"{op!r} reads a value of shape {v.shape} from"
+                        " before the last op that keeps a serving cache:"
+                        f" axis 1 is not the {handed.shape[1]} tokens")
+                with jax.named_scope("rows:pick"):
+                    rows[t.guid] = jax.lax.dynamic_slice_in_dim(
+                        v, final_row, 1, axis=1)
+            return rows[t.guid]
+
+        for i, op in enumerate(self.topo):
+            if i > cut and final_row is NO_ROW:
+                break
             if plan is not None and op.guid in plan.region_guids:
                 if op.guid == plan.first_op_guid:
                     x = ctx.values[plan.region_input.guid]
@@ -276,7 +355,8 @@ class Executor:
                 val = input_values[op.name]
                 ctx.values[op.outputs[0].guid] = ctx.constrain(val, op.outputs[0])
                 continue
-            ins = [ctx.values[t.guid] for t in op.inputs]
+            ins = [one_row(op, t) if i > cut else ctx.values[t.guid]
+                   for t in op.inputs]
             weights = dict(params.get(op.name, {}))
             for w in op.weights:
                 ws = w._weight_spec
@@ -294,6 +374,12 @@ class Executor:
                 if hasattr(v, "astype"):
                     v = v.astype(emit_dtype(self.config, t.dtype))
                 ctx.values[t.guid] = ctx.constrain(v, t)
+                if i > cut and t.guid in fed:
+                    if v.ndim < 2 or v.shape[1] != 1:
+                        raise RowCutError(
+                            f"{op!r}, given one token position, returned"
+                            f" shape {v.shape}")
+                    rows[t.guid] = ctx.values[t.guid]
         new_state = {
             op_name: {
                 var: ctx.state_updates.get((op_name, var), val)

@@ -65,6 +65,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from ...ffconst import CompMode
+from ...runtime.executor import NO_ROW
 from ..batcher import BatcherStopped
 from .admission import AdmissionController
 from .kvpool import (PagedKVPool, derive_num_slots, install_slot,
@@ -452,6 +453,10 @@ class ContinuousBatcher:
             raise ValueError(
                 "generation needs an op that keeps a serving cache"
                 " (multihead_attention, latent_attention, ssm_mixer)")
+        # a prefill dispatch runs what follows the last of them for the one
+        # position it samples, or not at all: a graph whose tail reads
+        # across positions is refused here (RowCutError), not served wrong
+        model.executor.row_cut()
         # per-sequence state (kvpool.py's second kind) is not addressable
         # by token position: what would need it AT a position is refused
         # here, typed, by the capability and never by an option
@@ -515,6 +520,7 @@ class ContinuousBatcher:
                 raise ValueError(
                     "draft model needs an attention op that keeps a"
                     " serving cache")
+            draft_model.executor.row_cut()
             tvocab = model.final_tensor.dims[-1]
             dvocab = draft_model.final_tensor.dims[-1]
             if tvocab != dvocab:
@@ -600,6 +606,11 @@ class ContinuousBatcher:
             "Continuous-batching requests by outcome", labels=("outcome",))
         self._c_tokens = registry.counter(
             "ff_serving_tokens_total", "Tokens generated")
+        self._c_head_rows = registry.counter(
+            "ff_serving_prefill_head_rows_total",
+            "Prompt positions a prefill dispatch ran past the last caching"
+            " op, through the vocabulary head (one a request)",
+            labels=("pool",))
         self._ewma_affinity_overlap: Optional[float] = None
         if self._affinity_probe is not None:
             self._c_affinity = registry.counter(
@@ -799,18 +810,18 @@ class ContinuousBatcher:
         def prefill_one(params, state, caches, tokens, slot, plen, key):
             """Prefill ONE request (tokens: (1, window), prompt in the
             first plen positions) into pool slot `slot`: run the batch-1
-            forward with fresh batch-1 caches, then scatter the filled
-            rows into the slot-dense pool caches and pick the first token
-            (the same _scatter_and_pick the fused chunked finish uses)."""
+            forward with fresh batch-1 caches — past the last caching op
+            for row plen-1 alone — then scatter the filled rows into the
+            slot-dense pool caches and pick the first token (the same
+            _scatter_and_pick the fused chunked finish uses)."""
             st = {**state, **small_caches(caches)}
             values, new_state, _ = executor.forward_values(
                 params, st, {input_name: tokens}, None,
                 CompMode.COMP_MODE_INFERENCE, fill_kv_cache=True,
-                valid_len=plen)
-            probs = values[final_guid]  # (1, window, V)
+                valid_len=plen, final_row=plen - 1)
             small = op_states(new_state, attn_names)
-            return _scatter_and_pick(caches, small, slot, probs, plen - 1,
-                                     plen - 1, key)
+            return _scatter_and_pick(caches, small, slot,
+                                     values[final_guid][0, 0], plen - 1, key)
 
         def decode_all(params, state, caches, toks, pos, keys):
             """One decode iteration over EVERY slot: toks (S,) last tokens,
@@ -837,13 +848,17 @@ class ContinuousBatcher:
                      for name in counter_names})
 
         def chunk_forward(executor_, input_name_, attn_names_, params,
-                          state, small, tokens, off, valid=None):
+                          state, small, tokens, off, row, valid=None):
             """The chunk-offset forward shared by TARGET and DRAFT
             prefill: run C tokens at prompt offset `off` through the
             chunk-offset decode entry (ops/attention.py _decode_step,
-            scalar pos, C queries) against batch-1 caches; returns
-            (final-tensor values, updated caches). Padded tail positions
-            of the last chunk write garbage rows at positions >= plen —
+            scalar pos, C queries) against batch-1 caches, and what
+            follows the last caching op for the chunk's position `row`
+            alone (NO_ROW: not at all — a chunk that is not the prompt's
+            last has no position anybody reads); returns (tensor values —
+            the final one (1, 1, V) where a row was asked for, absent
+            otherwise —, updated caches). Padded tail positions of the
+            last chunk write garbage rows at positions >= plen —
             harmless to a per-token cache, because decode overwrites row p
             before any query can attend it; per-sequence state is kept
             clear of them by `valid`, the chunk's count of real tokens
@@ -852,7 +867,7 @@ class ContinuousBatcher:
             values, new_state, _ = executor_.forward_values(
                 params, st, {input_name_: tokens}, None,
                 CompMode.COMP_MODE_INFERENCE, decode_pos=off,
-                valid_len=valid)
+                valid_len=valid, final_row=row)
             return values, op_states(new_state, attn_names_)
 
         def scatter_span(pool_caches, small, slot, attn_names_):
@@ -871,19 +886,18 @@ class ContinuousBatcher:
             return out
 
         def prefill_chunk(params, state, small, tokens, off):
-            """One chunked-prefill step for ONE request; returns the
-            chunk's (1, C, V) probs and the updated batch-1 caches."""
-            values, new_small = chunk_forward(
+            """One chunked-prefill step for ONE request that is not its
+            last: returns the updated batch-1 caches, and no
+            distribution."""
+            _, new_small = chunk_forward(
                 executor, input_name, attn_names, params, state, small,
-                tokens, off)
-            return values[final_guid], new_small
+                tokens, off, NO_ROW)
+            return new_small
 
-        def _scatter_and_pick(caches, small, slot, probs, idx, pos, key):
+        def _scatter_and_pick(caches, small, slot, probs_row, pos, key):
             new_caches = scatter_span(caches, small, slot, attn_names)
             with jax.named_scope("sample:pick"):
-                row = jax.lax.dynamic_slice(
-                    probs, (0, idx, 0), (1, 1, probs.shape[2]))[0, 0]  # (V,)
-                tok = pick_row(row, pos, key)
+                tok = pick_row(probs_row, pos, key)   # from (V,)
             return tok, new_caches
 
         def prefill_last_chunk(params, state, caches, small, tokens, off,
@@ -895,9 +909,9 @@ class ContinuousBatcher:
             path did."""
             values, new_small = chunk_forward(
                 executor, input_name, attn_names, params, state, small,
-                tokens, off, valid=idx + 1)
+                tokens, off, idx, valid=idx + 1)
             return _scatter_and_pick(caches, new_small, slot,
-                                     values[final_guid], idx, pos, key)
+                                     values[final_guid][0, 0], pos, key)
 
         def install_prefix(small, band, src_slot, src_row, n_rows):
             """Prefix-cache HIT: gather the matched band pages' K/V rows
@@ -973,10 +987,10 @@ class ContinuousBatcher:
 
         def draft_chunk(dparams, dstate, small, tokens, off):
             """One draft prefill chunk — `prefill_chunk` for the draft
-            model (its probs are discarded; only the K/V matter)."""
+            model (only the K/V matter)."""
             _, new_small = chunk_forward(
                 dexecutor, dinput_name, dattn_names, dparams, dstate,
-                small, tokens, off)
+                small, tokens, off, NO_ROW)
             return new_small
 
         def draft_last_chunk(dparams, dstate, dcaches, small, tokens,
@@ -986,7 +1000,7 @@ class ContinuousBatcher:
             pick-free sibling of `prefill_last_chunk`."""
             _, new_small = chunk_forward(
                 dexecutor, dinput_name, dattn_names, dparams, dstate,
-                small, tokens, off)
+                small, tokens, off, NO_ROW)
             return scatter_span(dcaches, new_small, slot, dattn_names)
 
         def spec_decode_all(params, state, caches, dparams, dstate,
@@ -2204,13 +2218,14 @@ class ContinuousBatcher:
                 padded[0, :plen] = req.prompt
                 with tracer.resume(req.trace), \
                         tracer.span("serve.prefill", request=req.id,
-                                    tokens=plen):
+                                    tokens=plen, head_rows=1):
                     t0 = time.monotonic()
                     tok, self._caches = self._prefill_fn(
                         params, state, self._caches, jnp.asarray(padded),
                         slot_idx, plen, jnp.asarray(key))
                     tok = int(tok)  # sync: the dispatch really ran
                     self._observe_prefill(plen, time.monotonic() - t0)
+                self._c_head_rows.inc(pool=self.pool.label)
                 s.pos = plen
                 s.last_tok = tok
                 self._first_token(s, tok)
@@ -2279,11 +2294,11 @@ class ContinuousBatcher:
             chunk_tokens += n
             with tracer.resume(s.req.trace), \
                     tracer.span("serve.prefill", request=s.req.id,
-                                offset=off, tokens=n):
+                                offset=off, tokens=n, head_rows=int(last)):
                 tokens = np.zeros((1, chunk), np.int32)
                 tokens[0, :n] = s.req.prompt[off:off + n]
                 if not last:
-                    probs, s.small = self._chunk_fn(
+                    s.small = self._chunk_fn(
                         params, state, s.small, jnp.asarray(tokens),
                         jnp.asarray(off, jnp.int32))
                     s.filled = off + n
@@ -2300,6 +2315,7 @@ class ContinuousBatcher:
                     jnp.asarray(s.key))
                 tok = int(tok)  # sync: int() blocks on the dispatch
                 self._observe_prefill(n, time.monotonic() - t0)
+            self._c_head_rows.inc(pool=self.pool.label)
             s.small = None
             s.filled = s.pos = s.plen
             s.last_tok = tok

@@ -301,6 +301,15 @@ def test_scheduler_iteration_is_covered_by_spans(lm):
     assert total == {"admitted": 5, "retired": 5,
                      "emitted": sum(len(o) for o in outs),
                      "prefill_tokens": sum(len(p) for p in prompts)}
+    # one position a request went through the vocabulary head, however
+    # many chunks its prompt took (chunk 4: three prompts took two)
+    fills = tr.events("serve.prefill")
+    assert len(fills) == 8 and sum(
+        e["args"]["tokens"] for e in fills) == total["prefill_tokens"]
+    assert sum(e["args"]["head_rows"] for e in fills) == len(prompts)
+    assert cb.registry.counter(
+        "ff_serving_prefill_head_rows_total", labels=("pool",)).value(
+            pool=cb.pool.label) == len(prompts)
     # the decode span kept its meaning: dispatch + fetch, nothing else
     for d in tr.events("serve.decode"):
         assert d["dur"] >= 50e3 and "requests" in d["args"]

@@ -219,9 +219,12 @@ def test_decode_all_copies_no_latent_cache_on_v5e(v5e):
 
 
 def test_decode_all_streams_the_expert_stacks_in_place_on_v5e(v5e):
-    """`mistral_small4_ep4`'s serving programs, one layer at the published
+    """`mistral_small4_ep4`'s serving programs, two layers at the published
     widths (32 held experts of 4,096 x 2,048, 128 slots x 4,096 rows) with
-    shapes for parameters. The decode iteration's 128 token rows take the
+    shapes for parameters (two, because a chunk that is not a prompt's last
+    runs nothing past the last layer's attention: the LAST layer's experts
+    are not in `prefill_chunk`, the first layer's still see the chunk's
+    1,024 rows). The decode iteration's 128 token rows take the
     few-rows form of the routed product (ops/moe.py `few_rows`): no
     grouped-GEMM kernel (at 4 rows a group its one 512-row tile is
     multiplied through all 32 groups: 29.7 of the 42 ms decode iteration,
@@ -236,7 +239,7 @@ def test_decode_all_streams_the_expert_stacks_in_place_on_v5e(v5e):
     from flexflow_tpu.ops.moe import FEW_ROWS_MAX
 
     cfg = harness.load_config("mistral_small4_ep4")
-    cfg["num_hidden_layers"] = 1
+    cfg["num_hidden_layers"] = layers = 2
     chunk = 1024
     cfg["deployment"] = dict(cfg["deployment"], prefill_chunk_tokens=chunk)
     assert cfg["deployment"]["num_slots"] <= FEW_ROWS_MAX < chunk
@@ -259,7 +262,8 @@ def test_decode_all_streams_the_expert_stacks_in_place_on_v5e(v5e):
     assert "ragged-dot" not in text["decode_all"]
     calls = [line for line in text["decode_all"].splitlines()
              if "tpu_custom_call" in line]
-    assert len(calls) == 1 and "mla:scores" in calls[0], calls
+    assert len(calls) == layers and all(
+        "mla:scores" in c for c in calls), calls
     assert text["prefill_chunk"].count("ragged-dot") >= 3
 
 
@@ -322,20 +326,20 @@ def cell_programs(v5e):
             i32(k, batch, seq, 1),
             jax.ShapeDtypeStruct((k, 2), jnp.uint32, sharding=v5e)))}
 
-    def get(name):
-        if name not in built:
+    def get(name, vocab_size=512):
+        if (name, vocab_size) not in built:
             cfg = harness.load_config(name)
-            cfg.update(num_hidden_layers=1, vocab_size=512)
+            cfg.update(num_hidden_layers=1, vocab_size=vocab_size)
             with mock.patch.object(jax, "default_backend", lambda: "tpu"):
                 if cfg["runner"] == "train_fit":
-                    built[name] = bert(cfg)
+                    built[name, vocab_size] = bert(cfg)
                 else:
                     if "n_routed_experts" in cfg:
                         cfg.update(n_routed_experts=4,
                                    moe_intermediate_size=256)
                     cfg["deployment"] = dict(cfg["deployment"], **small)
-                    built[name] = programs(cfg, v5e)
-        return built[name]
+                    built[name, vocab_size] = programs(cfg, v5e)
+        return built[name, vocab_size]
 
     return get
 
@@ -357,3 +361,47 @@ def test_cell_program_holds_the_cells_kernel_choice(
         jax.clear_caches()
         text = fn.lower(*args).compile().as_text()
     assert text.count("tpu_custom_call") == custom_calls_a_layer
+
+
+@pytest.mark.parametrize("config", sorted(
+    {c for c, prog, _ in CELL_PROGRAMS if prog == "prefill_last_chunk"}))
+def test_prefill_programs_run_the_head_for_the_sampled_row_alone(
+        cell_programs, config):
+    """The served configurations' two chunked-prefill programs, one layer
+    at the published widths, a vocabulary (1,920) that is no other width:
+    `prefill_chunk` holds no array whose last dimension is the vocabulary
+    (no logits, no distribution, not the head's kernel) and returns the
+    batch-1 caches alone; in `prefill_last_chunk` every such array but the
+    kernel (whole, or the row blocks the compiler prefetches a small one
+    in) has one row (or the 8 a tile pads it to), and the pick and the
+    install keep their device names."""
+    import re
+    from unittest import mock
+
+    from benchmark import harness
+
+    vocab, chunk = 1920, 64
+    hidden = int(harness.load_config(config)["hidden_size"])
+    progs = cell_programs(config, vocab)
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        jax.clear_caches()
+        compiled = {name: progs[name][0].lower(*progs[name][1]).compile()
+                    for name in ("prefill_chunk", "prefill_last_chunk")}
+    wide = {name: {tuple(int(d) for d in m.group(1).split(",") if d)
+                   for m in re.finditer(rf"\w+\[((?:\d+,)*){vocab}\]",
+                                        c.as_text())}
+            for name, c in compiled.items()}
+    assert not wide["prefill_chunk"], wide
+    fn, args = progs["prefill_chunk"]
+    small = args[2]
+    out = jax.eval_shape(fn, *args)
+    assert jax.tree.structure(out) == jax.tree.structure(small)
+    assert [a.shape for a in jax.tree.leaves(out)] == [
+        a.shape for a in jax.tree.leaves(small)]
+    assert (hidden,) in wide["prefill_last_chunk"]      # the head's kernel
+    rows = {lead for lead in wide["prefill_last_chunk"]
+            if not (len(lead) == 1 and lead[0] > chunk
+                    and hidden % lead[0] == 0)}
+    assert rows and all(np.prod(lead, dtype=int) <= 8 for lead in rows), rows
+    text = compiled["prefill_last_chunk"].as_text()
+    assert "sample:pick" in text and "kv:scatter_span" in text
