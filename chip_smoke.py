@@ -8,7 +8,7 @@ heads, seq 512, vocab 30,522, batch 8) and a causal LM of the same width
 request counts are a handful — the widths are what is full-size.
 
     python chip_smoke.py              # one chip: train, kernels, search,
-                                      # serve, latent
+                                      # serve, latent, hybrid, window
     python chip_smoke.py --chips 4    # ONLY the mesh phase + its 1-device twin
 
 It needs a TPU: with none it exits non-zero before doing any work. There is
@@ -62,6 +62,9 @@ class Sizes:
     # the hybrid block: Falcon-H1-34B's 20 query heads on 4 KV heads of 128,
     # and its mixer: inner width, heads, state, groups
     hybrid: Tuple[int, ...] = (20, 4, 128, 4096, 32, 256, 2)
+    # the window pair: Laguna-XS.2's 48 (full) and 64 (window) query heads
+    # on 8 KV heads of 128, and a window the longer prompts wrap 4 times
+    window_pair: Tuple[int, ...] = (48, 64, 8, 128, 128)
     # --chips 4: a global batch at which every plan's per-chip share is
     # past the flash crossover, so the kernels run inside the mesh step
     mesh_batch: int = 32
@@ -628,6 +631,85 @@ def phase_hybrid(sizes: Sizes, seed: int) -> Dict:
 
 
 # ---------------------------------------------------------------------------
+# phase: window
+# ---------------------------------------------------------------------------
+def phase_window(sizes: Sizes, seed: int) -> Dict:
+    """One WINDOW attention layer and one full layer of differing head
+    counts, each with its own rotation and a per-head gate
+    (ops/attention.py), under a causal LM head, through ContinuousBatcher,
+    greedy, against the lockstep GenerativeSession on the same weights:
+    the window layer's cache is a ring of its window's rows in the pool,
+    the batch-1 prefill holder and the session alike, the longer prompts
+    wrap it, and one prompt more than there are slots reuses a slot whose
+    ring the previous tenant left full."""
+    import flexflow_tpu as ff
+    from flexflow_tpu.serving.generate import GenerativeSession
+    from flexflow_tpu.serving.sched import ContinuousBatcher
+
+    full_heads, swa_heads, kv_heads, head_dim, window = sizes.window_pair
+    config = ff.FFConfig()
+    config.batch_size = 1
+    config.allow_mixed_precision = False
+    config.num_devices = 1
+    model = ff.FFModel(config)
+    tokens = model.create_tensor([1, sizes.window], ff.DataType.DT_INT32)
+    t = model.embedding(tokens, sizes.vocab, sizes.hidden,
+                        ff.AggrMode.AGGR_MODE_NONE, name="emb")
+    attend = lambda x, heads, name, **kw: model.multihead_attention(
+        x, x, x, sizes.hidden, heads, kdim=head_dim, vdim=head_dim,
+        bias=False, causal=True, kv_heads=kv_heads, head_gate=True,
+        name=name, **kw)
+    h = model.rms_norm(t, [-1], eps=1e-6, name="ln1")
+    t = model.add(t, attend(h, swa_heads, "l0_swa", window=window,
+                            rope_parameters={"rope_theta": 1e4}))
+    h = model.rms_norm(t, [-1], eps=1e-6, name="ln2")
+    t = model.add(t, attend(h, full_heads, "l1_attn", rope_parameters={
+        "rope_theta": 5e5, "rope_type": "yarn", "factor": 64,
+        "original_max_position_embeddings": 4096, "beta_slow": 1,
+        "beta_fast": 64, "attention_factor": 1.4158883083359672,
+        "partial_rotary_factor": 0.5}))
+    t = model.layer_norm(t, [-1], name="ln")
+    model.softmax(model.dense(t, sizes.vocab, name="lm_head"))
+    model.compile(optimizer=ff.SGDOptimizer(model, lr=0.0),
+                  loss_type=ff.LossType.LOSS_SPARSE_CATEGORICAL_CROSSENTROPY)
+    rng = np.random.RandomState(seed)
+    lengths = tuple(sizes.prompts) + (sizes.prompts[0] + 7,) * max(
+        0, sizes.slots + 1 - len(sizes.prompts))
+    prompts = [rng.randint(1, sizes.vocab, size=(n,)).astype(np.int32)
+               for n in lengths]
+    assert max(lengths) + sizes.new_tokens > 2 * window, \
+        "no prompt wraps the ring"
+    session = GenerativeSession(model, max_len=sizes.max_len)
+    refs = [np.asarray(session.generate(p[None, :], sizes.new_tokens)[0])
+            for p in prompts]
+    del session
+    chunk = 7 * sizes.page_size      # no divisor or multiple of the window
+    with ContinuousBatcher(
+            model, max_len=sizes.max_len, num_slots=sizes.slots,
+            page_size=sizes.page_size, max_queue=len(prompts),
+            prefill_chunk_tokens=chunk) as batcher:
+        handles = [batcher.submit(p, sizes.new_tokens) for p in prompts]
+        outs = [np.asarray(h.result(timeout=900.0)) for h in handles]
+        rings = dict(batcher._rings)
+    assert rings == {"l0_swa": min(window, sizes.max_len)}
+    near_ties: List[Dict] = []
+    identical = _count_identical("window", model, outs, refs, prompts,
+                                 near_ties)
+    _print_near_ties(near_ties)
+    return {"model": f"window-{window} attention {swa_heads}/{kv_heads}h"
+                     f" (ring of {rings['l0_swa']} rows) under full attention"
+                     f" {full_heads}/{kv_heads}h of {head_dim}, gated, at"
+                     f" hidden {sizes.hidden}, {sizes.slots} slots x"
+                     f" {sizes.max_len} rows, f32",
+            "prompt_lengths": list(lengths), "new_tokens": sizes.new_tokens,
+            "prefill_chunk_tokens": chunk, "ring_rows": rings["l0_swa"],
+            "compared": "greedy tokens vs lockstep GenerativeSession"
+                        f" (near-tie log-prob tolerance {NEAR_TIE_LOGPROB:g})",
+            "token_parity": f"{identical}/{len(prompts)} identical",
+            "near_ties": len(near_ties)}
+
+
+# ---------------------------------------------------------------------------
 # phase: mesh (--chips 4)
 # ---------------------------------------------------------------------------
 _COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
@@ -751,6 +833,7 @@ def main(argv=None) -> int:
         run_phase("serve", clock, phase_serve, sizes, args.seed)
         run_phase("latent", clock, phase_latent, sizes, args.seed)
         run_phase("hybrid", clock, phase_hybrid, sizes, args.seed)
+        run_phase("window", clock, phase_window, sizes, args.seed)
     print(json.dumps({"ok": True, "device": device}))
     return 0
 

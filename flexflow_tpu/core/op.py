@@ -178,11 +178,22 @@ class Op:
     # is the one home of how each kind is shaped, allocated and installed.
     def kv_cache_arrays(self) -> Optional[Dict[str, int]]:
         """What the op keeps PER TOKEN: {array name: values a token stores
-        in it} (`ctx.state[(op name, array name)]`, shaped (rows, max_len,
-        width): an attention's keys and values, a latent attention's
-        latent rows). Rows past a sequence's position are hidden by the
+        in it} (`ctx.state[(op name, array name)]`, shaped (rows,
+        max_len — or `kv_ring_rows()` —, width): an attention's keys and values, a
+        latent attention's latent rows). Rows past a sequence's position are hidden by the
         op's own `<= position` mask, so a reused slot needs no reset. None
         for an op that keeps nothing per token."""
+        return None
+
+    def kv_ring_rows(self) -> Optional[int]:
+        """None where each of those arrays keeps one row a position, up to
+        the holder's `max_len`. An op that never looks further back than a
+        window says how many rows it needs instead, and keeps a RING of
+        them: position p in row p mod R, written and masked by the op by
+        the position each row holds (ops/attention.py). Whoever allocates
+        asks here (kvpool.py `kv_cache_spec`); what addresses rows by
+        token position (pages of a prefix cache, a rollback, an export)
+        cannot address a ring (`kvpool.RingCacheUnsupported`)."""
         return None
 
     def sequence_state_arrays(self) -> Optional[Dict[str, tuple]]:

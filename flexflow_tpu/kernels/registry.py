@@ -15,7 +15,7 @@ observe, first match wins:
     against 8.0 ms a decode iteration of `ms4_decode_sat`, PERF.md
     section 6, PR 30); the two dense decode families: reference
     (kernels/pallas/decode.py is reachable by override alone until it has
-    a timed row on a serving cell).
+    a timed row on a serving cell); `grouped_experts`: `small_experts`.
 
 The mesh is the call sites' business (GSPMD cannot partition a Mosaic
 kernel): ops/attention.py runs flash under shard_map (`_on_mesh`) and
@@ -35,7 +35,7 @@ from typing import Dict, Optional, Tuple
 import jax
 
 FAMILIES = ("attention", "attention_decode", "attention_decode_mq",
-            "latent_decode")
+            "latent_decode", "grouped_experts")
 
 # per-chip f32 score-matrix bytes at the v5e-measured crossover: flash wins
 # from seq ~512 up; below that the blocks are too small to fill the grid and
@@ -51,6 +51,26 @@ def flash_crossover(batch: int, heads: int, q_len: int, k_len: int,
                     dp: int = 1) -> bool:
     score_bytes = (4.0 * batch * heads * q_len * k_len) / max(dp, 1)
     return score_bytes > FLASH_SCORE_BYTES_CROSSOVER
+
+
+# the widest matrix of one expert that the tiled grouped matmul takes as ONE
+# VMEM block (two of them in flight beside the row tiles, under the 16 MiB
+# a kernel may scope on a v5e), and the token rows past which sending every
+# row through every held expert leaves the memory's floor (a v5e, 256
+# experts of 2048 x 512 chosen 8 at a time, few-rows / tiled ms a layer:
+# 128 rows 2.18 / 2.24, 256 2.72 / 2.40, 384 3.36 / 2.50, 512 4.39 / 2.62,
+# 640 5.44 / 2.74; PERF.md section 6, PR 33)
+EXPERT_BLOCK_BYTES = 2 << 20
+EXPERT_RIDGE_ROWS = 256
+
+
+def small_experts(rows: int, matrix_bytes: int) -> bool:
+    """Whether routed experts over `rows` token rows, each matrix of
+    `matrix_bytes`, take the tiled grouped matmul (ops/moe.py `_tiled_dot`)
+    in place of the few-rows form: past the ridge every row through every
+    expert is compute the chosen experts do not need, and the kernel as it
+    is tiles the sorted rows alone."""
+    return rows > EXPERT_RIDGE_ROWS and matrix_bytes <= EXPERT_BLOCK_BYTES
 
 
 @dataclasses.dataclass(frozen=True)
@@ -93,13 +113,16 @@ class KernelRegistry:
 
     def select(self, family: str, *, param: Optional[bool] = None,
                scores: Optional[Tuple[int, int, int, int, int]] = None,
+               experts: Optional[Tuple[int, int]] = None,
                record: bool = True) -> KernelChoice:
         """Pick the impl for one op instance. `param` is the op's own
         explicit setting (attention's use_flash); `scores` the attention
         instance's `flash_crossover` arguments (batch, heads, q_len,
-        k_len, dp) — the dense decode families have no shape predicate and
-        stay on the reference, `latent_decode` is asked for one query a
-        slot alone and takes the kernel; `record=False` skips the
+        k_len, dp), `experts` the routed product's `small_experts`
+        arguments (token rows, bytes of one expert's matrix) — the dense
+        decode families have no shape predicate and stay on the reference,
+        `latent_decode` is asked for one query a slot alone and takes the
+        kernel; `record=False` skips the
         selection counter (the cost simulator asks thousands of times per
         search)."""
         _known(family)
@@ -113,7 +136,9 @@ class KernelRegistry:
         else:
             wins = family == "latent_decode" or (
                 family == "attention" and scores is not None
-                and flash_crossover(*scores))
+                and flash_crossover(*scores)) or (
+                family == "grouped_experts" and experts is not None
+                and small_experts(*experts))
             choice = KernelChoice(
                 family, "pallas" if wins else "reference", "shape")
         if record:

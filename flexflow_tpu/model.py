@@ -367,12 +367,18 @@ class FFModel:
         kv_heads: int = 0,
         rope_parameters=None,
         key_multiplier: float = 1.0,
+        window: int = 0,
+        head_gate: bool = False,
     ) -> Tensor:
         """`kv_heads` (0 = `num_heads`): grouped KV heads, query head j
         reading KV head j // (num_heads / kv_heads); `rope_parameters`: the
         model's published rotary group (ops/rope.py), queries and keys
         rotated at their positions before the cache write (half-split
-        pairs); `key_multiplier` scales the projected keys."""
+        pairs); `key_multiplier` scales the projected keys; `window` W
+        (0 = none; needs `causal`): query t sees the keys s with
+        0 <= t - s < W, and the serving cache is a ring of W rows;
+        `head_gate`: each head's context times the sigmoid of its own
+        logit, a projection (`wg`) of the op's query input."""
         # only what departs from plain multi-head attention becomes an op
         # parameter: the keys of the cost caches and of stored strategies
         # for every model without them stay what they were
@@ -383,6 +389,10 @@ class FFModel:
             extra["rope_parameters"] = dict(rope_parameters)
         if float(key_multiplier) != 1.0:
             extra["key_multiplier"] = float(key_multiplier)
+        if window:
+            extra["window"] = int(window)
+        if head_gate:
+            extra["head_gate"] = True
         return self._add_op(
             OpType.MULTIHEAD_ATTENTION,
             [query, key, value],
@@ -550,13 +560,17 @@ class FFModel:
 
     def moe_router(self, input: Tensor, num_exp: int, num_select: int,
                    scale: float = 1.0, kernel_initializer=None,
-                   name: str = "") -> Tuple[Tensor, Tensor]:
+                   name: str = "",
+                   scoring: str = "softmax") -> Tuple[Tensor, Tensor]:
         """Router of a dropless expert layer (ops/moe.py MoERouterOp):
         float32 logits over all `num_exp` experts, the `num_select`
-        largest, softmax over those. Returns (weights, expert ids)."""
+        largest, softmax over those (`scoring="sigmoid"`: their sigmoids
+        over the sum of those). Returns (weights, expert ids)."""
+        extra = {} if scoring == "softmax" else {"scoring": scoring}
         outs = self._add_op(
             OpType.MOE_ROUTER, [input], name, n=num_exp, k=num_select,
-            scale=scale, kernel_initializer=kernel_initializer).outputs
+            scale=scale, kernel_initializer=kernel_initializer,
+            **extra).outputs
         return outs[0], outs[1]
 
     def gated_experts(self, input: Tensor, gate_weights: Tensor,

@@ -110,6 +110,54 @@ def _context(probs, vc, together, kv_heads):
     return jnp.sum(jnp.where(_own_lanes(heads, kv_heads), wide, 0), axis=3)
 
 
+# the shortest prefix of a long cache that a chunk at an offset is attended
+# in; the next ones double it. The scores of 512 queries over the 12,799 rows
+# of `laguna_xs2_1chip`'s batch-1 holder are 1.26 GB of float32 a full layer,
+# for prompts whose chunks end at row 3,800 in the mean (PERF.md section 6,
+# PR 33)
+PREFIX_ROWS = 2048
+
+
+def _prefixes(rows: int) -> List[int]:
+    """The static lengths a cache of `rows` rows is attended in by queries
+    at a scalar offset: `PREFIX_ROWS`, its doubles under `rows`, and the
+    whole; a cache of up to two of them whole alone."""
+    if rows <= 2 * PREFIX_ROWS:
+        return [rows]
+    out, p = [], PREFIX_ROWS
+    while p < rows:
+        out.append(p)
+        p *= 2
+    return out + [rows]
+
+
+# A WINDOW op's cache is a RING of R rows (`kv_ring_rows`): position p
+# lives in row p mod R, so row j holds the largest position <= the newest
+# one that is congruent to j. Every mask below is computed from that
+# position, never from the row index: rows a young sequence has not
+# written yet hold a negative position and a previous tenant's rows hold
+# one the new sequence has since overwritten, so neither is ever read.
+
+def _ring_positions(newest, rows: int):
+    """(..., rows) position each ring row holds once `newest` (...,) is
+    the last position written; negative where nothing has been written."""
+    j = jnp.arange(rows)
+    newest = jnp.asarray(newest)[..., None]
+    return newest - (newest - j) % rows
+
+
+def _ring_write(cache, new, pos, valid=None):
+    """`cache` (B, R, e) with `new` (B, C, e) — the rows of positions
+    pos .. pos+C-1 (a scalar), of which the leading `valid` (all, if None)
+    are real — written at their ring places: the last min(valid, R) of
+    them land, as one gather, whatever C is to R."""
+    r, c = cache.shape[1], new.shape[1]
+    last = pos + (c if valid is None else valid) - 1
+    src = _ring_positions(last, r)           # what row j holds afterwards
+    idx = jnp.clip(src - pos, 0, c - 1)
+    return jnp.where((src >= pos)[None, :, None], new[:, idx], cache)
+
+
 @register_op
 class MultiHeadAttentionOp(Op):
     op_type = OpType.MULTIHEAD_ATTENTION
@@ -128,10 +176,20 @@ class MultiHeadAttentionOp(Op):
         query head j reads KV head j // (heads / kv_heads)."""
         return self.params.get("kv_heads") or self.params["num_heads"]
 
+    def _window(self):
+        """`window` W (None: every earlier position): key s is visible to
+        query t iff 0 <= t - s < W, the key itself counted."""
+        w = self.params.get("window")
+        return int(w) if w else None
+
     def output_shapes(self):
         q, k, v, embed, heads, kdim, vdim = self._dims()
         kv_heads = self._kv_heads()
         rope = self.params.get("rope_parameters")
+        window, gated = self._window(), self.params.get("head_gate")
+        if window and not self.params.get("causal"):
+            raise ValueError("multihead_attention: a window looks back"
+                             " from the query only; it needs causal=True")
         if heads % kv_heads:
             raise ValueError(
                 f"multihead_attention: num_heads={heads} is no multiple of"
@@ -139,15 +197,16 @@ class MultiHeadAttentionOp(Op):
         if rope is not None and kdim % 2:
             raise ValueError("multihead_attention: rotary positions need an"
                              f" even kdim, got {kdim}")
-        if kv_heads != heads or rope is not None:
+        if kv_heads != heads or rope is not None or window or gated:
             # the flash, ring and ulysses kernels take one K/V head a query
-            # head and unrotated projections (ROADMAP Reach M1)
+            # head, unrotated projections, a plain causal mask and no gate
+            # (ROADMAP Reach M1)
             for opt in ("use_flash", "sequence_parallel"):
                 if self.params.get(opt):
                     raise ValueError(
                         f"multihead_attention: {opt}=True takes neither"
-                        " grouped KV heads nor rotary positions; the"
-                        " einsum core serves them")
+                        " grouped KV heads, rotary positions, a window nor"
+                        " a head gate; the einsum core serves them")
         if self.params.get("sequence_parallel") and self.params.get("dropout", 0.0) > 0:
             # the ring kernel has no attention-probability dropout; fail loudly
             # rather than silently train with different regularization
@@ -185,6 +244,9 @@ class MultiHeadAttentionOp(Op):
             WeightSpec("wv", (v.dims[-1], kvh, vdim), dt, init(v.dims[-1], kvh * vdim)),
             WeightSpec("wo", (heads, vdim, embed), dt, init(heads * vdim, embed)),
         ]
+        if self.params.get("head_gate"):
+            specs.append(WeightSpec("wg", (q.dims[-1], heads), dt,
+                                    init(q.dims[-1], heads)))
         if self.params.get("bias", True):
             specs += [
                 WeightSpec("bq", (heads, kdim), dt, ZeroInitializer()),
@@ -201,6 +263,13 @@ class MultiHeadAttentionOp(Op):
         kvh = self._kv_heads()
         return {"k_cache": kvh * kdim, "v_cache": kvh * vdim}
 
+    def kv_ring_rows(self):
+        """A window op keeps a ring of its window's rows: a query reads
+        the last `window` positions and nothing older is ever needed
+        (write-then-attend: the row a new token overwrites is the one
+        that just left the window)."""
+        return self._window()
+
     def lower(self, ctx, inputs, weights):
         q_in, k_in, v_in = inputs[:3]
         p = self.params
@@ -211,6 +280,7 @@ class MultiHeadAttentionOp(Op):
         heads = weights["wq"].shape[1]
         kv_heads = weights["wk"].shape[1]
         rope = p.get("rope_parameters")
+        window = self._window()
         cdt = matmul_dtype(ctx.config, q_in.dtype)
 
         # iteration seq_length truncation (reference: FFIterationConfig
@@ -251,7 +321,8 @@ class MultiHeadAttentionOp(Op):
         # queries, einsum core) keeps the logical [b, l, h, d]. The KV
         # cache itself is stored packed, (rows, max_len, heads*head_dim).
         flash_selected = (
-            kv_heads == heads and rope is None
+            kv_heads == heads and rope is None and window is None
+            and "wg" not in weights
             and self._use_flash(ctx) and not dropout_active and kdim == vdim
             and not seq_parallel_active
         )
@@ -313,17 +384,25 @@ class MultiHeadAttentionOp(Op):
         # prototype). fill_kv_cache: a full (prefill) pass also writes its
         # K/V into the session cache. decode_pos: q is one new token; attend
         # against the cache up to the traced position.
+        # the per-head gate is of the op's INPUT at the query's position,
+        # so every entry below applies the same one to its context
+        gate = self._head_gate(q_in, weights, cdt)
         if decode_active:
-            return [self._decode_step(ctx, q, k, v, weights, scale)]
+            return [self._decode_step(ctx, q, k, v, weights, scale, gate)]
         if fill_active:
-            # the cache stores the packed (b, l, h*d) projection as it is
+            # the cache stores the packed (b, l, h*d) projection as it is;
+            # a ring keeps the tail of the real tokens at their ring places
             vc = ctx.state[(self.name, "v_cache")]
-            ctx.state_updates[(self.name, "k_cache")] = (
-                jax.lax.dynamic_update_slice(
-                    kc, _packed(k).astype(kc.dtype), (0, 0, 0)))
-            ctx.state_updates[(self.name, "v_cache")] = (
-                jax.lax.dynamic_update_slice(
-                    vc, _packed(v).astype(vc.dtype), (0, 0, 0)))
+            if window is None:
+                store = lambda cache, rows: jax.lax.dynamic_update_slice(
+                    cache, rows, (0, 0, 0))
+            else:
+                store = lambda cache, rows: _ring_write(
+                    cache, rows, 0, getattr(ctx, "valid_len", None))
+            ctx.state_updates[(self.name, "k_cache")] = store(
+                kc, _packed(k).astype(kc.dtype))
+            ctx.state_updates[(self.name, "v_cache")] = store(
+                vc, _packed(v).astype(vc.dtype))
 
         if seq_parallel_active:
             # sequence/context parallelism over the 'seq' mesh axis — two
@@ -386,6 +465,9 @@ class MultiHeadAttentionOp(Op):
                 if causal:
                     lq, lk = logits.shape[-2], logits.shape[-1]
                     mask = jnp.tril(jnp.ones((lq, lk), dtype=bool), lk - lq)
+                    if window is not None:   # a band: 0 <= t - s < window
+                        mask &= ~jnp.tril(jnp.ones((lq, lk), dtype=bool),
+                                          lk - lq - window)
                     logits = jnp.where(mask, logits, -1e30)
                 probs = jax.nn.softmax(logits, axis=-1)
                 if drop_key is not None:
@@ -408,6 +490,8 @@ class MultiHeadAttentionOp(Op):
                     policy=jax.checkpoint_policies.nothing_saveable)
             ctxv = attn_core(q, k, v, drop_key)
 
+        if gate is not None:
+            ctxv = self._gated(ctxv, gate)
         odt = emit_dtype(ctx.config, self.outputs[0].dtype)
         if use_packed:
             out = (ctxv.astype(cdt) @ weights["wo"].reshape(
@@ -448,7 +532,21 @@ class MultiHeadAttentionOp(Op):
         return jax.shard_map(kernel, mesh=ctx.mesh, in_specs=(spec,) * 3,
                              out_specs=spec, check_vma=False)
 
-    def _decode_step(self, ctx, q, k, v, weights, scale):
+    def _head_gate(self, x, weights, cdt):
+        """(B, L, h) float32 sigmoid of x W_g, one logit a head (`head_gate`;
+        None where the op has none)."""
+        if "wg" not in weights:
+            return None
+        return jax.nn.sigmoid(jnp.einsum(
+            "ble,eh->blh", x.astype(cdt), weights["wg"].astype(cdt),
+            preferred_element_type=jnp.float32))
+
+    @staticmethod
+    def _gated(ctxv, gate):
+        """Each head's context (B, L, h, d) times its gate, in float32."""
+        return (ctxv.astype(jnp.float32) * gate[..., None]).astype(ctxv.dtype)
+
+    def _decode_step(self, ctx, q, k, v, weights, scale, gate=None):
         """One incremental-decoding step: q/k/v are projections of the new
         token(s) (B, C, h, d); the K/V caches are STORED PACKED,
         (B, M, h*d) — lane-dense on the chip at every width whose h*d is a
@@ -500,7 +598,14 @@ class MultiHeadAttentionOp(Op):
         block-diagonally over the h*d lanes: no (…, h, d) layout of a
         whole cache is ever asked for. A prefill chunk (hundreds of
         queries on a batch-1 cache) and heads GSPMD shards over a mesh
-        axis contract head by head on a reshaped view."""
+        axis contract head by head on a reshaped view.
+
+        A WINDOW op's caches are rings (`kv_ring_rows`, the helpers above
+        the class): the decode iteration writes row pos mod R and masks by
+        the position each row holds (`_ring_decode`); a chunk at an offset
+        attends the ring as it stands AND its own rows under the band
+        mask, then leaves the ring holding the chunk's tail
+        (`_ring_chunk`)."""
         pos = ctx.decode_pos
         kc = ctx.state[(self.name, "k_cache")]
         vc = ctx.state[(self.name, "v_cache")]
@@ -508,6 +613,10 @@ class MultiHeadAttentionOp(Op):
         c = q.shape[1]
         k_rows = _packed(k).astype(kc.dtype)  # (B, C, h*d)
         v_rows = _packed(v).astype(vc.dtype)
+        if self._window() is not None:
+            ring = self._ring_decode if vector else self._ring_chunk
+            ctxv = ring(ctx, q, k_rows, v_rows, kc, vc, scale)
+            return self._decode_project(ctxv, q.dtype, weights, gate)
         if vector:
             rows = jnp.arange(kc.shape[0])
             if c == 1:
@@ -546,7 +655,7 @@ class MultiHeadAttentionOp(Op):
                 (kc.shape[0],), pos, jnp.int32)
             ctxv = fused(q, kc, vc, posv, scale=scale,
                          interpret=pallas_interpret())
-            return self._decode_project(ctxv, q.dtype, weights)
+            return self._decode_project(ctxv, q.dtype, weights, gate)
 
         if vector:
             # (B, C, M): query j of slot i attends rows <= pos[i]+j
@@ -555,24 +664,100 @@ class MultiHeadAttentionOp(Op):
             mask = (jnp.arange(kc.shape[1])[None, None, :]
                     <= qpos[:, :, None])[:, None, :, :]  # (B, 1, C, M)
         else:
-            qpos = pos + jnp.arange(c)  # (C,) absolute positions
-            mask = (jnp.arange(kc.shape[1])[None, :]
-                    <= qpos[:, None])[None, None, :, :]  # (1, 1, C, M)
+            ctxv = self._prefix_core(ctx, q, kc, vc, pos, scale)
+            return self._decode_project(ctxv, q.dtype, weights, gate)
+        ctxv = self._masked_core(ctx, q, kc, vc, mask, scale)
+        return self._decode_project(ctxv, q.dtype, weights, gate)
+
+    def _prefix_core(self, ctx, q, kc, vc, pos, scale):
+        """C queries at the scalar offset `pos` (a prefill chunk on a
+        batch-1 holder, the lockstep session's step): query j attends rows
+        <= pos + j, so no row past pos + C is visible, and the two
+        contractions run over the shortest static prefix of the caches
+        that holds them (`_prefixes`; a short cache whole, as ever)."""
+        c = q.shape[1]
+
+        def over(rows):
+            def core(q, kc, vc, pos):
+                qpos = pos + jnp.arange(c)  # (C,) absolute positions
+                mask = (jnp.arange(rows)[None, :]
+                        <= qpos[:, None])[None, None, :, :]  # (1, 1, C, M)
+                return self._masked_core(ctx, q, kc[:, :rows], vc[:, :rows],
+                                         mask, scale)
+            return core
+
+        prefixes = _prefixes(kc.shape[1])
+        if len(prefixes) == 1:
+            return over(prefixes[0])(q, kc, vc, pos)
+        which = sum(jnp.asarray(pos + c > p, jnp.int32) for p in prefixes[:-1])
+        return jax.lax.switch(which, [over(p) for p in prefixes],
+                              q, kc, vc, pos)
+
+    def _masked_core(self, ctx, q, keys, values, mask, scale):
+        """softmax(q keys^T * scale under `mask`) values on the caches as
+        stored: the reference chain's two contractions."""
+        kv_heads = keys.shape[-1] // q.shape[-1]
         # contracted as one row, a head-sharded last dimension would have
         # GSPMD gather the cache: tensor-parallel heads contract head by head
         together = (not self._heads_sharded(ctx)
-                    and _contract_heads_together(c, q.shape[2]))
-        logits = _scores(q, kc.astype(q.dtype), together) * scale
+                    and _contract_heads_together(q.shape[1], q.shape[2]))
+        logits = _scores(q, keys.astype(q.dtype), together) * scale
         logits = jnp.where(mask, logits, -1e30)  # (B, h, C, M)
         probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-        ctxv = _context(probs.astype(q.dtype), vc.astype(q.dtype), together,
-                        kv_heads)
-        return self._decode_project(ctxv, q.dtype, weights)
+        return _context(probs.astype(q.dtype), values.astype(q.dtype),
+                        together, kv_heads)
+
+    def _ring_decode(self, ctx, q, k_rows, v_rows, kc, vc, scale):
+        """The decode iteration on a ring: slot i writes its one row at
+        pos[i] mod R, then attends the rows whose position lies inside its
+        window."""
+        pos = ctx.decode_pos
+        if q.shape[1] != 1:
+            raise NotImplementedError(
+                f"multihead_attention {self.name}: several queries a slot"
+                " (speculative verify) on a window's ring: a rejected"
+                " draft would have overwritten rows the window still"
+                " needs")
+        r = kc.shape[1]
+        rows = jnp.arange(kc.shape[0])
+        kc = kc.at[rows, pos % r].set(k_rows[:, 0])
+        vc = vc.at[rows, pos % r].set(v_rows[:, 0])
+        ctx.state_updates[(self.name, "k_cache")] = kc
+        ctx.state_updates[(self.name, "v_cache")] = vc
+        held = _ring_positions(pos, r)                        # (B, R)
+        mask = (held >= 0) & (pos[:, None] - held < self._window())
+        return self._masked_core(ctx, q, kc, vc, mask[:, None, None, :],
+                                 scale)
+
+    def _ring_chunk(self, ctx, q, k_rows, v_rows, kc, vc, scale):
+        """C queries at offset `pos` (a scalar: a prefill chunk on a
+        batch-1 holder, the lockstep session's step): they attend the ring
+        as the earlier positions left it and the chunk's own rows, both
+        under the band mask; then the chunk's real rows take their ring
+        places."""
+        pos, window = ctx.decode_pos, self._window()
+        c, r = q.shape[1], kc.shape[1]
+        qpos = pos + jnp.arange(c)
+        held = _ring_positions(pos - 1, r)                    # (R,)
+        behind = qpos[:, None] - jnp.concatenate([held, qpos])[None, :]
+        mask = (behind >= 0) & (behind < window) & jnp.concatenate(
+            [held >= 0, jnp.ones((c,), bool)])[None, :]       # (C, R + C)
+        ctxv = self._masked_core(
+            ctx, q, jnp.concatenate([kc, k_rows], axis=1),
+            jnp.concatenate([vc, v_rows], axis=1), mask[None, None], scale)
+        valid = getattr(ctx, "valid_len", None)
+        ctx.state_updates[(self.name, "k_cache")] = _ring_write(
+            kc, k_rows, pos, valid)
+        ctx.state_updates[(self.name, "v_cache")] = _ring_write(
+            vc, v_rows, pos, valid)
+        return ctxv
 
     def _rotate(self, ctx, q, k, decode_active):
         """q (B, L, h, d) and k (B, L, n, d) rotated to their positions
-        (ops/rope.py, half-split pairs): 0..L-1 for a whole sequence,
-        `decode_pos` + 0..L-1 in the decode step's three forms."""
+        (ops/rope.py, half-split pairs; the leading `rotary_dim` values of
+        a head where the group has a `partial_rotary_factor`): 0..L-1 for
+        a whole sequence, `decode_pos` + 0..L-1 in the decode step's three
+        forms."""
         from . import rope as rope_mod
 
         steps = jnp.arange(q.shape[1])
@@ -583,8 +768,9 @@ class MultiHeadAttentionOp(Op):
             qpos = pos[:, None] + steps[None, :]        # (B, C)
         else:
             qpos = (pos + steps)[None, :]
-        cos, sin = rope_mod.cos_sin(qpos, q.shape[-1],
-                                    self.params["rope_parameters"])
+        rope = self.params["rope_parameters"]
+        cos, sin = rope_mod.cos_sin(
+            qpos, rope_mod.rotary_dim(q.shape[-1], rope), rope)
         cos, sin = cos[:, :, None, :], sin[:, :, None, :]
         return (rope_mod.rotate_half_split(q, cos, sin),
                 rope_mod.rotate_half_split(k, cos, sin))
@@ -599,9 +785,11 @@ class MultiHeadAttentionOp(Op):
                   if w._weight_spec.name == "wq").parallel_shape
         return wq is not None and wq.partition_spec()[1] is not None
 
-    def _decode_project(self, ctxv, cdt, weights):
+    def _decode_project(self, ctxv, cdt, weights, gate=None):
         """Output projection shared by the fused and reference decode
-        paths."""
+        paths, after the per-head gate where the op has one."""
+        if gate is not None:
+            ctxv = self._gated(ctxv, gate)
         out = jnp.einsum("bqhd,hde->bqe", ctxv.astype(cdt),
                          weights["wo"].astype(cdt))
         out = out.astype(self.outputs[0].dtype.jnp_dtype)

@@ -13,6 +13,7 @@ formulation expert-parallel all_to_all dispatch wants.
 """
 from __future__ import annotations
 
+import functools
 import math
 import jax
 import jax.numpy as jnp
@@ -370,13 +371,19 @@ class MoERouterOp(Op):
     """x (..., E) -> (weights (..., k) float32, expert ids (..., k) int32):
     logits over ALL `n` experts accumulated in float32, the k largest, and
     the softmax over those k logits (= softmax over n, top-k, renormalised:
-    `norm_topk_prob`), times `scale` (`routed_scaling_factor`). No bias, no
-    correction term."""
+    `norm_topk_prob`), times `scale` (`routed_scaling_factor`). With
+    `scoring="sigmoid"` each logit is scored by its own sigmoid (monotone:
+    the same k are chosen) and the k scores are divided by their sum before
+    `scale`. No bias, no correction term, no group limiting."""
 
     op_type = OpType.MOE_ROUTER
 
     def output_shapes(self):
         (x,) = self.inputs
+        scoring = self.params.get("scoring", "softmax")
+        if scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"moe_router {self.name}: scoring={scoring!r}"
+                             " is neither 'softmax' nor 'sigmoid'")
         out = tuple(x.dims[:-1]) + (self.params["k"],)
         return [out, out], [DataType.DT_FLOAT, DataType.DT_INT32]
 
@@ -401,7 +408,12 @@ class MoERouterOp(Op):
             logits = jnp.dot(x.astype(cdt), weights["kernel"].astype(cdt),
                              preferred_element_type=jnp.float32)
             top, idx = jax.lax.top_k(logits, self.params["k"])
-            w = jax.nn.softmax(top, axis=-1) * self.params.get("scale", 1.0)
+            if self.params.get("scoring", "softmax") == "sigmoid":
+                score = jax.nn.sigmoid(top)
+                w = score / jnp.sum(score, axis=-1, keepdims=True)
+            else:
+                w = jax.nn.softmax(top, axis=-1)
+            w = w * self.params.get("scale", 1.0)
         return [w, idx.astype(jnp.int32)]
 
     def flops(self) -> float:
@@ -475,13 +487,42 @@ def _few_rows_product(x, w, idx, weights, first: int, count: int):
     return y, sizes
 
 
-def _grouped_product(x, w, idx, weights, first: int, count: int):
+def _ragged_dot(a, m, sizes):
+    """XLA's grouped matmul: the chip's compiler lowers it to a grouped-GEMM
+    kernel of 512-row tiles."""
+    return jax.lax.ragged_dot(a, m, sizes, preferred_element_type=jnp.float32)
+
+
+# sorted rows a step of the tiled grouped matmul multiplies: a tile that
+# spans g groups is multiplied g times, so many small groups want a small
+# tile (a v5e, 512 token rows x 8 through 256 experts of 2048 x 512, ms a
+# layer: 2.60 at 64, 2.62 at 128, 2.75 at 256; PERF.md section 6, PR 33)
+GROUP_TILE_ROWS = 128
+
+
+def _tiled_dot(a, m, sizes):
+    """The same product by the Pallas grouped matmul (jax's megablox) at
+    tiles of `GROUP_TILE_ROWS` sorted rows by ONE expert's whole matrix
+    (`registry.small_experts`: it is one VMEM block). Each tile is
+    multiplied with the groups it spans alone, and an expert nobody chose
+    is not read."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    from ..runtime.platform import pallas_interpret
+
+    pad = -a.shape[0] % GROUP_TILE_ROWS      # rows of no group, at the end
+    out = gmm(jnp.pad(a, ((0, pad), (0, 0))), m, sizes, jnp.float32,
+              (GROUP_TILE_ROWS,) + m.shape[1:], interpret=pallas_interpret())
+    return out[:a.shape[0]]
+
+
+def _grouped_product(x, w, idx, weights, first: int, count: int,
+                     dot=_ragged_dot):
     """The same (y, assignments per local expert) for many rows:
     assignments sorted by local expert (absent ones last), the token rows
     gathered in that order, three grouped matmuls over the sorted rows
-    (`jax.lax.ragged_dot`: the chip's compiler lowers it to a grouped-GEMM
-    kernel), rows brought back to token order and the k weighted parts of
-    a token summed in float32."""
+    (`dot`: `_ragged_dot` or `_tiled_dot`), rows brought back to token
+    order and the k weighted parts of a token summed in float32."""
     k = idx.shape[-1]
     with jax.named_scope("moe:sort"):
         local, mine = local_assignments(idx, first, count)
@@ -491,8 +532,7 @@ def _grouped_product(x, w, idx, weights, first: int, count: int):
         rows = x[order // k]                        # (T*k, E)
         held = jnp.arange(rows.shape[0]) < jnp.sum(sizes)
     with jax.named_scope("moe:experts"):
-        grouped = lambda a, m: jax.lax.ragged_dot(
-            a, m.astype(x.dtype), sizes, preferred_element_type=jnp.float32)
+        grouped = lambda a, m: dot(a, m.astype(x.dtype), sizes)
         h = (jax.nn.silu(grouped(rows, weights["w_gate"]))
              * grouped(rows, weights["w_up"])).astype(x.dtype)
         # rows past the held assignments belong to no group: the grouped
@@ -526,7 +566,11 @@ class GatedExpertsOp(Op):
     The three products take one of two forms, chosen from the static
     number of token rows (`few_rows`): for few rows every row goes through
     every held expert (`_few_rows_product`), for many the assignments are
-    sorted by expert and multiplied group by group (`_grouped_product`).
+    sorted by expert and multiplied group by group (`_grouped_product`,
+    by XLA's `ragged_dot`). Few rows of SMALL experts past the chip's
+    ridge are sorted too and multiplied by the Pallas grouped matmul
+    (`_tiled_dot`; `kernels/registry.py small_experts` decides, serving
+    steps on one TPU alone).
 
     Router health, threaded by the continuous batcher from one decode
     iteration to the next (`serving_counters`): `assignments` (local
@@ -585,6 +629,22 @@ class GatedExpertsOp(Op):
                 WeightSpec("load", (count,), DataType.DT_INT32, z),
                 WeightSpec("few_rows_steps", (), DataType.DT_INT32, z)]
 
+    @staticmethod
+    def _tiled(ctx, rows: int, matrix, cdt) -> bool:
+        """Whether a step of few token rows sorts them and takes the Pallas
+        grouped matmul: the registry's call (`small_experts`), and only
+        where nothing differentiates the step (the kernel's rows of no
+        group are unwritten, which a gradient would read) and GSPMD does
+        not partition it."""
+        from ..ffconst import CompMode
+        from ..kernels.registry import KERNELS
+
+        return bool(
+            ctx.mode != CompMode.COMP_MODE_TRAINING
+            and not ctx.gspmd_partitioned()
+            and KERNELS.select("grouped_experts", experts=(
+                rows, matrix[0].size * jnp.dtype(cdt).itemsize)))
+
     def lower(self, ctx, inputs, weights):
         from .common import emit_dtype, matmul_dtype
 
@@ -596,6 +656,9 @@ class GatedExpertsOp(Op):
         cdt = matmul_dtype(getattr(ctx, "config", None), x.dtype)
         few = few_rows(x.shape[0])
         product = _few_rows_product if few else _grouped_product
+        if few and self._tiled(ctx, x.shape[0], weights["w_gate"], cdt):
+            few, product = False, functools.partial(_grouped_product,
+                                                    dot=_tiled_dot)
         out, sizes = product(x.astype(cdt), w.reshape(-1, k),
                              idx.reshape(-1, k), weights, first, count)
 

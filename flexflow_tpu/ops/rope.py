@@ -4,8 +4,12 @@ the rotation of interleaved or half-split pairs at given positions.
 `rope_parameters` is the published group of a model's `config.json`
 (`rope_theta`, and for `rope_type: "yarn"`: `factor`,
 `original_max_position_embeddings`, `beta_fast`, `beta_slow`, `mscale`,
-`mscale_all_dim`; Ministral's `llama_4_scaling_beta`); with no `rope_type`
+`mscale_all_dim`, or `attention_factor` given outright; Ministral's
+`llama_4_scaling_beta`; `partial_rotary_factor`); with no `rope_type`
 (or "default") the table is plain theta^(-2j/dim) and nothing is scaled.
+`partial_rotary_factor` f < 1 rotates the LEADING f * dim values of a head
+(`rotary_dim`; the tables are those of a head that wide) and passes the
+rest through.
 Pairs are (x[2j], x[2j+1]) where a model says `rope_interleave`
 (`rotate_interleaved`) and (x[j], x[j + dim/2]) otherwise
 (`rotate_half_split`). YaRN's conventions are the
@@ -59,11 +63,20 @@ def inv_freq(dim: int, rope: Optional[Dict] = None) -> np.ndarray:
     return base / factor * (1.0 - keep) + base * keep
 
 
+def rotary_dim(dim: int, rope: Optional[Dict] = None) -> int:
+    """How many leading values of a `dim`-wide head are rotated:
+    `partial_rotary_factor` of them (all, where the group has none)."""
+    return int(dim * float((rope or {}).get("partial_rotary_factor", 1.0)))
+
+
 def table_scale(rope: Optional[Dict]) -> float:
-    """What YaRN multiplies cos and sin by."""
+    """What YaRN multiplies cos and sin by: `attention_factor` where the
+    group gives it outright, else the ratio of its two mscales."""
     rope = rope or {}
     if rope.get("rope_type", rope.get("type")) != "yarn":
         return 1.0
+    if rope.get("attention_factor") is not None:
+        return float(rope["attention_factor"])
     factor = float(rope["factor"])
     return (yarn_mscale(factor, float(rope.get("mscale", 1.0)))
             / yarn_mscale(factor, float(rope.get("mscale_all_dim", 0.0))))
@@ -93,7 +106,8 @@ def position_scale(positions, rope: Optional[Dict]):
 
 
 def cos_sin(positions, dim: int, rope: Optional[Dict] = None):
-    """positions (...,) int -> cos, sin (..., dim/2) float32."""
+    """positions (...,) int -> cos, sin (..., dim/2) float32, for the `dim`
+    values that are rotated (`rotary_dim` of a head)."""
     ang = positions.astype(jnp.float32)[..., None] * jnp.asarray(
         inv_freq(dim, rope), jnp.float32)
     s = table_scale(rope)
@@ -118,9 +132,13 @@ def rotate_half_split(x, cos, sin):
     layout of the published rotary models), rotated by the angles of cos /
     sin (..., dim/2) — broadcast against x's leading dims by the caller.
     Computed in float32, returned in x's type; the halves stay where they
-    were."""
+    were. Tables narrower than dim/2 (`partial_rotary_factor`) rotate the
+    leading 2 * their width values, pairs (x[j], x[j + width]), and the
+    rest of x passes through."""
     xf = x.astype(jnp.float32)
-    half = xf.shape[-1] // 2
-    a, b = xf[..., :half], xf[..., half:]
-    return jnp.concatenate([a * cos - b * sin, a * sin + b * cos],
-                           axis=-1).astype(x.dtype)
+    half = cos.shape[-1]
+    a, b = xf[..., :half], xf[..., half:2 * half]
+    parts = [a * cos - b * sin, a * sin + b * cos]
+    if 2 * half < xf.shape[-1]:
+        parts.append(xf[..., 2 * half:])
+    return jnp.concatenate(parts, axis=-1).astype(x.dtype)

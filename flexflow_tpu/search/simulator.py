@@ -94,7 +94,7 @@ TP_WEIGHT_SHARD_DIMS = {
     OpType.LINEAR: {"kernel": -1, "bias": 0},
     OpType.EMBEDDING: {"weight": -1},
     OpType.MULTIHEAD_ATTENTION: {
-        "wq": 1, "wk": 1, "wv": 1, "wo": 0,
+        "wq": 1, "wk": 1, "wv": 1, "wo": 0, "wg": 1,
         "bq": 0, "bk": 0, "bv": 0,
     },
 }

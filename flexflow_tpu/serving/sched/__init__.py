@@ -35,17 +35,19 @@ from .admission import (AdmissionController, AdmissionError, QueueFull,
 from .continuous import (BatcherStopped, ContinuousBatcher, GenRequest,
                          RequestCancelled, RequestState, ResizeTicket)
 from .kvpool import (PagedKVPool, PoolExhausted, PrefixCache,
-                     SequenceStateUnsupported, derive_num_slots,
-                     kv_bytes_per_token, kv_cache_spec, prefix_route_chain,
-                     prefix_route_key, state_bytes_per_slot, write_slot_span,
-                     write_slot_state, zero_kv_caches)
+                     RingCacheUnsupported, SequenceStateUnsupported,
+                     derive_num_slots, kv_bytes_per_token, kv_cache_spec,
+                     prefix_route_chain, prefix_route_key,
+                     ring_bytes_per_slot, state_bytes_per_slot,
+                     write_slot_span, write_slot_state, zero_kv_caches)
 
 __all__ = [
     "AdmissionController", "AdmissionError", "QueueFull", "PoolSaturated",
     "RequestTooLarge", "BatcherStopped", "ContinuousBatcher", "GenRequest",
     "RequestCancelled", "RequestState", "ResizeTicket", "PagedKVPool",
     "PoolExhausted", "PrefixCache", "SLOExceeded",
-    "SequenceStateUnsupported", "derive_num_slots", "kv_bytes_per_token",
+    "RingCacheUnsupported", "SequenceStateUnsupported", "derive_num_slots",
+    "kv_bytes_per_token", "ring_bytes_per_slot",
     "kv_cache_spec", "prefix_route_chain", "prefix_route_key",
     "state_bytes_per_slot", "write_slot_span", "write_slot_state",
     "zero_kv_caches",

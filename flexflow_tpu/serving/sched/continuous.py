@@ -69,7 +69,8 @@ from ...runtime.executor import NO_ROW
 from ..batcher import BatcherStopped
 from .admission import AdmissionController
 from .kvpool import (PagedKVPool, derive_num_slots, install_slot,
-                     kv_bytes_per_token, op_states, refuse_sequence_state,
+                     kv_bytes_per_token, kv_cache_spec, op_states,
+                     refuse_ring, refuse_sequence_state, ring_bytes_per_slot,
                      state_bytes_per_slot, write_slot_span, zero_kv_caches)
 
 
@@ -464,12 +465,18 @@ class ContinuousBatcher:
             op.name: frozenset(op.sequence_state_arrays() or ())
             for op in self.attn_ops}
         self._stateful = any(self._seq_parts.values())
+        # so is a window op's ring (kvpool.py's third kind): no page names
+        # its rows
+        self._rings = {c.op: c.token_rows(self.max_len)
+                       for c in kv_cache_spec(model) if c.ring is not None}
+        self._unpaged = self._stateful or bool(self._rings)
         if role != "unified":
-            refuse_sequence_state(
-                model, f"role={role!r} (KV export/import between replicas)")
+            self._refuse_unpaged(
+                f"role={role!r} (KV export/import between replicas)")
         if draft_model is not None:
-            refuse_sequence_state(model, "speculative decoding")
+            self._refuse_unpaged("speculative decoding")
             refuse_sequence_state(draft_model, "speculative decoding")
+            refuse_ring(draft_model, "speculative decoding")
 
         # speculative decoding (docs/serving.md): a draft model proposes
         # `spec_tokens` greedy candidates per slot per iteration, the
@@ -547,11 +554,11 @@ class ContinuousBatcher:
         full_pages_per_slot = self.max_len // int(page_size)
         if prefix_cache_pages is None:
             prefix_pages = 2 * pages_per_slot if (
-                self.prefill_chunk_tokens and not self._stateful) else 0
+                self.prefill_chunk_tokens and not self._unpaged) else 0
         else:
             prefix_pages = int(prefix_cache_pages)
             if prefix_pages:
-                refuse_sequence_state(model, "the prefix cache")
+                self._refuse_unpaged("the prefix cache")
         if prefix_pages and not self.prefill_chunk_tokens:
             raise ValueError(
                 "prefix caching requires chunked prefill"
@@ -641,6 +648,17 @@ class ContinuousBatcher:
             "Bytes of per-sequence state one slot costs across all caching"
             " ops, whatever its sequence's length", labels=("pool",)).set(
                 state_bytes_per_slot(model), pool=self.pool.label)
+        registry.gauge(
+            "ff_kvpool_ring_bytes_per_slot",
+            "Bytes of window rings one slot costs across all caching ops,"
+            " whatever its sequence's length", labels=("pool",)).set(
+                ring_bytes_per_slot(model, self.max_len),
+                pool=self.pool.label)
+        g_ring = registry.gauge(
+            "ff_kvpool_ring_rows",
+            "Token rows of a window op's ring", labels=("pool", "op"))
+        for name, rows in self._rings.items():
+            g_ring.set(rows, pool=self.pool.label, op=name)
         # admissions of a model that keeps per-sequence state: each starts
         # its sequence from a zeroed batch-1 state that later overwrites
         # the slot's (`_admit_new`); `op_counters` reports it per op
@@ -730,6 +748,12 @@ class ContinuousBatcher:
                 "EWMA draft-token acceptance rate (accepted/proposed)",
                 labels=("pool",))
 
+    def _refuse_unpaged(self, feature: str) -> None:
+        """Typed refusal of what addresses cache rows by token position,
+        for a model one of whose ops keeps per-sequence state or a ring."""
+        refuse_sequence_state(self.model, feature)
+        refuse_ring(self.model, feature)
+
     # -- jitted device functions ------------------------------------------
     def _zero_caches(self):
         # zero_kv_caches allocates the SAME geometry (kv_cache_spec)
@@ -764,10 +788,12 @@ class ContinuousBatcher:
         as position plen-1 <= max_len-1, and without the slack
         `dynamic_update_slice` would CLAMP that write at the array edge,
         silently shifting real prompt K/V rows (pinned by
-        tests/test_prefix_cache.py::test_chunked_prefill_last_chunk_never_clamps)."""
-        rows = self.max_len + max(0, self.prefill_chunk_tokens - 1)
+        tests/test_prefix_cache.py::test_chunked_prefill_last_chunk_never_clamps).
+        A window op's holder is its ring, as the pool's: its op places the
+        chunk's real rows alone, so it takes no slack."""
         return zero_kv_caches(
-            model if model is not None else self.model, 1, rows)
+            model if model is not None else self.model, 1, self.max_len,
+            slack=max(0, self.prefill_chunk_tokens - 1))
 
     def _build_fns(self):
         import jax
@@ -874,14 +900,14 @@ class ContinuousBatcher:
             """Batch-1 -> pool-slot install, shared by the target's
             fused finish AND the draft's: each per-token array's first
             max_len rows (the batch-1 caches carry chunk-1 slack rows, see
-            _zero_small, that must not spill into the pool slot) and each
-            per-sequence array whole — which is what resets a reused
-            slot's state."""
+            _zero_small, that must not spill into the pool slot), a ring
+            whole, and each per-sequence array whole — which is what
+            resets a reused slot's state."""
             out = {}
             with jax.named_scope("kv:scatter_span"):
                 for name in attn_names_:
                     out[name] = install_slot(
-                        pool_caches[name], small[name], slot, max_len,
+                        pool_caches[name], small[name], slot,
                         seq_parts.get(name, ()))
             return out
 
@@ -1217,7 +1243,7 @@ class ContinuousBatcher:
         migrating every live sequence's OWNED cache rows into the new
         arrays, so in-flight requests keep decoding token-identically.
         Returns a ResizeTicket; `.wait()` blocks until applied."""
-        refuse_sequence_state(self.model, "a live resize")
+        self._refuse_unpaged("a live resize")
         if num_slots is None and machine is None:
             raise ValueError("give num_slots or a machine spec")
         if num_slots is None:
@@ -1258,7 +1284,7 @@ class ContinuousBatcher:
         (plen, heads*dim) host array of exactly the rows the page table
         owns. The request STAYS parked — a failed ship can still
         resume_parked with nothing lost."""
-        refuse_sequence_state(self.model, "KV export")
+        self._refuse_unpaged("KV export")
         ticket = HandoffTicket()
         with self._cv:
             if not self._running:
@@ -1282,7 +1308,7 @@ class ContinuousBatcher:
         the new GenRequest; fails typed — AdmissionError subclasses when
         this replica sheds, `KVGeometryMismatch` when the exporter's
         page regime differs (kvpool.py)."""
-        refuse_sequence_state(self.model, "KV import")
+        self._refuse_unpaged("KV import")
         ticket = HandoffTicket()
         payload = {"desc": desc, "rows": rows,
                    "prompt": np.asarray(prompt, np.int32),

@@ -2,8 +2,8 @@
 
 Physical layout vs logical pages
 --------------------------------
-The device arrays backing the pool are slot-dense, and of TWO kinds
-(`kv_cache_spec` is the geometry of both, `zero_kv_caches` the one
+The device arrays backing the pool are slot-dense, and of THREE kinds
+(`kv_cache_spec` is the geometry of all, `zero_kv_caches` the one
 allocation):
 
  - PER-TOKEN arrays, those a caching op's `kv_cache_arrays()` names, each
@@ -16,6 +16,16 @@ allocation):
    per iteration). `write_slot_span` is the one span write every holder of
    such arrays uses. Pages, the prefix cache, speculation's rollback,
    export/import and resize all address these rows by token position.
+ - RINGS: the per-token arrays of an op that declares `kv_ring_rows()`
+   (an attention that looks back a window only), each ``(num_slots, R,
+   width)`` with R the ring's rows, however long `max_len` is: position p
+   lives in row p mod R and the op masks by the position a row holds
+   (ops/attention.py), so a previous tenant's rows need no reset either. A
+   ring is a fixed cost a slot (`ring_bytes_per_slot`); a batch-1 prefill
+   holder keeps the same ring, so `install_slot` copies it whole. No page
+   names a ring's rows: the prefix cache, speculation's rollback,
+   export/import and resize are refused for such a model
+   (`refuse_ring`).
  - PER-SEQUENCE arrays, those `sequence_state_arrays()` names, each
    ``(num_slots,) + shape`` — a state-space mixer's recurrent state and
    convolution tail: a fixed cost a slot, whatever the sequence's length
@@ -57,8 +67,8 @@ compute and TTFT, tracked by `ff_kvpool_pages_saved`.
 Capacity comes from the machine spec's HBM through the SAME memory model
 the plan sanitizer gates compiles with (`analysis.plan_memory_bytes`):
 HBM minus the model's inference footprint, divided by what a slot costs:
-KV bytes per token times ``max_len``, plus the per-sequence state's fixed
-bytes (`derive_num_slots`).
+KV bytes per token times ``max_len``, plus the fixed bytes of its rings and
+of its per-sequence state (`derive_num_slots`).
 """
 from __future__ import annotations
 
@@ -114,6 +124,23 @@ class SequenceStateUnsupported(ValueError):
             f"{feature} is not available: op {op_name!r} keeps state per"
             " sequence (a recurrent state no token position addresses),"
             " and nothing snapshots, rolls back or ships it yet")
+
+
+class RingCacheUnsupported(ValueError):
+    """`SequenceStateUnsupported`'s sibling for a model one of whose ops
+    keeps its per-token rows in a RING (`Op.kv_ring_rows`): a prefix hit
+    would have to restore the ring as it stood at the page boundary, a
+    rejected draft has overwritten rows the window still needs, an export,
+    import or resize would ship the ring beside the pages. Typed and
+    raised where the feature is asked for."""
+
+    def __init__(self, feature: str, op_name: str):
+        self.feature = feature
+        self.op_name = op_name
+        super().__init__(
+            f"{feature} is not available: op {op_name!r} keeps a ring of"
+            " its window's rows (no page addresses them), and nothing"
+            " restores, rolls back or ships a ring yet")
 
 
 def _chain_key(parent: bytes, block: np.ndarray) -> bytes:
@@ -770,26 +797,36 @@ class PagedKVPool:
 
 class OpCache(NamedTuple):
     """One caching op's geometry: what it stores per token ({array: values
-    a token stores}, each array (rows, max_len, width) of `dtype`) and per
-    sequence ({array: (shape after the row axis, jnp dtype)})."""
+    a token stores}, each array (rows, `token_rows(max_len)`, width) of
+    `dtype`), per sequence ({array: (shape after the row axis, jnp
+    dtype)}), and `ring`: the rows of the op's ring (None: one row a
+    position)."""
     op: str
     per_token: Dict[str, int]
     dtype: object
     per_sequence: Dict[str, tuple]
+    ring: Optional[int] = None
+
+    def token_rows(self, max_len: int) -> int:
+        """Token rows the per-token arrays keep for a sequence of up to
+        `max_len` tokens (a ring never needs more than the sequence has)."""
+        return int(max_len) if self.ring is None else min(self.ring,
+                                                          int(max_len))
 
 
 def kv_cache_spec(model) -> List[OpCache]:
     """An `OpCache` for every op that keeps a serving cache of either kind
     (`Op.kv_cache_arrays`, `Op.sequence_state_arrays`) — THE cache geometry.
-    Per token, op `name` stores each named array as (rows, max_len, width):
+    Per token, op `name` stores each named array as (rows, max_len, width)
+    — (rows, R, width) where the op declares a ring of R rows —:
     a `multihead_attention` `k_cache` and `v_cache` of kv_heads*kdim and
     kv_heads*vdim; a latent attention `c_kv` of kv_lora_rank and `k_rope`,
     the rotary key padded to a 128-lane tile (ops/latent_attention.py says
     why). Per sequence, (rows,) + shape: a state-space mixer's `ssm_state`
     in the op's `state_dtype` (float32 unless the model states bfloat16;
     stepped in float32 either way) and `conv_tail` (ops/ssm.py). Shared by
-    pool sizing (`kv_bytes_per_token`, `state_bytes_per_slot`) and the one
-    allocation
+    pool sizing (`kv_bytes_per_token`, `ring_bytes_per_slot`,
+    `state_bytes_per_slot`) and the one allocation
     (`zero_kv_caches`: the ContinuousBatcher's slot, band, draft and batch-1
     caches, GenerativeSession's lockstep caches), so the HBM estimate can
     never drift from what actually gets allocated. `dtype` is the op's
@@ -808,7 +845,8 @@ def kv_cache_spec(model) -> List[OpCache]:
         out.append(OpCache(
             op.name, dict(arrays), cdt,
             {part: (tuple(shape), cdt if dt is None else dt.jnp_dtype)
-             for part, (shape, dt) in held.items()}))
+             for part, (shape, dt) in held.items()},
+            op.kv_ring_rows() if arrays else None))
     if not out:
         raise ValueError(
             "model has no op that keeps a serving cache (an attention op"
@@ -825,15 +863,30 @@ def refuse_sequence_state(model, feature: str) -> None:
             raise SequenceStateUnsupported(feature, op.name)
 
 
-def zero_kv_caches(model, rows: int, max_len: int) -> Dict[str, Dict]:
-    """{op_name: {array name: zeros}} AS STORED, both kinds: `rows`
+def refuse_ring(model, feature: str) -> None:
+    """Raise `RingCacheUnsupported` naming the first op of `model` that
+    keeps a ring; a no-op for every other model."""
+    for c in kv_cache_spec(model):
+        if c.ring is not None:
+            raise RingCacheUnsupported(feature, c.op)
+
+
+def zero_kv_caches(model, rows: int, max_len: int,
+                   slack: int = 0) -> Dict[str, Dict]:
+    """{op_name: {array name: zeros}} AS STORED, every kind: `rows`
     sequences (pool slots, band rows, or 1), per-token arrays of `max_len`
-    token rows of the width their op declares, per-sequence arrays of their
-    declared shape. The only place that writes the stored shapes out."""
+    token rows (+ `slack` more: a batch-1 prefill holder's room for its
+    last chunk's padded write) of the width their op declares — a ring of
+    its own rows and no slack, its op places only real rows —,
+    per-sequence arrays of their declared shape. The only place that
+    writes the stored shapes out."""
     import jax.numpy as jnp
 
+    def token_rows(c):
+        return c.token_rows(max_len) + (slack if c.ring is None else 0)
+
     return {
-        c.op: {**{part: jnp.zeros((rows, max_len, width), c.dtype)
+        c.op: {**{part: jnp.zeros((rows, token_rows(c), width), c.dtype)
                   for part, width in c.per_token.items()},
                **{part: jnp.zeros((rows,) + shape, dt)
                   for part, (shape, dt) in c.per_sequence.items()}}
@@ -870,24 +923,38 @@ def write_slot_state(cache, state, slot):
         cache, state.astype(cache.dtype), (slot,) + (0,) * (cache.ndim - 1))
 
 
-def install_slot(pool, small, slot, max_len: int, per_sequence=()):
+def install_slot(pool, small, slot, per_sequence=()):
     """One op's pool arrays with a batch-1 holder's written over slot
-    `slot`: the leading `max_len` token rows of each per-token array (the
-    holder may carry slack rows past them), each array named in
-    `per_sequence` whole."""
+    `slot`: of each per-token array the token rows the pool's has (the
+    holder may carry slack rows past them; a ring is the same ring in
+    both, so the tail of the prefill lands at its ring places), each array
+    named in `per_sequence` whole."""
     return {part: (write_slot_state(arr, small[part], slot)
                    if part in per_sequence
-                   else write_slot_span(arr, small[part][:, :max_len], slot))
+                   else write_slot_span(
+                       arr, small[part][:, :arr.shape[1]], slot))
             for part, arr in pool.items()}
 
 
 def kv_bytes_per_token(model) -> int:
-    """Bytes of cache one token position costs across every caching op
-    (see kv_cache_spec for the geometry/dtype contract)."""
+    """Bytes of cache one more token position costs across every caching
+    op that keeps a row a position (see kv_cache_spec for the
+    geometry/dtype contract); a ring costs its slot the same however long
+    the sequence (`ring_bytes_per_slot`)."""
     import jax.numpy as jnp
 
     return sum(sum(c.per_token.values()) * jnp.dtype(c.dtype).itemsize
-               for c in kv_cache_spec(model))
+               for c in kv_cache_spec(model) if c.ring is None)
+
+
+def ring_bytes_per_slot(model, max_len: int) -> int:
+    """Bytes of rings one slot costs across every op that keeps one, in a
+    pool of `max_len`-token slots (0 for a model without a window)."""
+    import jax.numpy as jnp
+
+    return sum(sum(c.per_token.values()) * c.token_rows(max_len)
+               * jnp.dtype(c.dtype).itemsize
+               for c in kv_cache_spec(model) if c.ring is not None)
 
 
 def state_bytes_per_slot(model) -> int:
@@ -903,7 +970,8 @@ def state_bytes_per_slot(model) -> int:
 def derive_num_slots(model, max_len: int, machine=None,
                      max_slots: int = 64, min_slots: int = 1) -> int:
     """Slots the machine's HBM can hold: (HBM - model inference footprint)
-    / (KV bytes per token x max_len + per-sequence state bytes a slot). The
+    / (KV bytes per token x max_len + ring and per-sequence state bytes a
+    slot). The
     model footprint comes from the
     SAME memory model the plan sanitizer's FFTA010 fit gate uses
     (`analysis.plan_memory_bytes`, optimizer_state_factor=1 — serving
@@ -921,6 +989,7 @@ def derive_num_slots(model, max_len: int, machine=None,
         model.graph, machine, model.config, optimizer_state_factor=1.0)
     free = machine.memory_budget_bytes() - model_bytes
     per_slot = (kv_bytes_per_token(model) * int(max_len)
+                + ring_bytes_per_slot(model, max_len)
                 + state_bytes_per_slot(model))
     slots = int(free // per_slot) if per_slot > 0 else min_slots
     return max(int(min_slots), min(int(max_slots), slots))

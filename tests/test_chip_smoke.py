@@ -26,7 +26,7 @@ TOY = chip_smoke.Sizes(
     search_layers=1, search_budget=2, search_devices=2,
     slots=2, window=24, max_len=32, page_size=8, prompts=(5, 20, 9),
     new_tokens=3, latent=(4, 32, 16, 8, 8, 16),
-    hybrid=(4, 2, 8, 32, 4, 8, 2), mesh_batch=4, mesh_layers=1, mesh_steps=3)
+    hybrid=(4, 2, 8, 32, 4, 8, 2), window_pair=(4, 8, 2, 8, 6), mesh_batch=4, mesh_layers=1, mesh_steps=3)
 
 
 @pytest.fixture(scope="module")
@@ -35,7 +35,7 @@ def clock():
 
 
 @pytest.mark.parametrize("phase", ["train", "kernels", "search", "serve",
-                                   "latent", "hybrid", "mesh"])
+                                   "latent", "hybrid", "window", "mesh"])
 def test_phase_at_toy_width(phase, clock, capsys):
     """Each phase runs end to end and prints its one JSON line. On this
     backend the kernels run interpreted and the search measures CPU op
@@ -76,6 +76,11 @@ def test_phase_at_toy_width(phase, clock, capsys):
         # 3 prompts through 2 slots: one slot reused, its state reset
         assert printed["token_parity"] == "3/3 identical"
         assert printed["state_resets"] == 3
+    elif phase == "window":
+        # 3 prompts through 2 slots: one slot's ring reused; the prompt of
+        # 20 tokens wraps a ring of 6 rows three times
+        assert printed["token_parity"] == "3/3 identical"
+        assert printed["ring_rows"] == 6
     else:
         assert printed["dp_x_tp"]["mesh_devices"] == 4
         assert printed["dp_x_tp"]["params"]["devices"] == [0, 1, 2, 3]
@@ -86,7 +91,8 @@ def test_phase_at_toy_width(phase, clock, capsys):
 
 
 @pytest.mark.parametrize("argv,phases", [
-    ([], ["train", "kernels", "search", "serve", "latent", "hybrid"]),
+    ([], ["train", "kernels", "search", "serve", "latent", "hybrid",
+          "window"]),
     (["--chips", "4", "--seed", "3"], ["mesh"]),
 ])
 def test_main_runs_the_right_phases_and_ends_with_the_ok_line(
@@ -97,7 +103,8 @@ def test_main_runs_the_right_phases_and_ends_with_the_ok_line(
     devices = jax.devices()
     monkeypatch.setattr(chip_smoke, "require_tpu",
                         lambda what, chips: devices)
-    for name in ("train", "kernels", "search", "serve", "hybrid", "mesh"):
+    for name in ("train", "kernels", "search", "serve", "hybrid", "window",
+                 "mesh"):
         monkeypatch.setattr(chip_smoke, f"phase_{name}",
                             lambda *a, _n=name: {"stub": _n})
     assert chip_smoke.main(argv) == 0

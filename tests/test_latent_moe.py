@@ -378,10 +378,15 @@ _COUNTERS = ("assignments", "experts_hit", "steps", "load", "few_rows_steps")
 
 def _experts_case(case, path, monkeypatch):
     """(model, op, inputs [x, w, idx], weights) of one case, the op steered
-    down `path` ('few_rows' | 'grouped') through the predicate's threshold."""
+    down `path` ('few_rows' | 'grouped' | 'tiled') through the predicate's
+    threshold; 'tiled' is few rows with the registry's family forced to the
+    Pallas grouped matmul (interpreted here)."""
+    from flexflow_tpu.kernels.registry import KERNELS
+
     tokens, forced = _SKEWS[case]
-    monkeypatch.setattr(moe, "FEW_ROWS_MAX",
-                        10**9 if path == "few_rows" else 0)
+    monkeypatch.setattr(moe, "FEW_ROWS_MAX", 0 if path == "grouped" else 10**9)
+    if path == "tiled":
+        monkeypatch.setitem(KERNELS._overrides, "grouped_experts", "pallas")
     cfg = tiny_cfg()
     m, router, experts = _experts_op(cfg, (2, 4), tokens)
     x = jax.random.normal(jax.random.PRNGKey(0), (tokens, 64), jnp.float32)
@@ -394,12 +399,12 @@ def _experts_case(case, path, monkeypatch):
     return m, experts, [x, w, idx], _weights_of(experts, 2)
 
 
-@pytest.mark.parametrize("path", ["few_rows", "grouped"])
+@pytest.mark.parametrize("path", ["few_rows", "grouped", "tiled"])
 @pytest.mark.parametrize("case", list(_SKEWS))
 def test_grouped_experts_equal_the_masked_oracle_and_drop_nothing(
         case, path, monkeypatch):
-    """Both forms of the routed product, on skews they treat differently,
-    equal the masked oracle; the four counters read the same on both, and
+    """All forms of the routed product, on skews they treat differently,
+    equal the masked oracle; the four counters read the same on each, and
     `few_rows_steps` counts the few-rows form alone."""
     m, experts, ins, ew = _experts_case(case, path, monkeypatch)
     x, w, idx = ins
@@ -450,6 +455,29 @@ def test_few_rows_is_chosen_from_the_static_rows_alone():
         moe.FEW_ROWS_MAX)
     assert not moe.few_rows(moe.FEW_ROWS_MAX + 1) and not moe.few_rows(8192)
     assert GatedExpertsOp.serving_counters == _COUNTERS
+
+
+def test_tiled_grouped_is_chosen_from_platform_shape_and_mode(monkeypatch):
+    """The Pallas grouped matmul takes a step of few rows on a TPU alone,
+    past the ridge, where one expert's matrix is one VMEM block
+    (`laguna_xs2_1chip`'s chunk of 512 rows, not its 40 decode rows, not
+    `mistral_small4_ep4`'s 4096 x 2048 experts), and never a training step
+    (rows of no group are unwritten: a gradient would read them)."""
+    from flexflow_tpu.kernels import registry
+
+    lgx, ms4 = 2048 * 512 * 2, 4096 * 2048 * 2
+    assert registry.small_experts(512, lgx)
+    assert not registry.small_experts(40, lgx)
+    assert not registry.small_experts(registry.EXPERT_RIDGE_ROWS, lgx)
+    assert not registry.small_experts(512, ms4)
+    matrix = jnp.zeros((4, 2048, 512), jnp.bfloat16)
+    tiled = lambda mode, rows=512: GatedExpertsOp._tiled(
+        LoweringContext(ff.FFConfig(), mode), rows, matrix, jnp.bfloat16)
+    assert not tiled(CompMode.COMP_MODE_INFERENCE)     # the CPU backend
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert tiled(CompMode.COMP_MODE_INFERENCE)
+    assert not tiled(CompMode.COMP_MODE_INFERENCE, rows=40)
+    assert not tiled(CompMode.COMP_MODE_TRAINING)
 
 
 def test_four_shares_and_the_shared_expert_add_up_to_the_uncut_layer():

@@ -314,6 +314,18 @@ DECISIONS = {
                                       "reference", "override"),
     "gpu-latent": ("gpu", "latent_decode", None, None, None,
                    "reference", "backend"),
+    # routed experts over few token rows (rows, bytes of one matrix): the
+    # tiled grouped matmul past the ridge where a matrix is one VMEM block
+    "tpu-experts-lgx-chunk": ("tpu", "grouped_experts", None, None,
+                              (512, 2048 * 512 * 2), "pallas", "shape"),
+    "tpu-experts-lgx-decode": ("tpu", "grouped_experts", None, None,
+                               (40, 2048 * 512 * 2), "reference", "shape"),
+    "tpu-experts-ms4-chunk": ("tpu", "grouped_experts", None, None,
+                              (512, 4096 * 2048 * 2), "reference", "shape"),
+    "cpu-experts-lgx-chunk": ("cpu", "grouped_experts", None, None,
+                              (512, 2048 * 512 * 2), "reference", "backend"),
+    "cpu-experts-override": ("cpu", "grouped_experts", None, "pallas",
+                             (40, 2048 * 512 * 2), "pallas", "override"),
 }
 
 
@@ -324,8 +336,9 @@ def test_select_decision_table(case):
     forced = (KERNELS.override(family, override) if override
               else contextlib.nullcontext())
     with mock.patch.object(jax, "default_backend", lambda: platform), forced:
-        choice = KERNELS.select(family, param=use_flash, scores=scores,
-                                record=False)
+        shape = "experts" if family == "grouped_experts" else "scores"
+        choice = KERNELS.select(family, param=use_flash, record=False,
+                                **{shape: scores})
     assert (choice.impl, choice.reason) == (impl, reason)
     assert bool(choice) == (impl == "pallas")
 

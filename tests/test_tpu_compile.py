@@ -27,6 +27,7 @@ from flexflow_tpu.kernels.flash_attention import (  # noqa: E402
 from flexflow_tpu.kernels.pallas import (  # noqa: E402
     fused_decode_attention, fused_multiquery_decode_attention,
     latent_decode_attention)
+from flexflow_tpu.ops.moe import _grouped_product, _tiled_dot  # noqa: E402
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 BERT = (8, 512, 1024)   # batch, seq, hidden of the bench config
@@ -67,6 +68,16 @@ def _decode(fn):
     return lambda q, k, v, pos: fn(q, k, v, pos, scale=HEAD_DIM ** -0.5)
 
 
+def _tiled_experts(x, w, idx, wg, wu, wd):
+    from unittest import mock
+
+    # traced as on the chip: compiled, not interpreted
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        return _grouped_product(
+            x, w, idx, {"w_gate": wg, "w_up": wu, "w_down": wd}, 0, 256,
+            dot=_tiled_dot)[0]
+
+
 _CACHE = ((SLOTS, ROWS, HEADS * HEAD_DIM), BF16)  # as the pool stores it
 # name -> (function, [(shape, dtype), ...])
 CASES = {
@@ -103,6 +114,13 @@ CASES = {
         latent_decode_attention,
         [((8, 32, 256), F32), ((8, 32, 128), F32), ((8, 256, 256), F32),
          ((8, 256, 128), F32), ((8,), I32), ((8,), F32)]),
+    # lgx_decode_sat's prefill chunk: 512 rows x 8 through 256 held experts
+    # of 2048 x 512, bf16 (the three tiled grouped matmuls)
+    "tiled_experts_chunk": (
+        _tiled_experts,
+        [((512, 2048), BF16), ((512, 8), F32), ((512, 8), I32),
+         ((256, 2048, 512), BF16), ((256, 2048, 512), BF16),
+         ((256, 512, 2048), BF16)]),
 }
 
 
@@ -284,7 +302,35 @@ CELL_PROGRAMS = [
     ("falcon_h1_34b_1chip", "decode_all", 0),
     ("falcon_h1_34b_1chip", "prefill_chunk", 0),
     ("falcon_h1_34b_1chip", "prefill_last_chunk", 0),
+    # a window layer's ring beside a full layer's cache, the gate and the
+    # sigmoid router are XLA's too
+    ("laguna_xs2_1chip", "decode_all", 0),
+    ("laguna_xs2_1chip", "prefill_chunk", 0),
+    ("laguna_xs2_1chip", "prefill_last_chunk", 0),
 ]
+# (argument bytes, temporary bytes) of each, by `memory_analysis()` at the
+# sizes `cell_programs` builds: what the parent commit of PR 33 compiled to,
+# read on both trees before `window`, `head_gate`, `partial_rotary_factor`
+# and `scoring` went in — at their defaults the programs are the same
+CELL_PROGRAM_BYTES = {
+    ("bert_osdi22", "multi_step"): (105073152, 121645056),
+    ("lm_osdi22w", "decode_all"): (71371776, 1225728),
+    ("lm_osdi22w", "prefill_chunk"): (13116416, 0),
+    ("lm_osdi22w", "prefill_last_chunk"): (73986048, 2193408),
+    ("mistral_small4_ep4", "decode_all"): (98597888, 0),
+    ("mistral_small4_ep4", "prefill_chunk"): (7071232, 0),
+    ("mistral_small4_ep4", "prefill_last_chunk"): (98841088, 3193344),
+    ("falcon_h1_34b_1chip", "decode_all"): (891956736, 0),
+    ("falcon_h1_34b_1chip", "prefill_chunk"): (113285120, 0),
+    ("falcon_h1_34b_1chip", "prefill_last_chunk"): (894750208, 1290240),
+    ("laguna_xs2_1chip", "decode_all"): (313023488, 5186560),
+    ("laguna_xs2_1chip", "prefill_chunk"): (172438528, 0),
+    ("laguna_xs2_1chip", "prefill_last_chunk"): (315381760, 4584448),
+}
+# layers a configuration is cut to here: one, unless its first layer is not
+# its usual one (laguna's is dense and full: the second is a window layer
+# over experts)
+CELL_LAYERS = {"laguna_xs2_1chip": 2}
 
 
 @pytest.fixture(scope="module")
@@ -329,7 +375,8 @@ def cell_programs(v5e):
     def get(name, vocab_size=512):
         if (name, vocab_size) not in built:
             cfg = harness.load_config(name)
-            cfg.update(num_hidden_layers=1, vocab_size=vocab_size)
+            cfg.update(num_hidden_layers=CELL_LAYERS.get(name, 1),
+                       vocab_size=vocab_size)
             with mock.patch.object(jax, "default_backend", lambda: "tpu"):
                 if cfg["runner"] == "train_fit":
                     built[name, vocab_size] = bert(cfg)
@@ -337,6 +384,8 @@ def cell_programs(v5e):
                     if "n_routed_experts" in cfg:
                         cfg.update(n_routed_experts=4,
                                    moe_intermediate_size=256)
+                    if "num_experts" in cfg:     # holds all, chooses 8
+                        cfg.update(num_experts=16, n_routed_experts=16)
                     cfg["deployment"] = dict(cfg["deployment"], **small)
                     built[name, vocab_size] = programs(cfg, v5e)
         return built[name, vocab_size]
@@ -353,14 +402,18 @@ def test_cell_program_holds_the_cells_kernel_choice(
     training step; in the serving programs the latent decode kernel in
     `mistral_small4_ep4`'s decode step and no Pallas call anywhere else
     (dense attention is the reference chain, its decode kernels wait
-    behind `KERNELS.override`; prefill keeps the expanded path)."""
+    behind `KERNELS.override`; prefill keeps the expanded path), and to
+    the bytes it is pinned at (`CELL_PROGRAM_BYTES`)."""
     from unittest import mock
 
     fn, args = cell_programs(config)[program]
     with mock.patch.object(jax, "default_backend", lambda: "tpu"):
         jax.clear_caches()
-        text = fn.lower(*args).compile().as_text()
-    assert text.count("tpu_custom_call") == custom_calls_a_layer
+        compiled = fn.lower(*args).compile()
+    assert compiled.as_text().count("tpu_custom_call") == custom_calls_a_layer
+    ma = compiled.memory_analysis()
+    assert (ma.argument_size_in_bytes, ma.temp_size_in_bytes) == \
+        CELL_PROGRAM_BYTES[config, program]
 
 
 @pytest.mark.parametrize("config", sorted(
