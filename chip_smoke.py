@@ -489,6 +489,42 @@ def phase_serve(sizes: Sizes, seed: int) -> Dict:
             "token_parity": runs, "near_ties": len(near_ties)}
 
 
+def _served_both_ways(family: str, model, prompts, new_tokens: int,
+                       counting_op: str, **batcher_kw):
+    """`prompts` through a ContinuousBatcher, greedy, with the decode kernel
+    family forced to its reference and to its kernel: ({impl: tokens a
+    prompt}, {impl: `counting_op`'s row counters}, what else the last
+    batcher says: its rings and op counters). Either side must have been
+    selected by the decode step it was forced on."""
+    from flexflow_tpu.kernels.registry import KERNELS
+    from flexflow_tpu.obs.attention_rows import (
+        publish_attention_row_metrics)
+    from flexflow_tpu.serving.sched import ContinuousBatcher
+
+    outs, rows, last = {}, {}, {}
+    for impl in ("reference", "pallas"):
+        before = _selected(family)
+        with KERNELS.override(family, impl), ContinuousBatcher(
+                model, max_queue=len(prompts), **batcher_kw) as batcher:
+            handles = [batcher.submit(p, new_tokens) for p in prompts]
+            outs[impl] = [np.asarray(h.result(timeout=900.0))
+                          for h in handles]
+            last = {"rings": dict(batcher._rings),
+                    "counters": batcher.op_counters()}
+            rows[impl] = publish_attention_row_metrics(
+                model, batcher.registry, state=last["counters"])[counting_op]
+        selected = _selected(family, before)
+        assert selected[impl] > 0, (
+            f"{family} forced to {impl}, but the decode step never"
+            f" selected it: {selected}")
+    return outs, rows, last
+
+
+def _over_read(rows) -> Dict[str, float]:
+    return {impl: round(r["rows_read"] / r["rows_filled"], 3)
+            for impl, r in rows.items()}
+
+
 # ---------------------------------------------------------------------------
 # phase: latent
 # ---------------------------------------------------------------------------
@@ -498,10 +534,6 @@ def phase_latent(sizes: Sizes, seed: int) -> Dict:
     forced to its reference and to its kernel, token against token. The
     prompts' positions span several of the kernel's row blocks."""
     import flexflow_tpu as ff
-    from flexflow_tpu.kernels.registry import KERNELS
-    from flexflow_tpu.obs.latent_attention import (
-        publish_latent_attention_metrics)
-    from flexflow_tpu.serving.sched import ContinuousBatcher
 
     heads, q_rank, kv_rank, nope, rope, v_dim = sizes.latent
     config = ff.FFConfig()
@@ -522,22 +554,10 @@ def phase_latent(sizes: Sizes, seed: int) -> Dict:
     prompts = [rng.randint(1, sizes.vocab, size=(n,)).astype(np.int32)
                for n in sizes.prompts]
 
-    outs, rows = {}, {}
-    for impl in ("reference", "pallas"):
-        before = _selected("latent_decode")
-        with KERNELS.override("latent_decode", impl), ContinuousBatcher(
-                model, max_len=sizes.max_len, num_slots=sizes.slots,
-                page_size=sizes.page_size,
-                max_queue=len(prompts)) as batcher:
-            handles = [batcher.submit(p, sizes.new_tokens) for p in prompts]
-            outs[impl] = [np.asarray(h.result(timeout=900.0))
-                          for h in handles]
-            rows[impl] = publish_latent_attention_metrics(
-                model, batcher.registry, state=batcher.op_counters())["attn"]
-        selected = _selected("latent_decode", before)
-        assert selected[impl] > 0, (
-            f"latent_decode forced to {impl}, but the decode step never"
-            f" selected it: {selected}")
+    outs, rows, _ = _served_both_ways(
+        "latent_decode", model, prompts, sizes.new_tokens, "attn",
+        max_len=sizes.max_len, num_slots=sizes.slots,
+        page_size=sizes.page_size)
     near_ties: List[Dict] = []
     identical = _count_identical("latent_decode_forced", model,
                                  outs["pallas"], outs["reference"], prompts,
@@ -553,9 +573,7 @@ def phase_latent(sizes: Sizes, seed: int) -> Dict:
                         f" log-prob tolerance {NEAR_TIE_LOGPROB:g})",
             "token_parity": f"{identical}/{len(prompts)} identical",
             "near_ties": len(near_ties),
-            "rows_read_over_filled": {
-                impl: round(r["rows_read"] / r["rows_filled"], 3)
-                for impl, r in rows.items()}}
+            "rows_read_over_filled": _over_read(rows)}
 
 
 # ---------------------------------------------------------------------------
@@ -567,10 +585,11 @@ def phase_hybrid(sizes: Sizes, seed: int) -> Dict:
     ContinuousBatcher, greedy, against the lockstep GenerativeSession on
     the same weights: chunked prefill that carries the recurrent state
     (chunks that do not divide the scan's block), one prompt more than
-    there are slots, so one slot is REUSED and its state reset."""
+    there are slots, so one slot is REUSED and its state reset. The
+    attention's decode core (`attention_decode`) forced to its reference
+    chain and to its kernel, each against the session."""
     import flexflow_tpu as ff
     from flexflow_tpu.serving.generate import GenerativeSession
-    from flexflow_tpu.serving.sched import ContinuousBatcher
 
     heads, kv_heads, head_dim, d_ssm, ssm_heads, d_state, groups = \
         sizes.hybrid
@@ -604,17 +623,16 @@ def phase_hybrid(sizes: Sizes, seed: int) -> Dict:
             for p in prompts]
     del session
     chunk = 7 * sizes.page_size      # no multiple of the scan's block
-    with ContinuousBatcher(
-            model, max_len=sizes.max_len, num_slots=sizes.slots,
-            page_size=sizes.page_size, max_queue=len(prompts),
-            prefill_chunk_tokens=chunk) as batcher:
-        handles = [batcher.submit(p, sizes.new_tokens) for p in prompts]
-        outs = [np.asarray(h.result(timeout=900.0)) for h in handles]
-        counts = batcher.op_counters()["mixer"]
+    outs, rows, last = _served_both_ways(
+        "attention_decode", model, prompts, sizes.new_tokens, "attn",
+        max_len=sizes.max_len, num_slots=sizes.slots,
+        page_size=sizes.page_size, prefill_chunk_tokens=chunk)
+    counts = last["counters"]["mixer"]
     assert counts["state_resets"] == len(prompts) > sizes.slots
     near_ties: List[Dict] = []
-    identical = _count_identical("hybrid", model, outs, refs, prompts,
-                                 near_ties)
+    identical = min(_count_identical(f"hybrid_{impl}", model, outs[impl],
+                                     refs, prompts, near_ties)
+                    for impl in sorted(outs))
     _print_near_ties(near_ties)
     return {"model": f"ssm mixer {ssm_heads}h x {d_ssm // ssm_heads} x"
                      f" {d_state} beside attention {heads}/{kv_heads}h of"
@@ -622,10 +640,12 @@ def phase_hybrid(sizes: Sizes, seed: int) -> Dict:
                      f" {sizes.slots} slots x {sizes.max_len} rows, f32",
             "prompt_lengths": list(lengths), "new_tokens": sizes.new_tokens,
             "prefill_chunk_tokens": chunk,
-            "compared": "greedy tokens vs lockstep GenerativeSession"
+            "compared": "greedy tokens, attention's decode kernel and its"
+                        " reference chain, each vs lockstep GenerativeSession"
                         f" (near-tie log-prob tolerance {NEAR_TIE_LOGPROB:g})",
             "token_parity": f"{identical}/{len(prompts)} identical",
             "near_ties": len(near_ties),
+            "rows_read_over_filled": _over_read(rows),
             "state_resets": int(counts["state_resets"]),
             "ssm_steps": int(counts["ssm_steps"])}
 
@@ -641,10 +661,11 @@ def phase_window(sizes: Sizes, seed: int) -> Dict:
     the window layer's cache is a ring of its window's rows in the pool,
     the batch-1 prefill holder and the session alike, the longer prompts
     wrap it, and one prompt more than there are slots reuses a slot whose
-    ring the previous tenant left full."""
+    ring the previous tenant left full. The full layer's decode core
+    (`attention_decode`) forced to its reference chain and to its kernel,
+    each against the session; the ring returns before that choice."""
     import flexflow_tpu as ff
     from flexflow_tpu.serving.generate import GenerativeSession
-    from flexflow_tpu.serving.sched import ContinuousBatcher
 
     full_heads, swa_heads, kv_heads, head_dim, window = sizes.window_pair
     config = ff.FFConfig()
@@ -684,17 +705,17 @@ def phase_window(sizes: Sizes, seed: int) -> Dict:
             for p in prompts]
     del session
     chunk = 7 * sizes.page_size      # no divisor or multiple of the window
-    with ContinuousBatcher(
-            model, max_len=sizes.max_len, num_slots=sizes.slots,
-            page_size=sizes.page_size, max_queue=len(prompts),
-            prefill_chunk_tokens=chunk) as batcher:
-        handles = [batcher.submit(p, sizes.new_tokens) for p in prompts]
-        outs = [np.asarray(h.result(timeout=900.0)) for h in handles]
-        rings = dict(batcher._rings)
+    outs, rows, last = _served_both_ways(
+        "attention_decode", model, prompts, sizes.new_tokens, "l1_attn",
+        max_len=sizes.max_len, num_slots=sizes.slots,
+        page_size=sizes.page_size, prefill_chunk_tokens=chunk)
+    rings = last["rings"]
     assert rings == {"l0_swa": min(window, sizes.max_len)}
+    assert "l0_swa" not in last["counters"]   # a ring's rows follow from pos
     near_ties: List[Dict] = []
-    identical = _count_identical("window", model, outs, refs, prompts,
-                                 near_ties)
+    identical = min(_count_identical(f"window_{impl}", model, outs[impl],
+                                     refs, prompts, near_ties)
+                    for impl in sorted(outs))
     _print_near_ties(near_ties)
     return {"model": f"window-{window} attention {swa_heads}/{kv_heads}h"
                      f" (ring of {rings['l0_swa']} rows) under full attention"
@@ -703,10 +724,13 @@ def phase_window(sizes: Sizes, seed: int) -> Dict:
                      f" {sizes.max_len} rows, f32",
             "prompt_lengths": list(lengths), "new_tokens": sizes.new_tokens,
             "prefill_chunk_tokens": chunk, "ring_rows": rings["l0_swa"],
-            "compared": "greedy tokens vs lockstep GenerativeSession"
-                        f" (near-tie log-prob tolerance {NEAR_TIE_LOGPROB:g})",
+            "compared": "greedy tokens, the full layer's decode kernel and"
+                        " its reference chain, each vs lockstep"
+                        " GenerativeSession (near-tie log-prob tolerance"
+                        f" {NEAR_TIE_LOGPROB:g})",
             "token_parity": f"{identical}/{len(prompts)} identical",
-            "near_ties": len(near_ties)}
+            "near_ties": len(near_ties),
+            "rows_read_over_filled": _over_read(rows)}
 
 
 # ---------------------------------------------------------------------------

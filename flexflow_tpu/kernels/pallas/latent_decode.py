@@ -28,6 +28,11 @@ as an inner grid axis whose index map is clamped to `pos // block`, a
 skipped step still costs 0.3 us: 1.2 ms of a decode iteration's 2.8 at 128
 slots x 8 blocks x 6 layers, PERF.md section 6, PR 30).
 
+The walk over a slot's filled blocks (`block_rows`, `rows_read`,
+`filled_blocks`, `filled`) and the online softmax (`softmax_start`,
+`softmax_step`) are shared with the dense twin, decode.py
+`fused_decode_attention` (K and V in place of the two latent arrays).
+
 Positions and scales are scalar-prefetched to SMEM. Inference-only, so no
 VJP. `W_kvb` stays outside on both sides (the absorb and the value
 products are plain matmuls XLA handles).
@@ -71,20 +76,31 @@ def _nt(a, b):
                                preferred_element_type=jnp.float32)
 
 
-def _kernel(pos_ref, scale_ref, ql_ref, qr_ref, c_hbm, r_hbm, o_ref,
-            c_buf, r_buf, sem, turn_ref, acc_ref, m_ref, l_ref, *, block,
-            slots):
+def filled_blocks(pos, hbm, bufs, sem, turn_ref, *, block, slots, compute):
+    """Grid step `ib` is slot `ib`: walk the blocks of `block` rows that
+    hold a filled row of the slot, `pos // block + 1` of them, each copied
+    from every cache of `hbm` ((slots, max_len, width), left where it
+    lies) into one of the two halves of its `bufs` entry ((2, block,
+    width) VMEM) while the block before it is computed. `compute(buf,
+    first, edge)` is handed the half that holds the block, the block's
+    first row and whether it is the EDGE block — the one that holds row
+    `pos` and rows past it; the blocks before it are filled whole. No
+    block past `pos` is ever copied.
+
+    The slots take turns at the two halves across the whole grid, not
+    within a slot (`turn_ref`, SMEM (1,)): each slot starts the copy of the
+    next slot's first block beside its own last, so no slot waits for its
+    own. `sem` is DMA (len(hbm), 2); the grid must run in order
+    (`dimension_semantics=("arbitrary",)`)."""
     ib = pl.program_id(0)
-    pos = pos_ref[ib]
     n_full = (pos + 1) // block     # blocks filled whole
     n_blocks = pos // block + 1     # blocks that hold a filled row
 
     def copies(buf, slot, blk):
         rows = pl.ds(pl.multiple_of(blk * block, block), block)
-        return (pltpu.make_async_copy(c_hbm.at[slot, rows], c_buf.at[buf],
-                                      sem.at[0, buf]),
-                pltpu.make_async_copy(r_hbm.at[slot, rows], r_buf.at[buf],
-                                      sem.at[1, buf]))
+        return [pltpu.make_async_copy(cache.at[slot, rows], held.at[buf],
+                                      sem.at[which, buf])
+                for which, (cache, held) in enumerate(zip(hbm, bufs))]
 
     @pl.when(ib == 0)
     def _first_copy():
@@ -92,14 +108,8 @@ def _kernel(pos_ref, scale_ref, ql_ref, qr_ref, c_hbm, r_hbm, o_ref,
         for copy in copies(0, 0, 0):
             copy.start()
 
-    # which buffer this slot's first block was copied into: the slots take
-    # turns at the two buffers across the whole grid, not within a slot
+    # which half this slot's first block was copied into
     turn = turn_ref[0]
-    acc_ref[:] = jnp.zeros_like(acc_ref)
-    m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-    l_ref[:] = jnp.zeros_like(l_ref)
-    ql, qr = ql_ref[0], qr_ref[0]                        # (H, kvr), (H, 128)
-    scale = scale_ref[ib]
 
     def step(i, edge):
         buf = (turn + i) % 2
@@ -115,27 +125,61 @@ def _kernel(pos_ref, scale_ref, ql_ref, qr_ref, c_hbm, r_hbm, o_ref,
 
         for copy in copies(buf, ib, i):
             copy.wait()
-        c = c_buf[buf].astype(ql.dtype)                  # (block, kvr)
-        r = r_buf[buf].astype(ql.dtype)
-        s = (_nt(ql, c) + _nt(qr, r)) * scale            # (H, block) f32
-        if edge:
-            first = i * block
-            s = jnp.where(first + jax.lax.broadcasted_iota(
-                jnp.int32, (1, block), 1) <= pos, s, NEG_INF)
-            c = jnp.where(first + jax.lax.broadcasted_iota(
-                jnp.int32, (block, 1), 0) <= pos, c, jnp.zeros_like(c))
-        m_prev = m_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        fix = jnp.exp(m_prev - m_new)
-        m_ref[:] = m_new
-        l_ref[:] = l_ref[:] * fix + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * fix + jnp.dot(
-            p.astype(ql.dtype), c, preferred_element_type=jnp.float32)
+        compute(buf, i * block, edge)
 
     jax.lax.fori_loop(0, n_full, lambda i, _: step(i, False), None)
     pl.when(n_full < n_blocks)(lambda: step(n_full, True))
     turn_ref[0] = (turn + n_blocks) % 2
+
+
+def filled(first, block, pos, axis):
+    """Whether each row of the block that starts at row `first` lies at or
+    before `pos`: (block, 1) along axis 0, (1, block) along axis 1."""
+    shape = (1, block) if axis else (block, 1)
+    return first + jax.lax.broadcasted_iota(jnp.int32, shape, axis) <= pos
+
+
+def softmax_start(acc_ref, m_ref, l_ref):
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+
+def softmax_step(s, values, acc_ref, m_ref, l_ref):
+    """One block of the online softmax: scores `s` (queries, block) in
+    float32 against the running maximum and sum ((queries, 1) float32),
+    the probabilities cast to the values' type before their product, the
+    context accumulated in float32 (queries, width)."""
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    fix = jnp.exp(m_prev - m_new)
+    m_ref[...] = m_new
+    l_ref[...] = l_ref[...] * fix + jnp.sum(p, axis=1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * fix + jnp.dot(
+        p.astype(values.dtype), values, preferred_element_type=jnp.float32)
+
+
+def _kernel(pos_ref, scale_ref, ql_ref, qr_ref, c_hbm, r_hbm, o_ref,
+            c_buf, r_buf, sem, turn_ref, acc_ref, m_ref, l_ref, *, block,
+            slots):
+    ib = pl.program_id(0)
+    pos = pos_ref[ib]
+    softmax_start(acc_ref, m_ref, l_ref)
+    ql, qr = ql_ref[0], qr_ref[0]                        # (H, kvr), (H, 128)
+    scale = scale_ref[ib]
+
+    def compute(buf, first, edge):
+        c = c_buf[buf].astype(ql.dtype)                  # (block, kvr)
+        r = r_buf[buf].astype(ql.dtype)
+        s = (_nt(ql, c) + _nt(qr, r)) * scale            # (H, block) f32
+        if edge:
+            s = jnp.where(filled(first, block, pos, 1), s, NEG_INF)
+            c = jnp.where(filled(first, block, pos, 0), c, jnp.zeros_like(c))
+        softmax_step(s, c, acc_ref, m_ref, l_ref)
+
+    filled_blocks(pos, (c_hbm, r_hbm), (c_buf, r_buf), sem, turn_ref,
+                  block=block, slots=slots, compute=compute)
     o_ref[0] = (acc_ref[:] / l_ref[:]).astype(o_ref.dtype)
 
 

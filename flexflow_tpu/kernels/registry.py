@@ -13,9 +13,12 @@ observe, first match wins:
     call site asks for one query a slot alone, and there the kernel reads
     the filled rows where the reference reads the allocated ones: 1.9
     against 8.0 ms a decode iteration of `ms4_decode_sat`, PERF.md
-    section 6, PR 30); the two dense decode families: reference
-    (kernels/pallas/decode.py is reachable by override alone until it has
-    a timed row on a serving cell); `grouped_experts`: `small_experts`.
+    section 6, PR 30); `attention_decode` (one query a slot on the dense
+    cache): `filled_rows_decode` — the same read, where the kernel has a
+    timed row; `attention_decode_mq` (chunks, speculative verify):
+    reference (its kernel in kernels/pallas/decode.py walks every
+    allocated block and is reachable by override alone);
+    `grouped_experts`: `small_experts`.
 
 The mesh is the call sites' business (GSPMD cannot partition a Mosaic
 kernel): ops/attention.py runs flash under shard_map (`_on_mesh`) and
@@ -73,6 +76,30 @@ def small_experts(rows: int, matrix_bytes: int) -> bool:
     return rows > EXPERT_RIDGE_ROWS and matrix_bytes <= EXPERT_BLOCK_BYTES
 
 
+# what the dense decode kernel (kernels/pallas/decode.py
+# `fused_decode_attention`) is admitted for: heads whose slice of the packed
+# cache row is whole 128-lane tiles, a cache of 2-byte values, a length that
+# whole blocks divide. Timed on a v5e, the core alone, ms a layer, reference
+# chain / kernel at 512-row blocks: 40 slots x 12,288 rows x 1,024 lanes, 48
+# heads on 8 KV heads, 2.73 / 0.98; 96 x 2,048 x 512 lanes, 20 on 4, 0.59 /
+# 0.25 (PERF.md section 6, PR 34). 64-lane heads and a float32 cache
+# (`lm_osdi22w`: products at `highest` under a 1e-4 limit) have no timed
+# row and keep the chain.
+DECODE_HEAD_LANES = 128
+DECODE_VALUE_BYTES = 2
+
+
+def filled_rows_decode(head_dim: int, value_bytes: int, max_len: int) -> bool:
+    """Whether one query a slot over a dense cache of `max_len` rows of
+    `value_bytes`-wide values, `head_dim` a head, takes the kernel that
+    reads the rows each slot has filled."""
+    from .pallas.latent_decode import block_rows
+
+    return (head_dim % DECODE_HEAD_LANES == 0
+            and value_bytes == DECODE_VALUE_BYTES
+            and block_rows(max_len) is not None)
+
+
 @dataclasses.dataclass(frozen=True)
 class KernelChoice:
     """One selection verdict; truthy iff the pallas impl was chosen."""
@@ -114,15 +141,18 @@ class KernelRegistry:
     def select(self, family: str, *, param: Optional[bool] = None,
                scores: Optional[Tuple[int, int, int, int, int]] = None,
                experts: Optional[Tuple[int, int]] = None,
+               decode: Optional[Tuple[int, int, int]] = None,
                record: bool = True) -> KernelChoice:
         """Pick the impl for one op instance. `param` is the op's own
         explicit setting (attention's use_flash); `scores` the attention
         instance's `flash_crossover` arguments (batch, heads, q_len,
         k_len, dp), `experts` the routed product's `small_experts`
-        arguments (token rows, bytes of one expert's matrix) — the dense
-        decode families have no shape predicate and stay on the reference,
-        `latent_decode` is asked for one query a slot alone and takes the
-        kernel; `record=False` skips the
+        arguments (token rows, bytes of one expert's matrix), `decode` the
+        dense decode step's `filled_rows_decode` arguments (head width,
+        bytes of a cached value, cache rows) — `attention_decode_mq` has
+        no shape predicate and stays on the reference, `latent_decode` is
+        asked for one query a slot alone and takes the kernel;
+        `record=False` skips the
         selection counter (the cost simulator asks thousands of times per
         search)."""
         _known(family)
@@ -138,7 +168,9 @@ class KernelRegistry:
                 family == "attention" and scores is not None
                 and flash_crossover(*scores)) or (
                 family == "grouped_experts" and experts is not None
-                and small_experts(*experts))
+                and small_experts(*experts)) or (
+                family == "attention_decode" and decode is not None
+                and filled_rows_decode(*decode))
             choice = KernelChoice(
                 family, "pallas" if wins else "reference", "shape")
         if record:

@@ -17,10 +17,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.op import Op, WeightSpec, register_op
-from ..ffconst import CompMode, OpType
+from ..ffconst import CompMode, DataType, OpType
 from ..runtime.initializers import DefaultInitializer, ZeroInitializer
 from ..runtime.platform import pallas_interpret
 from .common import emit_dtype, matmul_dtype
+from .latent_attention import _wide_add
 
 
 def _packed(x):
@@ -160,7 +161,19 @@ def _ring_write(cache, new, pos, valid=None):
 
 @register_op
 class MultiHeadAttentionOp(Op):
+    """State, where the op's shapes admit the decode kernel that reads the
+    rows each slot has filled (`_counts_rows`): how far the decode core's
+    read is from the rows the sequences hold, threaded by the continuous
+    batcher from one decode iteration to the next (`serving_counters`),
+    as `LatentAttentionOp` counts — `attn_steps` (decode and verify
+    steps), `rows_filled` (cache rows at or before each slot's position,
+    summed over slots and steps) and `rows_read` (the rows the core that
+    ran fetched: whole blocks up to the position under the kernel, slots
+    x max_len under the reference chain); the two row counts are wide
+    (ops/latent_attention.py `wide_count`)."""
+
     op_type = OpType.MULTIHEAD_ATTENTION
+    serving_counters = ("attn_steps", "rows_filled", "rows_read")
 
     def _dims(self):
         q, k, v = self.inputs[:3]
@@ -255,6 +268,26 @@ class MultiHeadAttentionOp(Op):
                 WeightSpec("bo", (embed,), dt, ZeroInitializer()),
             ]
         return specs
+
+    def _counts_rows(self) -> bool:
+        """Whether a serving cache of this op can meet the registry's rule
+        for the `attention_decode` kernel (kernels/registry.py
+        `filled_rows_decode`; the cache's type and length are the
+        pool's): a causal op without a window whose K and V heads are
+        whole 128-lane tiles of one width."""
+        _, _, _, _, _, kdim, vdim = self._dims()
+        from ..kernels.registry import DECODE_HEAD_LANES
+
+        return bool(self.params.get("causal") and self._window() is None
+                    and kdim == vdim and kdim % DECODE_HEAD_LANES == 0)
+
+    def state_specs(self):
+        if not self._counts_rows():
+            return []
+        z = ZeroInitializer()
+        return [WeightSpec("attn_steps", (), DataType.DT_INT32, z),
+                WeightSpec("rows_filled", (2,), DataType.DT_INT32, z),
+                WeightSpec("rows_read", (2,), DataType.DT_INT32, z)]
 
     def kv_cache_arrays(self):
         """A token's keys (after their rotation, where the op rotates) and
@@ -561,11 +594,18 @@ class MultiHeadAttentionOp(Op):
         each slot decodes its own sequence, so slot i writes its K/V at
         pos[i] and masks to its own length). The vector form is the
         continuous batcher's per-iteration hot loop, and a kernel
-        family (`attention_decode`): under `KERNELS.override` the
-        QK^T -> masked softmax -> V chain runs as ONE fused kernel over
-        the paged cache (kernels/pallas/decode.py) instead of
-        materializing the (B, h, 1, M) logits/probs in HBM; the einsum
-        chain below is its reference/parity oracle.
+        family (`attention_decode`): where kernels/registry.py
+        `filled_rows_decode` admits what the call holds — a TPU, heads of
+        whole 128-lane tiles, a cache of 2-byte values, a length whole
+        blocks divide — the QK^T -> masked softmax -> V chain runs as ONE
+        fused kernel that reads the rows each slot has FILLED
+        (kernels/pallas/decode.py, grouped KV heads included) instead of
+        contracting over every allocated row and materializing the
+        (B, h, 1, M) logits/probs in HBM; the einsum chain below is its
+        reference/parity oracle, and what 64-lane heads, a float32 cache
+        and a step jitted over a mesh keep. An op whose shapes can meet
+        that rule counts its decode steps, the rows filled and the rows
+        the core that ran read (`_counts_rows`, `serving_counters`).
 
         The scalar form doubles as the CHUNK-OFFSET PREFILL entry: with
         C > 1 query tokens at offset `pos`, the chunk's K/V rows are
@@ -585,11 +625,12 @@ class MultiHeadAttentionOp(Op):
         query can attend them.
 
         Every C > 1 entry (both forms) is the `attention_decode_mq`
-        kernel family: selected, the chunk runs as ONE fused
-        multi-query kernel over the paged cache
-        (kernels/pallas/decode.py) instead of materializing the
-        (B, h, C, M) logits/probs in HBM; the einsum chain below is the
-        reference/parity oracle for both families.
+        kernel family, which waits behind `KERNELS.override`: forced,
+        the chunk runs as ONE fused multi-query kernel over the whole
+        cache (kernels/pallas/decode.py; one K/V head a query head)
+        instead of materializing the (B, h, C, M) logits/probs in HBM;
+        the einsum chain below is the reference/parity oracle for both
+        families.
 
         The reference chain's CONTRACTION follows what it can see in its
         operands (`_contract_heads_together`). One query a row (the decode
@@ -636,25 +677,48 @@ class MultiHeadAttentionOp(Op):
         ctx.state_updates[(self.name, "k_cache")] = kc
         ctx.state_updates[(self.name, "v_cache")] = vc
 
+        from ..kernels.pallas import decode
+        from ..kernels.pallas.latent_decode import rows_read
         from ..kernels.registry import KERNELS
 
-        family = ("attention_decode" if vector and c == 1
-                  else "attention_decode_mq")
-        kv_heads = k.shape[2]
+        max_len = kc.shape[1]
+        one_query = vector and c == 1
         # GSPMD cannot partition a Mosaic kernel (see _on_mesh): a decode
-        # step jitted over a mesh keeps the reference chain below, as do
-        # grouped KV heads (the kernels take one K/V head a query head)
-        if (kv_heads == q.shape[2] and not ctx.gspmd_partitioned()
-                and KERNELS.select(family)):
-            from ..kernels.pallas import decode
-
-            fused = (decode.fused_decode_attention
-                     if family == "attention_decode"
-                     else decode.fused_multiquery_decode_attention)
-            posv = pos if vector else jnp.full(
-                (kc.shape[0],), pos, jnp.int32)
-            ctxv = fused(q, kc, vc, posv, scale=scale,
-                         interpret=pallas_interpret())
+        # step jitted over a mesh keeps the reference chain below. One
+        # query a slot asks the registry with what the call holds — the
+        # head's width, the cache's value size and length; the multi-query
+        # kernel takes one K/V head a query head
+        if ctx.gspmd_partitioned():
+            fused = False
+        elif one_query:
+            fused = kc.shape == vc.shape and bool(KERNELS.select(
+                "attention_decode",
+                decode=(q.shape[3], kc.dtype.itemsize, max_len)))
+        else:
+            fused = k.shape[2] == q.shape[2] and bool(
+                KERNELS.select("attention_decode_mq"))
+        if vector and (self.name, "rows_read") in ctx.state:
+            held = lambda var: ctx.state[(self.name, var)]
+            last = jnp.minimum(pos + c - 1, max_len - 1)
+            read = (rows_read(last, max_len) if fused and one_query
+                    else kc.shape[0] * max_len)
+            ctx.state_updates[(self.name, "attn_steps")] = (
+                held("attn_steps") + 1)
+            ctx.state_updates[(self.name, "rows_filled")] = _wide_add(
+                held("rows_filled"), jnp.sum(last + 1))
+            ctx.state_updates[(self.name, "rows_read")] = _wide_add(
+                held("rows_read"), read)
+        if fused:
+            if one_query:
+                ctxv = decode.fused_decode_attention(
+                    q, kc, vc, pos, scale=scale,
+                    interpret=pallas_interpret())
+            else:
+                posv = pos if vector else jnp.full(
+                    (kc.shape[0],), pos, jnp.int32)
+                ctxv = decode.fused_multiquery_decode_attention(
+                    q, kc, vc, posv, scale=scale,
+                    interpret=pallas_interpret())
             return self._decode_project(ctxv, q.dtype, weights, gate)
 
         if vector:

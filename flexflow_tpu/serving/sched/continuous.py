@@ -630,8 +630,8 @@ class ContinuousBatcher:
                 " the running batch", labels=("pool",))
 
         # state of the ops that count (Op.serving_counters: an expert
-        # layer's assignments and hits, a latent attention's rows filled
-        # and read), threaded through decode_all from one iteration to the
+        # layer's assignments and hits, an attention's rows filled and
+        # read), threaded through decode_all from one iteration to the
         # next; read by `op_counters()`
         self._op_counters: Dict[str, Dict[str, object]] = {
             op.name: {v: model.state[op.name][v]
@@ -1793,16 +1793,15 @@ class ContinuousBatcher:
     def publish_op_counters(self) -> Dict:
         """Mirror the counting ops' state into the registry: the expert
         layers' into the `ff_moe_*` families (obs/moe.py), whose numbers
-        are returned, the latent attentions' rows into `ff_mla_*`
-        (obs/latent_attention.py) and the state-space mixers' state
-        traffic into `ff_ssm_*` (obs/ssm.py)."""
-        from ...obs.latent_attention import publish_latent_attention_metrics
+        are returned, the attentions' rows filled and read into
+        `ff_mla_*` / `ff_attn_*` (obs/attention_rows.py) and the
+        state-space mixers' state traffic into `ff_ssm_*` (obs/ssm.py)."""
+        from ...obs.attention_rows import publish_attention_row_metrics
         from ...obs.moe import publish_moe_metrics
         from ...obs.ssm import publish_ssm_metrics
 
         state = self.op_counters()
-        publish_latent_attention_metrics(self.model, self.registry,
-                                         state=state)
+        publish_attention_row_metrics(self.model, self.registry, state=state)
         publish_ssm_metrics(self.model, self.registry, state=state)
         return publish_moe_metrics(self.model, self.registry, state=state)
 

@@ -54,6 +54,49 @@ def module_xla_cache():
                       prev_secs)
 
 
+def served_both_ways_counts_add_up(make_batcher, jobs, ops, slots, max_len,
+                                   block):
+    """`jobs` ((prompt, new tokens), ...) ONE REQUEST AT A TIME through the
+    batcher `make_batcher()` builds, with the dense decode core
+    (`attention_decode`) forced each way: the same greedy tokens, and the
+    counting attention ops `ops` add up — one live slot a decode step and
+    `slots - 1` idle ones at their dummy position 0; `rows_read` whole
+    `block`-row blocks up to the position under the kernel, every allocated
+    row under the reference; `ff_attn_rows_*` mirror them. Returns the
+    kernel side's {op: counters} and tokens."""
+    import numpy as np
+
+    from flexflow_tpu.kernels.registry import KERNELS
+    from flexflow_tpu.ops.latent_attention import wide_count
+
+    steps = sum(n - 1 for _, n in jobs)     # the first token is prefill's
+    filled = sum(len(p) + k + 1 for p, n in jobs for k in range(n - 1)) \
+        + (slots - 1) * steps
+    blocks = sum((len(p) + k) // block + 1 for p, n in jobs
+                 for k in range(n - 1)) + (slots - 1) * steps
+    tokens, got = {}, {}
+    for impl in ("pallas", "reference"):
+        with KERNELS.override("attention_decode", impl), \
+                make_batcher() as cb:
+            tokens[impl] = [np.asarray(cb.submit(p, n).result(timeout=300))
+                            for p, n in jobs]
+            cb.publish_op_counters()
+            got[impl] = cb.op_counters()
+            text = cb.registry.render()
+        read = block * blocks if impl == "pallas" else steps * slots * max_len
+        assert read >= filled
+        for name in ops:
+            c = got[impl][name]
+            assert (int(c["attn_steps"]), wide_count(c["rows_filled"]),
+                    wide_count(c["rows_read"])) == (steps, filled, read), name
+            assert f'ff_attn_rows_filled_total{{op="{name}"}} {filled}\n' \
+                in text, text
+            assert f'ff_attn_rows_read_total{{op="{name}"}} {read}\n' in text
+    for a, b in zip(tokens["pallas"], tokens["reference"]):
+        np.testing.assert_array_equal(a, b)
+    return got["pallas"], tokens["pallas"]
+
+
 def _serving_xla_cache_dir() -> str:
     """ONE fixed cache dir shared by every serving module, beside the
     program's own compile cache (runtime/platform.compile_cache_dir): jax

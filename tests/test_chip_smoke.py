@@ -26,7 +26,9 @@ TOY = chip_smoke.Sizes(
     search_layers=1, search_budget=2, search_devices=2,
     slots=2, window=24, max_len=32, page_size=8, prompts=(5, 20, 9),
     new_tokens=3, latent=(4, 32, 16, 8, 8, 16),
-    hybrid=(4, 2, 8, 32, 4, 8, 2), window_pair=(4, 8, 2, 8, 6), mesh_batch=4, mesh_layers=1, mesh_steps=3)
+    # heads of 128 as at the real width: narrower ones count no rows
+    hybrid=(4, 2, 128, 32, 4, 8, 2), window_pair=(4, 8, 2, 128, 6),
+    mesh_batch=4, mesh_layers=1, mesh_steps=3)
 
 
 @pytest.fixture(scope="module")
@@ -76,11 +78,16 @@ def test_phase_at_toy_width(phase, clock, capsys):
         # 3 prompts through 2 slots: one slot reused, its state reset
         assert printed["token_parity"] == "3/3 identical"
         assert printed["state_resets"] == 3
+        # a cache of one 32-row block: both sides read every allocated row
+        assert (printed["rows_read_over_filled"]["pallas"]
+                == printed["rows_read_over_filled"]["reference"] > 1)
     elif phase == "window":
         # 3 prompts through 2 slots: one slot's ring reused; the prompt of
         # 20 tokens wraps a ring of 6 rows three times
         assert printed["token_parity"] == "3/3 identical"
         assert printed["ring_rows"] == 6
+        assert (printed["rows_read_over_filled"]["pallas"]
+                == printed["rows_read_over_filled"]["reference"] > 1)
     else:
         assert printed["dp_x_tp"]["mesh_devices"] == 4
         assert printed["dp_x_tp"]["params"]["devices"] == [0, 1, 2, 3]

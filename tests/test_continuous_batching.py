@@ -22,7 +22,8 @@ from flexflow_tpu.serving.sched import (AdmissionController,
                                         QueueFull, RequestState,
                                         RequestTooLarge, derive_num_slots,
                                         kv_bytes_per_token)
-from tests.conftest import module_xla_cache
+from tests.conftest import (module_xla_cache,
+                            served_both_ways_counts_add_up)
 from tests.test_generate import _build_lm
 
 # module-scoped XLA compilation cache — see conftest.module_xla_cache
@@ -194,6 +195,48 @@ def test_continuous_slot_reuse_mid_decode(lm):
             np.testing.assert_array_equal(req.result(timeout=300),
                                           np.asarray(ref))
     assert cb.pool.free_slot_count() == 1
+
+
+def test_grouped_kv_decode_kernel_token_parity_and_row_counters(monkeypatch):
+    """A causal LM of 4 query heads on 2 KV heads of 128 — the width the
+    registry admits the dense decode kernel for — through the batcher with
+    `attention_decode` forced each way: the same greedy tokens as the
+    lockstep session, and the attentions' row counters add up
+    (`rows_read >= rows_filled`, `attn_steps` = decode iterations a
+    counting op, `ff_attn_rows_*` mirror them)."""
+    import flexflow_tpu as ff
+    from flexflow_tpu.kernels.pallas import latent_decode
+
+    monkeypatch.setattr(latent_decode, "BLOCK_ROWS", 16)
+    config = ff.FFConfig()
+    config.batch_size = 1
+    config.allow_mixed_precision = False
+    config.num_devices = 1      # a step jitted over a mesh keeps the chain
+    model = ff.FFModel(config)
+    tokens = model.create_tensor([1, 24], ff.DataType.DT_INT32)
+    t = model.embedding(tokens, 50, 32, ff.AggrMode.AGGR_MODE_NONE,
+                        name="emb")
+    for i in range(2):
+        attn = model.multihead_attention(
+            t, t, t, 32, 4, kdim=128, vdim=128, causal=True, kv_heads=2,
+            name=f"l{i}_attn")
+        t = model.layer_norm(model.add(t, attn), [-1], name=f"l{i}_ln")
+    model.softmax(model.dense(t, 50, name="lm_head"))
+    model.compile(optimizer=ff.SGDOptimizer(model, lr=0.0),
+                  loss_type=ff.LossType.LOSS_SPARSE_CATEGORICAL_CROSSENTROPY)
+    rng = np.random.RandomState(21)
+    jobs = [(rng.randint(1, 50, size=(n,)).astype(np.int32), new)
+            for n, new in ((21, 14), (9, 30))]
+    slots, max_len = 3, 64
+    session = GenerativeSession(model, max_len=max_len)
+    refs = [session.generate(p[None, :], n)[0] for p, n in jobs]
+    counts, outs = served_both_ways_counts_add_up(
+        lambda: ContinuousBatcher(model, max_len=max_len, num_slots=slots,
+                                  page_size=8, max_queue=4),
+        jobs, ("l0_attn", "l1_attn"), slots, max_len, 16)
+    assert set(counts) == {"l0_attn", "l1_attn"}
+    for out, ref in zip(outs, refs):
+        np.testing.assert_array_equal(out, np.asarray(ref))
 
 
 def test_continuous_eos_frees_slot_early(lm):

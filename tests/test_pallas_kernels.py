@@ -17,7 +17,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from flexflow_tpu.kernels.pallas import fused_decode_attention
+from flexflow_tpu.kernels.pallas import (fused_decode_attention,
+                                         latent_decode)
 from flexflow_tpu.kernels.registry import FLASH_COST_GAIN, KERNELS
 
 F32_TOL = dict(rtol=1e-5, atol=1e-5)
@@ -166,18 +167,19 @@ def _ref_decode(q, kc, vc, pos, scale):
                       vc.astype(q.dtype))
 
 
-@pytest.mark.parametrize("block_k", [64, 8])  # single- and multi-block
-def test_fused_decode_ragged_positions(block_k):
+@pytest.mark.parametrize("block_k", [64, 16])  # single- and multi-block
+def test_fused_decode_ragged_positions(block_k, monkeypatch):
+    # the block is derived from the cache's length (`block_rows`)
+    monkeypatch.setattr(latent_decode, "BLOCK_ROWS", block_k)
     rng = np.random.RandomState(6)
-    B, M, h, d = 5, 24, 3, 8
+    B, M, h, d = 5, 32, 3, 8
     q = _rand(rng, (B, 1, h, d))
     kc = _rand(rng, (B, M, h * d))
     vc = _rand(rng, (B, M, h * d))
     # ragged: includes pos 0 (one live row) and pos M-1 (the whole cache)
-    pos = jnp.asarray([0, 3, 11, 23, 7], dtype=jnp.int32)
+    pos = jnp.asarray([0, 3, 16, 31, 7], dtype=jnp.int32)
     scale = 1.0 / np.sqrt(d)
-    out = fused_decode_attention(q, kc, vc, pos, scale=scale,
-                                 block_k=block_k, interpret=True)
+    out = fused_decode_attention(q, kc, vc, pos, scale=scale, interpret=True)
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(_ref_decode(q, kc, vc, pos, scale)),
         rtol=1e-5, atol=1e-6)
@@ -204,6 +206,65 @@ def test_fused_decode_rejects_multi_query():
     with pytest.raises(ValueError, match="one query token"):
         fused_decode_attention(q, kc, vc, jnp.zeros((1,), jnp.int32),
                                scale=1.0, interpret=True)
+
+
+# the dense decode kernel against the op's own reference chain: 4 blocks of
+# 16 rows; positions at the first row, a block's last and the next block's
+# first, the cache's last, an idle slot's dummy position, mid-block
+_DECODE_POS = (0, 15, 16, 63, 0, 37)
+# what the rows past each slot's position hold: NaN, 1e30, or the rows of a
+# previous tenant of the slot that was longer (the whole cache random)
+_PAST = {"nan": jnp.nan, "1e30": 1e30, "tenant": None}
+
+
+@pytest.mark.parametrize("past", sorted(_PAST))
+@pytest.mark.parametrize("stored", ["bfloat16", "float32"])
+@pytest.mark.parametrize("heads,kv_heads,width", [
+    (48, 8, 128), (20, 4, 128), (16, 16, 64)])
+def test_fused_decode_equals_the_reference_chain(
+        heads, kv_heads, width, stored, past, monkeypatch):
+    """`fused_decode_attention` (interpret mode) against
+    `MultiHeadAttentionOp._masked_core` — the chain the op lowers to where
+    the registry says reference — at the head counts of
+    `laguna_xs2_1chip`'s full layers, `falcon_h1_34b_1chip` and
+    `lm_osdi22w`: grouped KV heads read from their own 128-lane slice of
+    the packed row, ragged positions over several blocks. What lies past a
+    slot's position never reaches the output: the blocks past it are not
+    copied, the block that holds it is masked in scores and in values. The
+    reference is handed the same cache with those rows zeroed."""
+    import flexflow_tpu as ff
+    from flexflow_tpu.core.op import LoweringContext
+    from flexflow_tpu.ffconst import CompMode
+
+    monkeypatch.setattr(latent_decode, "BLOCK_ROWS", 16)
+    b, m = len(_DECODE_POS), 64
+    sdt = jnp.dtype(stored)
+    cdt = sdt       # a bf16 model computes in bf16, a float32 one in float32
+    mdl = ff.FFModel(ff.FFConfig())
+    x = mdl.create_tensor([b, 1, 64])
+    mdl.multihead_attention(x, x, x, 64, heads, kdim=width, vdim=width,
+                            causal=True, kv_heads=kv_heads, name="attn")
+    op = mdl.ops[-1]
+    ks = jax.random.split(jax.random.PRNGKey(heads), 3)
+    q = jax.random.normal(ks[0], (b, 1, heads, width), cdt)
+    kc = jax.random.normal(ks[1], (b, m, kv_heads * width), sdt)
+    vc = jax.random.normal(ks[2], (b, m, kv_heads * width), sdt)
+    pos = jnp.asarray(_DECODE_POS, jnp.int32)
+    beyond = jnp.arange(m)[None, :, None] > pos[:, None, None]
+    scale = width ** -0.5
+    ctx = LoweringContext(mdl.config, CompMode.COMP_MODE_INFERENCE)
+    want = op._masked_core(
+        ctx, q, jnp.where(beyond, 0, kc), jnp.where(beyond, 0, vc),
+        (~beyond[:, :, 0])[:, None, None, :], scale)
+    if _PAST[past] is not None:
+        kc = jnp.where(beyond, _PAST[past], kc).astype(sdt)
+        vc = jnp.where(beyond, _PAST[past], vc).astype(sdt)
+    got = fused_decode_attention(q, kc, vc, pos, scale=scale, interpret=True)
+    assert got.shape == want.shape == (b, 1, heads, width)
+    assert got.dtype == q.dtype
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        **(BF16_TOL if stored == "bfloat16" else F32_TOL))
 
 
 # ---------------------------------------------------------------------
@@ -284,6 +345,38 @@ DECISIONS = {
                                  BERT_1CHIP, "reference", "shape"),
     "tpu-decode-override": ("tpu", "attention_decode", None, "pallas", None,
                             "pallas", "override"),
+    # one query a slot on the dense cache (head width, bytes of a cached
+    # value, cache rows): the kernel that reads the filled rows where the
+    # head is whole 128-lane tiles, the cache bf16 and its length divisible
+    "tpu-decode-laguna_xs2-full": ("tpu", "attention_decode", None, None,
+                                   dict(decode=(128, 2, 12288)),
+                                   "pallas", "shape"),
+    "tpu-decode-falcon_h1": ("tpu", "attention_decode", None, None,
+                             dict(decode=(128, 2, 2048)), "pallas", "shape"),
+    "tpu-decode-lm_osdi22w": ("tpu", "attention_decode", None, None,
+                              dict(decode=(64, 4, 2048)),
+                              "reference", "shape"),
+    "tpu-decode-64-wide-bf16": ("tpu", "attention_decode", None, None,
+                                dict(decode=(64, 2, 2048)),
+                                "reference", "shape"),
+    "tpu-decode-float32-cache": ("tpu", "attention_decode", None, None,
+                                 dict(decode=(128, 4, 2048)),
+                                 "reference", "shape"),
+    "tpu-decode-undividable-length": ("tpu", "attention_decode", None, None,
+                                      dict(decode=(128, 2, 1000)),
+                                      "reference", "shape"),
+    "tpu-decode-short-cache-one-block": ("tpu", "attention_decode", None,
+                                         None, dict(decode=(256, 2, 200)),
+                                         "pallas", "shape"),
+    "cpu-decode-laguna_xs2-full": ("cpu", "attention_decode", None, None,
+                                   dict(decode=(128, 2, 12288)),
+                                   "reference", "backend"),
+    "tpu-decode-laguna_xs2-override-reference": (
+        "tpu", "attention_decode", None, "reference",
+        dict(decode=(128, 2, 12288)), "reference", "override"),
+    "tpu-mq-ignores-decode-shape": ("tpu", "attention_decode_mq", None, None,
+                                    dict(decode=(128, 2, 12288)),
+                                    "reference", "shape"),
     "tpu-mq-override": ("tpu", "attention_decode_mq", None, "pallas", None,
                         "pallas", "override"),
     "tpu-bert-override-reference": ("tpu", "attention", None, "reference",
@@ -337,8 +430,9 @@ def test_select_decision_table(case):
               else contextlib.nullcontext())
     with mock.patch.object(jax, "default_backend", lambda: platform), forced:
         shape = "experts" if family == "grouped_experts" else "scores"
+        given = scores if isinstance(scores, dict) else {shape: scores}
         choice = KERNELS.select(family, param=use_flash, record=False,
-                                **{shape: scores})
+                                **given)
     assert (choice.impl, choice.reason) == (impl, reason)
     assert bool(choice) == (impl == "pallas")
 
@@ -644,7 +738,8 @@ def test_fused_multiquery_decode_bf16_cache():
 
 def test_fused_multiquery_c1_matches_single_query():
     """C = 1 through the multi-query entry is the single-query kernel's
-    exact math (shared body), in both block regimes."""
+    math in both block regimes (two bodies since PR 34: the single-query
+    one walks the filled blocks alone, so equal to float rounding)."""
     from flexflow_tpu.kernels.pallas import (
         fused_multiquery_decode_attention)
 
@@ -657,15 +752,14 @@ def test_fused_multiquery_c1_matches_single_query():
     for block_k in (64, 8):
         a = fused_multiquery_decode_attention(
             q, kc, vc, pos, scale=0.3, block_k=block_k, interpret=True)
-        b = fused_decode_attention(
-            q, kc, vc, pos, scale=0.3, block_k=block_k, interpret=True)
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        b = fused_decode_attention(q, kc, vc, pos, scale=0.3, interpret=True)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), **F32_TOL)
 
 
-def test_continuous_batcher_fused_decode_multiblock_token_parity():
+def test_continuous_batcher_fused_decode_multiblock_token_parity(monkeypatch):
     """Satellite 3 (lifts the PR 9 docs caveat): greedy decode through
     the continuous batcher with BOTH fused decode kernels forced and
-    the kernels' block_k SMALLER than the cache span — every decode streams
+    the kernels' blocks SMALLER than the cache span — decodes stream
     multiple KV blocks through the online softmax — stays
     token-identical to the pure-reference run. Ragged prompts, slot
     reuse (4 requests through 2 slots), chunked prefill through the
@@ -686,17 +780,17 @@ def test_continuous_batcher_fused_decode_multiblock_token_parity():
         with contextlib.ExitStack() as st:
             for fam in forced:
                 st.enter_context(KERNELS.override(fam, "pallas"))
-            for fn in ("fused_decode_attention",
-                       "fused_multiquery_decode_attention"):
-                # cache span 24 -> 3 KV blocks
-                st.enter_context(mock.patch.object(
-                    decode, fn,
-                    functools.partial(getattr(decode, fn), block_k=8)))
-            with ContinuousBatcher(lm, max_len=24, num_slots=2,
+            # cache span 32 -> 4 KV blocks of the multi-query kernel, 2
+            # of the single-query one (its block is derived: `block_rows`)
+            fn = "fused_multiquery_decode_attention"
+            st.enter_context(mock.patch.object(
+                decode, fn, functools.partial(getattr(decode, fn), block_k=8)))
+            with ContinuousBatcher(lm, max_len=32, num_slots=2,
                                    page_size=4, max_queue=8) as cb:
                 return [r.result(timeout=300).tolist()
                         for r in [cb.submit(p, 10) for p in prompts]]
 
+    monkeypatch.setattr(latent_decode, "BLOCK_ROWS", 16)
     ref = run(())
     fused = run(("attention_decode", "attention_decode_mq"))
     assert fused == ref

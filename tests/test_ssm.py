@@ -20,7 +20,8 @@ from flexflow_tpu.ops.latent_attention import wide_count
 from flexflow_tpu.serving.generate import GenerativeSession
 from flexflow_tpu.serving.sched import kvpool
 from flexflow_tpu.serving.sched.continuous import ContinuousBatcher
-from tests.conftest import module_xla_cache
+from tests.conftest import (module_xla_cache,
+                            served_both_ways_counts_add_up)
 
 _xla_cache = pytest.fixture(scope="module", autouse=True)(module_xla_cache)
 
@@ -264,6 +265,31 @@ def test_a_reused_slot_answers_as_a_fresh_one(lm):
         reused = serve([first, second])[1]
         np.testing.assert_array_equal(reused, fresh)
         assert _gaps(cfg, second, reused).max() < 1e-4
+
+
+def test_attention_decode_kernel_serves_the_hybrids_tokens(monkeypatch):
+    """The hybrid at 4 query heads on 2 KV heads of 128 (the width the
+    registry admits the kernel for), float32: with `attention_decode`
+    forced each way the batcher serves the same tokens, the reference's own
+    best, and the attentions' row counters add up beside the mixers'."""
+    from flexflow_tpu.kernels.pallas import latent_decode
+
+    monkeypatch.setattr(latent_decode, "BLOCK_ROWS", 16)
+    cfg = tiny_cfg(head_dim=128)
+    model = builder.build_model(cfg, SEED)
+    rng = np.random.default_rng(6)
+    jobs = [(rng.integers(0, 128, 21, dtype=np.int32), 14),
+            (rng.integers(0, 128, 9, dtype=np.int32), 30)]
+    dep = cfg["deployment"]
+    counts, tokens = served_both_ways_counts_add_up(
+        lambda: builder.build_batcher(model, cfg), jobs,
+        ("l0_attn", "l1_attn"), dep["num_slots"], dep["max_len"], 16)
+    assert set(counts) == {"l0_attn", "l1_attn", "l0_mixer", "l1_mixer"}
+    assert int(counts["l0_attn"]["attn_steps"]) == int(
+        counts["l0_mixer"]["ssm_steps"])
+    for (p, _), out in zip(jobs, tokens):
+        gap = _gaps(cfg, p, out)
+        assert gap.max() < 1e-4, gap
 
 
 def test_lockstep_session_equals_the_batcher(lm):

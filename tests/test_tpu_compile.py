@@ -95,6 +95,19 @@ CASES = {
         _decode(fused_decode_attention),
         [((SLOTS, 1, HEADS, HEAD_DIM), BF16), _CACHE, _CACHE,
          ((SLOTS,), I32)]),
+    # the two cells whose decode step runs it: lgx_decode_sat's full layers
+    # (40 slots x 12,288 rows x 1,024 lanes, 48 heads on 8 KV heads) and
+    # fh1_decode_sat (96 x 2,048 x 512 lanes, 20 on 4), bf16
+    "decode_c1_laguna_xs2_full": (
+        lambda q, k, v, pos: fused_decode_attention(q, k, v, pos,
+                                                    scale=128 ** -0.5),
+        [((40, 1, 48, 128), BF16), ((40, 12288, 1024), BF16),
+         ((40, 12288, 1024), BF16), ((40,), I32)]),
+    "decode_c1_falcon_h1": (
+        lambda q, k, v, pos: fused_decode_attention(q, k, v, pos,
+                                                    scale=128 ** -0.5),
+        [((96, 1, 20, 128), BF16), ((96, 2048, 512), BF16),
+         ((96, 2048, 512), BF16), ((96,), I32)]),
     "decode_mq_c5": (
         _decode(fused_multiquery_decode_attention),
         [((SLOTS, 5, HEADS, HEAD_DIM), BF16), _CACHE, _CACHE,
@@ -287,8 +300,10 @@ def test_decode_all_streams_the_expert_stacks_in_place_on_v5e(v5e):
 
 # the programs the benchmark's cells time, each with the kernel choice the
 # chip makes for it (compile_for_v5e.py at the real sizes: 36 custom calls
-# in 12 layers of multi_step; in serving the latent decode kernel alone,
-# one a layer of mistral_small4_ep4's decode step, since PR 30)
+# in 12 layers of multi_step; in serving the latent decode kernel, one a
+# layer of mistral_small4_ep4's decode step, since PR 30, and the dense
+# decode kernel, one a FULL layer of 128-wide heads on a bf16 cache —
+# falcon_h1_34b_1chip's and laguna_xs2_1chip's decode steps —, since PR 34)
 CELL_PROGRAMS = [
     ("bert_osdi22", "multi_step", 3),          # flash fwd + dq + dkv a layer
     ("lm_osdi22w", "decode_all", 0),
@@ -297,21 +312,26 @@ CELL_PROGRAMS = [
     ("mistral_small4_ep4", "decode_all", 1),     # the latent decode core
     ("mistral_small4_ep4", "prefill_chunk", 0),
     ("mistral_small4_ep4", "prefill_last_chunk", 0),
-    # grouped-KV rotary attention and the state-space mixer are XLA's: the
-    # recurrent state is donated beside the K/V, no Pallas call
-    ("falcon_h1_34b_1chip", "decode_all", 0),
+    # the state-space mixer is XLA's, the recurrent state donated beside
+    # the K/V; grouped-KV rotary attention: the decode kernel in the decode
+    # step, the reference chain in the chunks
+    ("falcon_h1_34b_1chip", "decode_all", 1),
     ("falcon_h1_34b_1chip", "prefill_chunk", 0),
     ("falcon_h1_34b_1chip", "prefill_last_chunk", 0),
-    # a window layer's ring beside a full layer's cache, the gate and the
-    # sigmoid router are XLA's too
-    ("laguna_xs2_1chip", "decode_all", 0),
+    # two layers here: the full layer's decode kernel and none in the
+    # window layer (its ring returns before the selection); the gate and
+    # the sigmoid router are XLA's
+    ("laguna_xs2_1chip", "decode_all", 1),
     ("laguna_xs2_1chip", "prefill_chunk", 0),
     ("laguna_xs2_1chip", "prefill_last_chunk", 0),
 ]
 # (argument bytes, temporary bytes) of each, by `memory_analysis()` at the
 # sizes `cell_programs` builds: what the parent commit of PR 33 compiled to,
 # read on both trees before `window`, `head_gate`, `partial_rotary_factor`
-# and `scoring` went in — at their defaults the programs are the same
+# and `scoring` went in — at their defaults the programs are the same; the
+# two `decode_all` that hold the dense decode kernel re-read at PR 34 (a
+# full layer's three row counters among the arguments: 1,536 B in their
+# tiles), every other line as it was
 CELL_PROGRAM_BYTES = {
     ("bert_osdi22", "multi_step"): (105073152, 121645056),
     ("lm_osdi22w", "decode_all"): (71371776, 1225728),
@@ -320,10 +340,10 @@ CELL_PROGRAM_BYTES = {
     ("mistral_small4_ep4", "decode_all"): (98597888, 0),
     ("mistral_small4_ep4", "prefill_chunk"): (7071232, 0),
     ("mistral_small4_ep4", "prefill_last_chunk"): (98841088, 3193344),
-    ("falcon_h1_34b_1chip", "decode_all"): (891956736, 0),
+    ("falcon_h1_34b_1chip", "decode_all"): (891958272, 0),
     ("falcon_h1_34b_1chip", "prefill_chunk"): (113285120, 0),
     ("falcon_h1_34b_1chip", "prefill_last_chunk"): (894750208, 1290240),
-    ("laguna_xs2_1chip", "decode_all"): (313023488, 5186560),
+    ("laguna_xs2_1chip", "decode_all"): (313025024, 6188032),
     ("laguna_xs2_1chip", "prefill_chunk"): (172438528, 0),
     ("laguna_xs2_1chip", "prefill_last_chunk"): (315381760, 4584448),
 }
@@ -400,10 +420,12 @@ def test_cell_program_holds_the_cells_kernel_choice(
     """With default settings on a TPU every cell's program lowers to the
     registry's one rule: flash's three custom calls a layer in the
     training step; in the serving programs the latent decode kernel in
-    `mistral_small4_ep4`'s decode step and no Pallas call anywhere else
-    (dense attention is the reference chain, its decode kernels wait
-    behind `KERNELS.override`; prefill keeps the expanded path), and to
-    the bytes it is pinned at (`CELL_PROGRAM_BYTES`)."""
+    `mistral_small4_ep4`'s decode step, the dense decode kernel in a full
+    layer of `falcon_h1_34b_1chip`'s and `laguna_xs2_1chip`'s, and no
+    Pallas call anywhere else (64-wide heads on a float32 cache, a
+    window's ring and every chunk keep the reference chain; prefill keeps
+    the expanded path), and to the bytes it is pinned at
+    (`CELL_PROGRAM_BYTES`)."""
     from unittest import mock
 
     fn, args = cell_programs(config)[program]

@@ -21,6 +21,7 @@ from flexflow_tpu.ops import rope
 from flexflow_tpu.serving.generate import GenerativeSession
 from flexflow_tpu.serving.sched import kvpool
 from flexflow_tpu.serving.sched.continuous import ContinuousBatcher
+from tests.conftest import served_both_ways_counts_add_up
 
 E, HEADS, KVH, D, W = 48, 6, 2, 8, 8
 YARN = {"rope_theta": 500000, "rope_type": "yarn", "factor": 64,
@@ -282,6 +283,37 @@ def test_served_tokens_are_the_references_best(served, chunk):
             assert np.all(_gaps(cfg, builder, prompt, toks) == 0.0)
     finally:
         cb.stop()
+
+
+def test_full_layers_decode_kernel_beside_a_ring(monkeypatch):
+    """A full layer and a window layer of 128-wide heads (the width the
+    registry admits the decode kernel for), float32: with
+    `attention_decode` forced each way the batcher serves the same tokens,
+    the reference's own best; the full layer counts the rows it filled and
+    read, the window layer — whose ring returns before the kernel choice —
+    counts nothing."""
+    from flexflow_tpu.kernels.pallas import latent_decode
+
+    monkeypatch.setattr(latent_decode, "BLOCK_ROWS", 16)
+    with jax.default_matmul_precision("highest"):
+        ctx = tiny_lgx_context(tensor_dtype="float32", num_hidden_layers=2,
+                               head_dim=128)
+        cfg = {**ctx.config,
+               "deployment": {**ctx.config["deployment"], **ctx.sizes}}
+        builder = harness.module_of("configs", cfg["builder"])
+        model = builder.build_model(cfg, 7)
+        rng = np.random.default_rng(12)
+        jobs = [(rng.integers(0, 128, 21, dtype=np.int32), 14),
+                (rng.integers(0, 128, 9, dtype=np.int32), 30)]
+        slots, max_len = 3, 64
+        counts, tokens = served_both_ways_counts_add_up(
+            lambda: ContinuousBatcher(
+                model, max_len=max_len, num_slots=slots, page_size=8,
+                prefill_chunk_tokens=12, prefix_cache_pages=0),
+            jobs, ("l0_attn",), slots, max_len, 16)
+        assert "l1_swa" not in counts
+        for (p, _), toks in zip(jobs, tokens):
+            assert np.all(_gaps(cfg, builder, p, toks) == 0.0)
 
 
 def test_lockstep_session_equals_the_batcher(served):
