@@ -124,6 +124,19 @@ def run_phase(name: str, clock: CompileClock, fn, *args) -> Dict:
     return line
 
 
+def cold_start_line() -> Dict:
+    """What the process has recorded of its own cold start so far
+    (docs/observability.md "Cold start"): every one-shot phase and every
+    program's first call, seconds by name — a bring-up on a new machine
+    shows where its start-up went without the benchmark."""
+    from flexflow_tpu.obs.startup import startup_table
+
+    line = {"phase": "cold_start",
+            "seconds": {name: round(s, 3) for name, s in startup_table()}}
+    print(json.dumps(line), flush=True)
+    return line
+
+
 def _check_close(what: str, got: float, want: float, rtol: float) -> float:
     delta = abs(got - want) / max(abs(want), 1e-8)
     if not (np.isfinite(got) and delta <= rtol):
@@ -858,6 +871,7 @@ def main(argv=None) -> int:
         run_phase("latent", clock, phase_latent, sizes, args.seed)
         run_phase("hybrid", clock, phase_hybrid, sizes, args.seed)
         run_phase("window", clock, phase_window, sizes, args.seed)
+    cold_start_line()
     print(json.dumps({"ok": True, "device": device}))
     return 0
 

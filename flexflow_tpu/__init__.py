@@ -7,6 +7,10 @@ parallelization strategy against a profiling-based cost model of the TPU pod;
 execution lowers to JAX/XLA (jit over a jax.sharding.Mesh, Pallas kernels,
 lax collectives) instead of Legion tasks + cuDNN/NCCL.
 """
+import time as _time
+
+_T_IMPORT = _time.perf_counter()   # first line: phase `startup.import`
+
 from .config import FFConfig, FFIterationConfig
 from .ffconst import (
     ActiMode,
@@ -37,6 +41,12 @@ from .runtime.initializers import (
     UniformInitializer,
     ZeroInitializer,
 )
+
+from .obs.startup import record_phase as _record_phase
+
+# last line of the import: the package's own modules, and jax's where this
+# is its first importer (docs/observability.md "Cold start")
+_record_phase("startup.import", _T_IMPORT, _time.perf_counter() - _T_IMPORT)
 
 __version__ = "0.1.0"
 

@@ -87,11 +87,12 @@ def make_mesh(axis_sizes: Dict[str, int], devices: Optional[Sequence] = None):
     devices are left out (reference analog: a MachineView covering a subset of
     the cluster).
     """
-    import jax
     from jax.sharding import Mesh
 
     if devices is None:
-        devices = jax.devices()
+        from ..runtime.platform import backend_devices
+
+        devices = backend_devices()
     names = tuple(axis_sizes.keys())
     sizes = tuple(axis_sizes.values())
     need = int(np.prod(sizes)) if sizes else 1

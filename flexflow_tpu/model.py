@@ -700,12 +700,15 @@ class FFModel:
         from layers, run the strategy search, build partitions/comms. Here:
         build the PCG, pick a strategy (data-parallel default; Unity search
         when search_budget > 0), build the mesh and compile the step
-        functions. The whole pass is one `compile` span (obs/tracing.py),
-        with the search, plan-analysis, and step-build phases nested
-        inside it."""
+        functions. The whole pass is one `compile` phase (obs/tracing.py
+        `Tracer.phase`: a span that also lands in `ff_startup_seconds`),
+        with the search, plan-analysis, weight-init and step-build phases
+        nested inside it."""
+        from .obs.startup import watch_compiles
         from .obs.tracing import get_tracer
 
-        with get_tracer().span("compile", ops=len(self.ops)):
+        watch_compiles()
+        with get_tracer().phase("compile", ops=len(self.ops)):
             self._compile_inner(optimizer, loss_type, metrics, comp_mode,
                                 parallel_axes)
 
@@ -882,17 +885,20 @@ class FFModel:
         self._verify_executed_reductions()
         import jax
 
-        self.params, self.state = self.executor.init_params(
-            jax.random.PRNGKey(self.config.seed)
-        )
-        # mesh-less compile with an explicit device subset (elastic: a
-        # single-survivor recovery): commit params to the chosen device so
-        # jitted steps execute there — jax.devices()[0], the default, may
-        # be the lost chip. opt_state inherits the placement via
-        # init_state(params) below.
-        if self.mesh is None and mesh_devices:
-            self.params = jax.device_put(self.params, mesh_devices[0])
-            self.state = jax.device_put(self.state, mesh_devices[0])
+        from .obs.tracing import get_tracer
+
+        with get_tracer().phase("compile.init_params"):
+            self.params, self.state = self.executor.init_params(
+                jax.random.PRNGKey(self.config.seed)
+            )
+            # mesh-less compile with an explicit device subset (elastic: a
+            # single-survivor recovery): commit params to the chosen device
+            # so jitted steps execute there — jax.devices()[0], the
+            # default, may be the lost chip. opt_state inherits the
+            # placement via init_state(params) below.
+            if self.mesh is None and mesh_devices:
+                self.params = jax.device_put(self.params, mesh_devices[0])
+                self.state = jax.device_put(self.state, mesh_devices[0])
         reg_fn = None
         if self.weight_regularizers:
             regs = list(self.weight_regularizers)
@@ -919,7 +925,7 @@ class FFModel:
     def _build_step_functions(self) -> None:
         from .obs.tracing import get_tracer
 
-        with get_tracer().span("compile.build_steps"):
+        with get_tracer().phase("compile.build_steps"):
             self._build_step_functions_inner()
 
     def _build_step_functions_inner(self) -> None:
@@ -1076,7 +1082,7 @@ class FFModel:
         from .elastic.events import EventLog
         from .obs.tracing import get_tracer
 
-        with get_tracer().span("compile.analysis"):
+        with get_tracer().phase("compile.analysis"):
             report = self.analyze_plan()
         # stashed so post-compile consumers (the elastic coordinator's
         # recovery event) reuse this run instead of re-running the pipeline

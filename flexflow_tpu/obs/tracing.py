@@ -50,6 +50,7 @@ contain their children.
 from __future__ import annotations
 
 import contextvars
+import functools
 import itertools
 import json
 import numbers
@@ -59,6 +60,8 @@ import time
 import uuid
 from collections import deque
 from typing import Any, Dict, List, Optional
+
+from .startup import record_first_dispatch, record_phase
 
 
 class _NullSpan:
@@ -279,6 +282,33 @@ class _Span:
         return False
 
 
+class _Phase:
+    """A span of a one-shot phase (`Tracer.phase`): the span it wraps, plus
+    its wall duration added to `ff_startup_seconds{phase}` on exit — a sink
+    that is readable when neither the ring nor a profiler was on."""
+
+    __slots__ = ("_span", "_name", "_t0")
+
+    def __init__(self, span, name: str):
+        self._span = span
+        self._name = name
+
+    def set(self, **args) -> "_Phase":
+        self._span.set(**args)
+        return self
+
+    def __enter__(self):
+        self._t0 = time.perf_counter()
+        self._span.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self._span.__exit__(exc_type, exc, tb)
+        record_phase(self._name, self._t0,
+                     time.perf_counter() - self._t0)
+        return False
+
+
 class Tracer:
     """A span buffer. One process-wide instance (`get_tracer()`) backs the
     whole runtime; independent Tracers exist for tests."""
@@ -321,6 +351,17 @@ class Tracer:
         (`jax.profiler.StepTraceAnnotation`: `_r=1` beside `step_num`),
         so its step view groups device work by the program's steps."""
         return self.span(name, step_num=step_num, _r=1, **args)
+
+    def phase(self, name: str, **args):
+        """A span of a ONE-SHOT phase (the package's import, the TPU
+        client's start, `FFModel.compile()`, a batcher's construction): a
+        `span()` whose wall duration is also added to
+        `ff_startup_seconds{phase=name}` of the default registry and
+        counted in `ff_startup_phase_runs_total`, so set-up can be read
+        after a run whose ring was off and whose profiler opened later.
+        For code that runs once a process or once a model: nothing on a
+        per-pass or per-dispatch path may call it."""
+        return _Phase(self.span(name, **args), name)
 
     def instant(self, name: str, **args) -> None:
         """A zero-duration marker (Chrome "i" event) — e.g. the moment a
@@ -506,17 +547,90 @@ def span(name: str, **args):
     return _TRACER.span(name, **args)
 
 
+class _FirstCall:
+    """What `first_call` leaves on `owner.attr` until the first call has
+    put `fn` back. Everything but the call itself (`lower`, `__name__`)
+    is `fn`'s own. The first call is timed INLINE: one frame between the
+    caller and `fn`, and none after."""
+
+    # weak-referenceable: jax.eval_shape and friends key caches by function
+    __slots__ = ("_fn", "_program", "_owner", "_attr", "_called",
+                 "__weakref__")
+
+    def __init__(self, fn, program, owner, attr):
+        self._fn, self._program = fn, program
+        self._owner, self._attr = owner, attr
+        self._called = False
+
+    def __getattr__(self, name):
+        return getattr(self._fn, name)
+
+    def __call__(self, *a, **k):
+        if self._called:    # a caller that kept this object: plain calls
+            return self._fn(*a, **k)
+        self._called = True
+        # a caller that swapped the attribute meanwhile keeps its own
+        if getattr(self._owner, self._attr, None) is self:
+            setattr(self._owner, self._attr, self._fn)
+        t0 = time.perf_counter()
+        try:
+            with _TRACER.span("first_dispatch", program=self._program):
+                return self._fn(*a, **k)
+        finally:
+            record_first_dispatch(self._program, t0,
+                                  time.perf_counter() - t0)
+
+
+def first_call(fn, program: str, owner, attr: str):
+    """`owner.attr = first_call(jitted, "decode_all", owner, "attr")`: the
+    FIRST call of `fn` is timed on the host clock, dispatch to return
+    (trace, lowering, cache lookup, compile or load, argument transfer,
+    enqueue — not the device's run, which nothing blocks on), under a
+    `first_dispatch` span into `ff_first_dispatch_seconds{program}`, and
+    puts the bare `fn` back on `owner.attr`: the second and every later
+    call go straight to it — no wrapper is left on the dispatch path."""
+    return _FirstCall(fn, program, owner, attr)
+
+
+def phased(name: str):
+    """Decorator: every call of the function is `Tracer.phase(name)` — for
+    a constructor, which is one-shot by nature (`ContinuousBatcher`)."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def wrapper(*a, **k):
+            with _TRACER.phase(name):
+                return fn(*a, **k)
+        return wrapper
+    return deco
+
+
 def traced_dispatch(fn, name: str):
     """Wrap a jitted step function so each host-side dispatch becomes a
     span. The wall time is the DISPATCH (host call until the result's
     futures are returned), not device completion — jax dispatch is async;
-    the per-step wall clock lives in StepStats."""
+    the per-step wall clock lives in StepStats. The first dispatch is also
+    the program's first call: its seconds go to
+    `ff_first_dispatch_seconds` under the jitted function's own name. It
+    is timed HERE, in this frame: a frame more between the span and `fn`
+    made the trace of a 24-layer train step with Pallas kernels 3 s
+    longer on the chip (PERF.md section 6, PR 35)."""
     tr = _TRACER
+    program = getattr(fn, "__name__", name)
+    first = True
 
     def wrapper(*a, **k):
+        nonlocal first
+        if first:
+            first = False
+            t0 = time.perf_counter()
+            try:
+                with tr.span(name):
+                    return fn(*a, **k)
+            finally:
+                record_first_dispatch(program, t0, time.perf_counter() - t0)
         with tr.span(name):
             return fn(*a, **k)
 
     wrapper.__wrapped__ = fn
-    wrapper.__name__ = getattr(fn, "__name__", name)
+    wrapper.__name__ = program
     return wrapper

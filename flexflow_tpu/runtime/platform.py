@@ -57,13 +57,31 @@ def cpu_mesh_from_env(n_host_devices: int = 8) -> None:
         force_platform("cpu", n_host_devices=n_host_devices)
 
 
+_devices_asked = False
+
+
+def backend_devices():
+    """`jax.devices()`. The first call through here is the one-shot phase
+    `startup.platform` (obs/startup.py): on a TPU it is the call that makes
+    jax create its backend client, seconds long; where something else has
+    created the client already the phase reads about nothing."""
+    global _devices_asked
+    import jax
+
+    if _devices_asked:
+        return jax.devices()
+    _devices_asked = True
+    from ..obs.tracing import get_tracer
+
+    with get_tracer().phase("startup.platform"):
+        return jax.devices()
+
+
 def require_tpu(what: str, min_devices: int = 1):
     """jax's devices, or SystemExit when they are not at least
     `min_devices` TPU chips: what measures the chip (bench.py,
     chip_smoke.py, scripts/) runs there or not at all."""
-    import jax
-
-    devices = jax.devices()
+    devices = backend_devices()
     if devices[0].platform != "tpu":
         raise SystemExit(
             f"{what}: needs a TPU; jax found {devices[0].platform!r}"

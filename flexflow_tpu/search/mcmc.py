@@ -79,7 +79,7 @@ def mcmc_search(graph: Graph, config, machine: MachineModel,
     as unity_optimize does — so the two searches are comparable)."""
     from ..obs.tracing import get_tracer
 
-    with get_tracer().span("search", algo="mcmc", n_devices=n_devices):
+    with get_tracer().phase("search", algo="mcmc", n_devices=n_devices):
         return _mcmc_search_inner(graph, config, machine, batch_size,
                                   n_devices, simulator)
 

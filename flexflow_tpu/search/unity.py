@@ -401,8 +401,8 @@ class GraphSearchHelper:
                        live_plan=None) -> SearchResult:
         from ..obs.tracing import get_tracer
 
-        with get_tracer().span("search", n_devices=n_devices,
-                               batch_size=batch_size) as sp:
+        with get_tracer().phase("search", n_devices=n_devices,
+                                batch_size=batch_size) as sp:
             result = self._graph_optimize_inner(batch_size, n_devices,
                                                 memory_budget_bytes,
                                                 rule_spec,
@@ -1447,9 +1447,9 @@ def unity_optimize(graph: Graph, config, machine: MachineModel,
         entry = cache.get_entry(key)
         if entry is not None:
             tier, data = entry
-            with get_tracer().span("search", backend="cache",
-                                   n_devices=n_devices,
-                                   batch_size=batch_size) as sp:
+            with get_tracer().phase("search", backend="cache",
+                                    n_devices=n_devices,
+                                    batch_size=batch_size) as sp:
                 result = _adopt_cached_plan(graph, config, machine, data,
                                             batch_size, n_devices)
                 if result is not None:
@@ -1509,8 +1509,8 @@ def unity_optimize(graph: Graph, config, machine: MachineModel,
 
         # the native core runs enumerate/prune/simulate internally;
         # one "search" span still marks the phase in the trace
-        with get_tracer().span("search", backend="native",
-                               n_devices=n_devices) as sp:
+        with get_tracer().phase("search", backend="native",
+                                n_devices=n_devices) as sp:
             applied = apply_substitutions(
                 graph, rule_set_from_spec(spec, is_taso))
             result = native.optimize_strategy(

@@ -10,6 +10,7 @@ from typing import Dict, Optional
 import numpy as np
 
 from ..ffconst import CompMode
+from ..obs.tracing import first_call
 
 
 def sampling_logits(probs, temperature: float, top_k):
@@ -87,8 +88,10 @@ class GenerativeSession:
                 CompMode.COMP_MODE_INFERENCE, decode_pos=pos)
             return values[final_guid], new_state
 
-        self._prefill = jax.jit(prefill)
-        self._decode = jax.jit(decode, donate_argnums=(1,))
+        self._prefill = first_call(jax.jit(prefill), "prefill", self,
+                                   "_prefill")
+        self._decode = first_call(jax.jit(decode, donate_argnums=(1,)),
+                                  "decode", self, "_decode")
         self._decode_raw = decode
         self._decode_scans: Dict[tuple, object] = {}
 

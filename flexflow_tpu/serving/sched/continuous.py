@@ -65,6 +65,8 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from ...ffconst import CompMode
+from ...obs.startup import watch_compiles
+from ...obs.tracing import first_call, get_tracer, phased
 from ...runtime.executor import NO_ROW
 from ..batcher import BatcherStopped
 from .admission import AdmissionController
@@ -371,6 +373,7 @@ class ContinuousBatcher:
     Pass an explicit `registry` only for isolated tests.
     """
 
+    @phased("serve.build")
     def __init__(self, model, max_len: int, num_slots: Optional[int] = None,
                  page_size: int = 16, machine=None, max_queue: int = 64,
                  queue_pages_budget: Optional[int] = None,
@@ -663,10 +666,14 @@ class ContinuousBatcher:
         # its sequence from a zeroed batch-1 state that later overwrites
         # the slot's (`_admit_new`); `op_counters` reports it per op
         self._state_resets = 0
-        self._build_fns()
-        self._caches = self._zero_caches()
-        self._band = self._zero_band()
-        self._draft_caches = self._zero_draft_caches()
+        watch_compiles()
+        tracer = get_tracer()
+        with tracer.phase("serve.build.programs"):
+            self._build_fns()
+        with tracer.phase("serve.build.kv_alloc"):
+            self._caches = self._zero_caches()
+            self._band = self._zero_band()
+            self._draft_caches = self._zero_draft_caches()
         self._rid = itertools.count()
         self._queue: List[GenRequest] = []
         self._slots: List[Optional[_Slot]] = [None] * self.num_slots
@@ -979,16 +986,22 @@ class ContinuousBatcher:
             return new_band
 
         # donate the pool caches: the scheduler always threads the newest
-        # ones through, so XLA updates them in place
-        self._prefill_fn = jax.jit(prefill_one, donate_argnums=(2,))
-        self._decode_fn = jax.jit(decode_all, donate_argnums=(2,))
-        self._chunk_fn = jax.jit(prefill_chunk, donate_argnums=(2,))
+        # ones through, so XLA updates them in place. Each program's first
+        # call is timed (`first_call`) and leaves the bare jitted function
+        # on its attribute.
+        def program(attr, fn, donate):
+            setattr(self, attr, first_call(
+                jax.jit(fn, donate_argnums=donate), fn.__name__, self,
+                attr))
+
+        program("_prefill_fn", prefill_one, (2,))
+        program("_decode_fn", decode_all, (2,))
+        program("_chunk_fn", prefill_chunk, (2,))
         # (donating `small` here too would warn: the fused output has no
         # batch-1 cache to reuse the buffers for — they just die)
-        self._last_chunk_fn = jax.jit(prefill_last_chunk,
-                                      donate_argnums=(2,))
-        self._install_fn = jax.jit(install_prefix, donate_argnums=(0,))
-        self._insert_fn = jax.jit(insert_pages, donate_argnums=(0,))
+        program("_last_chunk_fn", prefill_last_chunk, (2,))
+        program("_install_fn", install_prefix, (0,))
+        program("_insert_fn", insert_pages, (0,))
 
         def import_span(caches, small, slot):
             """KV-handoff import (disagg): scatter a shipped sequence's
@@ -999,7 +1012,7 @@ class ContinuousBatcher:
             dispatch queue once per cache array)."""
             return scatter_span(caches, small, slot, attn_names)
 
-        self._import_fn = jax.jit(import_span, donate_argnums=(0,))
+        program("_import_fn", import_span, (0,))
 
         if self.draft_model is None:
             return
@@ -1083,10 +1096,9 @@ class ContinuousBatcher:
             new_caches = op_states(new_state, attn_names)
             return tgt[:, :k_spec], counts, n_acc, new_caches, dc
 
-        self._draft_chunk_fn = jax.jit(draft_chunk, donate_argnums=(2,))
-        self._draft_last_fn = jax.jit(draft_last_chunk,
-                                      donate_argnums=(2,))
-        self._spec_fn = jax.jit(spec_decode_all, donate_argnums=(2, 5))
+        program("_draft_chunk_fn", draft_chunk, (2,))
+        program("_draft_last_fn", draft_last_chunk, (2,))
+        program("_spec_fn", spec_decode_all, (2, 5))
 
     # -- lifecycle ---------------------------------------------------------
     def start(self) -> None:
@@ -1171,8 +1183,6 @@ class ContinuousBatcher:
         prefill and emits its FIRST token, then PARKS — KV resident,
         slot held, no decoding — for the fleet KV-handoff plane
         (`request_export` / `release_parked` / `resume_parked`)."""
-        from ...obs.tracing import get_tracer
-
         prefill_only = bool(prefill_only) or self.role == "prefill"
         if prefill_only and self.draft_model is not None:
             raise ValueError(
@@ -1817,8 +1827,6 @@ class ContinuousBatcher:
                 and not self._pending_handoffs)
 
     def _loop(self) -> None:
-        from ...obs.tracing import get_tracer
-
         tracer = get_tracer()
         tracer.set_thread_name(self.trace_label)
         params = self.model.params

@@ -117,10 +117,24 @@ def test_main_runs_the_right_phases_and_ends_with_the_ok_line(
     assert chip_smoke.main(argv) == 0
     lines = capsys.readouterr().out.strip().splitlines()
     assert [json.loads(ln)["phase"] for ln in lines[:-1]] == (
-        ["device"] + phases)
+        ["device"] + phases + ["cold_start"])
     assert lines[-1] == json.dumps({"ok": True, "device": {
         "platform": devices[0].platform, "kind": devices[0].device_kind,
         "count": len(devices)}})
+
+
+def test_cold_start_line_names_the_phases_and_the_first_calls(clock, capsys):
+    """Printed once at the end of a run: what the process recorded of its
+    own start-up (obs/startup.py), seconds by phase and by program."""
+    chip_smoke.run_phase("train", clock, chip_smoke.phase_train, TOY, 0)
+    capsys.readouterr()
+    line = chip_smoke.cold_start_line()
+    assert json.loads(capsys.readouterr().out) == line
+    seconds = line["seconds"]
+    assert {"compile", "compile.init_params", "compile.build_steps",
+            "first_dispatch:multi_step"} <= set(seconds)
+    assert all(v >= 0 for v in seconds.values())
+    assert seconds["compile"] >= seconds["compile.init_params"]
 
 
 def test_script_exits_nonzero_without_a_tpu():

@@ -945,3 +945,142 @@ def test_conftest_fixture_resets_counters():
     assert checkpoint_counters() == {}
     assert diagnostic_counters() == {}
     assert obs.get_tracer().events() == []
+
+
+# ---------------------------------------------------------------------------
+# cold start: one-shot phases, first calls, compile stages by program
+# (docs/observability.md "Cold start")
+# ---------------------------------------------------------------------------
+def _startup(family, **labels):
+    fam = obs.REGISTRY.get(family)
+    return fam.value(**labels) if fam is not None else 0.0
+
+
+def test_phase_records_to_the_registry_ring_off_and_to_the_ring_when_on():
+    tr = Tracer()                   # ring off, no profiler anywhere
+    with tr.phase("toy.phase", n=1) as ph:
+        ph.set(found=2)
+        time.sleep(0.01)
+    first = _startup("ff_startup_seconds", phase="toy.phase")
+    assert 0.01 <= first < 1.0
+    assert _startup("ff_startup_phase_runs_total", phase="toy.phase") == 1
+    assert _startup("ff_startup_phase_at_seconds", phase="toy.phase") \
+        <= time.perf_counter() - first
+    assert tr.events() == []
+    tr.enable()
+    with tr.phase("toy.phase", n=2) as ph:
+        ph.set(found=3)
+    (ev,) = tr.events("toy.phase")
+    assert ev["args"] == {"n": 2, "found": 3} and ev["ph"] == "X"
+    # a gauge that ADDS, beside the count of runs to divide by
+    assert _startup("ff_startup_seconds", phase="toy.phase") >= first
+    assert _startup("ff_startup_phase_runs_total", phase="toy.phase") == 2
+
+
+def test_compile_phase_holds_its_four_children_each_run_once():
+    _small_model(batch=8, search_budget=4, num_devices=8,
+                 measure_op_costs=False)
+    children = ("search", "compile.analysis", "compile.init_params",
+                "compile.build_steps")
+    for phase in ("compile",) + children:
+        assert _startup("ff_startup_phase_runs_total", phase=phase) == 1, \
+            phase
+        assert _startup("ff_startup_seconds", phase=phase) > 0, phase
+    assert _startup("ff_startup_seconds", phase="compile") >= sum(
+        _startup("ff_startup_seconds", phase=c) for c in children)
+    start = {p: _startup("ff_startup_phase_at_seconds", phase=p)
+             for p in ("compile",) + children}
+    assert all(start[c] >= start["compile"] for c in children)
+
+
+def test_first_call_records_once_forwards_and_leaves_the_bare_function():
+    from flexflow_tpu.obs.tracing import first_call
+
+    calls = []
+
+    def program(a, b=0):
+        calls.append((a, b))
+        time.sleep(0.005)
+        return a + b
+
+    class Owner:
+        pass
+
+    owner = Owner()
+    owner.fn = held = first_call(program, "toy_program", owner, "fn")
+    assert held.__name__ == "program"       # everything else is fn's own
+    import weakref
+
+    weakref.ref(held)       # jax.eval_shape keys a cache by the function
+    t_before = time.perf_counter()
+    assert owner.fn(1, b=2) == 3
+    assert owner.fn is program              # no wrapper left behind
+    secs = _startup("ff_first_dispatch_seconds", program="toy_program")
+    assert 0.005 <= secs < 1.0
+    assert t_before <= _startup("ff_first_dispatch_at_seconds",
+                                program="toy_program") \
+        <= time.perf_counter() - secs
+    assert owner.fn(4) == 4 and held(5) == 5    # a kept handle still calls
+    assert _startup("ff_first_dispatch_seconds",
+                    program="toy_program") == secs   # recorded ONCE
+    assert calls == [(1, 2), (4, 0), (5, 0)]
+    # whoever swapped the attribute before the first call keeps their own
+    owner.fn = held2 = first_call(program, "toy_program", owner, "fn")
+    owner.fn = lambda *a: held2(*a)
+    swapped = owner.fn
+    assert owner.fn(7) == 7 and owner.fn is swapped
+
+
+def test_traced_dispatch_times_the_first_call_and_adds_no_frame_after():
+    """The executor's programs: the first dispatch lands in
+    `ff_first_dispatch_seconds` under the program's own name, timed in
+    the span wrapper's own frame — neither the first `fit()` dispatch (a
+    frame more slowed the trace of a deep train step on the chip) nor any
+    later one pays a frame for it."""
+    import sys
+
+    depths = []
+
+    def multi_step(x):
+        f, n = sys._getframe(), 0
+        while f is not None:
+            f, n = f.f_back, n + 1
+        depths.append(n)
+        return x
+
+    step = obs.traced_dispatch(multi_step, "executor.multi_step")
+    here = multi_step(0) or depths.pop()     # this frame + the function's
+    for i in range(3):
+        step(i)
+    assert depths[0] == depths[1] == depths[2] == here + 1  # + the wrapper
+    assert _startup("ff_first_dispatch_seconds", program="multi_step") > 0
+    assert step.__wrapped__ is multi_step
+
+    m = _small_model(num_devices=1)     # over a mesh the SECOND dispatch
+    x, y = _data()                      # compiles again (PERF.md section 7)
+    m.fit(x, y, epochs=1)
+    assert _startup("ff_first_dispatch_seconds", program="train_step") > 0
+    # compile stages by program, from jax.monitoring
+    for stage in ("trace", "lower", "backend"):
+        assert _startup("ff_compile_seconds_total", program="train_step",
+                        stage=stage) > 0, stage
+    assert _startup("ff_compiles_total", program="train_step") == 1
+
+
+def test_cold_start_families_pass_validate_exposition():
+    m = _small_model()
+    x, y = _data()
+    m.fit(x, y, epochs=1)
+    fams = validate_exposition(obs.REGISTRY.render())
+    for name, kind in (("ff_startup_seconds", "gauge"),
+                       ("ff_startup_phase_at_seconds", "gauge"),
+                       ("ff_startup_phase_runs_total", "counter"),
+                       ("ff_first_dispatch_seconds", "gauge"),
+                       ("ff_first_dispatch_at_seconds", "gauge"),
+                       ("ff_compile_seconds_total", "counter"),
+                       ("ff_compiles_total", "counter")):
+        assert fams[name]["type"] == kind and fams[name]["samples"], name
+    phases = {lbl["phase"] for _, lbl, _ in
+              fams["ff_startup_seconds"]["samples"]}
+    assert {"compile", "compile.init_params", "compile.build_steps"} \
+        <= phases
