@@ -18,7 +18,8 @@ observe, first match wins:
     timed row; `attention_decode_mq` (chunks, speculative verify):
     reference (its kernel in kernels/pallas/decode.py walks every
     allocated block and is reachable by override alone);
-    `grouped_experts`: `small_experts`.
+    `grouped_experts`: `small_experts` (two crossings: the compute ridge,
+    and a step whose assignments miss a good part of the experts).
 
 The mesh is the call sites' business (GSPMD cannot partition a Mosaic
 kernel): ops/attention.py runs flash under shard_map (`_on_mesh`) and
@@ -65,15 +66,41 @@ def flash_crossover(batch: int, heads: int, q_len: int, k_len: int,
 # 640 5.44 / 2.74; PERF.md section 6, PR 33)
 EXPERT_BLOCK_BYTES = 2 << 20
 EXPERT_RIDGE_ROWS = 256
+# the bytes of held experts a step's assignments are expected to leave
+# unchosen, past which the few-rows form's stream of every HELD expert
+# costs more than the tiled form's sort, gather and one grid step a group
+# (the same v5e and experts, whole op, few-rows / tiled ms a layer by token
+# rows, with the expected hit share and the unchosen MB of the 1,611 held: 8
+# rows 0.22 1,254 2.15 / 0.52, 16 0.39 976 2.15 / 0.90, 24 0.53 760 2.15 /
+# 1.19, 40 0.71 460 2.15 / 1.60, 64 0.87 217 2.16 / 1.92, 96 0.95 80 2.16 /
+# 2.15, 128 0.98 29 2.16 / 2.22: they cross at 96 rows, where the tiled
+# form's ~0.1 ms of extra fixed cost is 80 MB of the memory's stream;
+# PERF.md section 6, PR 36)
+EXPERT_UNREAD_BYTES = 128 << 20
 
 
-def small_experts(rows: int, matrix_bytes: int) -> bool:
-    """Whether routed experts over `rows` token rows, each matrix of
-    `matrix_bytes`, take the tiled grouped matmul (ops/moe.py `_tiled_dot`)
-    in place of the few-rows form: past the ridge every row through every
-    expert is compute the chosen experts do not need, and the kernel as it
-    is tiles the sorted rows alone."""
-    return rows > EXPERT_RIDGE_ROWS and matrix_bytes <= EXPERT_BLOCK_BYTES
+def expected_hit_share(assignments: int, experts_total: int) -> float:
+    """The share of the experts that `assignments` choices of a uniform
+    router over `experts_total` hit at least once; of the experts held
+    anywhere, the same share."""
+    return 1.0 - (1.0 - 1.0 / experts_total) ** assignments
+
+
+def small_experts(rows: int, k: int, held: int, experts_total: int,
+                  matrix_bytes: int) -> bool:
+    """Whether routed experts over `rows` token rows that each choose `k` of
+    `experts_total`, `held` of them here with three matrices of
+    `matrix_bytes` each, take the tiled grouped matmul (ops/moe.py
+    `_tiled_dot`) in place of the few-rows form. Two crossings: past the
+    ridge every row through every expert is compute the chosen experts do
+    not need; far under it, where the step's assignments are expected to
+    miss a good part of the held experts, every one of them streamed is
+    bytes the tiled form does not read. The kernel as it is tiles the
+    sorted rows alone, so on either side a matrix is one block."""
+    unread = (1.0 - expected_hit_share(rows * k, experts_total)) * (
+        held * 3 * matrix_bytes)
+    return matrix_bytes <= EXPERT_BLOCK_BYTES and (
+        rows > EXPERT_RIDGE_ROWS or unread > EXPERT_UNREAD_BYTES)
 
 
 # what the dense decode kernel (kernels/pallas/decode.py
@@ -140,14 +167,15 @@ class KernelRegistry:
 
     def select(self, family: str, *, param: Optional[bool] = None,
                scores: Optional[Tuple[int, int, int, int, int]] = None,
-               experts: Optional[Tuple[int, int]] = None,
+               experts: Optional[Tuple[int, int, int, int, int]] = None,
                decode: Optional[Tuple[int, int, int]] = None,
                record: bool = True) -> KernelChoice:
         """Pick the impl for one op instance. `param` is the op's own
         explicit setting (attention's use_flash); `scores` the attention
         instance's `flash_crossover` arguments (batch, heads, q_len,
         k_len, dp), `experts` the routed product's `small_experts`
-        arguments (token rows, bytes of one expert's matrix), `decode` the
+        arguments (token rows, experts a token, experts held, experts in
+        all, bytes of one expert's matrix), `decode` the
         dense decode step's `filled_rows_decode` arguments (head width,
         bytes of a cached value, cache rows) — `attention_decode_mq` has
         no shape predicate and stays on the reference, `latent_decode` is

@@ -496,7 +496,10 @@ def _ragged_dot(a, m, sizes):
 # sorted rows a step of the tiled grouped matmul multiplies: a tile that
 # spans g groups is multiplied g times, so many small groups want a small
 # tile (a v5e, 512 token rows x 8 through 256 experts of 2048 x 512, ms a
-# layer: 2.60 at 64, 2.62 at 128, 2.75 at 256; PERF.md section 6, PR 33)
+# layer: 2.60 at 64, 2.62 at 128, 2.75 at 256; PERF.md section 6, PR 33).
+# A decode step's few sorted rows do not want a smaller one (40 token rows
+# x 8, padded to 384: 1.651 at 16, 1.614 at 32, 1.598 at 64, 1.597 at 128;
+# 8 rows 0.529 / 0.529 / 0.517 / 0.520; PERF.md section 6, PR 36)
 GROUP_TILE_ROWS = 128
 
 
@@ -567,10 +570,14 @@ class GatedExpertsOp(Op):
     number of token rows (`few_rows`): for few rows every row goes through
     every held expert (`_few_rows_product`), for many the assignments are
     sorted by expert and multiplied group by group (`_grouped_product`,
-    by XLA's `ragged_dot`). Few rows of SMALL experts past the chip's
-    ridge are sorted too and multiplied by the Pallas grouped matmul
-    (`_tiled_dot`; `kernels/registry.py small_experts` decides, serving
-    steps on one TPU alone).
+    by XLA's `ragged_dot`). Few rows of SMALL experts are sorted too and
+    multiplied by the Pallas grouped matmul (`_tiled_dot`) on either side
+    of the few-rows form's own ground: past the chip's ridge, and where
+    the step's assignments are expected to miss a good part of the held
+    experts' bytes, which that kernel then does not read (a decode step of
+    40 rows x 8 on 256 experts hits 71 % of them);
+    `kernels/registry.py small_experts` decides, serving steps on one TPU
+    alone.
 
     Router health, threaded by the continuous batcher from one decode
     iteration to the next (`serving_counters`): `assignments` (local
@@ -630,12 +637,14 @@ class GatedExpertsOp(Op):
                 WeightSpec("few_rows_steps", (), DataType.DT_INT32, z)]
 
     @staticmethod
-    def _tiled(ctx, rows: int, matrix, cdt) -> bool:
-        """Whether a step of few token rows sorts them and takes the Pallas
-        grouped matmul: the registry's call (`small_experts`), and only
-        where nothing differentiates the step (the kernel's rows of no
-        group are unwritten, which a gradient would read) and GSPMD does
-        not partition it."""
+    def _tiled(ctx, rows: int, k: int, experts_total: int, matrix,
+               cdt) -> bool:
+        """Whether a step of few token rows, `k` of `experts_total` experts
+        a row through the held experts' `matrix` stack, sorts them and
+        takes the Pallas grouped matmul: the registry's call
+        (`small_experts`), and only where nothing differentiates the step
+        (the kernel's rows of no group are unwritten, which a gradient
+        would read) and GSPMD does not partition it."""
         from ..ffconst import CompMode
         from ..kernels.registry import KERNELS
 
@@ -643,7 +652,8 @@ class GatedExpertsOp(Op):
             ctx.mode != CompMode.COMP_MODE_TRAINING
             and not ctx.gspmd_partitioned()
             and KERNELS.select("grouped_experts", experts=(
-                rows, matrix[0].size * jnp.dtype(cdt).itemsize)))
+                rows, k, matrix.shape[0], experts_total,
+                matrix.size // matrix.shape[0] * jnp.dtype(cdt).itemsize)))
 
     def lower(self, ctx, inputs, weights):
         from .common import emit_dtype, matmul_dtype
@@ -656,7 +666,9 @@ class GatedExpertsOp(Op):
         cdt = matmul_dtype(getattr(ctx, "config", None), x.dtype)
         few = few_rows(x.shape[0])
         product = _few_rows_product if few else _grouped_product
-        if few and self._tiled(ctx, x.shape[0], weights["w_gate"], cdt):
+        if few and self._tiled(ctx, x.shape[0], k,
+                               self.params["experts_total"],
+                               weights["w_gate"], cdt):
             few, product = False, functools.partial(_grouped_product,
                                                     dot=_tiled_dot)
         out, sizes = product(x.astype(cdt), w.reshape(-1, k),

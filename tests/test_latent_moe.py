@@ -354,7 +354,9 @@ def _lower(m, op, ins, weights):
 
 
 # name -> (token rows, the (ids, weights) the experts are handed; None: the
-# router's own). The op holds experts 2..5 of 8, top-2.
+# router's own[, the geometry: experts in all, a token, (first, count) held]).
+# Unless it says otherwise the op holds experts 2..5 of 8, top-2.
+_NARROW, _DECODE = (8, 2, (2, 4)), (256, 8, (0, 256))
 _SKEWS = {
     # as the router assigns: about half of the assignments are absent
     "router": (24, None),
@@ -372,23 +374,37 @@ _SKEWS = {
         np.full((t, 2), 0.5))),
     "one_token": (1, None),
     "decode_128": (128, None),
+    # `lgx_decode_sat`'s decode step at narrow matrices: 40 token rows x 8
+    # over 256 HELD experts (all of them), so a step's 320 assignments miss
+    # a good part of them (~183 hit)
+    "decode_40x8_on_256": (40, None, _DECODE),
+    "decode_one_of_256": (40, lambda t: (np.full((t, 8), 3),
+                                         np.full((t, 8), 0.125)), _DECODE),
+    # every other expert empty: a tile spans groups nobody chose
+    "decode_every_other_empty": (40, lambda t: (
+        2 * ((np.arange(t)[:, None] * 8 + np.arange(8)) % 128),
+        np.full((t, 8), 0.125)), _DECODE),
+    # fewer assignments than experts: 32 on 256, and 8
+    "decode_4x8_on_256": (4, None, _DECODE),
+    "decode_1x8_on_256": (1, None, _DECODE),
 }
 _COUNTERS = ("assignments", "experts_hit", "steps", "load", "few_rows_steps")
 
 
 def _experts_case(case, path, monkeypatch):
-    """(model, op, inputs [x, w, idx], weights) of one case, the op steered
+    """(model, op, inputs [x, w, idx], weights, (first, count) held) of one
+    case, the op steered
     down `path` ('few_rows' | 'grouped' | 'tiled') through the predicate's
     threshold; 'tiled' is few rows with the registry's family forced to the
     Pallas grouped matmul (interpreted here)."""
     from flexflow_tpu.kernels.registry import KERNELS
 
-    tokens, forced = _SKEWS[case]
+    tokens, forced, (total, k, local) = (*_SKEWS[case], _NARROW)[:3]
     monkeypatch.setattr(moe, "FEW_ROWS_MAX", 0 if path == "grouped" else 10**9)
     if path == "tiled":
         monkeypatch.setitem(KERNELS._overrides, "grouped_experts", "pallas")
-    cfg = tiny_cfg()
-    m, router, experts = _experts_op(cfg, (2, 4), tokens)
+    cfg = tiny_cfg(router_width=total, num_experts_per_tok=k)
+    m, router, experts = _experts_op(cfg, local, tokens)
     x = jax.random.normal(jax.random.PRNGKey(0), (tokens, 64), jnp.float32)
     if forced is None:
         w, idx = _lower(m, router, [x], _weights_of(router, 1))
@@ -396,28 +412,32 @@ def _experts_case(case, path, monkeypatch):
     else:
         idx, w = forced(tokens)
         idx, w = jnp.asarray(idx, jnp.int32), jnp.asarray(w, jnp.float32)
-    return m, experts, [x, w, idx], _weights_of(experts, 2)
+    return m, experts, [x, w, idx], _weights_of(experts, 2), local
 
 
 @pytest.mark.parametrize("path", ["few_rows", "grouped", "tiled"])
 @pytest.mark.parametrize("case", list(_SKEWS))
 def test_grouped_experts_equal_the_masked_oracle_and_drop_nothing(
         case, path, monkeypatch):
-    """All forms of the routed product, on skews they treat differently,
-    equal the masked oracle; the four counters read the same on each, and
-    `few_rows_steps` counts the few-rows form alone."""
-    m, experts, ins, ew = _experts_case(case, path, monkeypatch)
+    """All forms of the routed product, on skews they treat differently —
+    among them a decode step's 40 rows x 8 over 256 held experts, which
+    miss a good part of them —, equal the masked oracle; the four counters
+    read the same on each, and `few_rows_steps` counts the few-rows form
+    alone."""
+    m, experts, ins, ew, (first, count) = _experts_case(case, path,
+                                                        monkeypatch)
     x, w, idx = ins
     ctx = LoweringContext(m.config, CompMode.COMP_MODE_INFERENCE)
     before = {"assignments": 7, "experts_hit": 5, "steps": 3,
-              "load": np.zeros(4, np.int32), "few_rows_steps": 2}
+              "load": np.zeros(count, np.int32), "few_rows_steps": 2}
     for name, val in before.items():
         ctx.state[("experts", name)] = jnp.asarray(val, jnp.int32)
     got = experts.lower(ctx, ins, ew)[0]
-    want = gated_experts_oracle(x, w, idx, ew, 2, 4)
+    want = gated_experts_oracle(x, w, idx, ew, first, count)
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=1e-5)
-    local = np.asarray(idx).reshape(-1) - 2
-    load = np.bincount(local[(local >= 0) & (local < 4)], minlength=4)
+    local = np.asarray(idx).reshape(-1) - first
+    load = np.bincount(local[(local >= 0) & (local < count)],
+                       minlength=count)
     after = {k: np.asarray(ctx.state_updates[("experts", k)])
              for k in _COUNTERS}
     assert after["assignments"] == 7 + load.sum()
@@ -425,6 +445,8 @@ def test_grouped_experts_equal_the_masked_oracle_and_drop_nothing(
     assert after["steps"] == 4
     np.testing.assert_array_equal(after["load"], load)
     assert after["few_rows_steps"] == 2 + (path == "few_rows")
+    if case.startswith("decode_") and count == 256:
+        assert (load > 0).sum() < count         # a good part is not hit
     if case == "all_absent":
         assert not np.asarray(got).any()
     if case == "one_expert":
@@ -436,7 +458,7 @@ def test_grouped_experts_equal_the_masked_oracle_and_drop_nothing(
 def test_experts_gradients_equal_the_oracles(case, path, monkeypatch):
     """d/d(x, router weights, the three stacks) of a scalar of the output,
     through either form, is the oracle's."""
-    m, experts, (x, w, idx), ew = _experts_case(case, path, monkeypatch)
+    m, experts, (x, w, idx), ew, _ = _experts_case(case, path, monkeypatch)
     probe = jax.random.normal(jax.random.PRNGKey(5), x.shape, jnp.float32)
     got = jax.grad(lambda x, w, ew: jnp.sum(
         probe * _lower(m, experts, [x, w, idx], ew)[0]), (0, 1, 2))(x, w, ew)
@@ -457,27 +479,82 @@ def test_few_rows_is_chosen_from_the_static_rows_alone():
     assert GatedExpertsOp.serving_counters == _COUNTERS
 
 
-def test_tiled_grouped_is_chosen_from_platform_shape_and_mode(monkeypatch):
-    """The Pallas grouped matmul takes a step of few rows on a TPU alone,
-    past the ridge, where one expert's matrix is one VMEM block
-    (`laguna_xs2_1chip`'s chunk of 512 rows, not its 40 decode rows, not
-    `mistral_small4_ep4`'s 4096 x 2048 experts), and never a training step
-    (rows of no group are unwritten: a gradient would read them)."""
+# (token rows, experts a token, experts held, experts in all, bytes of one
+# matrix) -> the tiled grouped matmul or not: both sides of both crossings
+_LGX, _MS4 = 2048 * 512 * 2, 4096 * 2048 * 2
+_SMALL_EXPERTS = {
+    "lgx-one-row": ((1, 8, 256, 256, _LGX), True),        # 3 % of them hit
+    "lgx-8-slots": ((8, 8, 256, 256, _LGX), True),        # 22 %
+    "lgx-decode-40": ((40, 8, 256, 256, _LGX), True),     # 71 %: 460 MB not
+    "lgx-64-slots": ((64, 8, 256, 256, _LGX), True),      # 87 %: 217 MB
+    "lgx-96-slots": ((96, 8, 256, 256, _LGX), False),     # 95 %: the tie
+    "lgx-128-slots": ((128, 8, 256, 256, _LGX), False),   # 98 %: all read
+    "lgx-at-the-ridge": ((256, 8, 256, 256, _LGX), False),
+    "lgx-chunk-512": ((512, 8, 256, 256, _LGX), True),    # past the ridge
+    "ms4-decode-128": ((128, 4, 32, 128, _MS4), False),   # 98 %, and 16 MiB
+    "ms4-chunk-512": ((512, 4, 32, 128, _MS4), False),    # no one block
+    "ms4-8-slots": ((8, 4, 32, 128, _MS4), False),        # 22 %, but 16 MiB
+    # few bytes held: nothing worth a sort is left unread, whatever the share
+    "four-held-of-256": ((1, 8, 4, 256, _LGX), False),
+    "sixteen-all-hit": ((8, 8, 16, 16, _LGX), False),
+    "sixteen-one-row": ((1, 8, 16, 16, _LGX), False),     # 60 % of 100 MB
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SMALL_EXPERTS))
+def test_small_experts_has_two_crossings(case):
+    """`registry.small_experts` from static shapes alone: the tiled grouped
+    matmul past the compute ridge, and far under it where the step's
+    assignments are expected to leave more of the held experts' bytes
+    unchosen than a sort is worth (`laguna_xs2_1chip`'s 40 decode rows, not
+    128 slots of it, not `mistral_small4_ep4`'s 4096 x 2048 experts at any
+    row count, not a handful of held experts)."""
     from flexflow_tpu.kernels import registry
 
-    lgx, ms4 = 2048 * 512 * 2, 4096 * 2048 * 2
-    assert registry.small_experts(512, lgx)
-    assert not registry.small_experts(40, lgx)
-    assert not registry.small_experts(registry.EXPERT_RIDGE_ROWS, lgx)
-    assert not registry.small_experts(512, ms4)
-    matrix = jnp.zeros((4, 2048, 512), jnp.bfloat16)
-    tiled = lambda mode, rows=512: GatedExpertsOp._tiled(
-        LoweringContext(ff.FFConfig(), mode), rows, matrix, jnp.bfloat16)
-    assert not tiled(CompMode.COMP_MODE_INFERENCE)     # the CPU backend
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert tiled(CompMode.COMP_MODE_INFERENCE)
-    assert not tiled(CompMode.COMP_MODE_INFERENCE, rows=40)
-    assert not tiled(CompMode.COMP_MODE_TRAINING)
+    shape, tiled = _SMALL_EXPERTS[case]
+    assert registry.small_experts(*shape) == tiled
+
+
+@pytest.mark.parametrize("rows,k,total", [(40, 8, 256), (128, 4, 128),
+                                          (128, 8, 256), (8, 8, 256)])
+def test_expected_hit_share_is_a_uniform_routers(rows, k, total):
+    """The rule's quantity against a count: `rows` tokens that each choose
+    `k` distinct experts of `total` uniformly hit, on average, the expected
+    share of them to a few percent (0.71 at `lgx_decode_sat`'s decode step,
+    0.98 at `ms4_decode_sat`'s)."""
+    from flexflow_tpu.kernels import registry
+
+    chosen = np.random.default_rng(0).random((200, rows, total)).argsort(
+        -1)[..., :k].reshape(200, -1)        # k distinct of `total` a row
+    hit = np.mean([np.unique(step).size for step in chosen]) / total
+    assert abs(hit - registry.expected_hit_share(rows * k, total)) < 0.02
+
+
+@pytest.mark.parametrize("case,mode,backend,rows,tiled", [
+    ("cpu-backend", CompMode.COMP_MODE_INFERENCE, "cpu", 512, False),
+    ("tpu-chunk", CompMode.COMP_MODE_INFERENCE, "tpu", 512, True),
+    ("tpu-decode-40", CompMode.COMP_MODE_INFERENCE, "tpu", 40, True),
+    ("cpu-decode-40", CompMode.COMP_MODE_INFERENCE, "cpu", 40, False),
+    ("tpu-decode-128", CompMode.COMP_MODE_INFERENCE, "tpu", 128, False),
+    ("tpu-training-512", CompMode.COMP_MODE_TRAINING, "tpu", 512, False),
+    ("tpu-training-40", CompMode.COMP_MODE_TRAINING, "tpu", 40, False),
+    ("tpu-gspmd-40", CompMode.COMP_MODE_INFERENCE, "tpu-gspmd", 40, False),
+    ("tpu-gspmd-512", CompMode.COMP_MODE_INFERENCE, "tpu-gspmd", 512, False),
+])
+def test_tiled_grouped_is_chosen_from_platform_shape_and_mode(
+        monkeypatch, case, mode, backend, rows, tiled):
+    """The Pallas grouped matmul takes a step of few rows on a TPU alone,
+    on either side of `small_experts`' two crossings, and never a training
+    step (rows of no group are unwritten: a gradient would read them) nor
+    one that GSPMD partitions (it cannot split a Mosaic kernel)."""
+    matrix = jax.ShapeDtypeStruct((256, 2048, 512), jnp.bfloat16)
+    ctx = LoweringContext(ff.FFConfig(), mode)
+    platform, _, gspmd = backend.partition("-")
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    if gspmd:
+        monkeypatch.setattr(ctx, "gspmd_partitioned", lambda: True)
+    assert GatedExpertsOp._tiled(ctx, rows, 8, 256, matrix,
+                                 jnp.bfloat16) == tiled
 
 
 def test_four_shares_and_the_shared_expert_add_up_to_the_uncut_layer():

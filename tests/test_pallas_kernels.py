@@ -309,6 +309,8 @@ LM_WINDOW = (1, 16, 1024, 1024, 1)      # lm_osdi22w's lockstep window
 SEQ_128 = (64, 16, 128, 128, 1)
 
 # (platform, family, use_flash, override, scores) -> (impl, reason)
+# bytes of one expert's matrix: laguna_xs2_1chip's, mistral_small4_ep4's
+LGX_EXPERT, MS4_EXPERT = 2048 * 512 * 2, 4096 * 2048 * 2
 DECISIONS = {
     "cpu-bert": ("cpu", "attention", None, None, BERT_1CHIP,
                  "reference", "backend"),
@@ -407,18 +409,37 @@ DECISIONS = {
                                       "reference", "override"),
     "gpu-latent": ("gpu", "latent_decode", None, None, None,
                    "reference", "backend"),
-    # routed experts over few token rows (rows, bytes of one matrix): the
-    # tiled grouped matmul past the ridge where a matrix is one VMEM block
+    # routed experts over few token rows (rows, experts a token, experts
+    # held, experts in all, bytes of one matrix): the tiled grouped matmul
+    # where a matrix is one VMEM block, past the ridge or where the
+    # assignments are expected to miss a good part of the held experts
     "tpu-experts-lgx-chunk": ("tpu", "grouped_experts", None, None,
-                              (512, 2048 * 512 * 2), "pallas", "shape"),
+                              (512, 8, 256, 256, LGX_EXPERT), "pallas",
+                              "shape"),
     "tpu-experts-lgx-decode": ("tpu", "grouped_experts", None, None,
-                               (40, 2048 * 512 * 2), "reference", "shape"),
+                               (40, 8, 256, 256, LGX_EXPERT), "pallas",
+                               "shape"),
+    "tpu-experts-lgx-8-slots": ("tpu", "grouped_experts", None, None,
+                                (8, 8, 256, 256, LGX_EXPERT), "pallas",
+                                "shape"),
+    "tpu-experts-lgx-128-slots": ("tpu", "grouped_experts", None, None,
+                                  (128, 8, 256, 256, LGX_EXPERT),
+                                  "reference", "shape"),
     "tpu-experts-ms4-chunk": ("tpu", "grouped_experts", None, None,
-                              (512, 4096 * 2048 * 2), "reference", "shape"),
+                              (512, 4, 32, 128, MS4_EXPERT), "reference",
+                              "shape"),
+    "tpu-experts-ms4-decode": ("tpu", "grouped_experts", None, None,
+                               (128, 4, 32, 128, MS4_EXPERT), "reference",
+                               "shape"),
     "cpu-experts-lgx-chunk": ("cpu", "grouped_experts", None, None,
-                              (512, 2048 * 512 * 2), "reference", "backend"),
+                              (512, 8, 256, 256, LGX_EXPERT), "reference",
+                              "backend"),
+    "cpu-experts-lgx-decode": ("cpu", "grouped_experts", None, None,
+                               (40, 8, 256, 256, LGX_EXPERT), "reference",
+                               "backend"),
     "cpu-experts-override": ("cpu", "grouped_experts", None, "pallas",
-                             (40, 2048 * 512 * 2), "pallas", "override"),
+                             (128, 8, 256, 256, LGX_EXPERT), "pallas",
+                             "override"),
 }
 
 

@@ -134,6 +134,13 @@ CASES = {
         [((512, 2048), BF16), ((512, 8), F32), ((512, 8), I32),
          ((256, 2048, 512), BF16), ((256, 2048, 512), BF16),
          ((256, 512, 2048), BF16)]),
+    # its decode step since PR 36: 40 rows x 8, 320 sorted rows padded to
+    # three tiles over the ~183 experts they hit
+    "tiled_experts_decode": (
+        _tiled_experts,
+        [((40, 2048), BF16), ((40, 8), F32), ((40, 8), I32),
+         ((256, 2048, 512), BF16), ((256, 2048, 512), BF16),
+         ((256, 512, 2048), BF16)]),
 }
 
 
@@ -320,7 +327,10 @@ CELL_PROGRAMS = [
     ("falcon_h1_34b_1chip", "prefill_last_chunk", 0),
     # two layers here: the full layer's decode kernel and none in the
     # window layer (its ring returns before the selection); the gate and
-    # the sigmoid router are XLA's
+    # the sigmoid router are XLA's. Cut to 16 experts (100 MB of them, all
+    # hit by 8 slots x 8) the expert layer keeps the few-rows form in every
+    # program: `small_experts` sends the cell's own 256 through the tiled
+    # grouped matmul (`tiled_experts_decode` / `_chunk` above compile those)
     ("laguna_xs2_1chip", "decode_all", 1),
     ("laguna_xs2_1chip", "prefill_chunk", 0),
     ("laguna_xs2_1chip", "prefill_last_chunk", 0),
