@@ -18,13 +18,32 @@ them as
 
 read / filled is the over-read: 1.0 is the algorithm's need, slots x
 max_len / filled what the reference pays.
+
+The latent op's prefill chunks are counted on the host, where the batcher
+dispatches them (`mla_prefill_chunks_counter`):
+
+ - ff_mla_prefill_chunks_total  Counter, labels=(op, rows): prefill chunks
+   by the rows of the batch-1 holder their expanded path ran over (the
+   shortest prefix that holds the chunk's last query,
+   `LatentAttentionOp.prefill_prefix_rows`); the holder's own length is
+   the whole
 """
 from __future__ import annotations
 
 from typing import Dict, Optional
 
 from .moe import _inc_to
-from .registry import REGISTRY, MetricsRegistry
+from .registry import REGISTRY, Counter, MetricsRegistry
+
+
+def mla_prefill_chunks_counter(
+        registry: Optional[MetricsRegistry] = None) -> Counter:
+    """The family `ff_mla_prefill_chunks_total{op, rows}`."""
+    reg = registry if registry is not None else REGISTRY
+    return reg.counter(
+        "ff_mla_prefill_chunks_total",
+        "Latent attention prefill chunks by the rows of the batch-1 holder"
+        " their expanded path ran over", labels=("op", "rows"))
 
 
 def publish_attention_row_metrics(

@@ -10,7 +10,7 @@ flexflow_tpu/kernels/ and are selected via params.
 from __future__ import annotations
 
 import functools
-from typing import List
+from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -120,13 +120,14 @@ def _context(probs, vc, together, kv_heads):
 PREFIX_ROWS = 2048
 
 
-def _prefixes(rows: int) -> List[int]:
+def _prefixes(rows: int, base: Optional[int] = None) -> List[int]:
     """The static lengths a cache of `rows` rows is attended in by queries
-    at a scalar offset: `PREFIX_ROWS`, its doubles under `rows`, and the
-    whole; a cache of up to two of them whole alone."""
-    if rows <= 2 * PREFIX_ROWS:
+    at a scalar offset: `base` (default `PREFIX_ROWS`), its doubles under
+    `rows`, and the whole; a cache of up to two bases whole alone."""
+    base = PREFIX_ROWS if base is None else base
+    if rows <= 2 * base:
         return [rows]
-    out, p = [], PREFIX_ROWS
+    out, p = [], base
     while p < rows:
         out.append(p)
         p *= 2

@@ -14,7 +14,10 @@ Two paths compute that, chosen from what the step sees:
 
   expanded  a prefill chunk, a one-shot prefill, training: k_nope and v of
             every row are materialised through W_kvb (hundreds of queries
-            share the expansion) and the core is plain attention.
+            share the expansion) and the core is plain attention. At a
+            scalar offset (a chunk on a batch-1 holder, the lockstep
+            session's step) "every row" is the shortest static prefix of
+            the holder that holds the queries' last row (`prefill_ladder`).
   absorbed  decode and speculative verify (a (B,) vector of positions): the
             query is carried into the latent space, q~_h = q_nope_h
             W_kvb[K,h]^T, scores and context are taken on the latent rows
@@ -91,6 +94,29 @@ def _wide_add(counter, by):
 def wide_count(counter) -> int:
     """The host's reading of a row counter of `serving_counters`."""
     return (int(counter[0]) << _WIDE_BITS) + int(counter[1])
+
+
+# the least base of `prefill_ladder`: a chunk of C queries starts its ladder
+# at C, a step of few queries (the lockstep session's one) here and not at
+# 1, 2, 4, ... rows. `mistral_small4_ep4`'s chunks of 512 see 743 rows of
+# their 4,607-row batch-1 holder in the mean (PERF.md section 6)
+PREFILL_PREFIX_FLOOR = 512
+
+
+def prefill_ladder(holder_rows: int, c: int) -> List[int]:
+    """The static prefixes of a holder of `holder_rows` rows that C queries
+    at a scalar offset are attended over: base max(C, PREFILL_PREFIX_FLOOR),
+    its doubles under the holder, and the whole (ops/attention.py
+    `_prefixes`); a holder of up to two bases is attended whole."""
+    from .attention import _prefixes
+
+    return _prefixes(holder_rows, max(c, PREFILL_PREFIX_FLOOR))
+
+
+def _prefix_index(ladder: List[int], end):
+    """Which entry of `ladder` is the first to hold rows [0, end): a host
+    int for a host `end`, a traced one for a traced `end`."""
+    return sum(end > p for p in ladder[:-1])
 
 
 @register_op
@@ -228,22 +254,22 @@ class LatentAttentionOp(Op):
                     held("rows_filled"), jnp.sum(last + 1))
                 ctx.state_updates[(self.name, "rows_read")] = _wide_add(
                     held("rows_read"), read)
+        elif pos is not None:     # a chunk at offset `pos` of a batch-1 cache
+            keys = [jax.lax.dynamic_update_slice(
+                c, part.astype(c.dtype), (0, pos, 0))
+                for c, part in zip(cache, parts)]
+            for n, c in zip(names, keys):
+                ctx.state_updates[(self.name, n)] = c
+            o = self._prefix_expanded(q_nope, q_rope, keys, pos, qpos,
+                                      qscale, weights, cdt)
         else:
-            keys = parts
-            if pos is not None:     # a chunk at offset `pos` of a batch-1 cache
-                keys = [jax.lax.dynamic_update_slice(
-                    c, part.astype(c.dtype), (0, pos, 0))
-                    for c, part in zip(cache, parts)]
-            elif cache is not None and getattr(ctx, "fill_kv_cache", False):
+            if cache is not None and getattr(ctx, "fill_kv_cache", False):
                 for n, c, part in zip(names, cache, parts):
                     ctx.state_updates[(self.name, n)] = (
                         jax.lax.dynamic_update_slice(
                             c, part.astype(c.dtype), (0, 0, 0)))
-            if pos is not None:
-                for n, c in zip(names, keys):
-                    ctx.state_updates[(self.name, n)] = c
-            o = self._expanded(q_nope, q_rope, keys[0].astype(cdt),
-                               keys[1].astype(cdt), qpos, qscale, weights,
+            o = self._expanded(q_nope, q_rope, parts[0].astype(cdt),
+                               parts[1].astype(cdt), qpos, qscale, weights,
                                cdt)
         with jax.named_scope("mla:out"):
             out = _mm("bqhv,hve->bqe", o, weights["wo"], cdt)
@@ -252,6 +278,36 @@ class LatentAttentionOp(Op):
     def _split_kvb(self, weights):
         nope = self.params["qk_nope_head_dim"]
         return weights["wkv_b"][..., :nope], weights["wkv_b"][..., nope:]
+
+    def prefill_prefix_rows(self, holder_rows: int, pos: int, c: int) -> int:
+        """Rows of a holder of `holder_rows` that C queries at the scalar
+        offset `pos` are attended over: the branch `lower` takes, read on
+        the host (the batcher counts it, `ff_mla_prefill_chunks_total`)."""
+        ladder = prefill_ladder(holder_rows, c)
+        return ladder[_prefix_index(ladder, pos + c)]
+
+    def _prefix_expanded(self, q_nope, q_rope, keys, pos, qpos, qscale,
+                         weights, cdt):
+        """C queries at the scalar offset `pos` over the holder `keys`
+        (c_kv, k_rope) as written: query j attends rows <= pos + j, so no
+        row past pos + C - 1 is visible, and the expanded path runs over
+        the shortest static prefix that holds them (`prefill_ladder`),
+        chosen as ops/attention.py `_prefix_core` chooses."""
+        c = q_nope.shape[1]
+
+        def over(rows):
+            def core(q_nope, q_rope, c_kv, k_rope):
+                return self._expanded(
+                    q_nope, q_rope, c_kv[:, :rows].astype(cdt),
+                    k_rope[:, :rows].astype(cdt), qpos, qscale, weights, cdt)
+            return core
+
+        ladder = prefill_ladder(keys[0].shape[1], c)
+        if len(ladder) == 1:
+            return over(ladder[0])(q_nope, q_rope, *keys)
+        which = jnp.asarray(_prefix_index(ladder, pos + c), jnp.int32)
+        return jax.lax.switch(which, [over(r) for r in ladder], q_nope,
+                              q_rope, *keys)
 
     def _expanded(self, q_nope, q_rope, c_kv, k_rope, qpos, qscale, weights,
                   cdt):

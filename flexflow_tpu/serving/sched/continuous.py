@@ -641,6 +641,14 @@ class ContinuousBatcher:
                       for v in op.serving_counters}
             for op in model.graph.ops.values()
             if op.serving_counters and op.name in (model.state or {})}
+        # a latent attention op's prefill chunks, by the prefix of the
+        # batch-1 holder each is attended over (`_count_mla_prefill`)
+        self._mla_ops = [op for op in self.attn_ops
+                         if hasattr(op, "prefill_prefix_rows")]
+        if self._mla_ops:
+            from ...obs.attention_rows import mla_prefill_chunks_counter
+
+            self._c_mla_prefill = mla_prefill_chunks_counter(registry)
         registry.gauge(
             "ff_kvpool_bytes_per_token",
             "Bytes of serving cache one token position costs across all"
@@ -2325,6 +2333,7 @@ class ContinuousBatcher:
                 continue
             chunks += 1
             chunk_tokens += n
+            self._count_mla_prefill(s.small, off)
             with tracer.resume(s.req.trace), \
                     tracer.span("serve.prefill", request=s.req.id,
                                 offset=off, tokens=n, head_rows=int(last)):
@@ -2357,6 +2366,17 @@ class ContinuousBatcher:
                     tracer.span("serve.emit", request=s.req.id, emitted=1):
                 self._first_token(s, tok)
         return len(prefilling), chunks, chunk_tokens
+
+    def _count_mla_prefill(self, small, off: int) -> None:
+        """Count a prefill chunk at offset `off` for every latent attention
+        op, by the rows of its batch-1 holder `small` the chunk is attended
+        over (`LatentAttentionOp.prefill_prefix_rows`, the rule its
+        lowering follows): host numbers alone, nothing read from the
+        device."""
+        for op in self._mla_ops:
+            rows = op.prefill_prefix_rows(small[op.name]["c_kv"].shape[1],
+                                          off, self.prefill_chunk_tokens)
+            self._c_mla_prefill.inc(op=op.name, rows=rows)
 
     def _step_draft_prefill(self, s: _Slot, tracer) -> None:
         """One DRAFT prefill chunk for a speculative slot (scheduler

@@ -170,6 +170,156 @@ def test_absorbed_decode_equals_expanded(start):
                                atol=2e-6)
 
 
+def _chunk_lower(m, op, w):
+    """Jitted: C queries at the scalar offset `pos` of a batch-1 holder ->
+    (output, the holder as the chunk leaves it)."""
+    def lower(x_in, cache, pos):
+        ctx = LoweringContext(m.config, CompMode.COMP_MODE_INFERENCE)
+        ctx.state[("attn", "c_kv")], ctx.state[("attn", "k_rope")] = cache
+        ctx.decode_pos = pos
+        out = op.lower(ctx, [x_in], w)[0]
+        return out, (ctx.state_updates[("attn", "c_kv")],
+                     ctx.state_updates[("attn", "k_rope")])
+    return jax.jit(lower)
+
+
+# (holder rows, chunk C, the offsets of one stream of chunks): with the
+# ladder's floor at 1 a holder of 37 rows is attended in [C, 2C, ...] and
+# the whole. `rows` is the prefix each chunk must take.
+_PREFIX_CASES = {
+    # C = 4 over [4, 8, 16, 32, 37]: pos + C on the edge 8, one row under
+    # it, one past it; the whole holder's last row
+    "on_edge": (37, 4, [4], [8]),
+    "one_under_edge": (37, 4, [3], [8]),
+    "one_past_edge": (37, 4, [5], [16]),
+    "holders_last_row": (37, 4, [33], [37]),
+    # a prompt of 1, 2 and 4 chunks of 8 over [8, 16, 32, 37]
+    "prompt_of_1_chunk": (37, 8, [0], [8]),
+    "prompt_of_2_chunks": (37, 8, [0, 8], [8, 16]),
+    "prompt_of_4_chunks": (37, 8, [0, 8, 16, 24], [8, 16, 32, 32]),
+    # a holder of at most two bases is one branch, the whole
+    "one_branch_holder": (15, 8, [0, 7], [15, 15]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PREFIX_CASES))
+def test_a_chunk_attends_the_shortest_prefix_of_the_holder(case,
+                                                           monkeypatch):
+    """A stream of chunks through the scalar-offset entry, each over the
+    shortest prefix of the holder that holds its last query, equals the
+    same stream over the whole holder: the outputs and the holder the
+    chunks leave (written whole, every row past them as the previous
+    tenant left it) to float32 rounding; the host's `prefill_prefix_rows`
+    names the prefix each takes."""
+    from flexflow_tpu.ops import latent_attention as mla
+
+    rows, c, offsets, prefixes = _PREFIX_CASES[case]
+    cfg = tiny_cfg()
+    m, op = _mla_op(cfg, 1, c)
+    w = _weights_of(op)
+    x = jax.random.normal(jax.random.PRNGKey(4), (1, rows + c, 64),
+                          jnp.float32)
+    # a previous tenant's rows: finite, so a row read by mistake shows
+    start = (jnp.full((1, rows, cfg["kv_lora_rank"]), 3.0),
+             jnp.full((1, rows, 128), 3.0))
+    got = {}
+    for floor in (1, 10**6):
+        monkeypatch.setattr(mla, "PREFILL_PREFIX_FLOOR", floor)
+        step, cache, outs = _chunk_lower(m, op, w), start, []
+        for off in offsets:
+            out, cache = step(x[:, off:off + c], cache, jnp.int32(off))
+            outs.append(out)
+        got[floor] = (outs, cache)
+        if floor == 1:
+            assert [op.prefill_prefix_rows(rows, off, c)
+                    for off in offsets] == prefixes
+        else:
+            assert {op.prefill_prefix_rows(rows, off, c)
+                    for off in offsets} == {rows}
+    (bounded, held), (whole, held_whole) = got[1], got[10**6]
+    for a, b in zip(bounded, whole):
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-6)
+    for a, b in zip(held, held_whole):
+        np.testing.assert_array_equal(a, b)
+    end = offsets[-1] + c
+    np.testing.assert_array_equal(held[0][0, end:], 3.0)
+
+
+def test_prefill_ladder_from_shapes_alone():
+    """The ladder of `mistral_small4_ep4`'s batch-1 holder (4,096 rows and
+    511 of slack) for its chunks of 512; a step of one query (the lockstep
+    session) starts at the floor, not at 1; the dense op's ladder keeps
+    its own base."""
+    from flexflow_tpu.ops import attention as attn_mod
+    from flexflow_tpu.ops.latent_attention import prefill_ladder
+
+    assert prefill_ladder(4607, 512) == [512, 1024, 2048, 4096, 4607]
+    assert prefill_ladder(4607, 1) == [512, 1024, 2048, 4096, 4607]
+    assert prefill_ladder(4607, 1024) == [1024, 2048, 4096, 4607]
+    assert prefill_ladder(1024, 1) == [1024] and prefill_ladder(319, 64) == [
+        319]
+    assert attn_mod._prefixes(4607) == [2048, 4096, 4607]
+    _, op = _mla_op(tiny_cfg(), 1, 512)
+    # decode_sat_4k's prompt quantiles: 15 chunks, none over the whole
+    chunks = [op.prefill_prefix_rows(4607, off, 512)
+              for n in (152, 215, 304, 431, 609, 861, 1218, 1722)
+              for off in range(0, n, 512)]
+    assert {r: chunks.count(r) for r in set(chunks)} == {
+        512: 8, 1024: 4, 2048: 3}
+
+
+def test_lockstep_decode_attends_a_prefix_of_the_cache(lm, monkeypatch):
+    """`GenerativeSession`'s step (one query at a scalar offset) over a
+    ladder of 4, 8, 16, 32 and 64 rows decodes the tokens it decodes over
+    the whole cache, each the reference's best to a rounding error."""
+    from flexflow_tpu.ops import latent_attention as mla
+    from flexflow_tpu.serving.generate import GenerativeSession
+
+    cfg, model = lm
+    prompt = np.random.default_rng(3).integers(0, 128, (1, 11),
+                                               dtype=np.int32)
+    tokens = {}
+    for floor in (4, 10**6):
+        monkeypatch.setattr(mla, "PREFILL_PREFIX_FLOOR", floor)
+        tokens[floor] = GenerativeSession(model, 64).generate(prompt, 40)[0]
+    np.testing.assert_array_equal(tokens[4], tokens[10**6])
+    z = ref_logits(cfg, np.concatenate([prompt[0], tokens[4]]))
+    rows = z[10:10 + 40]
+    gap = rows.max(-1) - rows[np.arange(40), tokens[4]]
+    assert gap.max() < 1e-4, gap
+
+
+def test_batcher_counts_prefill_chunks_by_the_prefix_they_attend(
+        lm, monkeypatch):
+    """Chunks of 16 on batch-1 holders of 64 + 15 rows, the ladder's floor
+    at 16 ([16, 32, 64, 79]): a known prompt mix lands in
+    `ff_mla_prefill_chunks_total` by the prefix each chunk is attended
+    over, for each latent op, and the served tokens are the reference's
+    best."""
+    from flexflow_tpu.ops import latent_attention as mla
+
+    monkeypatch.setattr(mla, "PREFILL_PREFIX_FLOOR", 16)
+    cfg, model = lm
+    rng = np.random.default_rng(9)
+    # chunks at offsets 0 | 0, 16 | 0, 16, 32 | 0, 16, 32, 48
+    prompts = [rng.integers(0, 128, n, dtype=np.int32)
+               for n in (5, 20, 37, 56)]
+    want = {"16": 4, "32": 3, "64": 3}
+    with builder.build_batcher(model, cfg) as cb:
+        outs = [cb.submit(p, 6).result(timeout=300) for p in prompts]
+        text = cb.registry.render()
+    for name in ("l0_attn", "l1_attn"):
+        for rows, n in want.items():
+            assert (f'ff_mla_prefill_chunks_total{{op="{name}",'
+                    f'rows="{rows}"}} {n}\n') in text, text
+    assert 'rows="79"' not in text
+    for p, out in zip(prompts, outs):
+        z = ref_logits(cfg, np.concatenate([p, out]))
+        rows = z[len(p) - 1:len(p) - 1 + len(out)]
+        gap = rows.max(-1) - rows[np.arange(len(out)), out]
+        assert gap.max() < 1e-4, gap
+
+
 # positions of 8 slots over a cache of 4 blocks of 32 rows: the first row, a
 # block's last and the next block's first, the cache's last, an idle slot's
 # dummy position (0 again), the middle of a block, and a block boundary

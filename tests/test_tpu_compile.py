@@ -348,7 +348,10 @@ CELL_PROGRAMS = [
 # and `scoring` went in — at their defaults the programs are the same; the
 # two `decode_all` that hold the dense decode kernel re-read at PR 34 (a
 # full layer's three row counters among the arguments: 1,536 B in their
-# tiles), every other line as it was
+# tiles), every other line as it was. `mistral_small4_ep4`'s chunks attend
+# a ladder of prefixes of their batch-1 holder, but this holder (256 + 63
+# rows) is one rung: its prefill lines are the bytes they were
+# (`test_latent_chunk_attends_a_ladder_of_prefixes_on_v5e` holds the cell's)
 CELL_PROGRAM_BYTES = {
     ("bert_osdi22", "multi_step"): (105073152, 121645056),
     ("lm_osdi22w", "decode_all"): (71371776, 1225728),
@@ -457,6 +460,46 @@ def test_cell_program_holds_the_cells_kernel_choice(
     ma = compiled.memory_analysis()
     assert (ma.argument_size_in_bytes, ma.temp_size_in_bytes) == \
         CELL_PROGRAM_BYTES[config, program]
+
+
+def test_latent_chunk_attends_a_ladder_of_prefixes_on_v5e(v5e, monkeypatch):
+    """`mistral_small4_ep4`'s `prefill_chunk` at the cell's batch-1 holder
+    (4,096 rows and 511 of slack, chunks of 512), two layers at the
+    published widths: the first layer's latent attention (the second's
+    output is read by no one in a chunk that is not the last) is ONE
+    conditional under `mla:` with a branch per entry of the ladder (512,
+    1,024, 2,048, 4,096 and the whole 4,607). The temporaries are the
+    whole holder's (the largest branch: 310.6 MB at two layers) and the
+    conditional's result, the 4 MiB context it hands `mla:out` (4.6 MB in
+    all): not a second set of scores. `CELL_PROGRAM_BYTES`' holder of 256
+    + 63 rows is two bases or fewer: one branch, the bytes as they were."""
+    import re
+    from unittest import mock
+
+    from benchmark import harness
+    from benchmark.tests.compile_ms4_for_v5e import programs
+    from flexflow_tpu.ops import latent_attention as mla
+
+    cfg = harness.load_config("mistral_small4_ep4")
+    cfg.update(num_hidden_layers=2, vocab_size=512, n_routed_experts=4,
+               moe_intermediate_size=256)
+    cfg["deployment"] = dict(cfg["deployment"], num_slots=8)
+    compiled = []
+    for floor in (mla.PREFILL_PREFIX_FLOOR, 10**6):
+        monkeypatch.setattr(mla, "PREFILL_PREFIX_FLOOR", floor)
+        with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+            fn, args = programs(cfg, v5e)["prefill_chunk"]
+            jax.clear_caches()
+            compiled.append(fn.lower(*args).compile())
+    bounded, whole = compiled
+    conds = [line for line in bounded.as_text().splitlines()
+             if " conditional(" in line]
+    assert len(conds) == 1 and "mla:" in conds[0], conds
+    branches = re.search(r"branch_computations=\{([^}]*)\}", conds[0])
+    assert len(branches.group(1).split(",")) == 5
+    assert " conditional(" not in whole.as_text()
+    temp = lambda c: c.memory_analysis().temp_size_in_bytes
+    assert 0 <= temp(bounded) - temp(whole) < 8 * 2**20
 
 
 @pytest.mark.parametrize("config", sorted(
